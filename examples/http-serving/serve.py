@@ -32,10 +32,6 @@ import numpy as np
 
 sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)),
                                 "..", ".."))
-sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                                ".."))
-from common import respect_jax_platforms  # noqa: E402
-respect_jax_platforms()
 
 import mxnet_tpu as mx  # noqa: E402
 from mxnet_tpu import serving  # noqa: E402

@@ -17,10 +17,6 @@ import tempfile
 
 sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)),
                                 "..", ".."))
-sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                                ".."))
-from common import respect_jax_platforms  # noqa: E402
-respect_jax_platforms()
 
 CLASSES = ["amphipod", "copepod", "diatom", "fish_larvae"]
 SIZE = 32

@@ -25,10 +25,6 @@ import sys
 
 sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)),
                                 "..", ".."))
-sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                                ".."))
-from common import respect_jax_platforms  # noqa: E402
-respect_jax_platforms()
 
 STYLE_LAYERS = ["relu1_1_output", "relu2_1_output"]
 CONTENT_LAYER = "relu3_1_output"

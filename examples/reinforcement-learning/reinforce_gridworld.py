@@ -22,10 +22,6 @@ import sys
 
 sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)),
                                 "..", ".."))
-sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                                ".."))
-from common import respect_jax_platforms  # noqa: E402
-respect_jax_platforms()
 
 
 class Corridor:

@@ -24,10 +24,6 @@ import sys
 
 sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)),
                                 "..", ".."))
-sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                                ".."))
-from common import respect_jax_platforms  # noqa: E402
-respect_jax_platforms()
 
 N_PHONES = 8             # classes 1..8; 0 is the CTC blank
 FEAT = 32                # filterbank bins per frame

@@ -11,6 +11,7 @@ include/mxnet/base.h:86-90 (version).
 from __future__ import annotations
 
 import ast
+import os
 from typing import Any
 
 import numpy as np
@@ -44,6 +45,29 @@ _DTYPE_MX_TO_NP[18] = np.dtype(np.bool_)
 
 class MXNetError(RuntimeError):
     """Error raised by the framework (reference: python/mxnet/base.py:71)."""
+
+
+#: JAX's persistent compilation cache when the environment names none: a
+#: fixed path inside the checkout. The path is part of the cache's key, so
+#: it never holds a pid, a time or a temporary directory.
+COMPILE_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))), ".jax_cache")
+
+
+def init_compile_cache() -> str:
+    """Place JAX's persistent compilation cache and return its directory.
+    An entry point (bench.py, chip_smoke.py) calls this once, before the
+    first compile. Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it
+    and nothing is set in code; otherwise the cache goes to
+    :data:`COMPILE_CACHE_DIR`. (The separate ``mxnet_tpu.progcache`` of
+    serialized executables is unrelated and stays off by default.)"""
+    d = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if d:
+        return d
+    import jax
+
+    jax.config.update("jax_compilation_cache_dir", COMPILE_CACHE_DIR)
+    return COMPILE_CACHE_DIR
 
 
 def dtype_np_to_mx(dtype) -> int:

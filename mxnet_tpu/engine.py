@@ -79,13 +79,11 @@ def _load_native() -> Optional[ctypes.CDLL]:
     so = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
                       "native", "libmxtpu_engine.so")
     if not os.path.exists(so):
-        try:
-            import subprocess
+        import subprocess
 
-            subprocess.run(["make", "-C", os.path.dirname(so),
-                            "libmxtpu_engine.so"], check=True,
-                           capture_output=True, timeout=120)
-        except Exception:
+        try:
+            _native.build("libmxtpu_engine.so")
+        except (OSError, subprocess.SubprocessError):
             return None
     try:
         lib = ctypes.CDLL(so)
@@ -351,6 +349,10 @@ def get() -> "NativeEngine | PythonEngine":
             try:
                 _engine = NativeEngine(workers, etype)
             except MXNetError:
+                logging.getLogger("mxnet_tpu").warning(
+                    "native engine library unavailable (make -C native "
+                    "failed or no toolchain): host ops run on the "
+                    "pure-Python engine")
                 _engine = PythonEngine(workers, etype)
         return _engine
 

@@ -61,8 +61,10 @@ def chip_peak_flops(device=None, precision: str = "bf16"
     double-rate 8-bit path) — quantized-matmul MFU must be quoted
     against THIS peak, not the bf16 one, to stay honest.
 
-    Returns (0.0, kind) when the chip is unknown (e.g. CPU backend) — MFU
-    is then not computable and callers should report throughput only.
+    Raises ValueError for a device_kind the table does not hold: a
+    utilization against a guessed peak is worse than none. A caller that
+    runs on the CPU on purpose (a CI smoke) reports throughput and does
+    not ask.
     """
     import jax
 
@@ -83,7 +85,10 @@ def chip_peak_flops(device=None, precision: str = "bf16"
             best = key
     if best:
         return CHIP_PEAK_BF16[best] * mult, kind
-    return 0.0, kind
+    raise ValueError(
+        "no nominal peak for device_kind %r (platform %r); add it to "
+        "flops.CHIP_PEAK_BF16 with its source (have %s)"
+        % (kind, getattr(device, "platform", "?"), sorted(CHIP_PEAK_BF16)))
 
 
 def count_flops(sym, **known_shapes) -> Dict[str, float]:

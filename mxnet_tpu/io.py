@@ -426,9 +426,7 @@ class DevicePrefetchIter(DataIter):
             if cast is not None and str(out.dtype) != str(cast):
                 out = out.astype(cast)  # on-device cast, off the wire
             # NO per-batch block_until_ready: transfers pipeline
-            # asynchronously (a blocking sync would cost a full dispatch
-            # round trip per batch on remote/tunneled devices); the queue
-            # depth bounds batches in flight.
+            # asynchronously; the queue depth bounds batches in flight.
             return _ndmod.NDArray(out)
 
         return DataBatch([put(d, self._cast) for d in batch.data],

@@ -125,7 +125,7 @@ class DataParallelExecutorGroup:
 
         self._exec = Executor(self.symbol, ctx0, args, grads or None, self.grad_req,
                               auxs, shared_exec=shared_exec,
-                              compute_dtype=self.compute_dtype)
+                              compute_dtype=self.compute_dtype, mesh=self.mesh)
         self.execs = [self._exec]  # reference-compat attribute
 
     def _alloc(self, shape, replicated=True):

@@ -652,6 +652,35 @@ class Module(BaseModule):
         self._params_dirty = True
         self._fused_dirty = True
 
+    # --- fused-step introspection (chip_smoke.py witnesses these) ----------
+    @property
+    def fit_step_path(self):
+        """Which path :meth:`fit_step` takes: ``"fused"`` (the one donated
+        program), ``"unfused"`` (forward_backward + update, because the
+        setup was ineligible), or None before the first step decides."""
+        if self._fused_fit is None:
+            return None
+        return "fused" if self._fused_fit else "unfused"
+
+    def fit_step_arrays(self):
+        """``(params, states)``: the live jax arrays the fused step threads
+        (name -> array, name -> optimizer-state leaves), to read placement
+        from. The next step donates them: do not hold on to the buffers."""
+        if self.fit_step_path != "fused":
+            raise MXNetError("fit_step is not on the fused path (%r)"
+                             % self.fit_step_path)
+        self._capture_fence()
+        return self._fused_fit["params"], self._fused_fit["states"]
+
+    def lower_fit_step(self):
+        """``jax.stages.Lowered`` of the fused step at the bound shapes and
+        current state, after at least one step — ``as_text()`` shows which
+        kernels the program holds. Traces and lowers; compiles nothing."""
+        params, states = self.fit_step_arrays()
+        fs = self._fused_fit
+        return fs["step"].lower(params, states, {}, fs["lw"][1],
+                                fs["lw"][2])
+
     def _fit_capture(self, fs, data_batch):
         """The fused path's CapturedTrainStep, or None when
         MXNET_ENGINE_CAPTURE is off. Auto-invalidates on reshape (a new

@@ -21,6 +21,17 @@ _lib = None
 _tried = False
 
 
+def build(target: str = "all", force: bool = False) -> None:
+    """``make -C native <target>`` from ``native/*.cc`` (default: both
+    libraries). ``force`` rebuilds whatever lies on disk (the ``.so``
+    files are not tracked by git, so a copied tree may carry stale ones).
+    Raises ``subprocess.CalledProcessError`` with the compiler's output
+    when the build fails."""
+    cmd = ["make", "-C", os.path.dirname(_SO), target]
+    subprocess.run(cmd + (["-B"] if force else []), check=True,
+                   capture_output=True, timeout=300)
+
+
 def load() -> Optional[ctypes.CDLL]:
     """Load (building if needed) the native library; None if unavailable."""
     global _lib, _tried
@@ -32,9 +43,8 @@ def load() -> Optional[ctypes.CDLL]:
              and os.path.getmtime(src) > os.path.getmtime(_SO))
     if not os.path.exists(_SO) or stale:
         try:
-            subprocess.run(["make", "-C", os.path.dirname(_SO)], check=True,
-                           capture_output=True, timeout=120)
-        except Exception:
+            build()
+        except (OSError, subprocess.SubprocessError):
             if stale:  # keep using the older (but loadable) build
                 pass
             else:

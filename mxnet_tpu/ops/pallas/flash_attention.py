@@ -34,11 +34,10 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+from jax.sharding import PartitionSpec as P
 
-try:  # pltpu only imports on TPU-enabled jaxlib builds
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pltpu = None
+from ...parallel.mesh import partition_mesh
 
 BLOCK_Q = 256
 # Round-5 block sweep on v5e (bq x bk over {256,512,1024}x{256,512},
@@ -290,7 +289,7 @@ def _fa_forward(q, k, v, causal, scale, interpret, with_lse=False):
         kernel = functools.partial(_fa_kernel_res, causal=causal,
                                    scale=scale, block_k=block_k,
                                    offset=tk - tq)
-        if pltpu is not None and not interpret:
+        if not interpret:
             kwargs["compiler_params"] = pltpu.CompilerParams(
                 dimension_semantics=("parallel", "arbitrary", "arbitrary"))
         res = pl.pallas_call(
@@ -311,8 +310,6 @@ def _fa_forward(q, k, v, causal, scale, interpret, with_lse=False):
             **kwargs,
         )(q, k, v)
         return (res[0], res[1]) if with_lse else res[0]
-    if pltpu is None:  # pragma: no cover - guarded by flash_attention()
-        raise RuntimeError("pallas TPU backend unavailable")
     super_k, num_super = _split_super(tk, block_k)
     kernel = functools.partial(_fa_kernel_stream, causal=causal,
                                scale=scale, block_k=block_k,
@@ -634,7 +631,7 @@ def _fa_backward(q, k, v, o, lse, do, causal, scale, interpret,
         dvec = dvec - g_lse.astype(jnp.float32)
     kwargs3 = {}
     kwargs4 = {}
-    if pltpu is not None and not interpret:
+    if not interpret:
         kwargs3["compiler_params"] = pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary"))
         kwargs4["compiler_params"] = pltpu.CompilerParams(
@@ -671,8 +668,6 @@ def _fa_backward(q, k, v, o, lse, do, causal, scale, interpret,
             **kwargs3,
         )(q, k, v, do, lse, dvec)
     else:
-        if pltpu is None:  # pragma: no cover
-            raise RuntimeError("pallas TPU backend unavailable")
         super_k, num_k_super = _split_super(tk, block_k)
         kv_idx = _kv_stream_idx(block_q, super_k, tk - tq, causal)
         dq = pl.pallas_call(
@@ -738,8 +733,6 @@ def _fa_backward(q, k, v, o, lse, do, causal, scale, interpret,
             **kwargs3,
         )(q, k, v, do, lse, dvec)
         return dq, dk.astype(k.dtype), dv.astype(v.dtype)
-    if pltpu is None:  # pragma: no cover
-        raise RuntimeError("pallas TPU backend unavailable")
     super_q, num_q_super = _split_super(tq, block_q)
     # causal: q superblocks strictly above this k block's diagonal are
     # fully masked; clamp their index so the dead steps re-address the
@@ -908,11 +901,6 @@ def flash_attention(q, k, v, causal=False, scale=None, interpret=None):
 
     # kernel_qualifies = the correctness contract; MIN_SEQ = the measured
     # perf threshold (auto mode only)
-    if pltpu is None and (tq > _RESIDENT_MAX or tk > _RESIDENT_MAX):
-        # the streaming kernels carry state in pltpu.VMEM scratch (both
-        # compiled and interpret mode) — without the TPU pallas backend,
-        # XLA path
-        return fallback()
     if interpret is None:
         if not (on_tpu()
                 and kernel_qualifies(tq, tk, d, causal=causal)
@@ -927,7 +915,19 @@ def flash_attention(q, k, v, causal=False, scale=None, interpret=None):
         return fallback()
 
     g = h // hkv
-    out = _flash(q.reshape(b * hkv, g, tq, d),
-                 k.reshape(b * hkv, tk, d),
-                 v.reshape(b * hkv, tk, d), causal, scale, interpret)
-    return out.reshape(b, h, tq, d)
+
+    def run(q, k, v):
+        rows = q.shape[0] * hkv
+        out = _flash(q.reshape(rows, g, tq, d), k.reshape(rows, tk, d),
+                     v.reshape(rows, tk, d), causal, scale, interpret)
+        return out.reshape(q.shape)
+
+    mesh = partition_mesh()
+    if mesh is not None:
+        # the SPMD partitioner cannot split a Mosaic kernel: split the
+        # batch over the data axis by hand (its rows are independent grid
+        # cells), or run it whole on every device where it does not divide
+        spec = P("data") if b % mesh.shape["data"] == 0 else P()
+        run = jax.shard_map(run, mesh=mesh, in_specs=(spec, spec, spec),
+                            out_specs=spec, check_vma=False)
+    return run(q, k, v)

@@ -18,44 +18,15 @@ from typing import Optional, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map  # the parallel modules import it from here
+from jax.ad_checkpoint import checkpoint_name as _checkpoint_name
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-try:  # moved to the jax namespace after 0.4.x
-    from jax import shard_map as _raw_shard_map
-except ImportError:
-    from jax.experimental.shard_map import shard_map as _raw_shard_map
-
-import inspect as _inspect
-
-_SHARD_MAP_KW = set(_inspect.signature(_raw_shard_map).parameters)
-
-
-def shard_map(f, **kw):
-    """Version-tolerant shard_map: newer jax renamed check_rep ->
-    check_vma (and moved the function out of jax.experimental). Translate
-    whichever spelling the caller used into the one this jax accepts, so
-    the parallel modules run on both."""
-    if "check_vma" in kw and "check_vma" not in _SHARD_MAP_KW:
-        kw["check_rep"] = kw.pop("check_vma")
-    elif "check_rep" in kw and "check_rep" not in _SHARD_MAP_KW:
-        kw["check_vma"] = kw.pop("check_rep")
-    return _raw_shard_map(f, **kw)
 
 
 def axis_size(axis_name):
     """Static size of a mapped mesh axis (or tuple of axes) from inside
-    shard_map'd code. jax.lax.axis_size only exists on newer jax; older
-    versions expose the bound frame via jax.core.axis_frame."""
-    if hasattr(jax.lax, "axis_size"):
-        return int(jax.lax.axis_size(axis_name))
-    from jax.core import axis_frame
-
-    if isinstance(axis_name, (tuple, list)):
-        out = 1
-        for a in axis_name:
-            out *= int(axis_frame(a))
-        return out
-    return int(axis_frame(axis_name))
+    shard_map'd code."""
+    return int(jax.lax.axis_size(axis_name))
 
 
 # --- in-shard_map primitives (use inside manually-sharded code) -----------
@@ -372,12 +343,6 @@ def zero2_grad_scatter(full, mesh: Mesh, axis_name: str = "data",
 
 ZERO3_GATHER_NAME = "zero3_allgather"
 
-try:
-    from jax.ad_checkpoint import checkpoint_name as _checkpoint_name
-except ImportError:  # very old jax: lose the tag, keep the math
-    def _checkpoint_name(x, name):
-        return x
-
 
 def _zero3_gather_leaf(x, repl, grad_sharding):
     """Per-leaf gather with an explicit cotangent rule: fwd gathers the
@@ -415,11 +380,8 @@ def zero3_remat(f):
     """Wrap the fwd function so gathered weights are NOT saved as
     residuals: policy saves anything except ZERO3_GATHER_NAME tags, so
     the only backward recompute is the (re-)gathers themselves."""
-    try:
-        policy = jax.checkpoint_policies.save_any_names_but_these(
-            ZERO3_GATHER_NAME)
-    except AttributeError:  # old jax: fall back to saving residuals
-        return f
+    policy = jax.checkpoint_policies.save_any_names_but_these(
+        ZERO3_GATHER_NAME)
     return jax.checkpoint(f, policy=policy)
 
 

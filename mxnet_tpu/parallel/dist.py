@@ -72,11 +72,7 @@ def init(coordinator_address: Optional[str] = None,
         # configured (a CPU-only host resolves to cpu; on accelerator
         # hosts the option only affects the secondary CPU client). TPU
         # backends form the global view natively.
-        try:
-            jax.config.update("jax_cpu_collectives_implementation",
-                              "gloo")
-        except Exception:  # older jaxlib without the option
-            pass
+        jax.config.update("jax_cpu_collectives_implementation", "gloo")
     jax.distributed.initialize(
         coordinator_address=coordinator_address,
         num_processes=num_processes,

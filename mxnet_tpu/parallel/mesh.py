@@ -7,7 +7,9 @@ names how the whole job's devices factor into parallelism axes.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
+import threading
 from typing import Optional, Sequence
 
 import jax
@@ -15,6 +17,28 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 AXES = ("data", "expert", "seq", "pipe", "model")
+
+_tracing = threading.local()
+
+
+@contextlib.contextmanager
+def partitioned_over(mesh: Optional[Mesh]):
+    """Entered by the executor around the trace of a graph that XLA's SPMD
+    partitioner will split over ``mesh`` (batch on its "data" axis). Most
+    ops need not know; an op the partitioner cannot split — a Mosaic
+    kernel — reads :func:`partition_mesh` and splits itself with shard_map.
+    Trace-time Python state only: nothing here reaches the program."""
+    prev = getattr(_tracing, "mesh", None)
+    _tracing.mesh = mesh if mesh is not None and mesh.size > 1 else None
+    try:
+        yield
+    finally:
+        _tracing.mesh = prev
+
+
+def partition_mesh() -> Optional[Mesh]:
+    """The mesh the graph being traced is partitioned over, or None."""
+    return getattr(_tracing, "mesh", None)
 
 
 @dataclasses.dataclass(frozen=True)

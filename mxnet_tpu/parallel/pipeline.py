@@ -48,7 +48,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from .collectives import axis_size, shard_map  # version-tolerant wrappers
+from .collectives import axis_size, shard_map
 
 
 def _fwd_scan(stage_fn, stage_params, x_mb, axis, with_aux):

@@ -19,7 +19,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from .collectives import axis_size, shard_map  # version-tolerant wrappers
+from .collectives import axis_size, shard_map
 
 _NEG = float(jnp.finfo(jnp.float32).min)
 

@@ -141,6 +141,7 @@ class Predictor:
             return tuple(outs)
 
         self._jitted = jax.jit(fwd)
+        self._in_shardings = None  # read off the executable on first use
         # Persistent program cache: the key is computable from metadata
         # alone (symbol + param CRCs + input signature), so a warm hit
         # skips lower AND compile — that headroom is the ≥3× warm-restart
@@ -216,10 +217,15 @@ class Predictor:
                     raise MXNetError("input %r not set" % n)
                 vals.append(
                     self._inputs[n]._data.astype(jnp.dtype(self._dtype)))
+        # inputs go where the program was compiled to read them: a host
+        # NDArray (mx.cpu(), the default context) is committed to the CPU
+        # device, which the AOT executable refuses next to a TPU program
+        if self._in_shardings is None:
+            self._in_shardings = self._exec.input_shardings[0]
+        placed = [jax.device_put(v, s)
+                  for v, s in zip(vals, self._in_shardings)]
         with self._device_scope():
-            outs = self._exec(
-                *[jax.device_put(v, self._device) for v in vals]
-                if self._device is not None else vals)
+            outs = self._exec(*placed)
         result = [NDArray(o) for o in outs]
         with self._run_lock:
             self._outputs = result

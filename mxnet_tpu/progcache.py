@@ -459,10 +459,17 @@ def load(key: str, kind: str = ""):
             skew = _check_meta(meta)
             if skew is not None:
                 raise ValueError("version skew: %s" % skew)
+            import jax
             from jax.experimental import serialize_executable as _sx
 
+            # load onto the devices the program was compiled for: the
+            # default is every device of the backend, which turns a
+            # one-device program into one that wants a shard per device
+            by_id = {dev.id: dev for dev in jax.devices()}
+            devices = [by_id[i] for i in meta["device_ids"]]
             serialized, in_tree, out_tree = pickle.loads(payload)
-            exe = _sx.deserialize_and_load(serialized, in_tree, out_tree)
+            exe = _sx.deserialize_and_load(serialized, in_tree, out_tree,
+                                           execution_devices=devices)
         except Exception as e:
             log.warning("progcache: entry %s unusable (%s) — falling back "
                         "to fresh compile", path, e)
@@ -498,6 +505,9 @@ def store(key: str, compiled, note: str = "", kind: str = "") -> bool:
                                    protocol=pickle.HIGHEST_PROTOCOL)
             meta = dict(_runtime_meta())
             meta["key"] = key
+            meta["device_ids"] = [
+                dev.id
+                for dev in compiled.runtime_executable().local_devices()]
             if note:
                 meta["note"] = note
             if kind:

@@ -33,13 +33,6 @@ from .ndarray import NDArray
 _CACHE: Dict[str, "Rtc"] = {}
 
 
-def _on_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:
-        return False
-
-
 class Rtc:
     """A runtime-compiled kernel (reference python/mxnet/rtc.py Rtc).
 
@@ -82,11 +75,13 @@ class Rtc:
         if self.mode == "pallas":
             from jax.experimental import pallas as pl
 
+            from .ops.pallas import on_tpu
+
             user = self._user_fn
             call = pl.pallas_call(
                 user,
                 out_shape=[jax.ShapeDtypeStruct(s, d) for s, d in out_specs],
-                interpret=not _on_tpu(),
+                interpret=not on_tpu(),
             )
             fn = jax.jit(lambda *ins: call(*ins))
         else:

@@ -136,6 +136,9 @@ class InferenceServer:
     input shapes WITHOUT the batch axis, e.g. ``{"data": (3, 224, 224)}``.
     ``devices``: optional jax devices, one replica pinned per device
     (round-robin dispatch); default all replicas on the default device.
+    ``decode`` builds its weights and every replica's KV slabs on the
+    default device, so it is refused together with ``devices`` that name
+    any other device (one server per device is the way to spread decode).
     """
 
     def __init__(self, symbol, params, example_shapes: Dict[str, tuple],
@@ -158,6 +161,15 @@ class InferenceServer:
         if devices is not None and len(devices) < n_rep:
             raise ServingError("need %d devices for %d replicas, got %d"
                                % (n_rep, n_rep, len(devices)))
+        if decode is not None and devices is not None:
+            # where jnp.asarray and jnp.zeros put the decode state
+            default = jnp.zeros(()).devices().pop()
+            if any(d != default for d in devices[:n_rep]):
+                raise ServingError(
+                    "decode= places its weights and all %d replicas' KV "
+                    "slabs on the default device (%s), not on devices=%s; "
+                    "drop devices= or run one server per device"
+                    % (n_rep, default, list(devices[:n_rep])))
         if self.config.router not in ("rr", "least_loaded"):
             raise ServingError(
                 "MXNET_SERVING_ROUTER must be 'rr' or 'least_loaded', got %r"

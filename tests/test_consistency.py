@@ -284,40 +284,6 @@ def test_pallas_lstm_step_matches_plain():
     np.testing.assert_allclose(np.asarray(h2), h_want, rtol=1e-5, atol=1e-5)
 
 
-def test_pallas_fused_updates_match_plain():
-    from mxnet_tpu.ops.pallas import fused_update as fu
-
-    rng = np.random.RandomState(2)
-    w = rng.randn(16).astype(np.float32)
-    g = rng.randn(16).astype(np.float32)
-    m = rng.randn(16).astype(np.float32)
-    v = rng.rand(16).astype(np.float32) + 0.1
-    lr, mom, wd = 0.1, 0.9, 1e-4
-    w2, m2 = fu.sgd_mom_update(jnp.asarray(w), jnp.asarray(g),
-                               jnp.asarray(m), lr, mom, wd, interpret=True)
-    # MXNet convention (optimizer_op-inl.h): m = mom*m - lr*(g + wd*w);
-    # w += m
-    m_want = mom * m - lr * (g + wd * w)
-    w_want = w + m_want
-    np.testing.assert_allclose(np.asarray(m2), m_want, rtol=1e-5, atol=1e-6)
-    np.testing.assert_allclose(np.asarray(w2), w_want, rtol=1e-5, atol=1e-6)
-
-    b1, b2, eps = 0.9, 0.999, 1e-8
-    w3, m3, v3 = fu.adam_update(jnp.asarray(w), jnp.asarray(g),
-                                jnp.asarray(m), jnp.asarray(v), lr,
-                                beta1=b1, beta2=b2, epsilon=eps,
-                                wd=wd, interpret=True)
-    # reference adam_update: no in-kernel bias correction (the optimizer
-    # folds it into lr)
-    gw = g + wd * w
-    m_want = b1 * m + (1 - b1) * gw
-    v_want = b2 * v + (1 - b2) * gw * gw
-    w_want = w - lr * m_want / (np.sqrt(v_want) + eps)
-    np.testing.assert_allclose(np.asarray(m3), m_want, rtol=1e-5, atol=1e-6)
-    np.testing.assert_allclose(np.asarray(v3), v_want, rtol=1e-5, atol=1e-6)
-    np.testing.assert_allclose(np.asarray(w3), w_want, rtol=1e-4, atol=1e-5)
-
-
 def test_pallas_kernel_coverage_is_complete():
     """Every public Pallas kernel entry point must have an interpret-vs-
     plain consistency test above (fails when a kernel is added without
@@ -328,9 +294,8 @@ def test_pallas_kernel_coverage_is_complete():
 
     from mxnet_tpu.ops import pallas
 
-    tested = {"flash_attention", "lstm_step", "sgd_mom_update",
-              "adam_update", "conv_wgrad"}
-    helpers = {"on_tpu", "use_for", "use_wgrad_for",
+    tested = {"flash_attention", "lstm_step"}
+    helpers = {"on_tpu", "use_for",
                "kernel_qualifies"}  # selection predicates, not kernels
     public = set()
     # enumerate the PACKAGE, not a hardcoded list, so a kernel added in a
@@ -346,43 +311,6 @@ def test_pallas_kernel_coverage_is_complete():
     assert not missing, (
         "Pallas kernels without an interpret-vs-plain consistency test: %s"
         % sorted(missing))
-
-
-def test_pallas_conv_wgrad_matches_plain():
-    """conv_bwd.conv_wgrad (interpret) vs the XLA vjp weight-grad across
-    kernel/stride/odd-size variants."""
-    from mxnet_tpu.ops.pallas.conv_bwd import conv_wgrad
-
-    def ref(x, dy, ksz, stride, pad):
-        dn = jax.lax.conv_dimension_numbers(
-            x.shape, (ksz, ksz, x.shape[-1], dy.shape[-1]),
-            ("NHWC", "HWIO", "NHWC"))
-
-        def f(w):
-            return jax.lax.conv_general_dilated(
-                x, w, (stride, stride), [(pad, pad), (pad, pad)],
-                dimension_numbers=dn)
-
-        w0 = jnp.zeros((ksz, ksz, x.shape[-1], dy.shape[-1]), x.dtype)
-        return jax.vjp(f, w0)[1](dy)[0]
-
-    rng = np.random.RandomState(0)
-    for (n, h, c, k, ksz, stride) in [(2, 8, 8, 16, 3, 1),
-                                      (2, 9, 8, 16, 3, 1),
-                                      (2, 8, 8, 16, 3, 2),
-                                      (1, 5, 4, 8, 1, 1),
-                                      (4, 7, 16, 32, 3, 1)]:
-        pad = (ksz - 1) // 2
-        oh = (h + 2 * pad - ksz) // stride + 1
-        x = jnp.asarray(rng.randn(n, h, h, c).astype(np.float32))
-        dy = jnp.asarray(rng.randn(n, oh, oh, k).astype(np.float32))
-        got = np.asarray(conv_wgrad(x, dy, ksz, stride, interpret=True))
-        want = np.asarray(ref(x, dy, ksz, stride, pad), np.float32)
-        # kernel computes in bf16 operands / f32 accumulation
-        np.testing.assert_allclose(
-            got, want, rtol=2e-2,
-            atol=2e-2 * max(1.0, np.abs(want).max()),
-            err_msg=str((n, h, c, k, ksz, stride)))
 
 
 def test_pallas_flash_backward_multiblock_causal():

@@ -71,6 +71,9 @@ def test_log_get_logger(tmp_path):
 
 
 def test_libinfo_find_lib_path():
+    from mxnet_tpu import native
+
+    native.build()  # the .so files are build outputs, not tracked files
     paths = mx.libinfo.find_lib_path()
     assert paths and all(p.endswith(".so") for p in paths)
     assert mx.libinfo.__version__

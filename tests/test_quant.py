@@ -216,7 +216,11 @@ def _teacher_forced_logits(model, kv_dtype, prompt, forced):
     tokens = np.zeros(slots, np.int32)
     for tok in forced:
         tokens[0] = tok
-        out = progs.decode(k, v, lengths, tokens, ks_slab=ks, vs_slab=vs)
+        # copies: dispatch is asynchronous and the CPU client may alias a
+        # numpy argument's memory, so the in-place updates below must not
+        # touch what the running step reads
+        out = progs.decode(k, v, lengths.copy(), tokens.copy(),
+                           ks_slab=ks, vs_slab=vs)
         if len(out) == 5:
             k, v, ks, vs = out[1:]
         else:

@@ -12,7 +12,13 @@ them into one mesh.
 
 Modes:
   --launcher local  spawn N local processes (the dmlc "local" tracker;
-                    multi-process CPU emulation or one-host multi-chip)
+                    multi-process CPU emulation). NOT a way to share one
+                    host's chips: a chip belongs to one process, and
+                    nothing here gives each worker its own (no
+                    TPU_VISIBLE_CHIPS or equivalent is set), so on a TPU
+                    host the second worker fails or hangs at start-up.
+                    Drive a host's chips from ONE process with a device
+                    list (context=[mx.tpu(i) ...]).
   --launcher ssh    one process per host listed in --hostfile
                     (the dmlc "ssh" tracker)
   --launcher mpi    delegate process placement to mpirun; per-rank
@@ -54,7 +60,8 @@ def _read_hostfile(path):
 
 
 def launch_local(n, cmd, env_extra=None, n_servers=0):
-    """Local multi-process launch (dmlc local tracker analogue). With
+    """Local multi-process launch (dmlc local tracker analogue; CPU
+    workers — see the module docstring on chips). With
     n_servers > 0, also spawns that many parameter-server processes and
     wires every process with the comma-separated MXNET_TPU_PS_URI list
     (the reference's `launch.py -n W -s S` worker/server topology; big
