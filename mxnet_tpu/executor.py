@@ -21,6 +21,7 @@ The optional `shared_exec` reuses argument/grad buffers across executors
 """
 from __future__ import annotations
 
+import contextlib
 import logging
 import os
 from typing import Any, Dict, List, Optional, Sequence
@@ -65,10 +66,29 @@ def _under_mesh(eval_fn, mesh):
     return under_mesh
 
 
+def bind_span():
+    """The ``executor.bind`` span: the one ``Symbol.simple_bind`` opened
+    around its allocations when the constructor runs inside it, else a
+    new one."""
+    open_ = _telemetry.open_spans()
+    if open_ and open_[-1].name == "executor.bind":
+        return contextlib.nullcontext(open_[-1])
+    return _telemetry.span("executor.bind", domain="executor")
+
+
 class Executor:
     def __init__(self, symbol, ctx, args, args_grad=None, grad_req="write",
                  aux_states=None, group2ctx=None, shared_exec=None,
                  compute_dtype=None, mesh=None):
+        with bind_span() as sp:
+            self._init(symbol, ctx, args, args_grad, grad_req, aux_states,
+                       group2ctx, shared_exec, compute_dtype, mesh)
+            sp.annotate(n_args=len(self.arg_dict), arg_bytes=sum(
+                a.size * np.dtype(a.dtype).itemsize
+                for a in self.arg_dict.values()))
+
+    def _init(self, symbol, ctx, args, args_grad, grad_req, aux_states,
+              group2ctx, shared_exec, compute_dtype, mesh):
         """mesh: the jax Mesh the bound arrays are sharded over, when the
         graph is partitioned by XLA's SPMD pass (the executor group's data
         mesh); ops that must split themselves read it at trace time.
@@ -134,6 +154,7 @@ class Executor:
         self._rng_counter = 0
         self._last_rng = None
         self._graph_needs_rng = None  # computed lazily on first use
+        self._train_steps = 0  # calls into make_train_step's step: span attr
 
     @staticmethod
     def _to_dict(values, names, what, allow_missing=False):
@@ -383,6 +404,14 @@ class Executor:
                                        aux_values, self._next_rng(), dv,
                                        *extra)
 
+        def _build(phase):
+            return _telemetry.span("executor.train_step.build",
+                                   domain="executor", phase=phase)
+
+        def _dispatch():
+            return _telemetry.span("executor.train_step.dispatch",
+                                   domain="executor")
+
         def _run_impl(params, states, data_values, *extra):
             rng = self._next_rng()
             aux_values, dv = _inputs(data_values)
@@ -391,8 +420,9 @@ class Executor:
                 # directly in the 1/N ZeRO layout (never
                 # replicated-then-sliced); returned values keep it, so
                 # this runs once
-                params = _coll.zero1_place(params, mesh, shard_axis)
-                states = _coll.zero1_place(states, mesh, shard_axis)
+                with _build("place"):
+                    params = _coll.zero1_place(params, mesh, shard_axis)
+                    states = _coll.zero1_place(states, mesh, shard_axis)
                 aot["placed"] = True
             elif mesh is None and not aot.get("placed"):
                 # commit the caller's snapshot to this executor's device.
@@ -401,8 +431,9 @@ class Executor:
                 # on that: a first call on uncommitted params compiles a
                 # program the second call cannot reuse
                 dev = self._ctx.jax_device()
-                params = jax.device_put(params, dev)
-                states = jax.device_put(states, dev)
+                with _build("place"):
+                    params = jax.device_put(params, dev)
+                    states = jax.device_put(states, dev)
                 aot["placed"] = True
             if not aot.get("gauges"):
                 # per-chip byte gauges, one series per ZeRO stage:
@@ -470,12 +501,13 @@ class Executor:
                     # Format above carries the sharding): a concrete
                     # jax.Array has a layout of its own, which jit
                     # refuses next to Layout.AUTO
-                    learned = jf.lower(_avals(params, sharding=False),
-                                       _avals(states, sharding=False),
-                                       aux_values, rng, dv,
-                                       *extra).compile()
-                    _witness.record_compile("train_step",
-                                            key="auto_layout")
+                    with _build("auto_layout_learn"):
+                        learned = jf.lower(_avals(params, sharding=False),
+                                           _avals(states, sharding=False),
+                                           aux_values, rng, dv,
+                                           *extra).compile()
+                        _witness.record_compile("train_step",
+                                                key="auto_layout")
                     pf, sf = (learned.input_formats[0][0],
                               learned.input_formats[0][1])
                     aot["informats"] = (pf, sf)
@@ -493,11 +525,13 @@ class Executor:
                 # avoided entirely after the first call
                 if not aot.get("relaid"):
                     pf, sf = aot["informats"]
-                    params = jax.device_put(params, pf)
-                    states = jax.device_put(states, sf)
+                    with _build("relayout"):
+                        params = jax.device_put(params, pf)
+                        states = jax.device_put(states, sf)
                     aot["relaid"] = True
-                outs, new_params, new_states, aux_up = aot["jit"](
-                    params, states, aux_values, rng, dv, *extra)
+                with _dispatch():
+                    outs, new_params, new_states, aux_up = aot["jit"](
+                        params, states, aux_values, rng, dv, *extra)
             else:
                 if _progcache.enabled() and "exec" not in aot:
                     # Persistent program cache for the fused step: key by
@@ -506,67 +540,69 @@ class Executor:
                     # key could collide across optimizer rules). Donation
                     # is part of the key and survives serialization. Any
                     # failure pins the plain-jit path for this step fn.
-                    try:
-                        lowered = jitted.lower(params, states, aux_values,
-                                               rng, dv, *extra)
-                        key = _progcache.lowered_key(
-                            lowered.as_text(), donate=(0, 1),
-                            extra="train_step")
-                        exe = _progcache.load(key, kind="train_step")
-                        if exe is None:
-                            exe = lowered.compile()
-                            _witness.record_compile("train_step",
-                                                    key=key[:16])
-                            _progcache.store(key, exe, note="train_step",
-                                             kind="train_step")
-                        aot["exec"] = exe
-                    except Exception:
-                        logging.getLogger("mxnet_tpu").warning(
-                            "progcache: train-step AOT path failed; "
-                            "using plain jit", exc_info=True)
-                        aot["exec"] = None
-                if aot.get("exec") is not None:
-                    try:
-                        outs, new_params, new_states, aux_up = aot["exec"](
-                            params, states, aux_values, rng, dv, *extra)
-                    except Exception:
-                        # a stale/incompatible loaded executable must never
-                        # fail the step: recompile via the jit path (inputs
-                        # are intact — argument processing precedes any
-                        # donation) and stop using the cached program
-                        logging.getLogger("mxnet_tpu").warning(
-                            "progcache: cached train step unusable; "
-                            "recompiling", exc_info=True)
-                        aot["exec"] = None
+                    with _build("progcache"):
+                        try:
+                            lowered = jitted.lower(params, states, aux_values,
+                                                   rng, dv, *extra)
+                            key = _progcache.lowered_key(
+                                lowered.as_text(), donate=(0, 1),
+                                extra="train_step")
+                            exe = _progcache.load(key, kind="train_step")
+                            if exe is None:
+                                exe = lowered.compile()
+                                _witness.record_compile("train_step",
+                                                        key=key[:16])
+                                _progcache.store(key, exe, note="train_step",
+                                                 kind="train_step")
+                            aot["exec"] = exe
+                        except Exception:
+                            logging.getLogger("mxnet_tpu").warning(
+                                "progcache: train-step AOT path failed; "
+                                "using plain jit", exc_info=True)
+                            aot["exec"] = None
+                with _dispatch():
+                    if aot.get("exec") is not None:
+                        try:
+                            outs, new_params, new_states, aux_up = \
+                                aot["exec"](params, states, aux_values, rng,
+                                            dv, *extra)
+                        except Exception:
+                            # a stale/incompatible loaded executable must
+                            # never fail the step: recompile via the jit
+                            # path (inputs are intact — argument processing
+                            # precedes any donation) and stop using the
+                            # cached program
+                            logging.getLogger("mxnet_tpu").warning(
+                                "progcache: cached train step unusable; "
+                                "recompiling", exc_info=True)
+                            aot["exec"] = None
+                            outs, new_params, new_states, aux_up = jitted(
+                                params, states, aux_values, rng, dv, *extra)
+                    else:
                         outs, new_params, new_states, aux_up = jitted(
                             params, states, aux_values, rng, dv, *extra)
-                else:
-                    outs, new_params, new_states, aux_up = jitted(
-                        params, states, aux_values, rng, dv, *extra)
             for n, v in aux_up.items():
                 self.aux_dict[n]._data = v
             self.outputs = [NDArray(o) for o in outs]
             return outs, new_params, new_states
 
         def run(params, states, data_values, *extra):
-            # jit dispatch is async: the span measures the HOST side of the
-            # step (argument prep, dispatch, first-call trace+compile); the
-            # device timeline comes from the jax trace merged at dump time
+            # jit dispatch is async: the span is the HOST side of the step.
+            # What the call does by its own hand before the dispatch
+            # (placing the snapshot, the AUTO-layout learning compile, the
+            # relayout, the program cache) is a `build` child each; the
+            # call into the program is the `dispatch` child, and the
+            # compile listener says on both whether, and for how long, jit
+            # traced, lowered, compiled or read its cache inside them
+            self._train_steps += 1
+            gather = aot.get("gather_bytes")  # reckoned on the first call
             with _telemetry.span("executor.train_step", domain="executor",
-                                 chain=chain, sharded=bool(sharded),
-                                 stage=stage):
-                if stage >= 3:
-                    # marks the dispatch window in which the device runs
-                    # the on-demand weight gathers (one-leaf prefetch under
-                    # XLA's latency-hiding scheduler) — dump_profile()
-                    # shows this span over the device timeline
-                    with _telemetry.span("train.allgather_prefetch",
-                                         domain="executor",
-                                         gather_bytes=aot.get(
-                                             "gather_bytes", 0)):
-                        return _run_impl(params, states, data_values,
-                                         *extra)
-                return _run_impl(params, states, data_values, *extra)
+                                 step=self._train_steps, chain=chain,
+                                 stage=stage, gather_bytes=gather or 0) as sp:
+                out = _run_impl(params, states, data_values, *extra)
+                if gather is None:
+                    sp.add("gather_bytes", aot.get("gather_bytes", 0))
+                return out
 
         # trace-and-fuse metadata (engine.FuseOp): the pure `step` plus the
         # facts a consumer needs to stage it into a fused CapturedSequence.

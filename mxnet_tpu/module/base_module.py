@@ -7,6 +7,7 @@ reference and kept so here.
 """
 from __future__ import annotations
 
+import itertools
 import logging
 import time
 from collections import namedtuple
@@ -15,6 +16,7 @@ import numpy as np
 
 from .. import metric as metric_mod
 from .. import ndarray as nd
+from .. import telemetry
 from ..base import MXNetError
 
 BatchEndParam = namedtuple(
@@ -180,11 +182,19 @@ class BaseModule:
         for epoch in range(begin_epoch, num_epoch):
             tic = time.time()
             eval_metric.reset()
-            for nbatch, data_batch in enumerate(train_data):
+            batches = iter(train_data)
+            for nbatch in itertools.count():
+                with telemetry.span("module.next_batch", domain="module"):
+                    data_batch = next(batches, None)
+                if data_batch is None:
+                    break
                 if monitor is not None:
                     monitor.tic()
                 self.fit_step(data_batch)
-                self.update_metric(eval_metric, data_batch.label)
+                # the call that may wait for the device: the metric reads
+                # the step's outputs
+                with telemetry.span("module.update_metric", domain="module"):
+                    self.update_metric(eval_metric, data_batch.label)
                 if monitor is not None:
                     monitor.toc_print()
                 if batch_end_callback is not None:

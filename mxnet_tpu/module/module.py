@@ -12,10 +12,12 @@ import logging
 
 import numpy as np
 
+import jax
 import jax.numpy as jnp
 
 from .. import ndarray as nd
 from .. import optimizer as opt
+from .. import telemetry as _telemetry
 from ..optimizer import (Optimizer, cached_lr_wd_arrays, state_leaves,
                          write_state_leaves)
 from ..base import MXNetError
@@ -82,6 +84,7 @@ class Module(BaseModule):
         self._data_shapes = None
         self._label_shapes = None
         self._fused_fit = None      # lazy fused fit-step state
+        self._fit_steps = 0         # fit_step calls: the spans' `step`
         self._fused_dirty = False   # fused params newer than exec buffers
         self._fused_refresh = False  # exec buffers newer than fused snapshot
         self._monitor_installed = False
@@ -565,65 +568,86 @@ class Module(BaseModule):
         optimizer state are threaded functionally through donated buffers;
         exec/arg_params buffers are refreshed lazily on get_params/eval.
         Falls back to forward_backward + update otherwise."""
-        fs = self._fused_fit_state()
-        if fs is not None and fs["hyper"] != self._optimizer._hyperparam_key():
-            # a baked-in hyperparameter (momentum/beta warmup schedule)
-            # mutated mid-training: the compiled step traced the old value —
-            # sync state out and rebuild (same contract as Updater.update_all)
-            self._sync_fused_to_exec()
-            self._close_fused_capture("hyperparameter change")
-            self._fused_fit = None
+        self._fit_steps += 1
+        with _telemetry.span("module.fit_step", domain="module",
+                             step=self._fit_steps) as sp:
+            prepared = self._fit_step_prepare()
+            if prepared is None:
+                sp.annotate(path="unfused")
+                self.forward_backward(data_batch)
+                self.update()
+                return
+            sp.annotate(path="fused")
+            self._fit_step_fused(data_batch, *prepared)
+
+    def _fit_step_prepare(self):
+        """The host's part of a fused step before the batch: the fused
+        state (built on the first call), the hyper-parameter check, the
+        update counts and the lr/wd arrays. ``(fs, lr, wd)``, or None when
+        the setup is not eligible for the fused step."""
+        with _telemetry.span("module.fit_step.prepare", domain="module",
+                             lw_rebuilt=False) as sp:
             fs = self._fused_fit_state()
-        if fs is None:
-            self.forward_backward(data_batch)
-            self.update()
-            return
-        if self._fused_refresh:
-            self._refresh_fused_snapshot(fs)
-        opt_ = self._optimizer
-        idx_of = fs["idx_of"]
-        # constant-lr fast path: when the optimizer uses the BASE
-        # effective_lr_wd (not a count-dependent override like Adam's
-        # bias correction) and has no scheduler, per-param lr/wd only
-        # move via optimizer.lr/.wd or the mult setters (which bump
-        # _mult_version) — skip the 2x n_params effective_lr_wd rebuild
-        # AND the per-param count loop (~1 ms/step combined on
-        # ResNet-50). Counts advance in LOCKSTEP in the fused path, so a
-        # single pending counter materializes into _index_update_count
-        # whenever the fused state is left (_sync_fused_to_exec) or the
-        # slow path below needs exact per-index t.
-        static_lw = (opt_.lr_scheduler is None
-                     and type(opt_).effective_lr_wd
-                     is Optimizer.effective_lr_wd)
-        if static_lw:
-            fs["pending_counts"] = fs.get("pending_counts", 0) + 1
-            opt_.num_update += 1
-        else:
-            self._materialize_fused_counts(fs)
-            for n in fs["names"]:
-                opt_._update_count(idx_of[n])
-        # fingerprint also keys on the mult dicts' identity/size so a
-        # reassignment (opt.lr_mult = {...}) or addition is seen even
-        # without the setters; in-place VALUE mutation of an existing
-        # entry requires set_lr_mult/set_wd_mult (documented there)
-        fp = (None if not static_lw
-              else (opt_.lr, opt_.wd, opt_._mult_version,
-                    id(opt_.lr_mult), len(opt_.lr_mult),
-                    id(opt_.wd_mult), len(opt_.wd_mult)))
-        if fp is None or fs.get("lw_fp") != fp or "lw" not in fs:
-            lw = np.array([opt_.effective_lr_wd(idx_of[n])
-                           for n in fs["names"]], np.float32)
-            # lr/wd arrays cached across steps (constant-lr: no re-upload);
-            # committed replicated over the data mesh under ZeRO-1 so the
-            # sharded step isn't fed single-device arrays
-            lw_sh = None
-            if fs.get("z1"):
-                from jax.sharding import NamedSharding, PartitionSpec
-                lw_sh = NamedSharding(fs["mesh"], PartitionSpec())
-            _, _, fs["lw"] = cached_lr_wd_arrays(fs.get("lw"), lw,
-                                                 sharding=lw_sh)
-            fs["lw_fp"] = fp
-        lr_arr, wd_arr = fs["lw"][1], fs["lw"][2]
+            if fs is not None and fs["hyper"] != self._optimizer._hyperparam_key():
+                # a baked-in hyperparameter (momentum/beta warmup schedule)
+                # mutated mid-training: the compiled step traced the old value —
+                # sync state out and rebuild (same contract as Updater.update_all)
+                self._sync_fused_to_exec()
+                self._close_fused_capture("hyperparameter change")
+                self._fused_fit = None
+                fs = self._fused_fit_state()
+            if fs is None:
+                return None
+            if self._fused_refresh:
+                self._refresh_fused_snapshot(fs)
+            opt_ = self._optimizer
+            idx_of = fs["idx_of"]
+            # constant-lr fast path: when the optimizer uses the BASE
+            # effective_lr_wd (not a count-dependent override like Adam's
+            # bias correction) and has no scheduler, per-param lr/wd only
+            # move via optimizer.lr/.wd or the mult setters (which bump
+            # _mult_version) — skip the 2x n_params effective_lr_wd rebuild
+            # AND the per-param count loop (~1 ms/step combined on
+            # ResNet-50). Counts advance in LOCKSTEP in the fused path, so a
+            # single pending counter materializes into _index_update_count
+            # whenever the fused state is left (_sync_fused_to_exec) or the
+            # slow path below needs exact per-index t.
+            static_lw = (opt_.lr_scheduler is None
+                         and type(opt_).effective_lr_wd
+                         is Optimizer.effective_lr_wd)
+            if static_lw:
+                fs["pending_counts"] = fs.get("pending_counts", 0) + 1
+                opt_.num_update += 1
+            else:
+                self._materialize_fused_counts(fs)
+                for n in fs["names"]:
+                    opt_._update_count(idx_of[n])
+            # fingerprint also keys on the mult dicts' identity/size so a
+            # reassignment (opt.lr_mult = {...}) or addition is seen even
+            # without the setters; in-place VALUE mutation of an existing
+            # entry requires set_lr_mult/set_wd_mult (documented there)
+            fp = (None if not static_lw
+                  else (opt_.lr, opt_.wd, opt_._mult_version,
+                        id(opt_.lr_mult), len(opt_.lr_mult),
+                        id(opt_.wd_mult), len(opt_.wd_mult)))
+            if fp is None or fs.get("lw_fp") != fp or "lw" not in fs:
+                lw = np.array([opt_.effective_lr_wd(idx_of[n])
+                               for n in fs["names"]], np.float32)
+                # lr/wd arrays cached across steps (constant-lr: no re-upload);
+                # committed replicated over the data mesh under ZeRO-1 so the
+                # sharded step isn't fed single-device arrays
+                lw_sh = None
+                if fs.get("z1"):
+                    from jax.sharding import NamedSharding, PartitionSpec
+                    lw_sh = NamedSharding(fs["mesh"], PartitionSpec())
+                _, _, fs["lw"] = cached_lr_wd_arrays(fs.get("lw"), lw,
+                                                     sharding=lw_sh)
+                fs["lw_fp"] = fp
+                sp.annotate(lw_rebuilt=True)
+            lr_arr, wd_arr = fs["lw"][1], fs["lw"][2]
+            return fs, lr_arr, wd_arr
+
+    def _fit_step_fused(self, data_batch, fs, lr_arr, wd_arr):
         cap = self._fit_capture(fs, data_batch)
         if cap is not None:
             # engine capture/replay (MXNET_ENGINE_CAPTURE): the two host
@@ -631,10 +655,8 @@ class Module(BaseModule):
             # for the warmup steps, then ONE engine submission per step.
             # The closures read fs at RUN time, so each replayed step
             # consumes the params/states its predecessor threaded through.
-            exec_group = self._exec_group
-
             def load(_db=data_batch):
-                exec_group._load_data(_db)
+                self._load_batch(_db)
 
             def stepped(_lr=lr_arr, _wd=wd_arr):
                 _, fs["params"], fs["states"] = fs["step"](
@@ -646,11 +668,20 @@ class Module(BaseModule):
         else:
             # place the batch with the group's device/sharding logic; the
             # step then reads the executor's data buffers (empty feed dict).
-            self._exec_group._load_data(data_batch)
+            self._load_batch(data_batch)
             _, fs["params"], fs["states"] = fs["step"](
                 fs["params"], fs["states"], {}, lr_arr, wd_arr)
         self._params_dirty = True
         self._fused_dirty = True
+
+    def _load_batch(self, data_batch):
+        """Place one batch in the executor's input buffers."""
+        with _telemetry.span("module.load_data", domain="module") as sp:
+            self._exec_group._load_data(data_batch)
+            sp.annotate(bytes=sum(
+                a.size * np.dtype(a.dtype).itemsize
+                for a in list(data_batch.data) + list(data_batch.label or ())
+                if hasattr(a, "dtype")))
 
     # --- fused-step introspection (chip_smoke.py witnesses these) ----------
     @property
@@ -873,25 +904,29 @@ class Module(BaseModule):
         their 1/N sharded layout and NEW optimizer state is created from the
         sharded weight (born sharded, never replicated-then-sliced);
         pre-existing state copies are resharded once here."""
-        hyper_key = self._optimizer._hyperparam_key()
-        if z1:
-            params = _collectives.zero1_place(
-                {n: exec_.arg_dict[n]._data for n in names}, mesh)
-        else:
-            params = {n: jnp.array(exec_.arg_dict[n]._data, copy=True)
-                      for n in names}
-        states = {}
-        for n in names:
-            i = idx_of[n]
+        with _telemetry.span("module.fused_snapshot", domain="module") as sp:
+            hyper_key = self._optimizer._hyperparam_key()
             if z1:
-                self._updater.ensure_state_sharded(i, exec_.arg_dict[n],
-                                                   mesh, key=hyper_key)
-                states[n] = _collectives.zero1_place(
-                    state_leaves(self._updater.states[i]), mesh)
+                params = _collectives.zero1_place(
+                    {n: exec_.arg_dict[n]._data for n in names}, mesh)
             else:
-                self._updater.ensure_state(i, exec_.arg_dict[n],
-                                           key=hyper_key)
-                states[n] = state_leaves(self._updater.states[i], copy=True)
+                params = {n: jnp.array(exec_.arg_dict[n]._data, copy=True)
+                          for n in names}
+            states = {}
+            for n in names:
+                i = idx_of[n]
+                if z1:
+                    self._updater.ensure_state_sharded(i, exec_.arg_dict[n],
+                                                       mesh, key=hyper_key)
+                    states[n] = _collectives.zero1_place(
+                        state_leaves(self._updater.states[i]), mesh)
+                else:
+                    self._updater.ensure_state(i, exec_.arg_dict[n],
+                                               key=hyper_key)
+                    states[n] = state_leaves(self._updater.states[i], copy=True)
+            sp.annotate(bytes=sum(
+                a.size * a.dtype.itemsize
+                for a in jax.tree_util.tree_leaves((params, states))))
         return params, states
 
     def _refresh_fused_snapshot(self, fs):

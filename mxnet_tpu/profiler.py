@@ -6,16 +6,17 @@ and dumps chrome://tracing JSON (MXDumpProfile). Here the host half of
 that picture comes from :mod:`mxnet_tpu.telemetry` (per-thread span ring
 buffers instrumenting the engine, serving, kvstore and executor layers)
 plus the engine's own per-op events; the device half is a jax.profiler
-trace (XLA → TensorBoard/perfetto). ``dump_profile()`` merges all of it
-into ONE chrome://tracing-loadable JSON file — and it ALWAYS writes that
-file at the configured ``filename`` (logging the path), even when the
-jax trace was never started and even with zero host events, so a
-CPU-only run has real output (docs/observability.md).
+trace, which holds the host spans too (every span is a
+``TraceAnnotation``). ``dump_profile()`` writes ONE
+chrome://tracing-loadable JSON file on ONE clock: the profiler's when
+the window took a jax trace, the ring's monotonic clock when it did not.
+It ALWAYS writes that file at the configured ``filename`` (logging the
+path), even when the jax trace was never started and even with zero
+host events, so a CPU-only run has real output (docs/observability.md).
 """
 from __future__ import annotations
 
 import glob
-import gzip
 import json
 import logging
 import os
@@ -25,7 +26,8 @@ from . import telemetry
 _log = logging.getLogger("mxnet_tpu")
 
 _state = {"running": False, "dir": None, "filename": "profile.json",
-          "jax": False, "engine_prof": False, "prev_domains": None}
+          "jax": False, "jax_taken": False, "engine_prof": False,
+          "prev_domains": None}
 
 
 def profiler_set_config(mode="symbolic", filename="profile.json"):
@@ -46,7 +48,6 @@ def profiler_set_state(state="stop"):
         _state["prev_domains"] = (telemetry.enabled_domains()
                                   if telemetry.enabled_domains() else None)
         telemetry.enable_spans(os.environ.get("MXNET_PROFILER") or "all")
-        telemetry.mark_begin("mxnet_profile", domain="profiler")
         try:
             from . import engine
 
@@ -61,14 +62,13 @@ def profiler_set_state(state="stop"):
                 trace_dir = (_state["dir"] or ".") + "/jax_trace"
                 os.makedirs(trace_dir, exist_ok=True)
                 jax.profiler.start_trace(trace_dir)
-                _state["jax"] = True
+                _state["jax"] = _state["jax_taken"] = True
             except Exception:
                 _log.exception("jax.profiler trace failed to start; "
                                "host-span profiling continues")
                 _state["jax"] = False
         _state["running"] = True
     elif state == "stop" and _state["running"]:
-        telemetry.mark_end("mxnet_profile", domain="profiler")
         if _state["jax"]:
             import jax
 
@@ -118,58 +118,80 @@ def _ring_file_events(dirs):
     return events
 
 
-def _jax_trace_events(trace_dir: str):
-    """Best-effort: pull traceEvents out of the jax/XLA trace artifacts
-    (``*.trace.json.gz`` under the TensorBoard plugin layout) so device
-    and host events share one timeline file."""
+def _xplane_events(trace_dir: str):
+    """The newest ``.xplane.pb`` under ``trace_dir`` as chrome events,
+    every plane (host threads and devices) on the profiler's clock: one
+    pid per plane, one tid per line. Empty when there is none."""
+    files = sorted(glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
+                             recursive=True), key=os.path.getmtime)
+    if not files:
+        return []
+    from jax.profiler import ProfileData
+
     events = []
-    try:
-        for path in glob.glob(os.path.join(trace_dir, "**", "*.trace.json*"),
-                              recursive=True):
-            try:
-                opener = gzip.open if path.endswith(".gz") else open
-                with opener(path, "rt") as f:
-                    data = json.load(f)
-                evs = data.get("traceEvents", []) \
-                    if isinstance(data, dict) else []
-                events.extend(e for e in evs if isinstance(e, dict))
-            except Exception:
-                continue
-    except Exception:
-        pass
+    for pid, plane in enumerate(ProfileData.from_file(files[-1]).planes, 1):
+        rows = []
+        for tid, line in enumerate(plane.lines, 1):
+            evs = [{"name": ev.name, "ph": "X", "pid": pid, "tid": tid,
+                    "ts": ev.start_ns / 1000.0,
+                    "dur": ev.duration_ns / 1000.0} for ev in line.events]
+            if evs:
+                rows.append({"name": "thread_name", "ph": "M", "pid": pid,
+                             "tid": tid, "args": {"name": line.name}})
+                rows.extend(evs)
+        if rows:
+            events.append({"name": "process_name", "ph": "M", "pid": pid,
+                           "args": {"name": plane.name}})
+            events.extend(rows)
     return events
 
 
 def dump_profile() -> str:
     """(reference MXDumpProfile) — stop the window if running and write
-    the merged chrome://tracing JSON at the configured ``filename``.
+    the chrome://tracing JSON at the configured ``filename``.
 
-    Always writes (zero events included) and returns the absolute path;
-    host spans come from ``telemetry`` (drained — a second dump only
-    contains newer events), engine per-op events from the native/python
-    engine profiler when it was on, device events from the jax trace dir
-    when one exists."""
+    Always writes (zero events included) and returns the absolute path.
+    ``traceEvents`` is on one clock. When the window took a jax trace it
+    is that trace, device lines and host rows alike: the host spans are
+    in it as annotations. The ring's events (host spans with their
+    attributes, the engine's per-op events, other processes' ring
+    files), which are on ``time.monotonic_ns``, then go beside it under
+    ``ringEvents``, never into the same list. Without a jax trace
+    ``traceEvents`` is the ring. The ring is drained either way: a
+    second dump only contains newer events."""
     if _state["running"]:
         profiler_set_state("stop")
     path = os.path.abspath(_state["filename"])
-    events = telemetry.chrome_events(clear=True)
-    n_host = len(events)
+    ring = telemetry.chrome_events(clear=True)
+    n_host = len(ring)
     if _state["engine_prof"]:
         try:
             from . import engine
 
-            events.extend(engine.get().dump_profile().get("traceEvents", []))
+            ring.extend(engine.get().dump_profile().get("traceEvents", []))
         except Exception:
             pass
         _state["engine_prof"] = False
-    events.extend(_ring_file_events(
+    ring.extend(_ring_file_events(
         [_state["dir"], os.environ.get("MXNET_TELEMETRY_RING_DIR")]))
-    events.extend(_jax_trace_events((_state["dir"] or ".") + "/jax_trace"))
+    doc = {"traceEvents": ring, "displayTimeUnit": "ms",
+           "clock": "monotonic_ns"}
+    if _state["jax_taken"]:
+        _state["jax_taken"] = False
+        try:
+            traced = _xplane_events((_state["dir"] or ".") + "/jax_trace")
+        except Exception:
+            _log.exception("the jax trace could not be read; the profile "
+                           "holds the host ring alone")
+            traced = []
+        if traced:
+            doc = {"traceEvents": traced, "displayTimeUnit": "ms",
+                   "clock": "profiler", "ringEvents": ring}
     os.makedirs(os.path.dirname(path) or ".", exist_ok=True)
     with open(path, "w") as f:
-        json.dump({"traceEvents": events, "displayTimeUnit": "ms"}, f)
-    _log.info("profile dumped to %s (%d events, %d host spans)",
-              path, len(events), n_host)
+        json.dump(doc, f)
+    _log.info("profile dumped to %s (%d events on the %s clock, %d host "
+              "spans)", path, len(doc["traceEvents"]), doc["clock"], n_host)
     return path
 
 

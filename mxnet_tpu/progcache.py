@@ -443,7 +443,8 @@ def load(key: str, kind: str = ""):
     if d is None:
         return None
     path = _entry_path(d, key)
-    with _telemetry.span("progcache.load", domain="progcache", key=key[:12]):
+    with _telemetry.span("progcache.load", domain="progcache",
+                         key=key[:12], hit=False) as sp:
         try:
             with open(path, "rb") as f:
                 blob = f.read()
@@ -470,6 +471,7 @@ def load(key: str, kind: str = ""):
             serialized, in_tree, out_tree = pickle.loads(payload)
             exe = _sx.deserialize_and_load(serialized, in_tree, out_tree,
                                            execution_devices=devices)
+            sp.annotate(hit=True)
         except Exception as e:
             log.warning("progcache: entry %s unusable (%s) — falling back "
                         "to fresh compile", path, e)

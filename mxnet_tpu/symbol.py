@@ -478,30 +478,31 @@ class Symbol:
         """Infer shapes from kwargs, allocate arrays, bind (reference
         python/mxnet/symbol.py:1117)."""
         from . import ndarray as nd
-        from .executor import Executor
+        from .executor import Executor, bind_span
 
-        type_dict = type_dict or {}
-        arg_shapes, _, aux_shapes = self.infer_shape(**kwargs)
-        arg_names = self.list_arguments()
-        aux_names = self.list_auxiliary_states()
-        _, _, _, structs = self._infer_structs(kwargs, {k: np.dtype(v) for k, v in type_dict.items()}, partial=False)
-        args = {}
-        for n, shp in zip(arg_names, arg_shapes):
-            st = structs["args"][n]
-            args[n] = nd.zeros(shp, ctx=ctx, dtype=str(st.dtype))
-        args_grad = None
-        if grad_req != "null":
-            args_grad = {
-                n: nd.zeros(a.shape, ctx=ctx, dtype=str(structs["args"][n].dtype))
-                for n, a in args.items()
+        with bind_span():
+            type_dict = type_dict or {}
+            arg_shapes, _, aux_shapes = self.infer_shape(**kwargs)
+            arg_names = self.list_arguments()
+            aux_names = self.list_auxiliary_states()
+            _, _, _, structs = self._infer_structs(kwargs, {k: np.dtype(v) for k, v in type_dict.items()}, partial=False)
+            args = {}
+            for n, shp in zip(arg_names, arg_shapes):
+                st = structs["args"][n]
+                args[n] = nd.zeros(shp, ctx=ctx, dtype=str(st.dtype))
+            args_grad = None
+            if grad_req != "null":
+                args_grad = {
+                    n: nd.zeros(a.shape, ctx=ctx, dtype=str(structs["args"][n].dtype))
+                    for n, a in args.items()
+                }
+            aux_states = {
+                n: nd.zeros(shp, ctx=ctx, dtype=str(structs["aux"][n].dtype))
+                for n, shp in zip(aux_names, aux_shapes)
             }
-        aux_states = {
-            n: nd.zeros(shp, ctx=ctx, dtype=str(structs["aux"][n].dtype))
-            for n, shp in zip(aux_names, aux_shapes)
-        }
-        return Executor(self, ctx, args, args_grad, grad_req, aux_states,
-                        group2ctx=group2ctx, shared_exec=shared_exec,
-                        compute_dtype=compute_dtype)
+            return Executor(self, ctx, args, args_grad, grad_req, aux_states,
+                            group2ctx=group2ctx, shared_exec=shared_exec,
+                            compute_dtype=compute_dtype)
 
     # --- evaluation helper used by Executor -------------------------------
     def build_eval(self, remat_segments=None):

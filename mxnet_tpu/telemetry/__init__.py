@@ -3,10 +3,14 @@
 Four pieces, all with branch-and-return disabled paths:
 
 - **tracing** (:mod:`.tracer`): per-thread ring-buffer span recorder.
-  Spans are OFF by default; enable domains with
+  Domains are OFF by default; enable them with
   ``MXNET_PROFILER=engine,serving,kvstore`` (or ``all``), or
-  programmatically via :func:`enable_spans`. ``profiler.dump_profile()``
-  drains every buffer into a chrome://tracing JSON.
+  programmatically via :func:`enable_spans`. The step-path spans
+  (``tracer.STEP_PATH``) record regardless. Every span is also a
+  ``jax.profiler.TraceAnnotation``, so a profiler session holds it on
+  the device trace's clock; :mod:`.compiles` charges traces, lowerings,
+  compiles and cache reads to the spans open when they happen.
+  ``profiler.dump_profile()`` writes a chrome://tracing JSON.
 - **metrics** (:mod:`.metrics`): the central :data:`registry` of
   counters/gauges/histograms plus adopted metric groups (ServingMetrics
   et al.), with ``get_name_value()`` and Prometheus ``exposition()``
@@ -25,19 +29,20 @@ jitted/shard_mapped functions — enforced by
 ``mxnet_tpu.analysis.trace_purity`` (rule ``telemetry-in-jit``), which
 also flags ``current_context()`` reads inside jitted code.
 """
-from .tracer import (begin, chrome_events, clock_ns, complete,
+from .tracer import (STEP_PATH, begin, chrome_events, clock_ns, complete,
                      disable_spans, drain_events, dump_ring, enable_spans,
-                     enabled, enabled_domains, end, instant, mark_begin,
-                     mark_end, reset, set_span_sink, span)
+                     enabled, enabled_domains, end, instant, open_spans,
+                     reset, set_span_sink, span)
 from .metrics import (CONTENT_TYPE_LATEST, Counter, Gauge, Histogram,
                       Registry, registry)
+from . import compiles
 from . import context
 from . import flight
 from .context import TraceContext, current_context
 
 __all__ = [
-    "span", "begin", "end", "complete", "instant", "mark_begin", "mark_end",
-    "enabled", "enable_spans", "disable_spans", "enabled_domains",
+    "span", "begin", "end", "complete", "instant", "open_spans",
+    "STEP_PATH", "enabled", "enable_spans", "disable_spans", "enabled_domains",
     "drain_events", "chrome_events", "clock_ns", "reset", "dump_ring",
     "set_span_sink",
     "registry", "Registry", "Counter", "Gauge", "Histogram",

@@ -16,13 +16,24 @@ blocks and merges them at dump time. Same design here:
 
 Domains (``engine``, ``serving``, ``kvstore``, ``executor``,
 ``monitor``, ...) are selected via ``MXNET_PROFILER=engine,serving``
-(or ``1``/``all``); spans are OFF by default. ``MXNET_TELEMETRY=0`` is
-the master kill for the whole subsystem (docs/observability.md,
-docs/env_var.md).
+(or ``1``/``all``); they are OFF by default. The short fixed list of
+step-path spans (:data:`STEP_PATH`: O(1) per device program launched)
+records whatever the domains say. ``MXNET_TELEMETRY=0`` is the master
+kill for the whole subsystem (docs/observability.md, docs/env_var.md).
 
-Timestamps use ``time.monotonic_ns()`` — the same clock family as the
-serving deadlines (``time.monotonic``), so request queue time can be
-reconstructed exactly with ``complete()``.
+Two clocks, kept apart. The ring's timestamps are
+``time.monotonic_ns()`` — the same clock family as the serving deadlines
+(``time.monotonic``), so request queue time can be reconstructed exactly
+with ``complete()``. Every span (``span``, ``begin``/``end``) is also a
+``jax.profiler.TraceAnnotation`` of the same name: while a profiler
+session runs it lies in the session's trace on the profiler's clock,
+beside the device lines, which is where a device gap is named from.
+
+Every span record carries ``id`` and ``parent`` (the span open on its
+thread when it began) in its args; durations that ``jax.monitoring``
+reports for traces, lowerings, compiles and compilation-cache reads are
+added to every span open on the thread they fire on
+(:mod:`.compiles`).
 
 Instrumentation calls must stay OUTSIDE jitted/shard_mapped code: a
 traced function runs once at trace time, so a span inside it measures
@@ -31,11 +42,14 @@ this (rule ``telemetry-in-jit``).
 """
 from __future__ import annotations
 
+import itertools
 import os
 import threading
 import time
 from collections import deque
 from typing import Dict, List, Optional, Tuple
+
+from jax.profiler import TraceAnnotation as _TraceMe
 
 #: per-thread ring size default (events beyond it age out oldest-first).
 #: MXNET_TELEMETRY_BUFFER is re-read at every ring CREATION — a test or
@@ -83,7 +97,37 @@ def _buf() -> _ThreadBuffer:
     return b
 
 
+def open_spans() -> list:
+    """The spans open on the calling thread, outermost first (the live
+    list: ``_Span.__enter__``/``__exit__`` push and pop it)."""
+    st = getattr(_local, "stack", None)
+    if st is None:
+        st = _local.stack = []
+    return st
+
+
+_ids = itertools.count(1)  # next() is atomic under the GIL
+
 # --- domain gating -----------------------------------------------------------
+#: Spans that record (and annotate the profiler's trace) without being
+#: asked: the step path. The rule for this list: a span that occurs O(1)
+#: times per device program launched — never per operator, per leaf or
+#: per token — at most 8 a training step. A constant, not a setting;
+#: ``MXNET_TELEMETRY=0`` still kills all of it.
+STEP_PATH = frozenset((
+    "executor.bind",
+    "executor.train_step",
+    "executor.train_step.build",
+    "executor.train_step.dispatch",
+    "module.fit_step",
+    "module.fit_step.prepare",
+    "module.load_data",
+    "module.fused_snapshot",
+    "module.update_metric",
+    "module.next_batch",
+    "progcache.load",
+))
+
 _spans_on = False
 _all_domains = False
 _domains: frozenset = frozenset()
@@ -157,7 +201,7 @@ def _tee(ph, name, domain, ts_ns, dur_ns, args):
 class _Span:
     """Context manager recording one complete ("X") event."""
 
-    __slots__ = ("name", "domain", "args", "t0")
+    __slots__ = ("name", "domain", "args", "t0", "id", "parent", "_tm")
 
     def __init__(self, name, domain, args):
         self.name = name
@@ -165,6 +209,12 @@ class _Span:
         self.args = args or None
 
     def __enter__(self):
+        st = open_spans()
+        self.parent = st[-1].id if st else 0
+        self.id = next(_ids)
+        st.append(self)
+        self._tm = _TraceMe(self.name)
+        self._tm.__enter__()
         self.t0 = clock_ns()
         return self
 
@@ -173,11 +223,26 @@ class _Span:
         self.args = dict(self.args or (), **args)
         return self
 
+    def add(self, key, value):
+        """Accumulate a number under ``key`` (the compile listener's
+        ``trace_s``/``lower_s``/``compile_s``/``cache_read_s``)."""
+        a = self.args
+        if a is None:
+            a = self.args = {}
+        a[key] = a.get(key, 0) + value
+
     def __exit__(self, *exc):
         t1 = clock_ns()
+        self._tm.__exit__(None, None, None)
+        st = open_spans()
+        if st and st[-1] is self:
+            st.pop()
+        elif self in st:  # exited out of order: keep the stack honest
+            st.remove(self)
+        args = dict(self.args or (), id=self.id, parent=self.parent)
         _buf().events.append(
-            ("X", self.name, self.domain, self.t0, t1 - self.t0, self.args))
-        _tee("X", self.name, self.domain, self.t0, t1 - self.t0, self.args)
+            ("X", self.name, self.domain, self.t0, t1 - self.t0, args))
+        _tee("X", self.name, self.domain, self.t0, t1 - self.t0, args)
         return False
 
 
@@ -190,6 +255,9 @@ class _NoopSpan:
     def annotate(self, **args):
         return self
 
+    def add(self, key, value):
+        pass
+
     def __exit__(self, *exc):
         return False
 
@@ -201,8 +269,9 @@ def span(name: str, domain: str = "app", **args):
     """``with telemetry.span("engine.op", domain="engine", vars=3): ...``
     — records an "X" event on the calling thread's ring buffer. Returns a
     shared no-op object when the domain is disabled (branch-and-return;
-    nothing is allocated)."""
-    if not (_spans_on and (_all_domains or domain in _domains)):
+    nothing is allocated), unless ``name`` is on the step path."""
+    if not ((_spans_on and (_all_domains or domain in _domains))
+            or (name in STEP_PATH and _master_enabled())):
         return _NOOP
     return _Span(name, domain, args)
 
@@ -215,20 +284,27 @@ def begin(name: str, domain: str = "app", **args) -> Optional[tuple]:
     elsewhere (the engine push_async shape)."""
     if not (_spans_on and (_all_domains or domain in _domains)):
         return None
-    return (_buf(), name, domain, clock_ns(), args or None)
+    st = open_spans()
+    args = dict(args, id=next(_ids), parent=st[-1].id if st else 0)
+    tm = _TraceMe(name)
+    tm.__enter__()
+    return (_buf(), name, domain, clock_ns(), args, tm)
 
 
 def end(token: Optional[tuple], **extra_args):
     """Finish an async span started with :func:`begin` (None-safe)."""
     if token is None:
         return
-    buf, name, domain, t0, args = token
+    buf, name, domain, t0, args, tm = token
+    dur = clock_ns() - t0
+    # the annotation lands on the ENDING thread's row of the profiler's
+    # trace, with the begin's start time; the ring keeps the begin's row
+    tm.__exit__(None, None, None)
     if extra_args:
-        args = dict(args or (), **extra_args)
+        args = dict(args, **extra_args)
     end_tid = threading.get_ident()
     if end_tid != buf.tid:
-        args = dict(args or (), end_tid=end_tid)
-    dur = clock_ns() - t0
+        args = dict(args, end_tid=end_tid)
     buf.events.append(("X", name, domain, t0, dur, args))
     _tee("X", name, domain, t0, dur, args)
 
@@ -257,21 +333,6 @@ def instant(name: str, domain: str = "app", **args):
     _tee("i", name, domain, t, 0, a)
 
 
-def mark_begin(name: str, domain: str = "app", **args):
-    """Emit a duration-begin ("B") event; pair with :func:`mark_end` ON
-    THE SAME THREAD (chrome matches B/E per tid). Used for user-delimited
-    windows like the profiler run/stop bracket."""
-    if not (_spans_on and (_all_domains or domain in _domains)):
-        return
-    _buf().events.append(("B", name, domain, clock_ns(), 0, args or None))
-
-
-def mark_end(name: str, domain: str = "app", **args):
-    if not (_spans_on and (_all_domains or domain in _domains)):
-        return
-    _buf().events.append(("E", name, domain, clock_ns(), 0, args or None))
-
-
 # --- drain / dump ------------------------------------------------------------
 def drain_events(clear: bool = True) -> List[tuple]:
     """Collect raw events from every thread buffer as
@@ -298,8 +359,8 @@ def drain_events(clear: bool = True) -> List[tuple]:
 
 
 def chrome_events(clear: bool = True) -> List[dict]:
-    """Drain to chrome://tracing ``traceEvents`` dicts (``ph`` "X"/"B"/
-    "E"/"i", pid/tid, ts/dur in µs), preceded by ``thread_name`` metadata
+    """Drain to chrome://tracing ``traceEvents`` dicts (``ph`` "X"/"i",
+    pid/tid, ts/dur in µs), preceded by ``thread_name`` metadata
     events, sorted so ts is monotonic per tid."""
     pid = os.getpid()
     raw = drain_events(clear=clear)

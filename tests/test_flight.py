@@ -101,6 +101,10 @@ def test_batch_span_trace_ids_fan_out_to_every_member():
 
 
 def test_on_anomaly_writes_one_bundle_and_bumps_counter(tmp_path):
+    # the counter is the process's: an earlier file in this worker may have
+    # written deadline_miss bundles already, so compare before and after
+    series = 'flight_bundles_total{trigger="deadline_miss"}'
+    before = dict(telemetry.registry.get_name_value()).get(series, 0)
     telemetry.enable_spans("serving")
     ctx = tctx.mint(request_id="victim")
     with telemetry.span("serving.queued", domain="serving",
@@ -119,8 +123,8 @@ def test_on_anomaly_writes_one_bundle_and_bumps_counter(tmp_path):
     assert bundle["detail"]["latency_ms"] == 12.0
     assert "MXNET_FLIGHT_DIR" in bundle["config"]
     assert "# TYPE" in bundle["metrics"]  # full exposition rides along
-    assert 'flight_bundles_total{trigger="deadline_miss"} 1' in \
-        telemetry.registry.exposition()
+    assert dict(telemetry.registry.get_name_value())[series] == before + 1
+    assert "%s %d" % (series, before + 1) in telemetry.registry.exposition()
     assert path in flight.summary()["bundles"]
 
 

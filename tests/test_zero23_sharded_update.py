@@ -17,7 +17,7 @@ XLA_FLAGS=--xla_force_host_platform_device_count=8). Covers:
   cotangent sharded; ``zero3_remat`` stays a callable);
 - the layout byte model (``stage_train_bytes``) behind the
   ``train_param_bytes``/``train_grad_bytes{stage=}`` gauges, plus the
-  gauges and the ``train.allgather_prefetch`` span themselves;
+  gauges and the step span's ``stage``/``gather_bytes`` themselves;
 - capture/fuse composition: stages 2/3 under MXNET_ENGINE_CAPTURE
   match eager bitwise, and MXNET_ENGINE_FUSE now stages the sharded
   step into the ONE donated fused program (the committed carry
@@ -270,10 +270,11 @@ def test_zero2_bucket_bytes_env(monkeypatch):
 
 # --- observability ----------------------------------------------------------
 
-def test_stage3_gauges_and_prefetch_span(monkeypatch):
+def test_stage3_gauges_and_step_span(monkeypatch):
     """The byte gauges carry the stage label and the layout-implied
-    values; stage 3 wraps its step in a train.allgather_prefetch span."""
-    telemetry.enable_spans("executor")
+    values; the step's span says the stage and the bytes its on-demand
+    weight gathers move."""
+    telemetry.reset()
     mod = _train_mlp(monkeypatch, 3, steps=2)
     fs = mod._fused_fit
     want_p, want_g = coll.stage_train_bytes(fs["params"], 3, DP)
@@ -286,8 +287,10 @@ def test_stage3_gauges_and_prefetch_span(monkeypatch):
         coll.per_device_bytes(fs["states"])
     expo = telemetry.registry.exposition()
     assert 'train_param_bytes{stage="3"}' in expo
-    names = [ev[1] for ev in telemetry.drain_events()]
-    assert "train.allgather_prefetch" in names
+    steps = [ev[5] for ev in telemetry.drain_events()
+             if ev[1] == "executor.train_step"]
+    assert len(steps) == 2
+    assert all(a["stage"] == 3 and a["gather_bytes"] > 0 for a in steps)
 
 
 # --- capture / fuse composition ---------------------------------------------
