@@ -1,7 +1,7 @@
 #!/usr/bin/env python
 """Transformer benchmarks: flash-attention fast path + LM training.
 
-Two measurements (the cuDNN-fast-path layering extended to attention,
+Three measurements (the cuDNN-fast-path layering extended to attention,
 SURVEY §7 / cudnn_rnn-inl.h:22 contract — the fast path must not lose
 where it is selected):
 
@@ -15,7 +15,12 @@ where it is selected):
    blocks with a scalar-loss head; head_dim 128 so the flash path is
    selected), flash on vs off in the SAME training program.
 
+3. kernels (``--kernels``, alone): the three flash kernels' milliseconds
+   (forward, dq, dkv), each alone, at the shape of the benchmark cell
+   ``lm_train_4k`` — the measurement PERF.md's PR-26 findings start from.
+
     python examples/transformer/bench_transformer.py
+    python examples/transformer/bench_transformer.py --kernels
 """
 import argparse
 import os
@@ -136,6 +141,79 @@ def micro(args):
                  tb_plain / tb_flash))
     fa.MIN_SEQ = saved_min_seq
     return rows
+
+
+def kernel_times(fa, batch, heads, kv_heads, seq, head_dim, causal=True,
+                 reps=20, seed=0, only=("fwd", "dq", "dkv")):
+    """Milliseconds of each of the three flash kernels alone (forward with
+    lse, dq, dkv) at one shape, bfloat16: each closure keeps ONE of the
+    kernels (XLA drops a pallas_call whose results nobody reads; checked
+    in the compiled text), runs ``reps`` times back to back and is read
+    once; the least of three such blocks. ``fa`` is the kernel module.
+    Returns ({"fwd": ms, "dq": ms, "dkv": ms}, {"fwd": (o, lse), "dq": dq,
+    "dkv": (dk, dv)}) for the kernels in ``only``."""
+    import numpy as np
+    import jax
+    import jax.numpy as jnp
+
+    interp = jax.default_backend() == "cpu"
+    g, rows = heads // kv_heads, batch * kv_heads
+    rng = np.random.RandomState(seed)
+
+    def rand(*shape):
+        return jnp.asarray(rng.randn(*shape).astype(np.float32),
+                           dtype=jnp.bfloat16)
+
+    q, do = rand(rows, g, seq, head_dim), rand(rows, g, seq, head_dim)
+    k, v = rand(rows, seq, head_dim), rand(rows, seq, head_dim)
+    scale = head_dim ** -0.5
+    fwd = jax.jit(lambda q, k, v: fa._fa_forward(
+        q, k, v, causal, scale, interp, with_lse=True))
+    o, lse = fwd(q, k, v)
+
+    def bwd(q, k, v, o, lse, do):
+        return fa._fa_backward(q, k, v, o, lse, do, causal, scale, interp)
+
+    arms = {"fwd": (fwd, (q, k, v)),
+            "dq": (jax.jit(lambda *a: bwd(*a)[0]), (q, k, v, o, lse, do)),
+            "dkv": (jax.jit(lambda *a: bwd(*a)[1:]), (q, k, v, o, lse, do))}
+    ms, outs = {}, {}
+    for name in only:
+        f, xs = arms[name]
+        if not interp:
+            n = f.lower(*xs).compile().as_text().count(
+                'custom_call_target="tpu_custom_call"')
+            assert n == 1, "%s: %d kernels in the program, not 1" % (name, n)
+        r = outs[name] = jax.block_until_ready(f(*xs))
+        best = None
+        for _ in range(3):
+            t0 = time.perf_counter()
+            for _ in range(reps):
+                r = f(*xs)
+            jax.block_until_ready(r)
+            t = (time.perf_counter() - t0) / reps
+            best = t if best is None else min(best, t)
+        ms[name] = best * 1e3
+    return ms, outs
+
+
+def kernels(args):
+    """The three flash kernels' milliseconds at the benchmark cell's shape
+    (lm_train_4k: batch 2, 24 heads over 2 KV heads, 4096, 128, causal,
+    bfloat16): one layer's calls. PERF.md (Findings, PR 26) has the
+    readings this repeats."""
+    import jax
+    from mxnet_tpu.ops.pallas import flash_attention as fa
+
+    on_cpu = jax.default_backend() == "cpu"
+    shape = (1, 2, 1, 256, 128) if on_cpu else (2, 24, 2, 4096, 128)
+    ms, _ = kernel_times(fa, *shape, causal=args.causal,
+                         reps=1 if on_cpu else 50)
+    print("kernels B=%d H=%d HKV=%d S=%d D=%d causal=%s: fwd %.3f ms  "
+          "dq %.3f ms  dkv %.3f ms  sum %.3f ms"
+          % (shape + (args.causal, ms["fwd"], ms["dq"], ms["dkv"],
+                      sum(ms.values()))))
+    return ms
 
 
 def gqa(args):
@@ -443,9 +521,15 @@ def main():
     p.add_argument("--skip-train", action="store_true")
     p.add_argument("--gqa", action="store_true",
                    help="run ONLY the grouped-query attention micro")
+    p.add_argument("--kernels", action="store_true",
+                   help="run ONLY the three flash kernels' timing at the "
+                        "lm_train_4k cell's shape")
     p.add_argument("--long", action="store_true",
                    help="run ONLY the long-context 16k/32k LM headline")
     args = p.parse_args()
+    if args.kernels:
+        kernels(args)
+        return
     if args.gqa:
         gqa(args)
         return

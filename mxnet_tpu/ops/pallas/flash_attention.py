@@ -3,25 +3,46 @@
 The fused fast path behind the MultiHeadAttention op (ops/attention.py) and
 the building block of ring attention (parallel/ring_attention.py). Never
 materializes the (Tq, Tk) score matrix in HBM: a grid cell owns one query
-block, streams key/value blocks through VMEM, and keeps the softmax
-running-max/running-sum in registers (f32) — the standard
-memory-bandwidth-optimal formulation for the MXU.
+block (one key block in the dK/dV kernel), walks the other side's tiles
+through VMEM, and keeps the softmax statistics and every accumulator in
+f32 — the standard memory-bandwidth-optimal formulation for the MXU.
 
-Two VMEM regimes, selected per shape:
+Three kernels (forward, dq, dkv), each ONE grid over (batch*kv-head, ...,
+block, superblock): the walked side arrives one superblock per grid step,
+an in-kernel loop walks the superblock's tiles, and the state (acc / running
+max / running sum, or dq, or dk and dv) lives in VMEM scratch that the tile
+body updates in place, so it crosses tiles and superblocks the same way.
+The superblock is sized per shape:
 
-- **resident** (seq <= _RESIDENT_MAX): the whole K/V (or, in the dK/dV
-  kernel, Q/dO) sequence sits in VMEM per grid cell and an in-kernel loop
-  walks its tiles with the carry in registers. Fastest form — no scratch
-  traffic, minimal grid steps — but VMEM scales with sequence length, so
-  it hits the 16 MiB scoped-VMEM wall just past 8k at head_dim 128.
-- **streaming** (longer): the sequence streams through an extra innermost
-  grid dim one ~SUPER_TARGET-sized superblock at a time, the kernel loops
-  the superblock's tiles in registers, and the carry lives in VMEM
-  scratch across supersteps. Nothing in VMEM scales with total sequence
-  length, so 16k/32k+ train in the same footprint as 4k. Measured ~1.5-2x
-  slower than resident at seqs where both run (per-superstep scratch
-  spill/fill + grid overhead), which is why it only engages where
-  resident cannot run at all.
+- **resident** (seq <= _RESIDENT_MAX): one superblock, the whole K/V (or,
+  in the dK/dV kernel, Q/dO) sequence in VMEM per grid cell — fewest grid
+  steps, but VMEM scales with sequence length, so it hits the 16 MiB
+  scoped-VMEM wall just past 8k at head_dim 128.
+- **streaming** (longer): ~SUPER_TARGET-sized superblocks through the
+  grid's innermost dim. Nothing in VMEM scales with total sequence
+  length, so 16k/32k+ train in the same footprint as 4k.
+
+What the tile loop costs besides its matrix products decides the speed
+(PERF.md, Findings, PR 26; measured on a v5e at 4096 causal, 12 query
+heads a KV head):
+
+- the state is updated IN VMEM, not carried through the loop: a carry of
+  96-128 vregs (acc, and m and l at one row per sublane) does not fit the
+  64 registers, and the loop spilled it at its top and filled it at its
+  bottom, 250 of 700 bundles a forward tile;
+- dK/dV are computed in TRANSPOSED form (scores as (BK, BQ) tiles: k q^T,
+  p^T dO, v dO^T, ds^T q), so every product contracts over the last dim
+  of one side, no tile goes through the XLU, and lse and D are used in
+  the (1, Tq) layout they are stored in;
+- 512 x 512 tiles: the MXU loads a 128 x 128 weight tile in the time it
+  multiplies 128 rows, so the rows streamed per weight tile (BQ; BK in
+  dkv) set how much of its time goes to products.
+
+Operands go to the MXU as f32 and are rounded there to bf16 in its one
+pass (measured: the product of f32 operands equals that of their bf16
+roundings to 6e-8), so widening the stored bf16 costs the MXU nothing and
+rounds p and ds for free; feeding the stored dtype and masking only the
+tiles on the diagonal were measured too and gave nothing (same place).
 
 Falls back to the XLA reference math off-TPU or for non-tile-aligned
 shapes, exactly as the reference falls back from cuDNN to the mshadow
@@ -39,15 +60,14 @@ from jax.sharding import PartitionSpec as P
 
 from ...parallel.mesh import partition_mesh
 
-BLOCK_Q = 256
-# Round-5 block sweep on v5e (bq x bk over {256,512,1024}x{256,512},
-# forward, causal, D=128): bk=512 wins the FORWARD at every selected
-# shape — 1.33x @S2048, 1.66x @S4096, 1.18x @S8192/GQA, 1.26x in the
-# 16k streaming regime, 1.08x at the S=1024 selection threshold — with
-# identical numerics (bf16 maxdiff 0.016 vs the XLA reference,
-# unchanged). The backward kernels are insensitive to both block sizes
-# (measured flat), so their cost model is untouched. bq=512 adds
-# nothing over bq=256 once bk=512.
+# Preferred tiles of all three kernels (`_pick_block` falls back to a
+# divisor of the length). PR-26 sweep on v5e, bq x bk over {256,512,1024}
+# x {128..1024}, causal S=4096 D=128 group 12, ms a call (PERF.md,
+# Findings, PR 26): forward 256x512 2.25, 512x256 2.81, 512x512 2.06,
+# 512x1024 2.30, 1024x512 2.20; dq 256x512 2.68, 512x512 2.41, 1024x512
+# 2.48, 1024x1024 2.42; dkv 256x512 3.86, 512x512 3.03, 512x1024 3.17,
+# 1024x512 3.17. The backward kernels are NOT flat in them any more.
+BLOCK_Q = 512
 BLOCK_K = 512
 # Selection gate (the cudnn-autotune "must not lose" contract): measured
 # on v5e (examples/transformer/bench_transformer.py micro). With the
@@ -65,6 +85,7 @@ _RESIDENT_MAX = 8192
 # Streaming superblock target size (keys or queries per grid step).
 SUPER_TARGET = 4096
 _NEG_INF = float(jnp.finfo(jnp.float32).min)
+_LANES = 128
 
 
 def _split_super(t, block, target=None):
@@ -84,86 +105,98 @@ def _split_super(t, block, target=None):
     return t // nsup, nsup
 
 
-# --- forward, resident regime ----------------------------------------------
+def _superblocks(t, block):
+    """(super, n_super) of the walked side: whole while it fits VMEM."""
+    return (t, 1) if t <= _RESIDENT_MAX else _split_super(t, block)
 
-def _fa_kernel_res(q_ref, k_ref, v_ref, o_ref, *maybe_lse_ref, causal,
-                   scale, block_k, offset):
-    """One (batch*kv-head, group, q-block) grid cell. Writes O, and the
-    per-row logsumexp when a ref for it is supplied (training forward —
-    the blocked backward needs it; inference skips the extra HBM write).
+
+# --- what the three tile bodies share -----------------------------------------
+
+_NT = (((1,), (1,)), ((), ()))     # a @ b.T
+_NN = (((1,), (0,)), ((), ()))     # a @ b
+
+
+def _dot(a, b, dims):
+    """f32 x f32 -> f32: the MXU rounds both operands to bf16 in its one
+    pass, so this is the bf16 product at f32 accumulation."""
+    return jax.lax.dot_general(a.astype(jnp.float32), b.astype(jnp.float32),
+                               dims, preferred_element_type=jnp.float32)
+
+
+def _keep(shape, q0, k0, q_axis):
+    """Causal keep-mask of one score tile whose first query sits at
+    position q0 and first key at k0, queries along ``q_axis``. Positions
+    are absolute and a query's includes ``offset`` = tk - tq: causal
+    masking aligns the LAST query with the last key (kv-cache decode),
+    matching the XLA paths' (tk - tq) query offset (attention.py
+    dot_product_attention / _grouped_attention)."""
+    q_pos = q0 + jax.lax.broadcasted_iota(jnp.int32, shape, q_axis)
+    k_pos = k0 + jax.lax.broadcasted_iota(jnp.int32, shape, 1 - q_axis)
+    return q_pos >= k_pos
+
+
+def _lanes(x, n):
+    """x (rows, _LANES), one value a row in every lane, at n lanes: whole
+    vregs are reused, nothing moves."""
+    if n == x.shape[1]:
+        return x
+    if n % x.shape[1] == 0:
+        return pltpu.repeat(x, n // x.shape[1], 1)
+    return jnp.broadcast_to(x[:, :1], (x.shape[0], n))
+
+
+def _when(known, cond):
+    """``pl.when(cond)``, or the bare call where the trace already knows
+    that cond holds (one superblock): no branch in the kernel and no
+    `cond` to trace."""
+    return (lambda body: body()) if known else pl.when(cond)
+
+
+def _key_tiles(causal, q0, bq, k_base, block_k, n):
+    """How many of the n key tiles from position k_base the query block
+    at q0 walks: a causal one stops at the (offset) diagonal."""
+    if not causal:
+        return n
+    return jnp.clip(pl.cdiv(q0 + bq - k_base, block_k), 0, n)
+
+
+def _walk(tile, lo, hi):
+    """Run ``tile(i)`` for i in [lo, hi): the state is in VMEM refs, so
+    the loop carries nothing."""
+    def body(i, carry):
+        tile(i)
+        return carry
+
+    jax.lax.fori_loop(lo, hi, body, 0)
+
+
+# --- forward -------------------------------------------------------------------
+
+def _fa_kernel(q_ref, k_ref, v_ref, o_ref, *rest, causal, scale, block_k,
+               offset, with_lse, num_super):
+    """One (batch*kv-head, group, q-block, k-superblock) grid cell: the
+    online softmax of the query block over the superblock's block_k
+    tiles, its state (acc, running max, running sum; the last two one
+    value a row in every lane) in VMEM scratch across tiles and
+    supersteps. Writes O on the last superstep, and the per-row logsumexp
+    when a ref for it is supplied (training forward — the blocked
+    backward needs it; inference skips the extra HBM write).
 
     Grouped-query layout: q is (B*Hkv, G, Tq, D) against k/v (B*Hkv, Tk,
     D) — the G query heads sharing one kv head iterate in the grid's
-    middle dim while the k/v block index stays fixed, so K/V are fetched
+    second dim while the k/v block index stays fixed, so K/V are fetched
     into VMEM once per KV head, not once per query head (the h/hkv
-    HBM-bandwidth saving GQA exists for). G=1 is standard MHA.
-
-    ``offset`` = tk - tq: causal masking aligns the LAST query with the
-    last key (kv-cache decode), matching the XLA paths' (tk - tq) query
-    offset (attention.py dot_product_attention / _grouped_attention)."""
-    q = q_ref[0, 0].astype(jnp.float32) * scale       # (BQ, D)
-    bq = q.shape[0]
-    tk = k_ref.shape[1]
-    qi = pl.program_id(2)
-    num_k_blocks = pl.cdiv(tk, block_k)
-
-    def body(kb, carry):
-        acc, m_prev, l_prev = carry
-        k = k_ref[0, pl.ds(kb * block_k, block_k), :].astype(jnp.float32)
-        v = v_ref[0, pl.ds(kb * block_k, block_k), :].astype(jnp.float32)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)        # (BQ, BK)
-        if causal:
-            q_pos = qi * bq + offset + jax.lax.broadcasted_iota(
-                jnp.int32, (bq, block_k), 0)
-            k_pos = kb * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (bq, block_k), 1)
-            s = jnp.where(q_pos >= k_pos, s, _NEG_INF)
-        m_cur = jnp.max(s, axis=1)
-        m_new = jnp.maximum(m_prev, m_cur)
-        p = jnp.exp(s - m_new[:, None])                # (BQ, BK)
-        alpha = jnp.exp(m_prev - m_new)
-        l_new = l_prev * alpha + jnp.sum(p, axis=1)
-        acc = acc * alpha[:, None] + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        return acc, m_new, l_new
-
-    d = q_ref.shape[-1]
-    init = (jnp.zeros((bq, d), jnp.float32),
-            jnp.full((bq,), _NEG_INF, jnp.float32),
-            jnp.zeros((bq,), jnp.float32))
-    if causal:
-        # only blocks at or left of the (offset) diagonal contribute
-        hi = jax.lax.min(num_k_blocks,
-                         pl.cdiv((qi + 1) * bq + offset, block_k))
-    else:
-        hi = num_k_blocks
-    acc, m, l = jax.lax.fori_loop(0, hi, body, init)
-    o_ref[0, 0] = (acc / l[:, None]).astype(o_ref.dtype)
-    if maybe_lse_ref:
-        maybe_lse_ref[0][0, 0, 0] = m + jnp.log(l)
-
-
-# --- forward, streaming regime ---------------------------------------------
-
-def _fa_kernel_stream(q_ref, k_ref, v_ref, o_ref, *rest, causal, scale,
-                      block_k, offset, with_lse, num_super):
-    """One (batch*kv-head, group, q-block, k-superblock) grid cell. K/V
-    stream through the grid's innermost dim one superblock at a time, the
-    kernel loops over its block_k tiles with the online-softmax state in
-    registers, and the state is carried ACROSS supersteps in VMEM scratch
-    (acc, running max, running sum). O/lse flush on the last superstep."""
+    HBM-bandwidth saving GQA exists for). G=1 is standard MHA."""
     lse_ref = rest[0] if with_lse else None
     acc_ref, m_ref, l_ref = rest[-3:]
-    bq = q_ref.shape[2]
+    bq, d = acc_ref.shape
     sk = k_ref.shape[1]                                # superblock size
-    qi = pl.program_id(2)
+    q0 = pl.program_id(2) * bq + offset
     ski = pl.program_id(3)
-    inner = pl.cdiv(sk, block_k)
+    k_base = ski * sk
+    one = num_super == 1
 
-    @pl.when(ski == 0)
+    @_when(one, ski == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
         m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
@@ -172,72 +205,37 @@ def _fa_kernel_stream(q_ref, k_ref, v_ref, o_ref, *rest, causal, scale,
     def _compute():
         q = q_ref[0, 0].astype(jnp.float32) * scale    # (BQ, D)
 
-        def body(kb, carry):
-            acc, m_prev, l_prev = carry
-            k = k_ref[0, pl.ds(kb * block_k, block_k), :].astype(
-                jnp.float32)
-            v = v_ref[0, pl.ds(kb * block_k, block_k), :].astype(
-                jnp.float32)
-            s = jax.lax.dot_general(
-                q, k, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32)    # (BQ, BK)
+        def tile(kb):
+            rows = pl.ds(kb * block_k, block_k)
+            s = _dot(q, k_ref[0, rows, :], _NT)        # (BQ, BK)
             if causal:
-                q_pos = qi * bq + offset + jax.lax.broadcasted_iota(
-                    jnp.int32, (bq, block_k), 0)
-                k_pos = (ski * sk + kb * block_k
-                         + jax.lax.broadcasted_iota(
-                             jnp.int32, (bq, block_k), 1))
-                s = jnp.where(q_pos >= k_pos, s, _NEG_INF)
-            m_cur = jnp.max(s, axis=1)
-            m_new = jnp.maximum(m_prev, m_cur)
-            p = jnp.exp(s - m_new[:, None])            # (BQ, BK)
+                s = jnp.where(_keep(s.shape, q0, k_base + kb * block_k, 0),
+                              s, _NEG_INF)
+            m_prev = m_ref[...]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+            p = jnp.exp(s - _lanes(m_new, block_k))
             alpha = jnp.exp(m_prev - m_new)
-            l_new = l_prev * alpha + jnp.sum(p, axis=1)
-            acc = acc * alpha[:, None] + jax.lax.dot_general(
-                p, v, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
-            return acc, m_new, l_new
+            l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=1,
+                                                      keepdims=True)
+            m_ref[...] = m_new
+            acc_ref[...] = (acc_ref[...] * _lanes(alpha, d)
+                            + _dot(p, v_ref[0, rows, :], _NN))
 
-        if causal:
-            # only tiles at or left of the (offset) diagonal contribute
-            hi = jnp.clip(
-                pl.cdiv((qi + 1) * bq + offset - ski * sk, block_k),
-                0, inner)
-        else:
-            hi = inner
-        # run the superblock with a REGISTER-local carry (seeding the
-        # loop from scratch refs measured 2x slower — Mosaic pins the
-        # carry to VMEM), then merge with the running state through the
-        # logsumexp once per superstep — the ring-attention shard merge
-        d = q_ref.shape[-1]
-        init = (jnp.zeros((bq, d), jnp.float32),
-                jnp.full((bq,), _NEG_INF, jnp.float32),
-                jnp.zeros((bq,), jnp.float32))
-        acc_l, m_l, l_l = jax.lax.fori_loop(0, hi, body, init)
-        m_prev, l_prev = m_ref[0], l_ref[0]
-        m_new = jnp.maximum(m_prev, m_l)
-        a_prev = jnp.exp(m_prev - m_new)
-        a_l = jnp.exp(m_l - m_new)
-        m_ref[0] = m_new
-        l_ref[0] = l_prev * a_prev + l_l * a_l
-        acc_ref[...] = (acc_ref[...] * a_prev[:, None]
-                        + acc_l * a_l[:, None])
+        _walk(tile, 0, _key_tiles(causal, q0, bq, k_base, block_k,
+                                  sk // block_k))
 
-    if causal:
-        # supersteps strictly right of the diagonal contribute nothing:
-        # skip the compute (their K/V fetch is also elided — the index
-        # map clamps to the diagonal superblock, and Pallas only issues
-        # a DMA when the block index CHANGES)
-        pl.when(ski * sk <= qi * bq + offset + bq - 1)(_compute)
-    else:
-        _compute()
+    # causal: supersteps strictly right of the diagonal contribute nothing:
+    # skip the compute (their K/V fetch is also elided — the index map
+    # clamps to the diagonal superblock, and Pallas only issues a DMA when
+    # the block index CHANGES)
+    _when(one or not causal, k_base <= q0 + bq - 1)(_compute)
 
-    @pl.when(ski == num_super - 1)
+    @_when(one, ski == num_super - 1)
     def _finalize():
-        l = l_ref[0]
-        o_ref[0, 0] = (acc_ref[...] / l[:, None]).astype(o_ref.dtype)
+        l = l_ref[...]
+        o_ref[0, 0] = (acc_ref[...] / _lanes(l, d)).astype(o_ref.dtype)
         if with_lse:
-            lse_ref[0, 0, 0] = m_ref[0] + jnp.log(l)
+            lse_ref[0, 0, 0] = (m_ref[...] + jnp.log(l))[:, 0]
 
 
 def _kv_stream_idx(block_q, super_k, offset, causal):
@@ -255,358 +253,171 @@ def _kv_stream_idx(block_q, super_k, offset, causal):
     return idx
 
 
+def _compiler_params(interpret):
+    if interpret:
+        return {}
+    return {"compiler_params": pltpu.CompilerParams(
+        dimension_semantics=("parallel", "arbitrary", "arbitrary",
+                             "arbitrary"))}
+
+
 def _fa_forward(q, k, v, causal, scale, interpret, with_lse=False):
     """q: (B*Hkv, G, Tq, D); k/v: (B*Hkv, Tk, D). Returns (B*Hkv, G, Tq,
     D) [+ lse (B*Hkv, G, 1, Tq) — the singleton keeps the last two block
     dims TPU-tileable]."""
     bkv, g, tq, d = q.shape
     tk = k.shape[1]
-    block_q = min(BLOCK_Q, tq)
+    block_q = _pick_block(tq, BLOCK_Q)
     block_k = _pick_block(tk, BLOCK_K)
-    resident = tk <= _RESIDENT_MAX
-    kwargs = {}
-    out_specs3 = [pl.BlockSpec((1, 1, block_q, d),
-                               lambda b, gi, i: (b, gi, i, 0))]
-    out_specs4 = [pl.BlockSpec((1, 1, block_q, d),
-                               lambda b, gi, i, ski: (b, gi, i, 0))]
+    super_k, num_super = _superblocks(tk, block_k)
+    q_spec = pl.BlockSpec((1, 1, block_q, d),
+                          lambda b, gi, i, ski: (b, gi, i, 0))
+    # k/v block index ignores (gi, i): Pallas re-fetches only on index
+    # change, so resident K/V stream from HBM once per KV head
+    kv_spec = pl.BlockSpec((1, super_k, d),
+                           _kv_stream_idx(block_q, super_k, tk - tq, causal))
+    out_specs = [q_spec]
     out_shape = [jax.ShapeDtypeStruct((bkv, g, tq, d), q.dtype)]
     if with_lse:
         # (bkv, g, 1, tq): TPU block rules need the last two block dims
         # divisible by (8, 128) or EQUAL to the array dims — the
         # singleton third dim gives (1, BQ) blocks with 1 == array dim
-        out_specs3.append(pl.BlockSpec((1, 1, 1, block_q),
-                                       lambda b, gi, i: (b, gi, 0, i)))
-        out_specs4.append(pl.BlockSpec((1, 1, 1, block_q),
-                                       lambda b, gi, i, ski: (b, gi, 0, i)))
+        out_specs.append(pl.BlockSpec((1, 1, 1, block_q),
+                                      lambda b, gi, i, ski: (b, gi, 0, i)))
         out_shape.append(jax.ShapeDtypeStruct((bkv, g, 1, tq),
                                               jnp.float32))
-    cost = pl.CostEstimate(
-        flops=4 * bkv * g * tq * tk * d,
-        bytes_accessed=(q.size + k.size + v.size) * q.dtype.itemsize,
-        transcendentals=bkv * g * tq * tk,
-    )
-    if resident:
-        kernel = functools.partial(_fa_kernel_res, causal=causal,
-                                   scale=scale, block_k=block_k,
-                                   offset=tk - tq)
-        if not interpret:
-            kwargs["compiler_params"] = pltpu.CompilerParams(
-                dimension_semantics=("parallel", "arbitrary", "arbitrary"))
-        res = pl.pallas_call(
-            kernel,
-            grid=(bkv, g, pl.cdiv(tq, block_q)),
-            in_specs=[
-                pl.BlockSpec((1, 1, block_q, d),
-                             lambda b, gi, i: (b, gi, i, 0)),
-                # k/v block index ignores (gi, i): Pallas re-fetches only
-                # on index change, so K/V stream from HBM once per KV head
-                pl.BlockSpec((1, tk, d), lambda b, gi, i: (b, 0, 0)),
-                pl.BlockSpec((1, tk, d), lambda b, gi, i: (b, 0, 0)),
-            ],
-            out_specs=out_specs3,
-            out_shape=out_shape,
-            cost_estimate=cost,
-            interpret=interpret,
-            **kwargs,
-        )(q, k, v)
-        return (res[0], res[1]) if with_lse else res[0]
-    super_k, num_super = _split_super(tk, block_k)
-    kernel = functools.partial(_fa_kernel_stream, causal=causal,
-                               scale=scale, block_k=block_k,
-                               offset=tk - tq, with_lse=with_lse,
-                               num_super=num_super)
-    if not interpret:
-        kwargs["compiler_params"] = pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary", "arbitrary",
-                                 "arbitrary"))
-    kv_idx = _kv_stream_idx(block_q, super_k, tk - tq, causal)
     res = pl.pallas_call(
-        kernel,
-        grid=(bkv, g, pl.cdiv(tq, block_q), num_super),
-        in_specs=[
-            pl.BlockSpec((1, 1, block_q, d),
-                         lambda b, gi, i, ski: (b, gi, i, 0)),
-            pl.BlockSpec((1, super_k, d), kv_idx),
-            pl.BlockSpec((1, super_k, d), kv_idx),
-        ],
-        out_specs=out_specs4,
+        functools.partial(_fa_kernel, causal=causal, scale=scale,
+                          block_k=block_k, offset=tk - tq,
+                          with_lse=with_lse, num_super=num_super),
+        grid=(bkv, g, tq // block_q, num_super),
+        in_specs=[q_spec, kv_spec, kv_spec],
+        out_specs=out_specs,
         out_shape=out_shape,
         scratch_shapes=[
-            pltpu.VMEM((block_q, d), jnp.float32),     # acc
-            pltpu.VMEM((1, block_q), jnp.float32),     # running max
-            pltpu.VMEM((1, block_q), jnp.float32),     # running sum
+            pltpu.VMEM((block_q, d), jnp.float32),         # acc
+            pltpu.VMEM((block_q, _LANES), jnp.float32),    # running max
+            pltpu.VMEM((block_q, _LANES), jnp.float32),    # running sum
         ],
-        cost_estimate=cost,
+        cost_estimate=pl.CostEstimate(
+            flops=4 * bkv * g * tq * tk * d,
+            bytes_accessed=(q.size + k.size + v.size) * q.dtype.itemsize,
+            transcendentals=bkv * g * tq * tk),
         interpret=interpret,
-        **kwargs,
+        **_compiler_params(interpret),
     )(q, k, v)
     return (res[0], res[1]) if with_lse else res[0]
 
 
 # --- blocked backward (FlashAttention-2 style: no S^2 materialization) ------
 
-def _fa_bwd_dq_kernel_res(q_ref, k_ref, v_ref, do_ref, lse_ref, dvec_ref,
-                          dq_ref, *, causal, scale, block_k, offset):
-    """dQ for one (batch*kv-head, group, q-block): stream k/v blocks,
-    rebuild p from the saved logsumexp, dq += (p * (dO v^T - D)) @ k *
-    scale."""
-    q = q_ref[0, 0].astype(jnp.float32)            # (BQ, D)
-    do = do_ref[0, 0].astype(jnp.float32)          # (BQ, D)
-    lse = lse_ref[0, 0, 0]                         # (BQ,)
-    dvec = dvec_ref[0, 0, 0]                       # (BQ,)
-    bq = q.shape[0]
-    tk = k_ref.shape[1]
-    qi = pl.program_id(2)
-    num_k_blocks = pl.cdiv(tk, block_k)
-
-    def body(kb, dq):
-        k = k_ref[0, pl.ds(kb * block_k, block_k), :].astype(jnp.float32)
-        v = v_ref[0, pl.ds(kb * block_k, block_k), :].astype(jnp.float32)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        if causal:
-            q_pos = qi * bq + offset + jax.lax.broadcasted_iota(
-                jnp.int32, (bq, block_k), 0)
-            k_pos = kb * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (bq, block_k), 1)
-            s = jnp.where(q_pos >= k_pos, s, _NEG_INF)
-        p = jnp.exp(s - lse[:, None])              # (BQ, BK), rows sum<=1
-        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        ds = p * (dp - dvec[:, None])
-        return dq + jax.lax.dot_general(ds, k, (((1,), (0,)), ((), ())),
-                                        preferred_element_type=jnp.float32)
-
-    hi = (jax.lax.min(num_k_blocks,
-                      pl.cdiv((qi + 1) * bq + offset, block_k))
-          if causal else num_k_blocks)
-    dq = jax.lax.fori_loop(0, hi, body,
-                           jnp.zeros((bq, q.shape[1]), jnp.float32))
-    dq_ref[0, 0] = (dq * scale).astype(dq_ref.dtype)
-
-
-def _fa_bwd_dq_kernel_stream(q_ref, k_ref, v_ref, do_ref, lse_ref,
-                             dvec_ref, dq_ref, dq_acc_ref, *, causal,
-                             scale, block_k, offset, num_super):
-    """dQ for one (batch*kv-head, group, q-block): k/v SUPERBLOCKS stream
-    through the grid's innermost dim, the kernel loops their block_k
-    tiles rebuilding p from the saved logsumexp, and dq accumulates
-    across supersteps in VMEM scratch, flushed on the last superstep."""
+def _fa_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dvec_ref,
+                      dq_ref, dq_acc_ref, *, causal, scale, block_k, offset,
+                      num_super):
+    """dQ for one (batch*kv-head, group, q-block, k-superblock): rebuild p
+    from the saved logsumexp tile by tile, dq += (p * (dO v^T - D)) @ k in
+    VMEM scratch, scaled and written on the last superstep."""
     bq = q_ref.shape[2]
     sk = k_ref.shape[1]
-    qi = pl.program_id(2)
+    q0 = pl.program_id(2) * bq + offset
     ski = pl.program_id(3)
-    inner = pl.cdiv(sk, block_k)
+    k_base = ski * sk
+    one = num_super == 1
 
-    @pl.when(ski == 0)
+    @_when(one, ski == 0)
     def _init():
         dq_acc_ref[...] = jnp.zeros_like(dq_acc_ref)
 
     def _compute():
-        q = q_ref[0, 0].astype(jnp.float32)        # (BQ, D)
-        do = do_ref[0, 0].astype(jnp.float32)      # (BQ, D)
-        lse = lse_ref[0, 0, 0]                     # (BQ,)
-        dvec = dvec_ref[0, 0, 0]                   # (BQ,)
+        q, do = q_ref[0, 0], do_ref[0, 0]              # (BQ, D)
+        lse, dvec = lse_ref[0, 0, 0], dvec_ref[0, 0, 0]    # (BQ,)
 
-        def body(kb, dq):
-            k = k_ref[0, pl.ds(kb * block_k, block_k), :].astype(
-                jnp.float32)
-            v = v_ref[0, pl.ds(kb * block_k, block_k), :].astype(
-                jnp.float32)
-            s = jax.lax.dot_general(
-                q, k, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32) * scale
+        def tile(kb):
+            rows = pl.ds(kb * block_k, block_k)
+            k, v = k_ref[0, rows, :], v_ref[0, rows, :]
+            s = _dot(q, k, _NT) * scale
             if causal:
-                q_pos = qi * bq + offset + jax.lax.broadcasted_iota(
-                    jnp.int32, (bq, block_k), 0)
-                k_pos = (ski * sk + kb * block_k
-                         + jax.lax.broadcasted_iota(
-                             jnp.int32, (bq, block_k), 1))
-                s = jnp.where(q_pos >= k_pos, s, _NEG_INF)
-            p = jnp.exp(s - lse[:, None])          # (BQ, BK), rows sum<=1
-            dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                                     preferred_element_type=jnp.float32)
-            ds = p * (dp - dvec[:, None])
-            return dq + jax.lax.dot_general(
-                ds, k, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
+                s = jnp.where(_keep(s.shape, q0, k_base + kb * block_k, 0),
+                              s, _NEG_INF)
+            # the two columns are spread across lanes HERE, tile by tile:
+            # spread once before the loop they hold 2 x BQ/8 vregs through
+            # it, and the kernel measured 9% slower
+            p = jnp.exp(s - lse[:, None])              # rows sum <= 1
+            ds = p * (_dot(do, v, _NT) - dvec[:, None])
+            dq_acc_ref[...] += _dot(ds, k, _NN)
 
-        if causal:
-            hi = jnp.clip(
-                pl.cdiv((qi + 1) * bq + offset - ski * sk, block_k),
-                0, inner)
-        else:
-            hi = inner
-        # register-local accumulation, one scratch add per superstep
-        # (seeding the loop carry from scratch pins it to VMEM — see the
-        # forward kernel's note)
-        dq_l = jax.lax.fori_loop(
-            0, hi, body,
-            jnp.zeros((q_ref.shape[2], q_ref.shape[3]), jnp.float32))
-        dq_acc_ref[...] += dq_l
+        _walk(tile, 0, _key_tiles(causal, q0, bq, k_base, block_k,
+                                  sk // block_k))
 
-    if causal:
-        pl.when(ski * sk <= qi * bq + offset + bq - 1)(_compute)
-    else:
-        _compute()
+    _when(one or not causal, k_base <= q0 + bq - 1)(_compute)
 
-    @pl.when(ski == num_super - 1)
+    @_when(one, ski == num_super - 1)
     def _finalize():
         dq_ref[0, 0] = (dq_acc_ref[...] * scale).astype(dq_ref.dtype)
 
 
-def _fa_bwd_dkv_kernel_res(q_ref, k_ref, v_ref, do_ref, lse_ref, dvec_ref,
-                           dk_ref, dv_ref, *, causal, scale, block_q,
-                           offset):
-    """dK/dV for one (batch*kv-head, k-block) pair: stream q/dO blocks.
-    The grid's LAST dim iterates the query-head group sequentially,
-    accumulating each group head's contribution into the same dk/dv
-    block (the GQA kv gradient is the sum over its group).
+def _fa_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dvec_ref,
+                       dk_ref, dv_ref, dk_acc_ref, dv_acc_ref, *, causal,
+                       scale, block_q, offset, g, num_super):
+    """dK/dV for one (batch*kv-head, k-block): q/dO/lse/D arrive through
+    the two inner grid dims (group head, then q-superblock, whose block_q
+    tiles the loop walks) while K/V stay put, and dk/dv accumulate across
+    ALL of them in f32 VMEM scratch — the GQA kv gradient is the sum over
+    the group — written once, in the output dtype, on the final (group,
+    q-superblock) step.
 
-    Known tradeoff of this layout: the q/do/lse/dvec block index changes
-    every grid step, so those are re-fetched num_k_blocks times per
+    TRANSPOSED form: scores as (BK, BQ) tiles, so the four products (k
+    q^T, p^T dO, v dO^T, ds^T q) contract over the last dim of one side
+    and no tile is transposed; lse and D are (1, BQ) rows, the layout
+    they are stored in.
+
+    Known tradeoff of this grid: the q/do/lse/dvec block index changes
+    with the group head, so those are re-fetched num_k_blocks times per
     group head (vs once in a (bkv, g, kb)-ordered grid — which would
-    break the dk/dv accumulation, since Pallas only accumulates across
-    CONSECUTIVE revisits of an output block). The kernel is MXU-bound at
-    every selected shape, so the extra q-side DMA rides otherwise-idle
-    bandwidth: measured fwd+bwd stays within 1-3% of the old full-H
-    layout while temp HBM drops g-fold (docs/perf.md GQA table)."""
-    k = k_ref[0].astype(jnp.float32)               # (BK, D)
-    v = v_ref[0].astype(jnp.float32)               # (BK, D)
-    bk = k.shape[0]
-    tq = q_ref.shape[2]
-    ki = pl.program_id(1)
-    gi = pl.program_id(2)
-    num_q_blocks = pl.cdiv(tq, block_q)
-
-    def body(qb, carry):
-        dk, dv = carry
-        q = q_ref[0, 0, pl.ds(qb * block_q, block_q), :].astype(jnp.float32)
-        do = do_ref[0, 0, pl.ds(qb * block_q, block_q), :].astype(jnp.float32)
-        lse = lse_ref[0, 0, 0, pl.ds(qb * block_q, block_q)]
-        dvec = dvec_ref[0, 0, 0, pl.ds(qb * block_q, block_q)]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        if causal:
-            q_pos = qb * block_q + offset + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, bk), 0)
-            k_pos = ki * bk + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, bk), 1)
-            s = jnp.where(q_pos >= k_pos, s, _NEG_INF)
-        p = jnp.exp(s - lse[:, None])              # (BQ, BK)
-        dv = dv + jax.lax.dot_general(p, do, (((0,), (0,)), ((), ())),
-                                      preferred_element_type=jnp.float32)
-        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        ds = p * (dp - dvec[:, None])
-        dk = dk + jax.lax.dot_general(ds, q, (((0,), (0,)), ((), ())),
-                                      preferred_element_type=jnp.float32)
-        return dk, dv
-
-    # causal: q blocks whose last (offset) query position precedes this
-    # k block's start contribute nothing (every entry masked)
-    lo = (jax.lax.max(ki * bk - offset, 0) // block_q) if causal else 0
-    d = k.shape[1]
-    dk, dv = jax.lax.fori_loop(
-        lo, num_q_blocks, body,
-        (jnp.zeros((bk, d), jnp.float32), jnp.zeros((bk, d), jnp.float32)))
-    dk = (dk * scale).astype(dk_ref.dtype)
-    dv = dv.astype(dv_ref.dtype)
-
-    # first group head initializes the output block; later ones add
-    @pl.when(gi == 0)
-    def _init():
-        dk_ref[0] = dk
-        dv_ref[0] = dv
-
-    @pl.when(gi > 0)
-    def _accum():
-        dk_ref[0] += dk
-        dv_ref[0] += dv
-
-
-def _fa_bwd_dkv_kernel_stream(q_ref, k_ref, v_ref, do_ref, lse_ref,
-                              dvec_ref, dk_ref, dv_ref, dk_acc_ref,
-                              dv_acc_ref, *, causal, scale, block_q,
-                              offset, g, num_q_super):
-    """dK/dV for one (batch*kv-head, k-block) pair: q/dO/lse/D stream
-    through the two inner grid dims (group head, then q-SUPERBLOCK, whose
-    block_q tiles the kernel loops over) while K/V stay resident, and
-    dk/dv accumulate across ALL of them in f32 VMEM scratch — the GQA kv
-    gradient is the sum over the group — flushed once on the final
-    (group, q-superblock) step. Nothing in VMEM scales with total
-    sequence length."""
+    break the dk/dv accumulation across the group). The q-side DMA rides
+    otherwise-idle bandwidth: measured fwd+bwd stayed within 1-3% of the
+    old full-H layout while temp HBM drops g-fold (docs/perf.md GQA
+    table)."""
     bk = k_ref.shape[1]
     sq = q_ref.shape[2]                            # q superblock size
-    ki = pl.program_id(1)
+    k0 = pl.program_id(1) * bk
     gi = pl.program_id(2)
     qsi = pl.program_id(3)
-    inner = pl.cdiv(sq, block_q)
+    q_base = qsi * sq + offset
+    one = num_super == 1
 
-    @pl.when((gi == 0) & (qsi == 0))
+    @_when(one and g == 1, (gi == 0) & (qsi == 0))
     def _init():
         dk_acc_ref[...] = jnp.zeros_like(dk_acc_ref)
         dv_acc_ref[...] = jnp.zeros_like(dv_acc_ref)
 
     def _compute():
-        k = k_ref[0].astype(jnp.float32)           # (BK, D)
-        v = v_ref[0].astype(jnp.float32)
+        k, v = k_ref[0], v_ref[0]                      # (BK, D)
 
-        def body(qb, carry):
-            dk, dv = carry
-            q = q_ref[0, 0, pl.ds(qb * block_q, block_q), :].astype(
-                jnp.float32)
-            do = do_ref[0, 0, pl.ds(qb * block_q, block_q), :].astype(
-                jnp.float32)
-            lse = lse_ref[0, 0, 0, pl.ds(qb * block_q, block_q)]
-            dvec = dvec_ref[0, 0, 0, pl.ds(qb * block_q, block_q)]
-            s = jax.lax.dot_general(
-                q, k, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32) * scale
+        def tile(qb):
+            rows = pl.ds(qb * block_q, block_q)
+            q, do = q_ref[0, 0, rows, :], do_ref[0, 0, rows, :]
+            st = _dot(k, q, _NT) * scale               # (BK, BQ)
             if causal:
-                q_pos = (qsi * sq + qb * block_q + offset
-                         + jax.lax.broadcasted_iota(
-                             jnp.int32, (block_q, bk), 0))
-                k_pos = ki * bk + jax.lax.broadcasted_iota(
-                    jnp.int32, (block_q, bk), 1)
-                s = jnp.where(q_pos >= k_pos, s, _NEG_INF)
-            p = jnp.exp(s - lse[:, None])          # (BQ, BK)
-            dv = dv + jax.lax.dot_general(
-                p, do, (((0,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
-            dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                                     preferred_element_type=jnp.float32)
-            ds = p * (dp - dvec[:, None])
-            dk = dk + jax.lax.dot_general(
-                ds, q, (((0,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
-            return dk, dv
+                st = jnp.where(
+                    _keep(st.shape, q_base + qb * block_q, k0, 1), st,
+                    _NEG_INF)
+            pt = jnp.exp(st - lse_ref[0, 0, :, rows])
+            dv_acc_ref[...] += _dot(pt, do, _NN)
+            dst = pt * (_dot(v, do, _NT) - dvec_ref[0, 0, :, rows])
+            dk_acc_ref[...] += _dot(dst, q, _NN)
 
-        if causal:
-            # tiles whose last (offset) query position precedes this k
-            # block's start contribute nothing (every entry masked)
-            lo = jnp.clip(
-                jax.lax.div(ki * bk - offset - qsi * sq, block_q),
-                0, inner)
-        else:
-            lo = 0
-        # register-local accumulation, one scratch add per superstep
-        d = k_ref.shape[2]
-        dk_l, dv_l = jax.lax.fori_loop(
-            lo, inner, body,
-            (jnp.zeros((bk, d), jnp.float32),
-             jnp.zeros((bk, d), jnp.float32)))
-        dk_acc_ref[...] += dk_l
-        dv_acc_ref[...] += dv_l
+        n = sq // block_q
+        # causal: tiles whose last (offset) query position precedes this
+        # k block's start contribute nothing (every entry masked)
+        _walk(tile, jnp.clip((k0 - q_base) // block_q, 0, n) if causal
+              else 0, n)
 
-    if causal:
-        # q superblocks entirely above the diagonal are skipped; their
-        # q-side fetches are elided by the clamped index map
-        pl.when(qsi * sq + sq - 1 + offset >= ki * bk)(_compute)
-    else:
-        _compute()
+    # causal: q superblocks entirely above the diagonal are skipped; their
+    # q-side fetches are elided by the clamped index map
+    _when(one or not causal, q_base + sq - 1 >= k0)(_compute)
 
-    @pl.when((gi == g - 1) & (qsi == num_q_super - 1))
+    @_when(one and g == 1, (gi == g - 1) & (qsi == num_super - 1))
     def _finalize():
         dk_ref[0] = (dk_acc_ref[...] * scale).astype(dk_ref.dtype)
         dv_ref[0] = dv_acc_ref[...].astype(dv_ref.dtype)
@@ -619,8 +430,7 @@ def _fa_backward(q, k, v, o, lse, do, causal, scale, interpret,
     the query-head group inside the kernel."""
     bkv, g, tq, d = q.shape
     tk = k.shape[1]
-    block_q = min(BLOCK_Q, tq)
-    block_k = _pick_block(tk, BLOCK_K)
+    offset = tk - tq
     # D_i = rowsum(dO * O): one cheap fused XLA pass. A cotangent on the
     # logsumexp output folds in here: d(lse)/ds = p, so ds gains
     # +g_lse*p, i.e. D := D - g_lse (ring attention's merge
@@ -629,159 +439,72 @@ def _fa_backward(q, k, v, o, lse, do, causal, scale, interpret,
                    axis=-1)[:, :, None, :]         # (bkv, g, 1, tq)
     if g_lse is not None:
         dvec = dvec - g_lse.astype(jnp.float32)
-    kwargs3 = {}
-    kwargs4 = {}
-    if not interpret:
-        kwargs3["compiler_params"] = pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary", "arbitrary"))
-        kwargs4["compiler_params"] = pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary", "arbitrary",
-                                 "arbitrary"))
-    dq_cost = pl.CostEstimate(
-        flops=6 * bkv * g * tq * tk * d,
-        bytes_accessed=(q.size + k.size + v.size + do.size)
-        * q.dtype.itemsize,
-        transcendentals=bkv * g * tq * tk)
-    if tk <= _RESIDENT_MAX:
-        dq = pl.pallas_call(
-            functools.partial(_fa_bwd_dq_kernel_res, causal=causal,
-                              scale=scale, block_k=block_k,
-                              offset=tk - tq),
-            grid=(bkv, g, pl.cdiv(tq, block_q)),
-            in_specs=[
-                pl.BlockSpec((1, 1, block_q, d),
-                             lambda b, gi, i: (b, gi, i, 0)),
-                pl.BlockSpec((1, tk, d), lambda b, gi, i: (b, 0, 0)),
-                pl.BlockSpec((1, tk, d), lambda b, gi, i: (b, 0, 0)),
-                pl.BlockSpec((1, 1, block_q, d),
-                             lambda b, gi, i: (b, gi, i, 0)),
-                pl.BlockSpec((1, 1, 1, block_q),
-                             lambda b, gi, i: (b, gi, 0, i)),
-                pl.BlockSpec((1, 1, 1, block_q),
-                             lambda b, gi, i: (b, gi, 0, i)),
-            ],
-            out_specs=pl.BlockSpec((1, 1, block_q, d),
-                                   lambda b, gi, i: (b, gi, i, 0)),
-            out_shape=jax.ShapeDtypeStruct((bkv, g, tq, d), q.dtype),
-            cost_estimate=dq_cost,
-            interpret=interpret,
-            **kwargs3,
-        )(q, k, v, do, lse, dvec)
-    else:
-        super_k, num_k_super = _split_super(tk, block_k)
-        kv_idx = _kv_stream_idx(block_q, super_k, tk - tq, causal)
-        dq = pl.pallas_call(
-            functools.partial(_fa_bwd_dq_kernel_stream, causal=causal,
-                              scale=scale, block_k=block_k,
-                              offset=tk - tq, num_super=num_k_super),
-            grid=(bkv, g, pl.cdiv(tq, block_q), num_k_super),
-            in_specs=[
-                pl.BlockSpec((1, 1, block_q, d),
-                             lambda b, gi, i, ski: (b, gi, i, 0)),
-                pl.BlockSpec((1, super_k, d), kv_idx),
-                pl.BlockSpec((1, super_k, d), kv_idx),
-                pl.BlockSpec((1, 1, block_q, d),
-                             lambda b, gi, i, ski: (b, gi, i, 0)),
-                pl.BlockSpec((1, 1, 1, block_q),
-                             lambda b, gi, i, ski: (b, gi, 0, i)),
-                pl.BlockSpec((1, 1, 1, block_q),
-                             lambda b, gi, i, ski: (b, gi, 0, i)),
-            ],
-            out_specs=pl.BlockSpec((1, 1, block_q, d),
-                                   lambda b, gi, i, ski: (b, gi, i, 0)),
-            out_shape=jax.ShapeDtypeStruct((bkv, g, tq, d), q.dtype),
-            scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-            cost_estimate=dq_cost,
-            interpret=interpret,
-            **kwargs4,
-        )(q, k, v, do, lse, dvec)
+    args = (q, k, v, do, lse, dvec)
+    in_bytes = (q.size + k.size + v.size + do.size) * q.dtype.itemsize
 
-    dkv_cost = pl.CostEstimate(
-        # 4 matmuls per (q,k) tile pair: s, p^T@dO, dO@v^T, ds^T@q
-        flops=8 * bkv * g * tq * tk * d,
-        bytes_accessed=(q.size + k.size + v.size + do.size)
-        * q.dtype.itemsize,
-        transcendentals=bkv * g * tq * tk)
-    if tq <= _RESIDENT_MAX:
-        # dk/dv accumulate over the group inside the kernel; for g > 1
-        # the running sum lives in the output block, so keep it f32 and
-        # cast after (bf16 += per group head would round g times)
-        kv_acc_dtype = k.dtype if g == 1 else jnp.float32
-        dk, dv = pl.pallas_call(
-            functools.partial(_fa_bwd_dkv_kernel_res, causal=causal,
-                              scale=scale, block_q=block_q,
-                              offset=tk - tq),
-            grid=(bkv, pl.cdiv(tk, block_k), g),
-            in_specs=[
-                pl.BlockSpec((1, 1, tq, d), lambda b, i, gi: (b, gi, 0, 0)),
-                pl.BlockSpec((1, block_k, d), lambda b, i, gi: (b, i, 0)),
-                pl.BlockSpec((1, block_k, d), lambda b, i, gi: (b, i, 0)),
-                pl.BlockSpec((1, 1, tq, d), lambda b, i, gi: (b, gi, 0, 0)),
-                pl.BlockSpec((1, 1, 1, tq), lambda b, i, gi: (b, gi, 0, 0)),
-                pl.BlockSpec((1, 1, 1, tq), lambda b, i, gi: (b, gi, 0, 0)),
-            ],
-            out_specs=[
-                pl.BlockSpec((1, block_k, d), lambda b, i, gi: (b, i, 0)),
-                pl.BlockSpec((1, block_k, d), lambda b, i, gi: (b, i, 0)),
-            ],
-            out_shape=[
-                jax.ShapeDtypeStruct((bkv, tk, d), kv_acc_dtype),
-                jax.ShapeDtypeStruct((bkv, tk, d), kv_acc_dtype),
-            ],
-            cost_estimate=dkv_cost,
-            interpret=interpret,
-            **kwargs3,
-        )(q, k, v, do, lse, dvec)
-        return dq, dk.astype(k.dtype), dv.astype(v.dtype)
-    super_q, num_q_super = _split_super(tq, block_q)
+    block_q = _pick_block(tq, BLOCK_Q)
+    block_k = _pick_block(tk, BLOCK_K)
+    super_k, num_super = _superblocks(tk, block_k)
+    q_spec = pl.BlockSpec((1, 1, block_q, d),
+                          lambda b, gi, i, ski: (b, gi, i, 0))
+    qrow_spec = pl.BlockSpec((1, 1, 1, block_q),
+                             lambda b, gi, i, ski: (b, gi, 0, i))
+    kv_spec = pl.BlockSpec((1, super_k, d),
+                           _kv_stream_idx(block_q, super_k, offset, causal))
+    dq = pl.pallas_call(
+        functools.partial(_fa_bwd_dq_kernel, causal=causal, scale=scale,
+                          block_k=block_k, offset=offset,
+                          num_super=num_super),
+        grid=(bkv, g, tq // block_q, num_super),
+        in_specs=[q_spec, kv_spec, kv_spec, q_spec, qrow_spec, qrow_spec],
+        out_specs=q_spec,
+        out_shape=jax.ShapeDtypeStruct((bkv, g, tq, d), q.dtype),
+        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
+        cost_estimate=pl.CostEstimate(
+            flops=6 * bkv * g * tq * tk * d, bytes_accessed=in_bytes,
+            transcendentals=bkv * g * tq * tk),
+        interpret=interpret,
+        **_compiler_params(interpret),
+    )(*args)
+
+    super_q, num_super = _superblocks(tq, block_q)
+
     # causal: q superblocks strictly above this k block's diagonal are
     # fully masked; clamp their index so the dead steps re-address the
-    # previous superblock (no DMA) while the kernel skips their compute
-    if causal:
-        def q_idx(b, i, gi, qsi):
-            lo = jax.lax.div(jax.lax.max(i * block_k - (tk - tq), 0),
-                             super_q)
-            return (b, gi, jnp.maximum(qsi, lo), 0)
+    # first live superblock (no DMA) while the kernel skips their compute
+    def first_live(i):
+        if not causal:
+            return 0
+        return jax.lax.div(jax.lax.max(i * block_k - offset, 0), super_q)
 
-        def qrow_idx(b, i, gi, qsi):
-            lo = jax.lax.div(jax.lax.max(i * block_k - (tk - tq), 0),
-                             super_q)
-            return (b, gi, 0, jnp.maximum(qsi, lo))
-    else:
-        q_idx = lambda b, i, gi, qsi: (b, gi, qsi, 0)      # noqa: E731
-        qrow_idx = lambda b, i, gi, qsi: (b, gi, 0, qsi)   # noqa: E731
+    q_spec = pl.BlockSpec(
+        (1, 1, super_q, d),
+        lambda b, i, gi, qsi: (b, gi, jnp.maximum(qsi, first_live(i)), 0))
+    qrow_spec = pl.BlockSpec(
+        (1, 1, 1, super_q),
+        lambda b, i, gi, qsi: (b, gi, 0, jnp.maximum(qsi, first_live(i))))
+    kv_spec = pl.BlockSpec((1, block_k, d), lambda b, i, gi, qsi: (b, i, 0))
     dk, dv = pl.pallas_call(
-        functools.partial(_fa_bwd_dkv_kernel_stream, causal=causal,
-                          scale=scale, block_q=block_q, offset=tk - tq,
-                          g=g, num_q_super=num_q_super),
-        grid=(bkv, pl.cdiv(tk, block_k), g, num_q_super),
-        in_specs=[
-            pl.BlockSpec((1, 1, super_q, d), q_idx),
-            pl.BlockSpec((1, block_k, d), lambda b, i, gi, qsi: (b, i, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, i, gi, qsi: (b, i, 0)),
-            pl.BlockSpec((1, 1, super_q, d), q_idx),
-            pl.BlockSpec((1, 1, 1, super_q), qrow_idx),
-            pl.BlockSpec((1, 1, 1, super_q), qrow_idx),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block_k, d), lambda b, i, gi, qsi: (b, i, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, i, gi, qsi: (b, i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((bkv, tk, d), k.dtype),
-            jax.ShapeDtypeStruct((bkv, tk, d), v.dtype),
-        ],
-        # dk/dv accumulate over the group AND all q superblocks in f32
-        # scratch (a bf16 += per contribution would round many times);
-        # single cast at the final flush
-        scratch_shapes=[
-            pltpu.VMEM((block_k, d), jnp.float32),
-            pltpu.VMEM((block_k, d), jnp.float32),
-        ],
-        cost_estimate=dkv_cost,
+        functools.partial(_fa_bwd_dkv_kernel, causal=causal, scale=scale,
+                          block_q=block_q, offset=offset, g=g,
+                          num_super=num_super),
+        grid=(bkv, tk // block_k, g, num_super),
+        in_specs=[q_spec, kv_spec, kv_spec, q_spec, qrow_spec, qrow_spec],
+        out_specs=[kv_spec, kv_spec],
+        out_shape=[jax.ShapeDtypeStruct((bkv, tk, d), k.dtype),
+                   jax.ShapeDtypeStruct((bkv, tk, d), v.dtype)],
+        # dk/dv accumulate over the group AND all q tiles in f32 scratch
+        # (a bf16 += per contribution would round many times); single
+        # cast at the final flush
+        scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
+                        pltpu.VMEM((block_k, d), jnp.float32)],
+        cost_estimate=pl.CostEstimate(
+            # 4 matmuls per (q,k) tile pair: s^T, p^T@dO, v@dO^T, ds^T@q
+            flops=8 * bkv * g * tq * tk * d, bytes_accessed=in_bytes,
+            transcendentals=bkv * g * tq * tk),
         interpret=interpret,
-        **kwargs4,
-    )(q, k, v, do, lse, dvec)
+        **_compiler_params(interpret),
+    )(*args)
     return dq, dk, dv
 
 
@@ -789,19 +512,19 @@ def _aligned(t, block):
     return t % min(block, t) == 0
 
 
-# Finest K tile the kernels accept: the CONTRACT is divisibility by this,
-# NOT by BLOCK_K — _pick_block falls back from the preferred (faster)
-# 512-wide tile to 256 for lengths like 768/1280/2816, so raising
-# BLOCK_K never narrows which shapes qualify (ring-attention chunks
-# that are odd multiples of 256 keep their flash path).
-_MIN_TILE_K = 256
+# Finest tile the kernels accept: the CONTRACT is divisibility by this,
+# NOT by the preferred blocks — _pick_block falls back from the preferred
+# (faster) 512 tile to 256 for lengths like 768/1280/2816, so raising a
+# block never narrows which shapes qualify (ring-attention chunks that
+# are odd multiples of 256 keep their flash path).
+_MIN_TILE = 256
 
 
 def _pick_block(t, pref):
-    """Largest tile in {pref, pref/2, ..., _MIN_TILE_K} dividing t
-    (t itself when t < _MIN_TILE_K)."""
+    """Largest tile in {pref, pref/2, ..., _MIN_TILE} dividing t
+    (t itself when t < _MIN_TILE)."""
     b = min(pref, t)
-    while b > _MIN_TILE_K and t % b:
+    while b > _MIN_TILE and t % b:
         b //= 2
     return b
 
@@ -809,15 +532,15 @@ def _pick_block(t, pref):
 def kernel_qualifies(tq, tk, d, compiled=True, causal=False):
     """The kernel's CORRECTNESS contract: sequence lengths divide into
     whole blocks (a ragged final block would read padding into the
-    softmax) — K at the finest `_MIN_TILE_K` granularity (the actual
-    tile is picked per shape by `_pick_block`); the compiled path
+    softmax) at the finest `_MIN_TILE` granularity (the actual tiles
+    are picked per shape by `_pick_block`); the compiled path
     additionally needs a lane-aligned head_dim; causal calls need
     tq <= tk (with tq > tk the first tk-tq query rows are FULLY masked —
     the XLA path's finfo.min masking degrades to uniform attention
     there, while the kernel's l=0 would produce NaN). Shared by
     flash_attention() and ring_attention's per-shard selection so the
     two paths cannot drift."""
-    return (_aligned(tq, BLOCK_Q) and _aligned(tk, _MIN_TILE_K)
+    return (_aligned(tq, _MIN_TILE) and _aligned(tk, _MIN_TILE)
             and (not causal or tq <= tk)
             and (not compiled or d % 128 == 0))
 

@@ -479,3 +479,164 @@ def _run_streaming_cases(fa, rng, B, D, cases):
             np.testing.assert_allclose(
                 np.asarray(a), np.asarray(b), rtol=2e-3, atol=5e-4,
                 err_msg="%s %s" % (name, (h, hkv, tq, tk, causal)))
+
+
+# (h, hkv, tq, tk, causal, dtype, blocks (BLOCK_Q, BLOCK_K) or None for the
+# module's, streaming (_RESIDENT_MAX, SUPER_TARGET) or None for resident)
+_FLASH_CASES = {
+    # 3 x 3 tiles of 256: q-block 0 walks one diagonal tile, q-block 2 two
+    # interior tiles and a diagonal one; k-block 0 the same way round in dkv
+    "causal_diagonal_and_interior_tiles":
+        (2, 2, 768, 768, True, "float32", (256, 256), None),
+    # offset = tk - tq = 384, no multiple of block_k 256: the diagonal
+    # crosses the middle of a key tile
+    "causal_offset_inside_a_key_tile":
+        (2, 2, 128, 512, True, "float32", (256, 256), None),
+    "causal_offset_rectangular_tiles":
+        (2, 1, 256, 1024, True, "float32", (256, 512), None),
+    # the module's own 512 x 512 tiles, 2 x 2 of them
+    "causal_default_tiles": (1, 1, 1024, 1024, True, "float32", None, None),
+    "noncausal_default_tiles": (2, 1, 512, 1024, False, "float32", None,
+                                None),
+    "group1_noncausal": (2, 2, 512, 512, False, "float32", (256, 256), None),
+    "group3_causal": (6, 2, 512, 512, True, "float32", (256, 256), None),
+    "mqa_noncausal_one_query_tile":
+        (4, 1, 256, 768, False, "float32", (256, 256), None),
+    # one grid step is one superblock of two tiles; the state crosses
+    # supersteps and group heads in the same VMEM scratch
+    "streaming_causal_gqa_offset":
+        (4, 2, 512, 1024, True, "float32", (256, 256), (256, 512)),
+    "streaming_noncausal_group1":
+        (2, 2, 1024, 512, False, "float32", (256, 256), (256, 512)),
+    "streaming_causal_one_tile_superblocks":
+        (2, 1, 768, 768, True, "float32", (256, 256), (256, 256)),
+    # bfloat16 in, bfloat16 out: against the bf16 XLA path
+    "bf16_causal_gqa": (4, 2, 512, 512, True, "bfloat16", (256, 256), None),
+    "bf16_noncausal_default_tiles":
+        (2, 2, 512, 1024, False, "bfloat16", None, None),
+    "bf16_streaming_causal":
+        (2, 1, 512, 1024, True, "bfloat16", (256, 256), (256, 512)),
+}
+
+
+def _flash_case_setup(monkeypatch, fa, blocks, streaming):
+    if blocks:
+        monkeypatch.setattr(fa, "BLOCK_Q", blocks[0])
+        monkeypatch.setattr(fa, "BLOCK_K", blocks[1])
+    if streaming:
+        monkeypatch.setattr(fa, "_RESIDENT_MAX", streaming[0])
+        monkeypatch.setattr(fa, "SUPER_TARGET", streaming[1])
+
+
+@pytest.mark.parametrize("case", sorted(_FLASH_CASES))
+def test_pallas_flash_cases_match_xla(case, monkeypatch):
+    """Forward and all three gradients of the flash kernels against the
+    XLA reference, one case per way the tile walk can go: which tiles a
+    block visits, where the diagonal crosses them, the group's sum in
+    dk/dv, the superblock regime, and bfloat16 operands."""
+    from mxnet_tpu.ops.pallas import flash_attention as fa
+    from mxnet_tpu.ops.attention import _grouped_attention
+
+    h, hkv, tq, tk, causal, dtype, blocks, streaming = _FLASH_CASES[case]
+    _flash_case_setup(monkeypatch, fa, blocks, streaming)
+    rng = np.random.RandomState(sorted(_FLASH_CASES).index(case))
+    B, D = 1, 8
+    q = jnp.asarray(rng.randn(B, h, tq, D).astype(np.float32), dtype)
+    k = jnp.asarray(rng.randn(B, hkv, tk, D).astype(np.float32), dtype)
+    v = jnp.asarray(rng.randn(B, hkv, tk, D).astype(np.float32), dtype)
+
+    def loss(att):
+        return lambda q, k, v: jnp.sum(
+            att(q, k, v).astype(jnp.float32) ** 2)
+
+    def kernel(q, k, v):
+        return fa.flash_attention(q, k, v, causal=causal, interpret=True)
+
+    def ref(q, k, v):
+        return _grouped_attention(q, k, v, hkv, causal)
+
+    # float32: exact up to summation order; bfloat16: the XLA path rounds
+    # its probabilities to bfloat16 and every result is stored in it
+    tol = (dict(rtol=2e-4, atol=2e-4), dict(rtol=2e-3, atol=5e-4)) \
+        if dtype == "float32" else (dict(rtol=2e-2, atol=2e-2),
+                                    dict(rtol=4e-2, atol=6e-2))
+    got, want = kernel(q, k, v), ref(q, k, v)
+    assert got.dtype == q.dtype and got.shape == q.shape
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want, np.float32),
+                               err_msg="fwd", **tol[0])
+    gk = jax.grad(loss(kernel), argnums=(0, 1, 2))(q, k, v)
+    gr = jax.grad(loss(ref), argnums=(0, 1, 2))(q, k, v)
+    assert gk[1].shape == k.shape and gk[1].dtype == k.dtype
+    for name, a, b in zip("qkv", gk, gr):
+        np.testing.assert_allclose(np.asarray(a, np.float32),
+                                   np.asarray(b, np.float32),
+                                   err_msg="d" + name, **tol[1])
+
+
+@pytest.mark.parametrize("case", ["resident_causal_gqa",
+                                  "resident_noncausal",
+                                  "streaming_causal_offset"])
+def test_pallas_flash_with_lse_cotangent(case, monkeypatch):
+    """`_flash_with_lse` (ring attention's per-shard call): lse is a real
+    output, and a non-zero cotangent on it folds into D in the backward.
+    A loss over BOTH outputs against the same loss through plain jnp."""
+    from mxnet_tpu.ops.pallas import flash_attention as fa
+
+    g, tq, tk, causal, streaming = {
+        "resident_causal_gqa": (2, 512, 512, True, None),
+        "resident_noncausal": (1, 256, 512, False, None),
+        "streaming_causal_offset": (2, 512, 1024, True, (256, 512)),
+    }[case]
+    _flash_case_setup(monkeypatch, fa, (256, 256), streaming)
+    rng = np.random.RandomState(3)
+    rows, D = 2, 8
+    scale = 0.4
+    q = jnp.asarray(rng.randn(rows, g, tq, D).astype(np.float32))
+    k = jnp.asarray(rng.randn(rows, tk, D).astype(np.float32))
+    v = jnp.asarray(rng.randn(rows, tk, D).astype(np.float32))
+    w = jnp.asarray(rng.randn(rows, g, 1, tq).astype(np.float32))
+
+    def ref(q, k, v):
+        s = jnp.einsum("rgqd,rkd->rgqk", q, k) * scale
+        if causal:
+            keep = (jnp.arange(tq)[:, None] + (tk - tq)
+                    >= jnp.arange(tk)[None, :])
+            s = jnp.where(keep, s, -1e30)
+        lse = jax.nn.logsumexp(s, axis=-1)
+        out = jnp.einsum("rgqk,rkd->rgqd", jnp.exp(s - lse[..., None]), v)
+        return out, lse[:, :, None, :]
+
+    def kernel(q, k, v):
+        return fa._flash_with_lse(q, k, v, causal, scale, True)
+
+    def loss(f):
+        def fn(q, k, v):
+            out, lse = f(q, k, v)
+            return jnp.sum(out ** 2) + jnp.sum(w * lse)
+        return fn
+
+    (out, lse), (out_r, lse_r) = kernel(q, k, v), ref(q, k, v)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(out_r),
+                               rtol=2e-4, atol=2e-4)
+    np.testing.assert_allclose(np.asarray(lse), np.asarray(lse_r),
+                               rtol=2e-4, atol=2e-4)
+    gk = jax.grad(loss(kernel), argnums=(0, 1, 2))(q, k, v)
+    gr = jax.grad(loss(ref), argnums=(0, 1, 2))(q, k, v)
+    for name, a, b in zip("qkv", gk, gr):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=2e-3,
+                                   atol=5e-4, err_msg="d" + name)
+
+
+@pytest.mark.parametrize("t,pref,want", [
+    (4096, 512, 512), (768, 512, 256), (1280, 512, 256), (2816, 512, 256),
+    (1024, 512, 512), (128, 512, 128), (512, 256, 256)])
+def test_pallas_flash_pick_block(t, pref, want):
+    """The tile is the largest of {pref, pref/2, ..., 256} that divides
+    the length (the length itself under 256): raising the preferred tile
+    never narrows which lengths the kernels take."""
+    from mxnet_tpu.ops.pallas import flash_attention as fa
+
+    assert fa._pick_block(t, pref) == want
+    assert fa.kernel_qualifies(t, t, 128)
+    assert t % want == 0

@@ -1,0 +1,72 @@
+"""Compile the Pallas kernels of the main path for a DESCRIBED v5e, at real
+widths: what interpret mode cannot see (a block over the scoped-VMEM
+limit, a slice off the tiling, a layout Mosaic refuses) fails here, at no
+chip time. Nothing runs, so nothing here is a result or a time.
+
+All such tests live in THIS file: the worker that gets it loads the TPU's
+library and keeps it, and no other may (on-chip-measurement guide, section
+2). The topology is described inside a fixture, never at import.
+"""
+import os
+
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler in this installation
+        pytest.skip("no v5e:2x2 topology can be described here: %s" % e)
+    return SingleDeviceSharding(topo.devices[0])
+
+
+# (rows = batch * kv heads, group, tq, tk, causal, dtype)
+_FLASH_SHAPES = {
+    # the benchmark cell lm_train_4k: 2 x 2 KV heads, 12 query heads each
+    "lm_train_4k": (4, 12, 4096, 4096, True, "bfloat16"),
+    # the longest resident sequence, in the widest dtype: the VMEM wall
+    "resident_8192_f32": (1, 2, 8192, 8192, False, "float32"),
+    "streaming_16384": (1, 2, 16384, 16384, True, "bfloat16"),
+    # serving prefill against a cache: tq < tk, forward without lse too
+    "prefill_512_of_4096": (2, 12, 512, 4096, True, "bfloat16"),
+    # an odd multiple of 256 falls back to 256-wide tiles
+    "odd_multiple_768": (2, 1, 768, 1280, True, "bfloat16"),
+}
+
+
+@pytest.mark.parametrize("name", sorted(_FLASH_SHAPES))
+def test_flash_kernels_compile_for_v5e(name, one_chip):
+    from mxnet_tpu.ops.pallas import flash_attention as fa
+
+    rows, g, tq, tk, causal, dtype = _FLASH_SHAPES[name]
+    d = 128
+
+    def sds(shape, dt=dtype):
+        return jax.ShapeDtypeStruct(shape, jnp.dtype(dt), sharding=one_chip)
+
+    q, kv = sds((rows, g, tq, d)), sds((rows, tk, d))
+    lse = sds((rows, g, 1, tq), "float32")
+    scale = d ** -0.5
+
+    def fwd(q, k, v):
+        return (fa._fa_forward(q, k, v, causal, scale, False),
+                fa._fa_forward(q, k, v, causal, scale, False, with_lse=True))
+
+    def bwd(q, k, v, o, lse, do):
+        return fa._fa_backward(q, k, v, o, lse, do, causal, scale, False)
+
+    text = jax.jit(fwd).lower(q, kv, kv).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    text = jax.jit(bwd).lower(q, kv, kv, q, lse, q).compile().as_text()
+    # dq and dkv stay two kernels: the benchmark's roofline counts three
+    # flash calls a layer (benchmark/lib/counts.py flash_calls)
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
