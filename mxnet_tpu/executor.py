@@ -37,19 +37,42 @@ from .analysis import compile_witness as _witness
 from .base import MXNetError
 from .context import Context, default_context
 from .ndarray import NDArray
+from .ops.matrix import gathered_rows_as
 
 
-def _cast_floats(tree, dtype, src=None):
+def _cast_floats(tree, dtype, src=None, skip=()):
     """Cast float leaves of a list/dict tree to dtype (inside jit, so XLA
     fuses the converts into neighbouring ops). Only leaves of dtype `src`
-    (default float32) are touched, so integer/bool leaves pass through."""
+    (default float32) are touched, so integer/bool leaves pass through, and
+    so do the entries of a dict tree that `skip` names."""
     src = jnp.float32 if src is None else jnp.dtype(src)
 
     def cast(v):
         if hasattr(v, "dtype") and v.dtype == src:
             return v.astype(dtype)
         return v
+    if skip:
+        return {k: v if k in skip else cast(v) for k, v in tree.items()}
     return jax.tree_util.tree_map(cast, tree)
+
+
+def _gathered_only(symbol):
+    """The argument leaves of ``symbol`` that nothing reads but the
+    ``weight`` input of an ``Embedding``. Casting such a table is a pass
+    over the whole of it for the sake of the few rows a step gathers; the
+    fused step leaves it in its master dtype and ``Embedding`` casts the
+    rows (ops/matrix.py ``gathered_rows_as``)."""
+    only = {}
+    for node in symbol._nodes():
+        if node.is_var:
+            continue
+        for pos, (child, _) in enumerate(node.inputs):
+            if child.is_var and not child.is_aux:
+                gathered = (node.op.name == "Embedding" and
+                            node.op.get_arg_names(node.attrs)[pos] == "weight")
+                only[child.name] = only.get(child.name, True) and gathered
+    heads = {node.name for node, _ in symbol._entries if node.is_var}
+    return frozenset(n for n, ok in only.items() if ok and n not in heads)
 
 
 def _under_mesh(eval_fn, mesh):
@@ -301,6 +324,7 @@ class Executor:
         grad_names = list(self._grad_names_list())
         data_names = [n for n in self._arg_names if n not in set(grad_names)]
         cd = self._compute_dtype
+        tables = _gathered_only(self._symbol) if cd is not None else ()
         chain = max(1, int(chain))
         from .parallel import collectives as _coll
         stage = _coll.sharded_stage(mesh, shard_axis)
@@ -330,9 +354,10 @@ class Executor:
                 av.update(full)
                 auxv = aux_values
                 if cd is not None:
-                    av = _cast_floats(av, cd)
+                    av = _cast_floats(av, cd, skip=tables)
                     auxv = _cast_floats(auxv, cd)
-                outs, aux_up = eval_fn(av, auxv, True, rng)
+                with gathered_rows_as(cd):
+                    outs, aux_up = eval_fn(av, auxv, True, rng)
                 if cd is not None:
                     outs = _cast_floats(outs, jnp.float32, src=cd)
                     aux_up = _cast_floats(aux_up, jnp.float32, src=cd)
@@ -461,6 +486,11 @@ class Executor:
                 aot["gather_bytes"] = sum(
                     int(a.size * jnp.dtype(a.dtype).itemsize)
                     for a in jax.tree_util.tree_leaves(params))
+                # what _cast_floats would have cast and, being a table
+                # that only gathers read, is left in its master dtype
+                aot["uncast_table_bytes"] = sum(
+                    4 * int(a.size) for n, a in {**dv, **params}.items()
+                    if n in tables and a.dtype == jnp.float32)
                 aot["gauges"] = True
             if use_auto:
                 if not aot.get("informats"):
@@ -595,13 +625,17 @@ class Executor:
             # compile listener says on both whether, and for how long, jit
             # traced, lowered, compiled or read its cache inside them
             self._train_steps += 1
-            gather = aot.get("gather_bytes")  # reckoned on the first call
+            # both byte counts are reckoned on the first call
+            counts = ("gather_bytes", "uncast_table_bytes")
+            known = {k: aot[k] for k in counts if k in aot}
             with _telemetry.span("executor.train_step", domain="executor",
                                  step=self._train_steps, chain=chain,
-                                 stage=stage, gather_bytes=gather or 0) as sp:
+                                 stage=stage,
+                                 **(known or dict.fromkeys(counts, 0))) as sp:
                 out = _run_impl(params, states, data_values, *extra)
-                if gather is None:
-                    sp.add("gather_bytes", aot.get("gather_bytes", 0))
+                if not known:
+                    for k in counts:
+                        sp.add(k, aot.get(k, 0))
                 return out
 
         # trace-and-fuse metadata (engine.FuseOp): the pure `step` plus the

@@ -63,17 +63,19 @@ def get_symbol(num_classes=32000, seq_len=512, num_layers=4, num_heads=8,
                model_dim=512, ffn_dim=2048, num_kv_heads=0, use_flash=None,
                scalar_loss=False, **kwargs):
     """Decoder LM symbol. scalar_loss=True emits a MakeLoss mean-NLL head
-    instead of SoftmaxOutput — the (batch*seq, vocab) probability output
-    is the right inference surface but costs a fresh device buffer per
-    step, which benchmark/training loops that only need the loss avoid
-    (docs/perf.md LSTM caveat)."""
+    (output ``loss``) instead of SoftmaxOutput — the (batch*seq, vocab)
+    probability output is the right inference surface but costs a fresh
+    device buffer per step, which benchmark/training loops that only need
+    the loss avoid (docs/perf.md LSTM caveat). The head is
+    ``softmax_cross_entropy`` (the closed form: a float32 logsumexp over
+    the vocabulary less the label's logit, and a backward that builds no
+    one-hot) over the number of rows, which is counted in float32 from the
+    label's shape and folds to a constant."""
     pred = _backbone(num_classes, num_layers, num_heads, model_dim, ffn_dim,
                      num_kv_heads, use_flash)
     label = sym.Reshape(data=sym.Variable('softmax_label'), shape=(-1,))
     if scalar_loss:
-        logp = sym.log_softmax(pred, axis=-1)
-        onehot = sym.one_hot(label, depth=num_classes)
-        nll = sym._mul_scalar(
-            sym.mean(sym.sum(sym._mul(logp, onehot), axis=1)), scalar=-1.0)
+        rows = sym.sum(sym.ones_like(sym.Cast(label, dtype='float32')))
+        nll = sym._div(sym.softmax_cross_entropy(pred, label), rows)
         return sym.MakeLoss(nll, name='loss')
     return sym.SoftmaxOutput(data=pred, label=label, name='softmax')

@@ -7,6 +7,10 @@ the MXU primitive.
 """
 from __future__ import annotations
 
+import contextlib
+import functools
+import threading
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -269,6 +273,49 @@ alias("Pad", "pad")
 
 
 # --- indexing (reference indexing_op.cc) ------------------------------------
+_tracing = threading.local()
+
+
+@contextlib.contextmanager
+def gathered_rows_as(dtype):
+    """Entered by the executor around the trace of a graph it computes in
+    ``dtype`` (None: as stored). A float32 table that reaches ``Embedding``
+    under it is one the executor left in its master dtype because only
+    gathers read it: the rows are cast, not the table. Trace-time Python
+    state only: nothing here reaches the program."""
+    prev = getattr(_tracing, "rows", None)
+    _tracing.rows = dtype
+    try:
+        yield
+    finally:
+        _tracing.rows = prev
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
+def _rows_as(weight, ids, dtype):
+    return jnp.take(weight, ids, axis=0).astype(dtype)
+
+
+def _rows_as_fwd(weight, ids, dtype):
+    # the table itself is no residual: an empty slice carries its height
+    return _rows_as(weight, ids, dtype), (ids, weight[:, :0])
+
+
+def _rows_as_bwd(dtype, res, g):
+    # the rows' cotangent scatter-adds into a table of the compute dtype,
+    # as it did when the table itself was cast, and widens where the
+    # update reads it
+    ids, height = res
+    table = jax.ShapeDtypeStruct(
+        height.shape[:1] + g.shape[ids.ndim:], g.dtype)
+    (dw,) = jax.linear_transpose(
+        lambda w: jnp.take(w, ids, axis=0), table)(g)
+    return dw.astype(height.dtype), None
+
+
+_rows_as.defvjp(_rows_as_fwd, _rows_as_bwd)
+
+
 @defop(
     "Embedding",
     arg_names=("data", "weight"),
@@ -278,7 +325,11 @@ alias("Pad", "pad")
 def _embedding(attrs, data, weight):
     """Table lookup; backward is a scatter-add handled by jax.vjp of take
     (reference indexing_op.cc Embedding + EmbeddingOpBackward)."""
-    return jnp.take(weight, data.astype(jnp.int32), axis=0)
+    ids = data.astype(jnp.int32)
+    dtype = getattr(_tracing, "rows", None)
+    if dtype is not None and weight.dtype == jnp.float32:
+        return _rows_as(weight, ids, dtype)
+    return jnp.take(weight, ids, axis=0)
 
 
 @defop("take", arg_names=("a", "indices"), param_spec={"axis": 0, "mode": "clip"}, no_grad_inputs=("indices",))

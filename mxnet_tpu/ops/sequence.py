@@ -77,6 +77,41 @@ def _sequence_reverse(attrs, data, sequence_length=None):
     )
 
 
+@jax.custom_vjp
+def _summed_nll(logits, label):
+    """sum over rows of logsumexp(row) - row[label], in closed form: the
+    vocabulary is read for the float32 logsumexp and for nothing else (the
+    label's logit is a gather of one number a row), and the backward is
+    written out, so that neither a one-hot array nor a log_softmax array
+    exists and the cotangent is never reduced over the vocabulary: the
+    gradient is the one array the backward writes."""
+    return _summed_nll_fwd(logits, label)[0]
+
+
+def _summed_nll_fwd(logits, label):
+    lse = jax.nn.logsumexp(logits.astype(jnp.float32), axis=-1)
+    picked = jnp.take_along_axis(logits, label[:, None], axis=1)[:, 0]
+    # the scalar leaves in float32, as it was summed: a bfloat16 sum over
+    # 8192 rows would step by 512
+    nll = jnp.sum(lse - picked.astype(jnp.float32))
+    return nll, (logits, label, lse)
+
+
+def _summed_nll_bwd(res, g):
+    logits, label, lse = res
+    p = jnp.exp(logits.astype(jnp.float32) - lse[:, None])
+    col = jax.lax.broadcasted_iota(jnp.int32, logits.shape, 1)
+    d = g * (p - (col == label[:, None]))
+    # written once: a head multiplies it twice and sums it once, and XLA
+    # would put the exp above into the prologue of each. On the v5e that
+    # held the two matmuls at 16.9 and 14.4 ms a step where they take 13.5
+    # on the stored array, which costs 2.4 ms to write (PERF.md, PR 30)
+    return jax.lax.optimization_barrier(d.astype(logits.dtype)), None
+
+
+_summed_nll.defvjp(_summed_nll_fwd, _summed_nll_bwd)
+
+
 @defop(
     "softmax_cross_entropy",
     arg_names=("data", "label"),
@@ -84,7 +119,6 @@ def _sequence_reverse(attrs, data, sequence_length=None):
     no_grad_inputs=("label",),
 )
 def _softmax_cross_entropy(attrs, data, label):
-    """Scalar summed cross-entropy (reference loss_binary_op.cc)."""
-    logp = jax.nn.log_softmax(data, axis=-1)
-    picked = jnp.take_along_axis(logp, label.astype(jnp.int32).reshape(-1, 1), axis=1)
-    return -jnp.sum(picked)
+    """Scalar summed cross-entropy (reference loss_binary_op.cc), float32
+    whatever the logits' dtype."""
+    return _summed_nll(data, label.astype(jnp.int32).reshape(-1))

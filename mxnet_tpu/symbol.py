@@ -566,10 +566,13 @@ class Symbol:
                 node_rng = None
                 if op.needs_rng:
                     node_rng = jax.random.fold_in(rng, ni)
-                outs, aux_out = op.impl(
-                    attrs, tuple(vals[:n_args]), tuple(vals[n_args:]),
-                    OpContext(is_train, node_rng),
-                )
+                # the node's name on every device operation traced from it:
+                # the profiler and the compiled text say whose a fusion is
+                with jax.named_scope(node.name):
+                    outs, aux_out = op.impl(
+                        attrs, tuple(vals[:n_args]), tuple(vals[n_args:]),
+                        OpContext(is_train, node_rng),
+                    )
                 for i, o in enumerate(outs):
                     env[(id(node), i)] = o
                     if i in tagged_out:
@@ -663,9 +666,11 @@ class Symbol:
                                 [(id(c), i) in tags for c, i in node.inputs])
                         node_rng = (jax.random.fold_in(c_rng, ni)
                                     if op.needs_rng else None)
-                        outs, aux_out = op.impl(
-                            attrs, tuple(vals[:n_args]), tuple(vals[n_args:]),
-                            OpContext(is_train, node_rng))
+                        with jax.named_scope(node.name):
+                            outs, aux_out = op.impl(
+                                attrs, tuple(vals[:n_args]),
+                                tuple(vals[n_args:]),
+                                OpContext(is_train, node_rng))
                         for i, o in enumerate(outs):
                             local[(id(node), i)] = o
                             if i in tagged_out:

@@ -7,6 +7,7 @@ All such tests live in THIS file: the worker that gets it loads the TPU's
 library and keeps it, and no other may (on-chip-measurement guide, section
 2). The topology is described inside a fixture, never at import.
 """
+import importlib.util
 import os
 
 import pytest
@@ -70,3 +71,34 @@ def test_flash_kernels_compile_for_v5e(name, one_chip):
     # dq and dkv stay two kernels: the benchmark's roofline counts three
     # flash calls a layer (benchmark/lib/counts.py flash_calls)
     assert text.count('custom_call_target="tpu_custom_call"') == 2
+
+
+def test_fused_lm_step_makes_no_needless_pass_over_the_vocabulary(one_chip):
+    """The fused step of ``lm_train_4k`` at one layer, as the chip's compiler
+    builds it (``tools/step_ops.py``): besides the logits and their
+    gradient, written once, no operation writes a (rows, vocabulary)
+    array: no one-hot, no log_softmax. No operation casts the
+    embedding table, which is gathered from in its master dtype (the head's
+    weight is cast inside the fusions that multiply by it). The three flash
+    kernels are there."""
+    spec = importlib.util.spec_from_file_location("step_ops", os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        "tools", "step_ops.py"))
+    step_ops = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(step_ops)
+    cfg = {"vocab_size": 49152, "hidden_size": 3072, "num_hidden_layers": 1,
+           "intermediate_size": 12288, "num_attention_heads": 24,
+           "num_key_value_heads": 2}
+    traffic = {"batch": 2, "seq_len": 4096, "compute_dtype": "bfloat16",
+               "optimizer": {"learning_rate": 0.01, "momentum": 0.9}}
+    compiled, sym = step_ops.compile_step(cfg, traffic)
+    ops = step_ops.device_ops(compiled.as_text(), step_ops.node_groups(sym))
+    over_vocab = [o["name"] for o in ops if "[8192,49152]" in o["result"]]
+    assert len(over_vocab) == 2, over_vocab
+    casts = [o["name"] for o in ops if o["result"] == "bf16[49152,3072]"
+             and "f32[49152,3072]" in o["operands"]]
+    assert not casts, casts
+    assert sum(o["kernel"] for o in ops) == 3
+    groups = {o["group"] for o in ops}
+    assert {"head and loss", "embedding", "feed-forward", "flash",
+            "attention projections", "norms"} <= groups
