@@ -45,7 +45,7 @@ import numpy as np
 
 @dataclasses.dataclass(frozen=True)
 class Sizes:
-    # the model (bench.py's transformer cell)
+    # the model
     d_model: int = 2048
     heads: int = 16
     kv_heads: int = 4

@@ -70,16 +70,6 @@ print('sanitizer: 0 reports (decode)')"
 # dir must serve the same traffic with 0 fresh bucket compiles (ladder
 # disk-loaded before traffic) and bitwise-identical outputs.
 JAX_PLATFORMS=cpu python -c "import __graft_entry__ as g; g.dryrun_progcache()"
-# Trace-and-fuse gate (MXNET_ENGINE_FUSE): the same 8 identically-seeded
-# train steps run eager, captured/replayed, and captured+fused — final
-# weights must be BITWISE identical across all three; the fused arms run
-# under MXNET_ENGINE_SANITIZER=1 with zero reports; and a warm process
-# over the same progcache dir must disk-load the fused executable with
-# zero fresh fuse compiles. A second pass repeats replay/fused/warm at
-# ZeRO stage 3 (ISSUE 20): the sharded step must STAGE (fused_runs > 0,
-# no bail), match replay bitwise, and warm-restart from the progcache
-# with 0 fresh fused compiles under MXNET_COMPILE_WITNESS=1.
-JAX_PLATFORMS=cpu python -c "import __graft_entry__ as g; g.dryrun_fuse()"
 # Quantized-inference gate (ISSUE 14): int8-weight + int8-KV paged decode
 # streams must be bitwise-identical to sequential quantized generation and
 # track the f32 arm's greedy tokens (first-token exact, LCP >= 60%) inside
@@ -141,9 +131,9 @@ timeout -k 5 15 env JAX_PLATFORMS=cpu python -m mxnet_tpu.analysis --fail-on-new
 # Self-check: the known-bad fixtures must trip the gate (a silently
 # lobotomized analyzer would otherwise pass CI forever).
 for bad in abba_deadlock undeclared_mutable impure_jit telemetry_in_jit \
-        capture_unstable raw_write_progcache fuse_ineligible \
-        undeclared_var_access unfenced_host_read var_use_after_delete \
-        weight_closure stray_jit donated_arg_reuse undeclared_budget; do
+        raw_write_progcache undeclared_var_access unfenced_host_read \
+        var_use_after_delete weight_closure stray_jit donated_arg_reuse \
+        undeclared_budget; do
     if JAX_PLATFORMS=cpu python -m mxnet_tpu.analysis \
             --root "tests/fixtures/analysis/${bad}.py" \
             --baseline none --fail-on-new >/dev/null 2>&1; then

@@ -49,8 +49,7 @@ def score(network, batch, dtype, iters, dev):
     for _ in range(3):
         outs = exe.forward(is_train=False)
     sync(outs)
-    # median-of-N (best-of-N over-reports under contention noise; same
-    # discipline as bench.py)
+    # median-of-N (best-of-N over-reports under contention noise)
     times = []
     for _ in range(max(1, int(float(os.environ.get("BENCH_REPEATS", "3"))))):
         t0 = time.perf_counter()

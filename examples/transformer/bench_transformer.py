@@ -348,7 +348,7 @@ def lm_train(args, use_flash, num_kv_heads=0, remat=False, steps=None,
         return _lm_train_inner(args, use_flash, num_kv_heads, steps, quiet)
     finally:
         # never strip a USER-set env var, and never leak ours past an
-        # OOM (same contract as bench.py run_config)
+        # OOM
         if _remat_set_here:
             os.environ.pop("MXNET_BACKWARD_DO_MIRROR", None)
 
@@ -412,10 +412,10 @@ def lm_mfu(sym, batch, seq, step_s):
     """Model FLOPs utilization of one training step: analytic matmul
     FLOPs over the LM graph (flops.count_flops — FC projections + the
     MultiHeadAttention node at its USEFUL causal count), 3x for the
-    training step, against the chip's nominal bf16 peak. Same guards as
-    bench.py's ResNet headline: None (not a number) on the CPU backend
-    and for non-bf16 compute (the bf16 denominator would be wrong), and
-    the BENCH_PEAK_TFLOPS calibration override is honored. An accelerator
+    training step, against the chip's nominal bf16 peak. None (not a
+    number) on the CPU backend and for non-bf16 compute (the bf16
+    denominator would be wrong), and the BENCH_PEAK_TFLOPS calibration
+    override is honored. An accelerator
     missing from flops.CHIP_PEAK_BF16 raises."""
     import jax
     from mxnet_tpu import flops as _flops

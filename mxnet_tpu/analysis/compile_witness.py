@@ -3,16 +3,16 @@
 ``MXNET_COMPILE_WITNESS=1`` arms a process-wide recorder that every
 sanctioned compile surface (``predict.Predictor._compile``,
 ``quant.QuantizedPredictor._compile``, ``serving.generate.programs``,
-``engine.FusedSequence``, the executor train-step AOT path, and
-``progcache.load``) reports into: each fresh XLA compile is recorded with
-(kind, key, shapes, stack), each persistent-progcache disk load with
-(kind, key). After :func:`steady_state` is called — the phase marker a
-server flips once warmup is done — ANY fresh compile is a violation:
+the executor train-step AOT path, and ``progcache.load``) reports into:
+each fresh XLA compile is recorded with (kind, key, shapes, stack), each
+persistent-progcache disk load with (kind, key). After
+:func:`steady_state` is called — the phase marker a server flips once
+warmup is done — ANY fresh compile is a violation:
 the recompile storm the bounded-program invariant forbids, caught with
 the stack that caused it instead of a latency cliff in production.
 
 Disabled (the default) every hook is one branch-and-return, mirroring the
-telemetry discipline; the bench serving arm gates the overhead at <1%.
+telemetry discipline.
 
 Locking: ``_lock`` is a LEAF (rank 100 in
 :data:`.lockorder.LOCK_HIERARCHY`) guarding only the record tables —

@@ -58,7 +58,6 @@ SANCTIONED_SURFACES: Tuple[str, ...] = (
     "Executor._get_fwd",
     "Executor._get_fwd_bwd",
     "Executor.make_train_step",
-    "FusedSequence",
 )
 
 #: Declared program budgets: sanctioned surface id -> the ladder+k bound
@@ -93,11 +92,6 @@ PROGRAM_BUDGETS: Dict[str, str] = {
     "executor.Executor.make_train_step":
         "1 per (update_fn, chain, avals) — the fused train step; "
         "chain-K folds K sub-steps into the one program.",
-    "engine.FusedSequence":
-        "1 per stabilized capture signature, progcache-keyed by the "
-        "fused lowered text; carry/feed avals fold in the committed "
-        "sharding signature, so a ZeRO stage or mesh change is a new "
-        "signature (re-stage), never a silent respecialization.",
 }
 
 #: names whose presence as a traced-fn FREE variable means weights are

@@ -25,23 +25,6 @@ other pushed op. Rules:
                                    element, i.e. a hand-rolled multi-var
                                    fence — ``engine.fence(vars)`` is one
                                    pushed op and also fences callbacks
-- ``capture-unstable-push``        a push on a ``CapturedSequence`` whose
-                                   var list names a container mutated in
-                                   the same function — the mutated list
-                                   changes the recorded signature between
-                                   iterations, so the capture silently
-                                   never stabilizes (or replay-bails
-                                   every step); snapshot with
-                                   ``tuple(...)`` before pushing
-- ``fuse-ineligible-op``           in a module that consumes
-                                   ``MXNET_ENGINE_FUSE`` (references
-                                   ``fuse_enabled``/the env var), a
-                                   capture-region push carries no
-                                   ``fuse=`` metadata — one such op marks
-                                   the whole sequence fuse-ineligible and
-                                   it silently stays on replay; pass
-                                   ``fuse=engine.FuseOp(...)`` or an
-                                   explicit ``fuse=None`` to opt out
 
 Only *engine* pushes are matched (``push_async`` anywhere; ``push`` only
 via an engine module alias / ``self._engine`` / an import from engine) so
@@ -106,61 +89,6 @@ def _has_var_decl(call: ast.Call) -> bool:
                for kw in call.keywords)
 
 
-def _capture_seq_names(fn: ast.AST) -> Set[str]:
-    """Names bound to a ``CapturedSequence(...)`` construction (locals and
-    self-attributes) — the receivers that open a capture region."""
-    names: Set[str] = set()
-    for node in ast.walk(fn):
-        if isinstance(node, ast.Assign) and isinstance(node.value, ast.Call):
-            d = dotted(node.value.func)
-            if d is not None and d.split(".")[-1] == "CapturedSequence":
-                for t in node.targets:
-                    if isinstance(t, ast.Name):
-                        names.add(t.id)
-                    elif isinstance(t, ast.Attribute):
-                        names.add(t.attr)
-    return names
-
-
-def _bare_list_names(call: ast.Call) -> Set[str]:
-    """Bare Names passed AS a const_vars/mutable_vars expression or as a
-    direct list/tuple element of one — the spellings where a mutated
-    container flows straight into the recorded signature. Names nested
-    under attributes (``rep.var``) are vars, not containers: skipped."""
-    exprs: List[ast.AST] = list(call.args[1:3])
-    for kw in call.keywords:
-        if kw.arg in ("const_vars", "mutable_vars"):
-            exprs.append(kw.value)
-    names: Set[str] = set()
-    for e in exprs:
-        if isinstance(e, ast.Name):
-            names.add(e.id)
-        elif isinstance(e, (ast.List, ast.Tuple)):
-            for el in e.elts:
-                if isinstance(el, ast.Name):
-                    names.add(el.id)
-    return names
-
-
-def _container_mutations(fn: ast.AST) -> Dict[str, int]:
-    """bare name -> first line where it is container-mutated in ``fn``
-    (mutator method call, subscript store, or augmented assignment)."""
-    muts: Dict[str, int] = {}
-    for node in ast.walk(fn):
-        if isinstance(node, ast.Call) and \
-                isinstance(node.func, ast.Attribute) and \
-                node.func.attr in _MUTATORS and \
-                isinstance(node.func.value, ast.Name):
-            muts.setdefault(node.func.value.id, node.lineno)
-        elif isinstance(node, (ast.Assign, ast.AugAssign)):
-            targets = getattr(node, "targets", None) or [node.target]
-            for t in targets:
-                if isinstance(t, ast.Subscript) and \
-                        isinstance(t.value, ast.Name):
-                    muts.setdefault(t.value.id, node.lineno)
-    return muts
-
-
 def _store_base(node: ast.AST) -> Optional[str]:
     """Innermost Name of a subscript/attribute store target."""
     seen_deref = False
@@ -223,31 +151,14 @@ def _closure_mutations(fn: ast.AST) -> List[Tuple[str, int]]:
             if n not in params and n not in local and n != "self"]
 
 
-def _module_consumes_fuse(tree: ast.AST) -> bool:
-    """True when the module opts captured sequences into trace-and-fuse:
-    it references ``fuse_enabled`` (the engine gate) or spells the
-    ``MXNET_ENGINE_FUSE`` env var itself."""
-    for node in ast.walk(tree):
-        if isinstance(node, ast.Name) and node.id == "fuse_enabled":
-            return True
-        if isinstance(node, ast.Attribute) and node.attr == "fuse_enabled":
-            return True
-        if isinstance(node, ast.Constant) and \
-                node.value == "MXNET_ENGINE_FUSE":
-            return True
-    return False
-
-
 class _FnLint:
     def __init__(self, mod: SourceModule, aliases: Dict[str, str],
-                 qualname: str, fn: ast.AST, findings: List[Finding],
-                 fuse_consumer: bool = False):
+                 qualname: str, fn: ast.AST, findings: List[Finding]):
         self.mod = mod
         self.aliases = aliases
         self.qualname = qualname
         self.fn = fn
         self.findings = findings
-        self.fuse_consumer = fuse_consumer
         # local defs/lambdas by name, for resolving the pushed closure
         self.local_fns: Dict[str, ast.AST] = {}
         for node in ast.walk(fn):
@@ -279,48 +190,7 @@ class _FnLint:
                     "function: it drains the queue but is not a "
                     "happens-before edge for host callbacks — use "
                     "engine.fence(vars).wait()"))
-        self._check_capture_pushes(calls)
         self._check_drain_loops()
-
-    def _check_capture_pushes(self, calls: List[ast.Call]):
-        seqs = _capture_seq_names(self.fn)
-        if not seqs:
-            return
-        muts = None  # lazy: most capture regions have clean var lists
-        for node in calls:
-            f = node.func
-            if not (isinstance(f, ast.Attribute)
-                    and f.attr in ("push", "push_async")):
-                continue
-            recv = f.value
-            recv_name = recv.id if isinstance(recv, ast.Name) else (
-                recv.attr if isinstance(recv, ast.Attribute) else None)
-            if recv_name not in seqs:
-                continue
-            if self.fuse_consumer and not any(
-                    kw.arg == "fuse" for kw in node.keywords):
-                self.findings.append(Finding(
-                    "engine", "fuse-ineligible-op", self.mod.relpath,
-                    node.lineno, self.qualname,
-                    "%s.%s" % (recv_name, f.attr),
-                    "capture-region push in a MXNET_ENGINE_FUSE consumer "
-                    "carries no traceable metadata — one such op marks "
-                    "the whole sequence fuse-ineligible and it silently "
-                    "stays on replay; pass fuse=engine.FuseOp(...) or an "
-                    "explicit fuse=None to opt this op out"))
-            if muts is None:
-                muts = _container_mutations(self.fn)
-            for nm in sorted(_bare_list_names(node)):
-                if nm in muts:
-                    self.findings.append(Finding(
-                        "engine", "capture-unstable-push",
-                        self.mod.relpath, node.lineno, self.qualname,
-                        "%s:%s" % (recv_name, nm),
-                        "capture-region push takes its var list from "
-                        "'%s', a container mutated in this function "
-                        "(line %d) — the changing list breaks sequence "
-                        "stability silently; snapshot it (tuple(%s)) "
-                        "before pushing" % (nm, muts[nm], nm)))
 
     def _check_push(self, call: ast.Call, kind: str):
         if not _has_var_decl(call):
@@ -397,7 +267,6 @@ def check(modules: Sequence[SourceModule]) -> List[Finding]:
     findings: List[Finding] = []
     for m in modules:
         aliases = import_aliases(m.tree)
-        fuse_mod = _module_consumes_fuse(m.tree)
         # module-level statements + every def (methods get Class.method)
         _FnLint(m, aliases, "%s:" % m.modname,
                 ast.Module(body=[s for s in m.tree.body
@@ -405,11 +274,11 @@ def check(modules: Sequence[SourceModule]) -> List[Finding]:
                                                        ast.AsyncFunctionDef,
                                                        ast.ClassDef))],
                            type_ignores=[]),
-                findings, fuse_mod).run()
+                findings).run()
         for node in m.tree.body:
             if isinstance(node, (ast.FunctionDef, ast.AsyncFunctionDef)):
                 _FnLint(m, aliases, "%s:%s" % (m.modname, node.name),
-                        node, findings, fuse_mod).run()
+                        node, findings).run()
             elif isinstance(node, ast.ClassDef):
                 for sub in node.body:
                     if isinstance(sub, (ast.FunctionDef,
@@ -417,5 +286,5 @@ def check(modules: Sequence[SourceModule]) -> List[Finding]:
                         _FnLint(m, aliases,
                                 "%s:%s.%s" % (m.modname, node.name,
                                               sub.name),
-                                sub, findings, fuse_mod).run()
+                                sub, findings).run()
     return findings

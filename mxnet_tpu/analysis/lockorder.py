@@ -62,9 +62,6 @@ LOCK_HIERARCHY: Dict[str, int] = {
     # in-flight gauge table: leaf — the begin/end hooks run inside engine
     # worker callbacks and must never wait on anything ranked.
     "engine._inflight_lock": 100,
-    # capture/replay state machine: leaf — state flips only; pushes,
-    # callbacks, and logging all happen outside the hold.
-    "engine.CapturedSequence._lock": 100,
     # happens-before sanitizer shadow tables: leaf — epoch/guard bookkeeping
     # only; report logging and the telemetry counter inc happen after release.
     "engine._san_lock": 100,

@@ -36,8 +36,7 @@ import builtins
 from typing import Dict, List, Optional, Sequence, Set, Tuple
 
 from .core import Finding, SourceModule, dotted, import_aliases
-from .engine_lint import (_MUTATORS, _capture_seq_names, _declared_names,
-                          _is_engine_push)
+from .engine_lint import _MUTATORS, _declared_names, _is_engine_push
 from .lockorder import FuncKey, _Index, _collect_summaries
 
 #: call tails that establish a happens-before edge for host reads
@@ -168,7 +167,7 @@ def _decl_exprs(call: ast.Call) -> List[ast.AST]:
 
 
 class _Site:
-    """One engine/capture push site and what its closure touches."""
+    """One engine push site and what its closure touches."""
 
     __slots__ = ("fnkey", "cls", "qualname", "relpath", "line", "name",
                  "declared", "touched")
@@ -225,10 +224,8 @@ class _HostScanner:
         self.aliases = index.aliases.get(modname, {})
         self.local_fns: Dict[str, ast.AST] = {}
         self.alias_map: Dict[str, str] = {}
-        self.capture_seqs: Set[str] = set()
 
     def scan(self, fn: ast.AST):
-        self.capture_seqs = _capture_seq_names(fn)
         a = getattr(fn, "args", None)
         if a is not None:
             for grp in (a.posonlyargs, a.args, a.kwonlyargs):
@@ -302,15 +299,7 @@ class _HostScanner:
 
     def _visit_call(self, call: ast.Call):
         f = call.func
-        kind = _is_engine_push(call, self.aliases)
-        if kind is None and isinstance(f, ast.Attribute) and \
-                f.attr in ("push", "push_async"):
-            recv = f.value
-            recv_name = recv.id if isinstance(recv, ast.Name) else (
-                recv.attr if isinstance(recv, ast.Attribute) else None)
-            if recv_name in self.capture_seqs:
-                kind = f.attr
-        if kind is not None:
+        if _is_engine_push(call, self.aliases) is not None:
             self._record_push(call)
             return
         tail = f.attr if isinstance(f, ast.Attribute) else (

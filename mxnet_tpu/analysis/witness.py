@@ -18,7 +18,7 @@ metrics path::
     assert not witness.violations()
 
 Overhead is one thread-local list append per acquire; intended for tests
-and canary deployments (MXNET_ANALYSIS_WITNESS=1), not the hot path.
+and canary deployments (opt-in through ``wrap()``), not the hot path.
 """
 from __future__ import annotations
 

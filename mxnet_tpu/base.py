@@ -56,9 +56,9 @@ COMPILE_CACHE_DIR = os.path.join(
 
 def init_compile_cache() -> str:
     """Place JAX's persistent compilation cache and return its directory.
-    An entry point (bench.py, chip_smoke.py) calls this once, before the
-    first compile. Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it
-    and nothing is set in code; otherwise the cache goes to
+    An entry point (chip_smoke.py, tools/probe_fit_step.py) calls this once,
+    before the first compile. Where ``JAX_COMPILATION_CACHE_DIR`` is set,
+    JAX reads it and nothing is set in code; otherwise the cache goes to
     :data:`COMPILE_CACHE_DIR`. (The separate ``mxnet_tpu.progcache`` of
     serialized executables is unrelated and stays off by default.)"""
     d = os.environ.get("JAX_COMPILATION_CACHE_DIR")

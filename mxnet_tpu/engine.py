@@ -39,6 +39,8 @@ from typing import Callable, Dict, List, Optional, Sequence, Tuple
 from . import telemetry as _telemetry
 from .base import MXNetError
 
+_log = logging.getLogger("mxnet_tpu")
+
 _OPR_FN = ctypes.CFUNCTYPE(None, ctypes.c_void_p, ctypes.c_void_p)
 _DEL_FN = ctypes.CFUNCTYPE(None, ctypes.c_void_p)
 
@@ -349,7 +351,7 @@ def get() -> "NativeEngine | PythonEngine":
             try:
                 _engine = NativeEngine(workers, etype)
             except MXNetError:
-                logging.getLogger("mxnet_tpu").warning(
+                _log.warning(
                     "native engine library unavailable (make -C native "
                     "failed or no toolchain): host ops run on the "
                     "pure-Python engine")
@@ -461,863 +463,6 @@ def fence(vars: Sequence[int], priority: int = 0,
     return Fence(ev, len(vs), fence_vars=vs)
 
 
-# --- capture/replay of steady-state dispatch sequences -----------------------
-# PyGraph-style (PAPERS.md): the per-op host cost of dynamic dispatch —
-# _dedup, the pending-table lock, the ctypes marshalling, the native
-# scheduler walk — is paid once during a short warmup, then the whole
-# sequence replays as ONE engine submission whose internal ordering comes
-# from a precomputed edge list.
-
-_log = logging.getLogger("mxnet_tpu")
-
-
-def capture_enabled() -> bool:
-    """True when ``MXNET_ENGINE_CAPTURE`` opts steady-state callers
-    (``Module.fit_step``, serving dispatch) into capture/replay. Read at
-    point of use so tests and dryruns can flip it mid-process."""
-    return os.environ.get("MXNET_ENGINE_CAPTURE", "0").lower() \
-        not in ("0", "", "false", "off")
-
-
-def capture_warmup() -> int:
-    """Warmup iterations before a sequence is eligible to replay
-    (``MXNET_ENGINE_CAPTURE_WARMUP``, default 3, floor 2 — stability is
-    meaningless with a single observation)."""
-    try:
-        n = int(os.environ.get("MXNET_ENGINE_CAPTURE_WARMUP", "3"))
-    except ValueError:
-        n = 3
-    return max(2, n)
-
-
-def fuse_enabled() -> bool:
-    """True when ``MXNET_ENGINE_FUSE`` opts stable captured sequences into
-    trace-and-fuse: the recorded op stream is lowered into ONE jitted XLA
-    program (requires capture — a sequence that never stabilizes has
-    nothing to fuse). Read at point of use, like :func:`capture_enabled`."""
-    return os.environ.get("MXNET_ENGINE_FUSE", "0").lower() \
-        not in ("0", "", "false", "off")
-
-
-class _FuseBail(Exception):
-    """Per-iteration fuse bail (feed drift, executable failure before any
-    side effect): the iteration falls back to replay-style execution."""
-
-
-class _FuseIneligible(Exception):
-    """The recorded sequence cannot be fused at all (an op lacks traceable
-    metadata, or the metadata contradicts the declared var sets)."""
-
-
-class FuseOp:
-    """Traceable metadata for one captured push (trace-and-fuse).
-
-    A push site that wants its op fused passes ``fuse=FuseOp(...)`` to
-    :meth:`CapturedSequence.push`/``push_async``. The eager closure still
-    runs during warmup/replay/bail; once the sequence stabilizes with
-    every slot carrying a FuseOp, :class:`FusedSequence` stages the
-    ``jax_fn``s into one jitted program and the closures stop running.
-
-    - ``jax_fn(*registers, *feeds) -> tuple(out registers)``: pure,
-      traceable. Registers are arbitrary pytrees keyed by engine var —
-      the op consumes its ``in_vars``' registers (in order) plus the
-      per-iteration ``feed`` values, and produces one register per
-      ``out_vars`` entry.
-    - ``in_vars``/``out_vars``: engine vars read/written. Must be covered
-      by the push's declared const/mutable sets (the pre-resolved
-      RAW/WAR/WAW edges are the fused program's dependency structure).
-    - ``feed``: per-iteration concrete inputs — a tuple, or a zero-arg
-      callable returning one (evaluated inside the fused engine op).
-      Shapes/dtypes must stay stable; drift bails the iteration to
-      replay.
-    - ``init``: dict var -> value-or-callable seeding the register of a
-      var that is read before it is written (live-in). Evaluated once at
-      staging time, after a quiescing fence.
-    - ``writeback``: host callable receiving ``{var: final value}`` for
-      this op's out_vars after each fused iteration — the hook that keeps
-      consumer-visible state (param snapshots, serving responses) in sync
-      so a later bail resumes correctly. Runs on the engine worker,
-      inside the fused push.
-    - ``fingerprint``: stable content hash of the computation for the
-      progcache key; ``None`` means "hash the lowered program text".
-    """
-
-    __slots__ = ("jax_fn", "in_vars", "out_vars", "feed", "init",
-                 "writeback", "fingerprint")
-
-    def __init__(self, jax_fn, in_vars: Sequence[int] = (),
-                 out_vars: Sequence[int] = (), feed=(), init=None,
-                 writeback=None, fingerprint: Optional[str] = None):
-        self.jax_fn = jax_fn
-        self.in_vars = tuple(int(v) for v in in_vars)
-        self.out_vars = tuple(int(v) for v in out_vars)
-        self.feed = feed
-        self.init = init or {}
-        self.writeback = writeback
-        self.fingerprint = fingerprint
-
-
-# process-wide trace-and-fuse accounting: the dict is the test/dryrun
-# surface (always on), the registry counters the telemetry export
-_fuse_stats = {"runs": 0, "bails": 0, "ineligible": 0, "compiles": 0,
-               "disk_loads": 0}
-_fused_runs_counter = _telemetry.registry.counter(
-    "engine_fused_runs_total",
-    help="captured-sequence iterations executed as one fused XLA program")
-_fuse_bails_counter = _telemetry.registry.counter(
-    "engine_fuse_bails_total",
-    help="trace-and-fuse bails back to replay (ineligible sequence, "
-         "staging failure, feed drift, runtime error)")
-
-
-def fused_stats() -> Dict[str, int]:
-    """Snapshot of trace-and-fuse counters (runs, bails, ineligible,
-    compiles, disk_loads) since process start / last reset."""
-    return dict(_fuse_stats)
-
-
-def fused_stats_reset():
-    for k in _fuse_stats:
-        _fuse_stats[k] = 0
-
-
-def _count_fuse_bail(kind: str):
-    _fuse_stats["bails"] += 1
-    if kind == "ineligible":
-        _fuse_stats["ineligible"] += 1
-    _fuse_bails_counter.inc()
-
-
-def _sharding_sig(leaf):
-    """Stable signature of a committed jax.sharding, or None.
-
-    Sharded carries (MXNET_SHARDED_UPDATE stages 1-3) lower into the
-    fused program with their NamedSharding baked into the executable, so
-    the placement must be part of the staging aval: a progcache entry
-    serialized for one mesh/spec must never be handed a differently
-    placed carry, and a placement change must re-stage rather than feed
-    a stale program. Single-device / uncommitted / non-jax leaves all
-    map to None so the unsharded path's keys are unchanged.
-    """
-    sh = getattr(leaf, "sharding", None)
-    if sh is None:
-        return None
-    try:
-        import jax
-        if not isinstance(sh, jax.sharding.NamedSharding):
-            return None
-        mesh = sh.mesh
-        return (tuple(mesh.axis_names), tuple(mesh.devices.shape),
-                str(sh.spec))
-    except Exception:
-        return None
-
-
-class FusedSequence:
-    """One stable :class:`CapturedSequence` lowered into ONE jitted XLA
-    program (``MXNET_ENGINE_FUSE``; ROADMAP trace-and-fuse).
-
-    Construction runs on the sequence's driving thread at the first ready
-    ``end_step`` and performs the whole staging pipeline:
-
-    1. **Quiesce**: fence the union var set so every warmup iteration's
-       effects are settled before live-in registers are seeded.
-    2. **Liveness** over the per-op ``in_vars``/``out_vars``: a var read
-       before its first write is *carried* (live-in AND live-out — its
-       register threads across iterations and is seeded from
-       ``FuseOp.init``); a var written then only consumed inside the
-       iteration is an *intermediate* (donated, dead at iteration end,
-       DCE'd by XLA unless a writeback needs it). Var ids are normalized
-       to sequence-local indices so the staged program — and its cache
-       key — are process-independent.
-    3. **Stitch**: each op's ``jax_fn`` is staged in recorded order,
-       consuming registers exactly along the pre-resolved RAW/WAR/WAW
-       edges, into one function ``(carry, feeds) -> (carry', mats)``
-       jitted with the carry donated.
-    4. **Cache**: the executable is keyed in progcache by the capture
-       signature — sha1 over per-op fingerprints, the edge set and in/out
-       avals (plus the lowered text when an op has no explicit
-       fingerprint) — so a warm restart disk-loads it with zero fresh
-       compiles (``kind="fused"`` in the entry meta).
-
-    Per iteration, :meth:`run_iteration` (on the engine worker, inside
-    the single ``fused:<name>`` push) evaluates the fresh ``FuseOp``
-    feeds, checks their avals against the staged ones (drift raises
-    :class:`_FuseBail` BEFORE the executable runs — the iteration is then
-    replayed untouched), executes the program, and runs the writebacks.
-    """
-
-    def __init__(self, name: str, ops: List[tuple], fuses: List[FuseOp],
-                 union: Tuple[tuple, tuple]):
-        import jax  # deferred: the engine itself must import without jax
-
-        self.name = name
-        u_const, u_mut = union
-        # 1. quiesce: warmup iterations still in flight wrote the state
-        # the init callables are about to read
-        fence(list(u_const) + list(u_mut),
-              name="fuse_stage:%s" % name).wait(120)
-        declared_mut = [set(int(v) for v in sig[4]) for sig, _ in ops]
-        declared_all = [set(int(v) for v in sig[3]) | declared_mut[i]
-                        for i, (sig, _) in enumerate(ops)]
-        for i, f in enumerate(fuses):
-            if not set(f.in_vars) <= declared_all[i]:
-                raise _FuseIneligible(
-                    "op %d (%s) fuse metadata reads vars outside its "
-                    "declared set" % (i, ops[i][0][1]))
-            if not set(f.out_vars) <= declared_mut[i]:
-                raise _FuseIneligible(
-                    "op %d (%s) fuse metadata writes vars outside its "
-                    "declared mutable set" % (i, ops[i][0][1]))
-        # 2. liveness under normalized (process-independent) var indices
-        var_idx: Dict[int, int] = {}
-        for v in list(u_const) + list(u_mut):
-            var_idx[int(v)] = len(var_idx)
-        first: Dict[int, str] = {}
-        order: List[int] = []
-        for f in fuses:
-            for v in f.in_vars:
-                if v not in first:
-                    first[v] = "r"
-                    order.append(v)
-            for v in f.out_vars:
-                if v not in first:
-                    first[v] = "w"
-                    order.append(v)
-        carried = tuple(v for v in order if first[v] == "r")
-        wb_ops = tuple(i for i, f in enumerate(fuses)
-                       if f.writeback is not None)
-        mat_vars = tuple(v for i in wb_ops for v in fuses[i].out_vars
-                         if v not in carried)
-        carry0 = {}
-        for v in carried:
-            src = None
-            for f in fuses:
-                if v in f.init:
-                    src = f.init[v]
-                    break
-            if src is None:
-                raise _FuseIneligible(
-                    "live-in var %d has no FuseOp.init seed" % v)
-            carry0[var_idx[v]] = src() if callable(src) else src
-        self._var_idx = var_idx
-        self._carried_idx = tuple(var_idx[v] for v in carried)
-        self._mat_idx = tuple(sorted(var_idx[v] for v in set(mat_vars)))
-        self._wb_ops = wb_ops
-        self._in_idx = tuple(tuple(var_idx[v] for v in f.in_vars)
-                             for f in fuses)
-        self._out_idx = tuple(tuple(var_idx[v] for v in f.out_vars)
-                              for f in fuses)
-        self._out_vars = tuple(f.out_vars for f in fuses)
-        # 3. staged feeds: evaluated once here (they double as the lowering
-        # example args and the aval reference for drift checks), then the
-        # first run_iteration consumes them instead of re-evaluating
-        feeds0, defs, avals = [], [], []
-        for i, f in enumerate(fuses):
-            fv = tuple(f.feed()) if callable(f.feed) else tuple(f.feed)
-            leaves, treedef = jax.tree_util.tree_flatten(fv)
-            feeds0.append(fv)
-            defs.append(treedef)
-            avals.append(tuple(self._aval(l) for l in leaves))
-        self._feed_defs = tuple(defs)
-        self._feed_avals = tuple(avals)
-        self._pending_feeds: Optional[tuple] = tuple(feeds0)
-        jax_fns = tuple(f.jax_fn for f in fuses)
-        in_idx, out_idx = self._in_idx, self._out_idx
-        carried_idx, mat_idx = self._carried_idx, self._mat_idx
-        names = tuple(sig[1] for sig, _ in ops)
-
-        def fused(carry, feeds):
-            regs = dict(carry)
-            for i, fn in enumerate(jax_fns):
-                res = fn(*[regs[k] for k in in_idx[i]], *feeds[i])
-                if not isinstance(res, (tuple, list)):
-                    res = (res,)
-                if len(res) != len(out_idx[i]):
-                    raise _FuseIneligible(
-                        "op %d (%s) jax_fn returned %d value(s) for %d "
-                        "out var(s)" % (i, names[i], len(res),
-                                        len(out_idx[i])))
-                for k, val in zip(out_idx[i], res):
-                    regs[k] = val
-            # materialized registers BEFORE the carry: with the carry
-            # donated, XLA pairs donated buffers to outputs in flattened
-            # output order, and the unfused step emits its outputs ahead
-            # of the updated params/states — matching that order keeps
-            # the fused program's buffer aliasing (and therefore its CPU
-            # SPMD codegen) bitwise-identical to the replay arm's.
-            return ({k: regs[k] for k in mat_idx},
-                    {k: regs[k] for k in carried_idx})
-
-        # 4. lower + compile-or-disk-load, keyed by the capture signature
-        jitted = jax.jit(fused, donate_argnums=(0,))
-        lowered = jitted.lower(dict(carry0), tuple(feeds0))
-        sigparts = []
-        for i, (sig, deps) in enumerate(ops):
-            sigparts.append((sig[1], sig[0], fuses[i].fingerprint,
-                             in_idx[i], out_idx[i], deps, avals[i]))
-        carry_avals = tuple(
-            (k, tuple(self._aval(l)
-                      for l in jax.tree_util.tree_leaves(carry0[k])))
-            for k in sorted(carry0))
-        from . import progcache as _progcache
-        from .analysis import compile_witness as _witness
-        need_text = any(f.fingerprint is None for f in fuses)
-        key = _progcache.fused_key(
-            repr((sigparts, carry_avals)),
-            lowered.as_text() if need_text else None)
-        self.signature = key
-        exe = (_progcache.load(key, kind="fused")
-               if _progcache.enabled() else None)
-        if exe is not None:
-            _fuse_stats["disk_loads"] += 1
-        else:
-            exe = lowered.compile()
-            _fuse_stats["compiles"] += 1
-            _witness.record_compile("fused", key=key[:16])
-            if _progcache.enabled():
-                _progcache.store(key, exe, note="fused:%s" % name,
-                                 kind="fused")
-        self._exe = exe
-        self._carry = carry0
-        self._san_seen = None
-        _log.info("engine fuse '%s': staged %d op(s) into one program "
-                  "(%d live-in, %d materialized, key %s…)", name,
-                  len(ops), len(carried), len(mat_vars), key[:12])
-
-    @staticmethod
-    def _aval(leaf):
-        # (shape, dtype, sharding) — the sharding leg keys the staged
-        # program (and its progcache entry) to the carry placement so
-        # ZeRO stage-1/2/3 runs fuse instead of bailing; see
-        # ``_sharding_sig``. None everywhere on the unsharded path.
-        if hasattr(leaf, "shape") and hasattr(leaf, "dtype"):
-            return (tuple(leaf.shape), str(leaf.dtype),
-                    _sharding_sig(leaf))
-        import numpy as np
-        a = np.asarray(leaf)
-        return (tuple(a.shape), str(a.dtype), None)
-
-    def _eval_feeds(self, fuses) -> tuple:
-        import jax
-        vals = []
-        for i, f in enumerate(fuses):
-            fv = tuple(f.feed()) if callable(f.feed) else tuple(f.feed)
-            leaves, treedef = jax.tree_util.tree_flatten(fv)
-            if treedef != self._feed_defs[i] or \
-                    tuple(self._aval(l) for l in leaves) \
-                    != self._feed_avals[i]:
-                raise _FuseBail(
-                    "feed for op %d drifted from the staged shapes/dtypes"
-                    % i)
-            vals.append(fv)
-        return tuple(vals)
-
-    def san_check(self, ops):
-        """Sanitizer validation: the declared edge set (transitively, with
-        program order inside the one fused push) must dominate the full
-        conflict-predecessor map — the same contract replay's
-        ``on_replay_child`` enforces dynamically."""
-        san = _san
-        if san is None or self._san_seen is san:
-            return
-        self._san_seen = san
-        conf = _Sanitizer.replay_conflicts(ops)
-        reach: List[set] = []
-        for i, (_sig, deps) in enumerate(ops):
-            r = set(deps)
-            for d in deps:
-                r |= reach[d]
-            reach.append(r)
-        for i, cset in enumerate(conf):
-            for j in cset:
-                if j not in reach[i]:
-                    sig_i, sig_j = ops[i][0], ops[j][0]
-                    shared = sorted(
-                        ({int(v) for v in sig_i[3]}
-                         | {int(v) for v in sig_i[4]})
-                        & ({int(v) for v in sig_j[3]}
-                           | {int(v) for v in sig_j[4]}))
-                    san._emit(san._mk(
-                        "fused-edge-violation",
-                        shared[0] if shared else -1, sig_i[1],
-                        "%s[%d]" % (self.name, i), sig_j[1],
-                        "%s[%d]" % (self.name, j),
-                        detail="fused program's declared edge set does "
-                               "not dominate the conflict between ops "
-                               "%d and %d (shared vars %r)"
-                               % (i, j, shared)))
-
-    def run_iteration(self, fuses):
-        """Execute one iteration (engine worker, inside the fused push).
-        Raises :class:`_FuseBail` before any side effect when the
-        iteration can still be replayed; lets writeback errors propagate
-        (results are already partially published — replaying would
-        double-apply)."""
-        feeds = self._pending_feeds
-        if feeds is not None:
-            self._pending_feeds = None
-        else:
-            feeds = self._eval_feeds(fuses)
-        try:
-            mats, new_carry = self._exe(self._carry, feeds)
-        except Exception as e:
-            raise _FuseBail("fused executable failed: %s" % e)
-        self._carry = new_carry
-        regs = dict(new_carry)
-        regs.update(mats)
-        for i in self._wb_ops:
-            wb = fuses[i].writeback
-            if wb is not None:
-                wb({v: regs[self._var_idx[v]] for v in self._out_vars[i]
-                    if self._var_idx[v] in regs})
-
-
-class CapturedSequence:
-    """Record a steady-state push sequence once, replay it with near-zero
-    host overhead.
-
-    Protocol — the owning thread brackets each iteration::
-
-        cs = engine.CapturedSequence(name="fit_step")
-        for batch in loader:
-            cs.begin_step()
-            cs.push(load_fn, mutable_vars=[data_var], name="load")
-            cs.push(step_fn, const_vars=[data_var],
-                    mutable_vars=[step_var], name="step")
-            cs.end_step()
-
-    For the first ``warmup`` iterations every push forwards eagerly
-    through the module-level :func:`push`/:func:`push_async` (so behavior
-    is identical to not capturing) while the ``(is_async, name, priority,
-    const_vars, mutable_vars)`` signature stream is recorded. If all
-    warmup iterations produced the SAME signature stream, the sequence
-    compiles: per-op ``_dedup`` runs once, RAW/WAR/WAW edges between the
-    recorded ops are resolved into a static dependency list, and the
-    union of all vars becomes the replay submission's var set. If the
-    stream was unstable (different ops or different var topology across
-    iterations) the sequence **bails to eager** with a logged reason and
-    stays eager until :meth:`invalidate` is called.
-
-    A compiled iteration is submitted by ``end_step()`` as ONE
-    module-level :func:`push_async` — so per-var in-flight accounting
-    counts the replay's vars exactly once per replay, :func:`fence` over
-    any of the union vars orders after the whole replay (including its
-    async children's ``on_complete``), and file vars in the recorded
-    signatures keep their write ordering. Inside the replay op the
-    recorded ops run in recorded order on one engine worker, waiting only
-    on precomputed edges to async predecessors — no per-op ``_dedup``, no
-    scheduler-queue lock, no ctypes marshalling.
-
-    If a replayed iteration deviates from the recording (different op at
-    slot i, or fewer/more ops), the already-matched prefix is flushed
-    eagerly in order, the rest of the iteration runs eagerly, and the
-    sequence returns to capturing — a mismatch never loses or reorders
-    an op.
-
-    Threading: one thread drives ``begin_step``/``push``/``end_step``;
-    :meth:`invalidate` may be called from any thread (e.g. a retune op on
-    an engine worker) — it sets a flag consumed at the next
-    ``begin_step``. ``_lock`` is a declared leaf (rank 100): no call
-    leaves the package while it is held.
-    """
-
-    def __init__(self, name: str = "seq", warmup: Optional[int] = None,
-                 fuse: Optional[bool] = None):
-        self._name = name
-        self._warmup = max(2, warmup) if warmup is not None \
-            else capture_warmup()
-        self._lock = threading.Lock()
-        # state: "capture" (recording + eager), "ready" (replaying),
-        # "flush" (mid-step after a mismatch: eager, not recording),
-        # "eager" (bailed on unstable warmup: eager until invalidate())
-        self._state = "capture"
-        self._iters: List[list] = []     # signature stream per warmup iter
-        self._cur: Optional[list] = None
-        self._ops: Optional[List[tuple]] = None  # [(sig, deps), ...]
-        self._union: Tuple[tuple, tuple] = ((), ())
-        self._slots: List[Callable] = []
-        self._invalid_reason: Optional[str] = None
-        self.replays = 0
-        self.bails = 0
-        # trace-and-fuse (MXNET_ENGINE_FUSE; None = read env at use time):
-        # _fuse_state is None (unstaged) / "staged" / "ineligible" / "dead";
-        # _fused holds the staged FusedSequence while "staged"
-        self._fuse_opt = fuse
-        self._fuse_state: Optional[str] = None
-        self._fused: Optional[FusedSequence] = None
-        self._fuse_slots: List[Optional[FuseOp]] = []
-        self.fused_runs = 0
-        self.fuse_bails = 0
-
-    @property
-    def name(self) -> str:
-        return self._name
-
-    @property
-    def warmup(self) -> int:
-        return self._warmup
-
-    @property
-    def state(self) -> str:
-        return self._state
-
-    def invalidate(self, reason: str):
-        """Discard the recording at the next ``begin_step`` (thread-safe;
-        an already-submitted replay is unaffected — its vars and closures
-        were frozen at submission)."""
-        with self._lock:
-            if self._invalid_reason is None:
-                self._invalid_reason = reason
-
-    # -- step bracketing ------------------------------------------------
-
-    def begin_step(self):
-        reason = None
-        with self._lock:
-            if self._invalid_reason is not None:
-                reason = self._invalid_reason
-                self._invalid_reason = None
-                self._reset_locked()
-            elif self._state == "flush":  # caller skipped end_step
-                self._reset_locked()
-            if self._state == "ready":
-                self._slots = []
-                self._fuse_slots = []
-            elif self._state == "capture":
-                self._cur = []
-        if reason is not None:
-            _log.info("engine capture '%s': invalidated (%s), recapturing",
-                      self._name, reason)
-
-    def end_step(self):
-        st = self._state
-        if st == "ready":
-            with self._lock:
-                slots, self._slots = self._slots, []
-                fuses, self._fuse_slots = self._fuse_slots, []
-            if len(slots) != len(self._ops):
-                self._flush_eager(
-                    slots, "iteration ended after %d of %d recorded ops"
-                    % (len(slots), len(self._ops)))
-                with self._lock:
-                    self._reset_locked()
-                return
-            if self._fuse_wanted():
-                with self._lock:
-                    fstate = self._fuse_state
-                if fstate is None:
-                    self._stage_fuse(fuses)
-                    with self._lock:
-                        fstate = self._fuse_state
-                if fstate == "staged":
-                    if all(f is not None for f in fuses):
-                        self._submit_fused(slots, fuses)
-                        self.fused_runs += 1
-                        return
-                    # a recorded slot lost its metadata mid-stream: the
-                    # staged registers would go stale — kill the program
-                    # and fall through to replay
-                    self._fuse_dead("a slot was pushed without fuse "
-                                    "metadata", "run")
-            self._submit_replay(slots)
-            self.replays += 1
-        elif st == "capture":
-            cur, self._cur = self._cur, None
-            if cur is not None:
-                self._iters.append(cur)
-                if len(self._iters) >= self._warmup:
-                    self._compile()
-        elif st == "flush":
-            with self._lock:
-                self._reset_locked()
-
-    # -- pushes ---------------------------------------------------------
-
-    def push(self, fn: Callable[[], None], const_vars: Sequence[int] = (),
-             mutable_vars: Sequence[int] = (), priority: int = 0,
-             name: str = "op", fuse: Optional[FuseOp] = None):
-        """Sync push routed through the capture state machine. ``fuse``
-        carries the op's traceable metadata (trace-and-fuse); ``None``
-        marks the op non-traceable, keeping the sequence on replay."""
-        self._push(False, fn, const_vars, mutable_vars, priority, name,
-                   fuse)
-
-    def push_async(self, fn: Callable[[Callable[[], None]], None],
-                   const_vars: Sequence[int] = (),
-                   mutable_vars: Sequence[int] = (), priority: int = 0,
-                   name: str = "op", fuse: Optional[FuseOp] = None):
-        """Async push routed through the capture state machine. ``fuse``
-        as in :meth:`push` — a fused iteration publishes the op's effects
-        through ``FuseOp.writeback`` instead of running ``fn``."""
-        self._push(True, fn, const_vars, mutable_vars, priority, name,
-                   fuse)
-
-    def _push(self, is_async, fn, const_vars, mutable_vars, priority, name,
-              fuse=None):
-        sig = (is_async, name, int(priority),
-               tuple(const_vars), tuple(mutable_vars))
-        st = self._state
-        if st == "ready":
-            i = len(self._slots)
-            if i < len(self._ops) and self._ops[i][0] == sig:
-                self._slots.append(fn)
-                self._fuse_slots.append(fuse)
-                return
-            with self._lock:
-                slots, self._slots = self._slots, []
-                self._fuse_slots = []
-                self._state = "flush"
-            self._flush_eager(
-                slots, "op %d is %r, recorded %r" % (
-                    i, name,
-                    self._ops[i][0][1] if i < len(self._ops) else "<end>"))
-        elif st == "capture":
-            if self._cur is not None:
-                self._cur.append(sig)
-        # capture warmup, flush, and bailed-eager all forward eagerly
-        if is_async:
-            push_async(fn, const_vars, mutable_vars, priority, name)
-        else:
-            push(fn, const_vars, mutable_vars, priority, name)
-
-    # -- internals ------------------------------------------------------
-
-    def _reset_locked(self):
-        self._state = "capture"
-        self._iters = []
-        self._cur = None
-        self._ops = None
-        self._slots = []
-        self._fuse_slots = []
-        self._fuse_state = None
-        self._fused = None
-
-    def _flush_eager(self, slots, why):
-        """Replay deviated: run the already-matched prefix eagerly, in
-        recorded order, so nothing is lost or reordered."""
-        self.bails += 1
-        _log.info("engine capture '%s': replay mismatch (%s); flushing %d "
-                  "op(s) eagerly and recapturing", self._name, why,
-                  len(slots))
-        for j, fn in enumerate(slots):
-            s_async, s_name, s_pri, s_const, s_mut = self._ops[j][0]
-            if s_async:
-                push_async(fn, s_const, s_mut, s_pri, s_name)
-            else:
-                push(fn, s_const, s_mut, s_pri, s_name)
-
-    def _compile(self):
-        """All warmup iterations observed: verify stability, resolve the
-        dependency edges once, or bail to eager."""
-        first = self._iters[0]
-        if not first:
-            self._iters = []  # empty steps: nothing to replay, keep looking
-            return
-        for k, it in enumerate(self._iters[1:], 1):
-            if it != first:
-                with self._lock:
-                    self._state = "eager"
-                    self._iters = []
-                self.bails += 1
-                _log.info(
-                    "engine capture '%s': unstable across warmup (iteration "
-                    "%d has %d op(s), first had %d; or var topology "
-                    "changed) — staying eager until invalidated",
-                    self._name, k, len(it), len(first))
-                return
-        ops = []
-        last_writer: Dict[int, int] = {}
-        readers_since: Dict[int, list] = {}
-        union_mut: Dict[int, None] = {}
-        union_const: Dict[int, None] = {}
-        for i, sig in enumerate(first):
-            const, mut = _dedup(sig[3], sig[4])  # per-op _dedup, done ONCE
-            deps = set()
-            for v in const:
-                if v in last_writer:
-                    deps.add(last_writer[v])            # RAW
-            for v in mut:
-                if v in last_writer:
-                    deps.add(last_writer[v])            # WAW
-                deps.update(readers_since.get(v, ()))   # WAR
-            for v in const:
-                readers_since.setdefault(v, []).append(i)
-                union_const.setdefault(v)
-            for v in mut:
-                last_writer[v] = i
-                readers_since[v] = []
-                union_mut.setdefault(v)
-            ops.append((sig, tuple(sorted(deps))))
-        u_mut = tuple(union_mut)
-        u_const = tuple(v for v in union_const if v not in union_mut)
-        with self._lock:
-            self._ops = ops
-            self._union = (u_const, u_mut)
-            self._iters = []
-            self._state = "ready"
-        _log.info("engine capture '%s': captured %d op(s) over %d vars, "
-                  "replaying", self._name, len(ops),
-                  len(u_const) + len(u_mut))
-
-    def _submit_replay(self, slots):
-        """Submit one iteration as a single module-level push_async. The
-        union var set makes fence()/in-flight/file-var semantics hold for
-        the whole sequence; inside, ops run in recorded order waiting
-        only on precomputed edges to async predecessors."""
-        ops = self._ops
-        seq_name = self._name
-
-        def replay(on_complete, _slots=slots, _ops=ops):
-            tok = _telemetry.begin("engine.replay", domain="engine",
-                                   ops=len(_ops), sequence=seq_name) \
-                if _telemetry.enabled("engine") else None
-            self._replay_children(_slots, _ops, seq_name)
-            if tok is not None:
-                _telemetry.end(tok)
-            on_complete()
-
-        push_async(replay, self._union[0], self._union[1],
-                   name="replay:%s" % seq_name)
-
-    @staticmethod
-    def _replay_children(slots, ops, seq_name):
-        """Run one iteration's recorded ops in order on the current engine
-        worker, waiting only on the precomputed edges to async
-        predecessors — the body of a replay submission, shared with the
-        fused path's bail-to-replay fallback."""
-        on_engine = _telemetry.enabled("engine")
-        san = _san  # read once per replay: tests may toggle mid-run
-        conf = san.replay_conflicts(ops) if san is not None else None
-        events: List[Optional[threading.Event]] = [None] * len(ops)
-        for i, (sig, deps) in enumerate(ops):
-            is_async, opname = sig[0], sig[1]
-            for d in deps:
-                ev = events[d]
-                if ev is not None:  # sync deps completed in program order
-                    ev.wait()
-            if conf is not None:
-                # after the declared-edge waits, every conflicting
-                # predecessor must already be done — or an edge is missing
-                san.on_replay_child(seq_name, i, ops, conf, events)
-            fn = slots[i]
-            try:
-                if is_async:
-                    done_ev = threading.Event()
-                    events[i] = done_ev
-                    if on_engine:
-                        optok = _telemetry.begin(opname, domain="engine",
-                                                 replay=True)
-
-                        def done(_ev=done_ev, _t=optok):
-                            _telemetry.end(_t)
-                            _ev.set()
-                    else:
-                        done = done_ev.set
-                    fn(done)
-                else:
-                    if on_engine:
-                        with _telemetry.span(opname, domain="engine",
-                                             replay=True):
-                            fn()
-                    else:
-                        fn()
-            except Exception as e:  # mirror _dispatch: never escape the op
-                traceback.print_exc()
-                _notify_op_error(opname, e)
-                if events[i] is not None:
-                    events[i].set()
-        # the submission completes only when every child has: that is
-        # what keeps fence()/in-flight release correct under replay
-        for ev in events:
-            if ev is not None:
-                ev.wait()
-
-    # -- trace-and-fuse -------------------------------------------------
-
-    def _fuse_wanted(self) -> bool:
-        return self._fuse_opt if self._fuse_opt is not None \
-            else fuse_enabled()
-
-    def _fuse_dead(self, why: str, kind: str):
-        with self._lock:
-            self._fused = None
-            self._fuse_state = "dead"
-        self.fuse_bails += 1
-        _count_fuse_bail(kind)
-        _log.info("engine fuse '%s': %s; falling back to replay until the "
-                  "sequence recaptures", self._name, why)
-
-    def _stage_fuse(self, fuses):
-        """First ready iteration with fusing requested: lower the recorded
-        sequence into a FusedSequence, or mark why it cannot be."""
-        try:
-            missing = [i for i, f in enumerate(fuses) if f is None]
-            if missing:
-                raise _FuseIneligible(
-                    "op(s) %s (%s) carry no traceable metadata"
-                    % (missing,
-                       ", ".join(self._ops[i][0][1] for i in missing)))
-            prog = FusedSequence(self._name, self._ops, fuses, self._union)
-        except _FuseIneligible as e:
-            with self._lock:
-                self._fuse_state = "ineligible"
-            self.fuse_bails += 1
-            _count_fuse_bail("ineligible")
-            _log.info("engine fuse '%s': ineligible (%s); staying on "
-                      "replay", self._name, e)
-        except Exception:
-            with self._lock:
-                self._fuse_state = "dead"
-            self.fuse_bails += 1
-            _count_fuse_bail("stage")
-            _log.warning("engine fuse '%s': staging failed; staying on "
-                         "replay", self._name, exc_info=True)
-        else:
-            with self._lock:
-                self._fused = prog
-                self._fuse_state = "staged"
-
-    def _submit_fused(self, slots, fuses):
-        """Submit one iteration as a single module-level push_async running
-        the staged program — same union var set as replay, so fences,
-        in-flight accounting (one count) and async-completion semantics
-        are unchanged. A pre-execution bail replays the iteration's
-        recorded closures inline on the same worker."""
-        prog = self._fused
-        ops = self._ops
-        seq_name = self._name
-        prog.san_check(ops)
-
-        def fused_run(on_complete, _slots=slots, _fuses=fuses, _prog=prog,
-                      _ops=ops):
-            tok = _telemetry.begin("engine.fused_run", domain="engine",
-                                   ops=len(_ops), sequence=seq_name,
-                                   signature=_prog.signature[:12]) \
-                if _telemetry.enabled("engine") else None
-            try:
-                _prog.run_iteration(_fuses)
-                _fuse_stats["runs"] += 1
-                _fused_runs_counter.inc()
-            except _FuseBail as e:
-                # nothing was published yet: the iteration replays whole
-                self._fuse_dead("bailed (%s)" % e, "run")
-                self._replay_children(_slots, _ops, seq_name)
-            except Exception as e:
-                # a writeback failed mid-publish: replaying could double-
-                # apply effects, so surface it like any failed engine op
-                self._fuse_dead("writeback failed (%s)" % e, "error")
-                traceback.print_exc()
-                _notify_op_error("fused:%s" % seq_name, e)
-            finally:
-                if tok is not None:
-                    _telemetry.end(tok)
-                on_complete()
-
-        push_async(fused_run, self._union[0], self._union[1],
-                   name="fused:%s" % seq_name)
-
-
 # --- happens-before sanitizer (MXNET_ENGINE_SANITIZER) -----------------------
 # Dynamic half of mxnet_tpu.analysis.racecheck: with MXNET_ENGINE_SANITIZER=1
 # (or sanitizer_enable()), every module-level push is checked against shadow
@@ -1327,11 +472,7 @@ class CapturedSequence:
 # deep); reaching it without declaring its var, while a prior access is not
 # yet settled by a fence/wait on that var, is a race: the engine has no edge
 # ordering the two ops. Checks run at push time only — op fns execute exactly
-# as without the sanitizer (so MXNET_FAULT_PLAN composes untouched). Replays
-# additionally validate that CapturedSequence's pre-resolved edge set
-# dominates the conflict set: when a child starts, every conflicting async
-# predecessor's done-event must already be set (declared edges + program
-# order make that transitively true iff no edge is missing).
+# as without the sanitizer (so MXNET_FAULT_PLAN composes untouched).
 #
 # Disabled path: `_san` stays None and every hook is one global load + branch.
 _san = None
@@ -1524,48 +665,6 @@ class _Sanitizer:
                              frozenset(declared))
         for rep in out:
             self._emit(rep)
-
-    # -- replay validation ---------------------------------------------------
-    @staticmethod
-    def replay_conflicts(ops):
-        """Full conflict-predecessor map over a captured sequence: for each
-        child, every earlier child sharing a var with at least one writer.
-        The pre-resolved edge set must dominate this."""
-        conf = []
-        writers: Dict[int, List[int]] = {}
-        readers: Dict[int, List[int]] = {}
-        for i, (sig, _deps) in enumerate(ops):
-            const, mutv = _dedup(sig[3], sig[4])
-            c = set()
-            for v in const:
-                c.update(writers.get(v, ()))
-            for v in mutv:
-                c.update(writers.get(v, ()))
-                c.update(readers.get(v, ()))
-            conf.append(tuple(sorted(c)))
-            for v in const:
-                readers.setdefault(v, []).append(i)
-            for v in mutv:
-                writers.setdefault(v, []).append(i)
-                readers[v] = []
-        return conf
-
-    def on_replay_child(self, seq, i, ops, conf, events):
-        for j in conf[i]:
-            ev = events[j]
-            if ev is None or ev.is_set():
-                continue  # sync child (done in program order) or completed
-            sig_i, sig_j = ops[i][0], ops[j][0]
-            shared = sorted(
-                ({int(v) for v in sig_i[3]} | {int(v) for v in sig_i[4]})
-                & ({int(v) for v in sig_j[3]} | {int(v) for v in sig_j[4]}))
-            self._emit(self._mk(
-                "replay-edge-violation", shared[0] if shared else -1,
-                sig_i[1], "%s[%d]" % (seq, i), sig_j[1], "%s[%d]" % (seq, j),
-                detail="replay child %d starts before conflicting async "
-                       "child %d completed (shared vars %r): pre-resolved "
-                       "edges do not dominate the access set" % (i, j,
-                                                                 shared)))
 
     # -- reporting -----------------------------------------------------------
     @staticmethod

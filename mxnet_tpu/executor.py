@@ -24,7 +24,7 @@ from __future__ import annotations
 import contextlib
 import logging
 import os
-from typing import Any, Dict, List, Optional, Sequence
+from typing import Any, Dict, List, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -296,10 +296,10 @@ class Executor:
         layouts for params/states (jax.experimental.layout): without
         this, XLA keeps the f32 master weights in the row-major entry
         layout and inserts per-step layout copies around every conv
-        weight's use and update (~1 ms/step at bs128 — measured via
-        tools/profile_step.py, the 214 anonymous data-formatting
-        copies). The first call relayouts the caller's arrays once;
-        returned params stay in the chosen layouts thereafter.
+        weight's use and update (~1 ms/step at bs128 for ResNet-50 in a
+        device trace: the 214 anonymous data-formatting copies). The
+        first call relayouts the caller's arrays once; returned params
+        stay in the chosen layouts thereafter.
         MXNET_STEP_AUTO_LAYOUT=0 disables.
 
         ``mesh``: a jax Mesh with a data-parallel axis ``shard_axis``.
@@ -638,18 +638,10 @@ class Executor:
                         sp.add(k, aot.get(k, 0))
                 return out
 
-        # trace-and-fuse metadata (engine.FuseOp): the pure `step` plus the
-        # facts a consumer needs to stage it into a fused CapturedSequence.
-        # AUTO-layout keeps its own compiled artifacts (learned formats)
-        # that a re-trace inside a fused program would not reproduce, so
-        # it is fuse-ineligible. The ZeRO paths (stages 1-3) fuse: the
-        # carry is committed-sharded and FusedSequence keys the staged
-        # program on the placement ("sharded"/"stage" stay here for
-        # observers, not as a bail condition).
         run.lower = lower
-        run.fuse = {"step": step, "data_names": data_names,
-                    "executor": self, "use_auto": use_auto,
-                    "sharded": bool(sharded), "stage": stage}
+        # the pure function the program is jitted from, for tools that
+        # compile it themselves (tools/step_ops.py, for a described chip)
+        run.step = step
         return run
 
     def _next_rng(self):
@@ -814,60 +806,3 @@ class Executor:
 
     def print_summary(self):
         return self._symbol.debug_str()
-
-
-class CapturedTrainStep:
-    """Engine capture/replay harness for a steady-state train step
-    (MXNET_ENGINE_CAPTURE; see engine.CapturedSequence).
-
-    Each step is two engine ops — ``fit.load_data`` writes the executor's
-    data buffers (mutable ``data_var``) and ``fit.step`` reads them and
-    advances the donated params/states (const ``data_var``, mutable
-    ``step_var``). The WAR edge data_var gives the replayed graph makes
-    step N's read precede load N+1's write, so consecutive fit_steps
-    pipeline safely through one submission per step after warmup.
-
-    ``fence()`` is the happens-before edge readers of the fused state
-    need (param writeback, metric update, output reads); callers must
-    ``close()`` before dropping the harness so the engine vars retire.
-    """
-
-    def __init__(self, name: str = "train_step"):
-        from . import engine
-        self._engine = engine
-        self.data_var: Optional[int] = engine.new_variable()
-        self.step_var: Optional[int] = engine.new_variable()
-        self.seq = engine.CapturedSequence(name=name)
-
-    def step(self, load_fn, step_fn, fuse_load=None, fuse_step=None):
-        """Run one iteration through the capture state machine: eager
-        during warmup, one replayed submission once the sequence is
-        stable. ``fuse_load``/``fuse_step`` carry the ops' traceable
-        metadata (engine.FuseOp) so a stable sequence can lower into ONE
-        fused XLA program under MXNET_ENGINE_FUSE; None keeps replay."""
-        seq = self.seq
-        seq.begin_step()
-        seq.push(load_fn, mutable_vars=(self.data_var,),
-                 name="fit.load_data", fuse=fuse_load)
-        seq.push(step_fn, const_vars=(self.data_var,),
-                 mutable_vars=(self.step_var,), name="fit.step",
-                 fuse=fuse_step)
-        seq.end_step()
-
-    def invalidate(self, reason: str):
-        self.seq.invalidate(reason)
-
-    def fence(self):
-        """Order every pushed/replayed step before the caller proceeds."""
-        if self.data_var is not None:
-            self._engine.fence([self.data_var, self.step_var],
-                               name="fit.capture_fence").wait()
-
-    def close(self):
-        """Drain outstanding steps and retire the engine vars."""
-        if self.data_var is None:
-            return
-        self.fence()
-        self._engine.delete_variable(self.data_var)
-        self._engine.delete_variable(self.step_var)
-        self.data_var = self.step_var = None

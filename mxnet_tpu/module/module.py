@@ -163,9 +163,6 @@ class Module(BaseModule):
         # stale so explicitly-set parameters take effect on the next step
         # (the compiled step program is kept — no per-epoch recompile)
         self._sync_fused_to_exec()
-        fs = self._fused_fit
-        if isinstance(fs, dict) and fs.get("capture") is not None:
-            fs["capture"].invalidate("param-set")
         self._fused_refresh = True
 
         if self._arg_params is None:
@@ -264,7 +261,6 @@ class Module(BaseModule):
         self._exec_group = None
         self._data_shapes = None
         self._label_shapes = None
-        self._close_fused_capture("rebind")
         if self._fused_fit:
             # force_rebind discards the fused state: flush its deferred
             # lockstep counts first or _index_update_count permanently
@@ -319,7 +315,6 @@ class Module(BaseModule):
 
         self.optimizer_initialized = True
         self._sync_fused_to_exec()
-        self._close_fused_capture("optimizer re-init")
         self._fused_fit = None  # re-evaluate fused eligibility
         if self._preload_opt_states is not None:
             self.load_optimizer_states(self._preload_opt_states)
@@ -373,12 +368,10 @@ class Module(BaseModule):
             )
 
     def update_metric(self, eval_metric, labels):
-        self._capture_fence()  # outputs are set on an engine worker
         self._exec_group.update_metric(eval_metric, labels)
 
     def get_outputs(self, merge_multi_context=True):
         assert self.binded and self.params_initialized
-        self._capture_fence()  # outputs are set on an engine worker
         return self._exec_group.get_outputs(merge_multi_context=merge_multi_context)
 
     def get_input_grads(self, merge_multi_context=True):
@@ -414,7 +407,6 @@ class Module(BaseModule):
     def load_optimizer_states(self, fname):
         assert self.optimizer_initialized
         self._sync_fused_to_exec()  # keep fused params; pre-load states moot
-        self._close_fused_capture("optimizer state load")
         self._fused_fit = None      # rebuild so loaded states are picked up
         if self._update_on_kvstore:
             self._kvstore.load_optimizer_states(fname)
@@ -479,9 +471,6 @@ class Module(BaseModule):
         """ZeRO local-write snapshot: host arrays from the live fused
         1/N-sharded params/optimizer state, without replicating anything
         on device (see :meth:`get_checkpoint_state`)."""
-        cap = fs.get("capture")
-        if cap is not None:  # in-flight replayed steps finish first
-            cap.fence()
         self._materialize_fused_counts(fs)
         arrays = {}
         for n in fs["names"]:
@@ -529,7 +518,6 @@ class Module(BaseModule):
         if not (self.optimizer_initialized and self._updater is not None):
             return
         self._sync_fused_to_exec()
-        self._close_fused_capture("checkpoint restore")
         self._fused_fit = None  # re-snapshot from the restored buffers
         nd_dev = len(self._context)
         exec_ = self._exec_group._exec
@@ -593,7 +581,6 @@ class Module(BaseModule):
                 # mutated mid-training: the compiled step traced the old value —
                 # sync state out and rebuild (same contract as Updater.update_all)
                 self._sync_fused_to_exec()
-                self._close_fused_capture("hyperparameter change")
                 self._fused_fit = None
                 fs = self._fused_fit_state()
             if fs is None:
@@ -605,13 +592,13 @@ class Module(BaseModule):
             # constant-lr fast path: when the optimizer uses the BASE
             # effective_lr_wd (not a count-dependent override like Adam's
             # bias correction) and has no scheduler, per-param lr/wd only
-            # move via optimizer.lr/.wd or the mult setters (which bump
-            # _mult_version) — skip the 2x n_params effective_lr_wd rebuild
-            # AND the per-param count loop (~1 ms/step combined on
-            # ResNet-50). Counts advance in LOCKSTEP in the fused path, so a
-            # single pending counter materializes into _index_update_count
-            # whenever the fused state is left (_sync_fused_to_exec) or the
-            # slow path below needs exact per-index t.
+            # move via optimizer.lr/.wd or the mult dicts — skip the 2x
+            # n_params effective_lr_wd rebuild AND the per-param count loop
+            # (~1 ms/step combined on ResNet-50). Counts advance in LOCKSTEP
+            # in the fused path, so a single pending counter materializes
+            # into _index_update_count whenever the fused state is left
+            # (_sync_fused_to_exec) or the slow path below needs exact
+            # per-index t.
             static_lw = (opt_.lr_scheduler is None
                          and type(opt_).effective_lr_wd
                          is Optimizer.effective_lr_wd)
@@ -622,14 +609,11 @@ class Module(BaseModule):
                 self._materialize_fused_counts(fs)
                 for n in fs["names"]:
                     opt_._update_count(idx_of[n])
-            # fingerprint also keys on the mult dicts' identity/size so a
-            # reassignment (opt.lr_mult = {...}) or addition is seen even
-            # without the setters; in-place VALUE mutation of an existing
-            # entry requires set_lr_mult/set_wd_mult (documented there)
+            # the mult dicts are keyed by value: a reassignment, an addition
+            # and an in-place change of an entry all rebuild the arrays
             fp = (None if not static_lw
-                  else (opt_.lr, opt_.wd, opt_._mult_version,
-                        id(opt_.lr_mult), len(opt_.lr_mult),
-                        id(opt_.wd_mult), len(opt_.wd_mult)))
+                  else (opt_.lr, opt_.wd, tuple(opt_.lr_mult.items()),
+                        tuple(opt_.wd_mult.items())))
             if fp is None or fs.get("lw_fp") != fp or "lw" not in fs:
                 lw = np.array([opt_.effective_lr_wd(idx_of[n])
                                for n in fs["names"]], np.float32)
@@ -648,29 +632,11 @@ class Module(BaseModule):
             return fs, lr_arr, wd_arr
 
     def _fit_step_fused(self, data_batch, fs, lr_arr, wd_arr):
-        cap = self._fit_capture(fs, data_batch)
-        if cap is not None:
-            # engine capture/replay (MXNET_ENGINE_CAPTURE): the two host
-            # ops of a steady-state step ride a CapturedSequence — eager
-            # for the warmup steps, then ONE engine submission per step.
-            # The closures read fs at RUN time, so each replayed step
-            # consumes the params/states its predecessor threaded through.
-            def load(_db=data_batch):
-                self._load_batch(_db)
-
-            def stepped(_lr=lr_arr, _wd=wd_arr):
-                _, fs["params"], fs["states"] = fs["step"](
-                    fs["params"], fs["states"], {}, _lr, _wd)
-
-            f_load, f_step = self._fit_fuse_ops(fs, cap, data_batch,
-                                                lr_arr, wd_arr)
-            cap.step(load, stepped, fuse_load=f_load, fuse_step=f_step)
-        else:
-            # place the batch with the group's device/sharding logic; the
-            # step then reads the executor's data buffers (empty feed dict).
-            self._load_batch(data_batch)
-            _, fs["params"], fs["states"] = fs["step"](
-                fs["params"], fs["states"], {}, lr_arr, wd_arr)
+        # place the batch with the group's device/sharding logic; the
+        # step then reads the executor's data buffers (empty feed dict).
+        self._load_batch(data_batch)
+        _, fs["params"], fs["states"] = fs["step"](
+            fs["params"], fs["states"], {}, lr_arr, wd_arr)
         self._params_dirty = True
         self._fused_dirty = True
 
@@ -700,7 +666,6 @@ class Module(BaseModule):
         if self.fit_step_path != "fused":
             raise MXNetError("fit_step is not on the fused path (%r)"
                              % self.fit_step_path)
-        self._capture_fence()
         return self._fused_fit["params"], self._fused_fit["states"]
 
     def lower_fit_step(self):
@@ -711,139 +676,6 @@ class Module(BaseModule):
         fs = self._fused_fit
         return fs["step"].lower(params, states, {}, fs["lw"][1],
                                 fs["lw"][2])
-
-    def _fit_capture(self, fs, data_batch):
-        """The fused path's CapturedTrainStep, or None when
-        MXNET_ENGINE_CAPTURE is off. Auto-invalidates on reshape (a new
-        batch geometry changes what the closures dispatch, so the
-        recording must re-warm)."""
-        from .. import engine
-        if not engine.capture_enabled():
-            cap = fs.pop("capture", None)
-            if cap is not None:  # env flipped off mid-run: drain + retire
-                cap.close()
-            return None
-        cap = fs.get("capture")
-        if cap is None:
-            from ..executor import CapturedTrainStep
-            cap = CapturedTrainStep(name="fit_step")
-            fs["capture"] = cap
-        shapes = tuple(tuple(a.shape) for a in
-                       list(data_batch.data) + list(data_batch.label or []))
-        prev = fs.get("capture_shapes")
-        if prev is not None and prev != shapes:
-            cap.invalidate("reshape: %s -> %s" % (prev, shapes))
-            cap.fence()  # old-geometry steps complete before the new load
-        fs["capture_shapes"] = shapes
-        return cap
-
-    def _fit_fuse_ops(self, fs, cap, data_batch, lr_arr, wd_arr):
-        """(fuse_load, fuse_step) FuseOp pair lowering the captured
-        fit_step into ONE fused XLA program (MXNET_ENGINE_FUSE;
-        engine.FusedSequence), or (None, None) when this setup can't be
-        traced faithfully. The step register carried across iterations on
-        ``cap.step_var`` is ``(params, states, aux, outs)``; its writeback
-        keeps ``fs``/aux_dict/outputs in sync each iteration so a bail's
-        replay closures resume from exactly the published state. The
-        AUTO-layout path owns compiled artifacts (learned formats) a
-        plain re-trace would not reproduce, so it stays on replay; the
-        ZeRO paths (MXNET_SHARDED_UPDATE stages 1-3) DO fuse — the carry
-        leaves are committed-sharded before staging and FusedSequence
-        folds their placement into the staged avals and fused_key, so
-        the one donated program lowers with the right shardings."""
-        from .. import engine
-        if not engine.fuse_enabled():
-            return None, None
-        meta = getattr(fs["step"], "fuse", None)
-        if meta is None or meta["use_auto"]:
-            return None, None
-        exec_ = meta["executor"]
-        exec_group = self._exec_group
-        dvar, svar = cap.data_var, cap.step_var
-        pairs = [(n, i, False) for i, n in enumerate(exec_group.data_names)
-                 if n in exec_.arg_dict]
-        if exec_group.label_names and data_batch.label:
-            pairs += [(n, i, True)
-                      for i, n in enumerate(exec_group.label_names)
-                      if n in exec_.arg_dict]
-        # feed names the step reads but the batch never writes come from
-        # the exec buffers, exactly like _run_impl's arg_dict fill-in
-        batch_names = {n for n, _i, _l in pairs}
-        extra_names = tuple(n for n in meta["data_names"]
-                            if n not in batch_names and n in exec_.arg_dict)
-        feed_names = tuple(n for n, _i, _l in pairs) + extra_names
-
-        def load_feed(_db=data_batch):
-            # placed on the engine worker with _load_data's exact
-            # cast/sharding so fused and eager batches are bit-identical
-            vals = [exec_group._place(exec_.arg_dict[n],
-                                      (_db.label if is_l else _db.data)[i])
-                    for n, i, is_l in pairs]
-            vals += [exec_.arg_dict[n]._data for n in extra_names]
-            return tuple(vals)
-
-        def load_jax(*vals, _names=feed_names):
-            return ({n: v for n, v in zip(_names, vals)},)
-
-        fuse_load = engine.FuseOp(
-            load_jax, out_vars=(dvar,), feed=load_feed,
-            fingerprint="fit.load_data:v1:%r" % (feed_names,))
-
-        step_pure = meta["step"]
-
-        def step_feed(_lr=lr_arr, _wd=wd_arr):
-            return (exec_._next_rng(), _lr, _wd)
-
-        # the step register leads with outs so the fused program's
-        # flattened output order (outs, params, states, aux) matches the
-        # unfused step's return order: with the carry donated, XLA pairs
-        # donated buffers to outputs in that order, and keeping the
-        # orders equal keeps the fused CPU-SPMD codegen (stages 2/3
-        # reduce-scatter placement) bitwise with the replay arm.
-        def step_jax(data_reg, step_reg, rng, lr, wd):
-            _outs, params, states, aux = step_reg
-            outs, new_p, new_s, aux_up = step_pure(params, states, aux,
-                                                   rng, data_reg, lr, wd)
-            na = dict(aux)
-            na.update(aux_up)
-            return ((tuple(outs), new_p, new_s, na),)
-
-        def step_init():
-            return (tuple(o._data for o in exec_.outputs),
-                    fs["params"], fs["states"],
-                    {n: a._data for n, a in exec_.aux_dict.items()})
-
-        def step_writeback(d, _svar=svar):
-            outs, new_p, new_s, na = d[_svar]
-            fs["params"], fs["states"] = new_p, new_s
-            for n, v in na.items():
-                if n in exec_.aux_dict:
-                    exec_.aux_dict[n]._data = v
-            exec_.outputs = [nd.NDArray(o) for o in outs]
-
-        fuse_step = engine.FuseOp(
-            step_jax, in_vars=(dvar, svar), out_vars=(svar,),
-            feed=step_feed, init={svar: step_init},
-            writeback=step_writeback)
-        return fuse_load, fuse_step
-
-    def _capture_fence(self):
-        """Happens-before for readers of fused-step results when engine
-        capture pipelines fit_step (no-op otherwise)."""
-        fs = self._fused_fit
-        cap = fs.get("capture") if isinstance(fs, dict) else None
-        if cap is not None:
-            cap.fence()
-
-    def _close_fused_capture(self, reason=None):
-        """Drain + retire the fused path's capture harness (before the
-        fused state is dropped or rebuilt)."""
-        fs = self._fused_fit
-        cap = fs.pop("capture", None) if isinstance(fs, dict) else None
-        if cap is not None:
-            if reason:
-                cap.invalidate(reason)
-            cap.close()
 
     def _fused_fit_state(self):
         """Build (once) or fetch the fused-step state; None if ineligible."""
@@ -934,9 +766,6 @@ class Module(BaseModule):
         fused snapshot (after set_params / a manual update), reusing the
         already-compiled step program. Under ZeRO-1 the refreshed copies go
         straight back to the sharded layout the compiled step expects."""
-        cap = fs.get("capture")
-        if cap is not None:  # in-flight replayed steps finish first
-            cap.fence()
         exec_ = self._exec_group._exec
         fs["params"], fs["states"] = self._fused_snapshot(
             exec_, fs["names"], fs["idx_of"], fs["mesh"], fs["z1"])
@@ -959,7 +788,6 @@ class Module(BaseModule):
     def _sync_fused_to_exec(self):
         """Refresh executor arg buffers + updater state NDArrays from the
         fused step's threaded (donated) values."""
-        self._capture_fence()  # replayed steps land in fs before we read it
         fs = self._fused_fit
         if fs:
             self._materialize_fused_counts(fs)
@@ -981,6 +809,5 @@ class Module(BaseModule):
         assert self.binded
         self._monitor_installed = True
         self._sync_fused_to_exec()
-        self._close_fused_capture("monitor install")
         self._fused_fit = None  # monitor needs per-op taps: unfused path
         self._exec_group.install_monitor(mon)

@@ -104,10 +104,6 @@ class Optimizer:
         self.begin_num_update = begin_num_update
         self.num_update = begin_num_update
         self._index_update_count = {}
-        # bumped by set_lr_mult/set_wd_mult: fit_step's constant-lr cache
-        # fingerprints on it (in-place mutation of the mult dicts must go
-        # through the setters to be seen there)
-        self._mult_version = 0
         self.clip_gradient = clip_gradient
         if param_idx2name is None:
             param_idx2name = {}
@@ -163,13 +159,11 @@ class Optimizer:
 
     def set_lr_mult(self, args_lr_mult):
         self.lr_mult.update(args_lr_mult)
-        self._mult_version += 1
 
     def set_wd_mult(self, args_wd_mult):
         """Reference semantics (optimizer.py set_wd_mult): params whose name
         does not end in _weight/_gamma default to wd_mult 0, symbol attrs
         override, explicit args override both."""
-        self._mult_version += 1
         self.wd_mult = {}
         for n in self.idx2name.values():
             if not (n.endswith("_weight") or n.endswith("_gamma")):
@@ -216,11 +210,7 @@ class Optimizer:
     # baked into pure_rule() at trace time and must invalidate caches.
     _DYNAMIC_OR_BOOKKEEPING = frozenset({
         "lr", "wd", "lr_scheduler", "lr_mult", "wd_mult", "idx2name",
-        "sym", "num_update", "begin_num_update", "_index_update_count",
-        # mult-dict version: consumed by fit_step's cheap lw fingerprint;
-        # including it in the hyper key would turn every set_*_mult into
-        # a full fused-step rebuild instead of a one-off lw recompute
-        "_mult_version"})
+        "sym", "num_update", "begin_num_update", "_index_update_count"})
 
     def _hyperparam_key(self):
         """Hashable tuple of every scalar hyperparameter closed over by

@@ -88,8 +88,8 @@ _stats = {"hits": 0, "misses": 0, "fallbacks": 0, "stores": 0,
 # the progcache_bytes gauge reads this instead of hitting the disk.
 _bytes_by_dir: Dict[str, int] = {}
 
-# Same, split by entry kind (predictor / train_step / fused / "" for
-# legacy entries) — a per-kind gauge is registered lazily when a kind
+# Same, split by entry kind (predictor / train_step / "" for legacy
+# entries) — a per-kind gauge is registered lazily when a kind
 # first appears so the exposition only grows for kinds actually in use.
 _bytes_by_dir_kind: Dict[str, Dict[str, int]] = {}
 _kind_gauges: Dict[str, object] = {}
@@ -232,24 +232,6 @@ def lowered_key(lowered_text: str, donate: Sequence[int] = (),
     h.update(str(tuple(donate)).encode())
     if extra:
         h.update(extra.encode())
-    h.update(json.dumps(_runtime_meta(), sort_keys=True).encode())
-    return h.hexdigest()
-
-
-def fused_key(capture_sig: str, lowered_text: Optional[str] = None) -> str:
-    """Cache key for a trace-and-fused CapturedSequence (engine
-    ``FusedSequence``): sha1 over the capture signature — per-op
-    fingerprints, the resolved edge set and in/out avals, already
-    normalized to process-independent var indices — plus the lowered
-    StableHLO text when any op had no explicit fingerprint, plus the
-    runtime facts. Warm restarts of the same captured program re-derive
-    the same key and disk-load with zero fresh compiles."""
-    h = hashlib.sha1()
-    h.update(b"fused\x00")
-    h.update(capture_sig.encode())
-    if lowered_text is not None:
-        h.update(b"\x00text\x00")
-        h.update(lowered_text.encode())
     h.update(json.dumps(_runtime_meta(), sort_keys=True).encode())
     return h.hexdigest()
 
@@ -490,8 +472,8 @@ def load(key: str, kind: str = ""):
 def store(key: str, compiled, note: str = "", kind: str = "") -> bool:
     """Serialize ``compiled`` and commit it under ``key`` atomically,
     then update the manifest and evict past the byte budget. ``kind``
-    classifies the entry (``predictor`` / ``train_step`` / ``fused`` /
-    ``decode`` / ``quant``) for the per-kind byte accounting. Best-effort: returns
+    classifies the entry (``predictor`` / ``train_step`` / ``decode`` /
+    ``quant``) for the per-kind byte accounting. Best-effort: returns
     False (never raises) when serialization or I/O fails — the caller
     already has its compiled program either way."""
     d = cache_dir()

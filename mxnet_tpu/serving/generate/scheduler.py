@@ -12,10 +12,6 @@ and new ones join mid-flight.
 Compile discipline: all device work goes through the fixed
 ``DecodePrograms`` set (prefill ladder + one decode step + one admit per
 replica), so steady state compiles nothing regardless of traffic shape.
-The decode-step push is optionally routed through an
-``engine.CapturedSequence`` per replica (``MXNET_ENGINE_CAPTURE`` /
-``GenerateConfig.capture``): its signature is occupancy-independent, so
-the steady-state step replays with near-zero host dispatch overhead.
 
 Paged mode (``MXNET_DECODE_PAGED=1``, PR 13): the same loop drives
 ``PagedDecodePrograms`` + ``PagedKVCacheManager`` — admission goes
@@ -104,10 +100,6 @@ class GenerateConfig:
     queue_depth: int = dataclasses.field(
         default_factory=lambda: _env_int("MXNET_DECODE_QUEUE_DEPTH", 64))
     eos_id: Optional[int] = dataclasses.field(default_factory=_env_eos)
-    capture: bool = dataclasses.field(
-        default_factory=lambda: os.environ.get(
-            "MXNET_DECODE_CAPTURE", "0").lower()
-        not in ("0", "", "false", "off"))
     rope_base: float = 10000.0
     # paged KV (PR 13): block pool + prefix reuse; 0 blocks = auto-size
     # to byte parity with the unpaged config (slots * ceil(capacity/T))
@@ -217,7 +209,6 @@ class DecodeScheduler:
         self._active: Dict[Tuple[int, int], _Active] = {}
         self._state = "stopped"                  # running|draining|stopped
         self._thread: Optional[threading.Thread] = None
-        self._captures: List[Optional[_engine.CapturedSequence]] = []
         self.steps = 0
         # speculative-decode accounting (spec off: drafted stays 0 and
         # step_tokens == seq_steps, i.e. tokens/step is exactly 1.0)
@@ -282,10 +273,6 @@ class DecodeScheduler:
         else:
             self.caches = [KVCacheManager(self.programs, i)
                            for i in range(self.replicas)]
-        use_capture = self.config.capture or _engine.capture_enabled()
-        self._captures = [
-            _engine.CapturedSequence(name="decode_step_r%d" % i)
-            if use_capture else None for i in range(self.replicas)]
         kv_total = sum(c.kv_bytes() for c in self.caches)
         self._m_kv.set(kv_total)
         self._m_kv_dtype.set(kv_total)
@@ -325,9 +312,6 @@ class DecodeScheduler:
             leftovers.append(a.stream)
         for s in leftovers:
             s._fail(ServingError("decode scheduler stopped", code=code))
-        for cs in self._captures:
-            if cs is not None:
-                cs.invalidate("scheduler stopped")
         if self.caches:
             _engine.fence([c.var for c in self.caches]).wait()
             for c in self.caches:
@@ -708,14 +692,7 @@ class DecodeScheduler:
                 except Exception as e:          # noqa: BLE001
                     holder["error"] = e
 
-            cs = self._captures[rep] if rep < len(self._captures) else None
-            if cs is not None:
-                cs.begin_step()
-                cs.push(op, mutable_vars=[cache.var], name="decode.step")
-                cs.end_step()
-            else:
-                _engine.push(op, mutable_vars=[cache.var],
-                             name="decode.step")
+            _engine.push(op, mutable_vars=[cache.var], name="decode.step")
         if not stepped:
             return
         _engine.fence(touched).wait()

@@ -31,7 +31,7 @@ never allocates a block mid-stream — exactly the vanilla invariant.
 
 Everything here runs inside the ONE engine op the scheduler pushes per
 replica per iteration (``decode.draft``/``decode.verify`` spans nest
-under ``decode.step``), so capture, sanitizer, fault plans and
+under ``decode.step``), so the sanitizer, fault plans and
 ``stop(drain=True)`` compose unchanged.
 """
 from __future__ import annotations
@@ -175,15 +175,7 @@ class SpecDecoder:
                 except Exception as e:          # noqa: BLE001
                     holder["error"] = e
 
-            cs = sched._captures[rep] if rep < len(sched._captures) \
-                else None
-            if cs is not None:
-                cs.begin_step()
-                cs.push(op, mutable_vars=[cache.var], name="decode.step")
-                cs.end_step()
-            else:
-                _engine.push(op, mutable_vars=[cache.var],
-                             name="decode.step")
+            _engine.push(op, mutable_vars=[cache.var], name="decode.step")
         if not stepped:
             return
         _engine.fence(touched).wait()

@@ -94,15 +94,6 @@ class ServingConfig:
     #: min observed requests before the tuner will propose a ladder
     retune_min_samples: int = field(default_factory=lambda: int(
         os.environ.get("MXNET_SERVING_RETUNE_MIN_SAMPLES", "64")))
-    #: engine capture/replay of the steady-state dispatch submission —
-    #: one CapturedSequence per (replica, nominal bucket), invalidated by
-    #: adaptive ladder swaps (engine.CapturedSequence, docs/perf.md)
-    capture: bool = field(default_factory=lambda: engine.capture_enabled())
-    #: trace-and-fuse the captured dispatch (MXNET_ENGINE_FUSE; requires
-    #: ``capture``): a stable per-(replica, bucket) sequence lowers into
-    #: ONE fused XLA program, bailing back to replay when acquire()
-    #: resolves a different bucket/program than the staged one
-    fuse: bool = field(default_factory=lambda: engine.fuse_enabled())
     #: post-training weight quantization for the replicas: "" (off,
     #: default — f32 path bitwise untouched) | int8 | fp8_e4m3. Each
     #: replica binds a mxnet_tpu.quant.QuantizedPredictor; the whole
@@ -113,8 +104,7 @@ class ServingConfig:
 
 
 class _Replica:
-    __slots__ = ("index", "cache", "var", "staging", "dispatched",
-                 "captures")
+    __slots__ = ("index", "cache", "var", "staging", "dispatched")
 
     def __init__(self, index: int, cache: BucketCache, var: int,
                  staging: StagingPool):
@@ -123,9 +113,6 @@ class _Replica:
         self.var = var
         self.staging = staging
         self.dispatched = 0
-        # bucket -> CapturedSequence (ServingConfig.capture); written by
-        # the former thread, invalidated+cleared by retune/stop
-        self.captures: Dict[int, "engine.CapturedSequence"] = {}
 
 
 class InferenceServer:
@@ -354,9 +341,6 @@ class InferenceServer:
             engine.untrack_inflight(rep.var)
             engine.delete_variable(rep.var)
             rep.var = None
-            # recorded sequences reference the deleted var; start()
-            # issues a fresh var, so capture re-warms from scratch
-            rep.captures.clear()
         if self._tuner_var is not None:
             engine.wait_for_var(self._tuner_var)
             engine.delete_variable(self._tuner_var)
@@ -520,117 +504,12 @@ class InferenceServer:
             nbatch = self._nbatch
             dispatch = (lambda done, batch=batch, rep=rep, nbatch=nbatch:
                         self._dispatch(batch, rep, nbatch, done))
-            if self.config.capture:
-                self._push_captured(rep, batch, dispatch, nbatch)
-            else:
-                engine.push_async(
-                    dispatch, mutable_vars=[rep.var],
-                    name="serving_dispatch_r%d" % rep.index)
+            engine.push_async(
+                dispatch, mutable_vars=[rep.var],
+                name="serving_dispatch_r%d" % rep.index)
             if (self._tuner is not None and self.config.retune_interval > 0
                     and nbatch % self.config.retune_interval == 0):
                 self._push_retune()
-
-    def _push_captured(self, rep: _Replica, batch: List[Request],
-                       dispatch: Callable, nbatch: int):
-        """Dispatch through the replica's per-bucket CapturedSequence
-        (ServingConfig.capture). The NOMINAL bucket — smallest current
-        ladder rung holding the batch — keys the sequence, so each
-        steady-state shape replays its own recording; ``acquire()`` still
-        chooses the real bucket atomically at run time, so a ladder swap
-        mid-flight never strands a request (its sequence is merely
-        invalidated back to warmup by ``_retune_op``). Only the former
-        thread writes ``rep.captures``."""
-        ladder = self._ladder  # atomic tuple snapshot
-        rows = sum(r.rows for r in batch)
-        bucket = next((b for b in ladder if b >= rows), ladder[-1])
-        cs = rep.captures.get(bucket)
-        if cs is None:
-            cs = engine.CapturedSequence(
-                name="serving_r%d_b%d" % (rep.index, bucket),
-                fuse=True if self.config.fuse else None)
-            rep.captures[bucket] = cs
-        fuse = (self._fuse_dispatch_op(rep, bucket, batch, nbatch)
-                if self.config.fuse else None)
-        cs.begin_step()
-        cs.push_async(dispatch, mutable_vars=(rep.var,),
-                      name="serving_dispatch_r%d" % rep.index, fuse=fuse)
-        cs.end_step()
-
-    def _fuse_dispatch_op(self, rep: _Replica, bucket: int,
-                          batch: List[Request], nbatch: int):
-        """Traceable metadata for one captured dispatch (ServingConfig.fuse;
-        engine.FuseOp): the nominal bucket predictor's jitted forward is
-        the staged computation, the per-iteration feed re-runs the atomic
-        ``acquire()`` + pad on the engine worker and bails to replay when
-        it resolves a different bucket or program than the staged one, and
-        the writeback publishes results exactly like ``_dispatch``'s
-        post-forward tail. None when the executor exposes no traceable
-        forward (keeps the sequence on replay)."""
-        exe = rep.cache.prepare(bucket)
-        jitted = getattr(exe, "_jitted", None)
-        if jitted is None:
-            return None
-        names = self._input_names
-        dtype = jnp.dtype(getattr(exe, "_dtype", self._dtype))
-        fp = getattr(exe, "_progcache_model_fp", None)
-
-        def fwd_fn(*vals, _jit=jitted):
-            return (tuple(_jit(*vals)),)
-
-        def feed(_batch=batch, _exe=exe, _bucket=bucket):
-            # any failure here happened BEFORE any result was published:
-            # converting it to a bail makes the whole iteration replay
-            # through _dispatch, whose handler owns request error delivery
-            try:
-                rows = sum(r.rows for r in _batch)
-                b, got = rep.cache.acquire(rows)
-                if got is not _exe:
-                    raise engine._FuseBail(
-                        "bucket drift: acquire() resolved b%d, staged b%d"
-                        % (b, _bucket))
-                if self.config.zero_copy:
-                    fd = rep.staging.fill(_batch, b, names)
-                else:
-                    fd = {}
-                    for name in names:
-                        cat = np.concatenate(
-                            [r.inputs[name] for r in _batch], axis=0)
-                        if b > rows:
-                            pad = np.zeros(
-                                (b - rows,) + cat.shape[1:], cat.dtype)
-                            cat = np.concatenate([cat, pad], axis=0)
-                        fd[name] = cat
-                return tuple(jnp.asarray(fd[n]).astype(dtype)
-                             for n in names)
-            except engine._FuseBail:
-                raise
-            except BaseException as e:
-                raise engine._FuseBail("dispatch feed failed: %s: %s"
-                                       % (type(e).__name__, e))
-
-        def writeback(d, _batch=batch, _nbatch=nbatch, _bucket=bucket):
-            outs = d[rep.var]
-            try:
-                self._publish_outputs(_batch, rep, _nbatch, _bucket,
-                                      sum(r.rows for r in _batch), outs)
-            except BaseException as e:  # mirror _dispatch's error contract
-                err = e if isinstance(e, ServingError) else ServingError(
-                    "dispatch failed: %s: %s" % (type(e).__name__, e),
-                    "dispatch_error")
-                self.metrics.record_error(err.code)
-                for r in _batch:
-                    if not r.done():
-                        r.set_error(err)
-                        _flight.request_end(r.trace, ok=False,
-                                            code=err.code,
-                                            latency_ms=r.latency_ms,
-                                            request_id=r.request_id)
-
-        return engine.FuseOp(
-            fwd_fn, out_vars=(rep.var,), feed=feed, writeback=writeback,
-            fingerprint=(None if fp is None
-                         else "serving:%s:b%d:%s:%r" % (fp, bucket,
-                                                        dtype, names)))
 
     def _pick_replica(self) -> _Replica:
         """Routing policy. ``rr``: classic round-robin. ``least_loaded``:
@@ -695,14 +574,6 @@ class InferenceServer:
                     rep.staging.retain(ladder)
                 self._ladder = tuple(ladder)
                 self._ladder_version += 1
-                # captured dispatch sequences recorded against the old
-                # ladder re-warm against the new one; a replay already
-                # submitted keeps running (acquire() is swap-atomic)
-                for rep in self._replicas:
-                    for cs in list(rep.captures.values()):
-                        cs.invalidate("ladder swap v%d"
-                                      % self._ladder_version)
-                    rep.captures.clear()
             telemetry.instant("serving.ladder_swap", domain="serving",
                               version=self._ladder_version,
                               ladder=str(ladder))
@@ -784,11 +655,10 @@ class InferenceServer:
 
     def _publish_outputs(self, batch: List[Request], rep: _Replica,
                          nbatch: int, bucket: int, rows: int, outs):
-        """Post-forward publication tail shared by ``_dispatch`` and the
-        fused writeback: batch-axis check, per-request result slicing,
-        metrics and the batch_end_callback. Raises on contract violations
-        — the caller owns request error delivery."""
-        outs = [np.asarray(o) for o in outs]
+        """Post-forward publication tail of ``_dispatch``: batch-axis
+        check, per-request result slicing, metrics and the
+        batch_end_callback. Raises on contract violations — the caller
+        owns request error delivery."""
         for o in outs:
             if o.shape[:1] != (bucket,):
                 raise ServingError(

@@ -13,7 +13,7 @@ if "xla_force_host_platform_device_count" not in flags:
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 # Tests are strictly the virtual 8-device CPU mesh, whatever the machine
-# holds; the chip is reached through chip_smoke.py and bench.py.
+# holds; the chip is reached through chip_smoke.py and benchmark/run.py.
 import jax  # noqa: E402
 
 jax.config.update("jax_platforms", "cpu")

@@ -82,31 +82,6 @@ def test_telemetry_in_jit_fixture_flags_trace_time_instrumentation():
     assert all("run" not in f.qualname for f in hits)
 
 
-def test_capture_unstable_fixture_flags_mutated_var_container():
-    fs = analysis.run_analysis(fixture("capture_unstable.py"))
-    hits = [f for f in fs if f.rule == "capture-unstable-push"]
-    # the push whose var list IS the list grown every iteration is
-    # flagged with both the sequence and the container named
-    assert len(hits) == 1
-    assert hits[0].subject == "seq:vars_"
-    assert "unstable_capture" in hits[0].qualname
-    assert "tuple(vars_)" in hits[0].message
-    # the snapshot-tuple shape is clean
-    assert not any(f.qualname.endswith(":stable_capture") for f in fs)
-
-
-def test_fuse_ineligible_fixture_flags_blind_capture_push():
-    fs = analysis.run_analysis(fixture("fuse_ineligible.py"))
-    hits = [f for f in fs if f.rule == "fuse-ineligible-op"]
-    # only the metadata-less push in the MXNET_ENGINE_FUSE consumer
-    assert len(hits) == 1
-    assert hits[0].subject == "seq.push"
-    assert "fuse_blind_capture" in hits[0].qualname
-    assert "fuse=" in hits[0].message
-    # FuseOp-carrying and explicit fuse=None pushes are both clean
-    assert not any("fuse_aware_capture" in f.qualname for f in fs)
-
-
 def test_raw_write_progcache_fixture_flags_nonatomic_commits():
     fs = analysis.run_analysis(fixture("raw_write_progcache.py"))
     hits = [f for f in fs if f.rule == "raw-binary-commit"]
@@ -155,10 +130,6 @@ def test_cli_fail_on_new_gate():
     assert cli_main(["--root", fixture("impure_jit.py"),
                      "--baseline", "none", "--fail-on-new"]) == 1
     assert cli_main(["--root", fixture("telemetry_in_jit.py"),
-                     "--baseline", "none", "--fail-on-new"]) == 1
-    assert cli_main(["--root", fixture("capture_unstable.py"),
-                     "--baseline", "none", "--fail-on-new"]) == 1
-    assert cli_main(["--root", fixture("fuse_ineligible.py"),
                      "--baseline", "none", "--fail-on-new"]) == 1
     # clean fixture: green even with no baseline
     assert cli_main(["--root", fixture("clean_locks.py"),

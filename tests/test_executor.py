@@ -221,9 +221,43 @@ def test_make_train_step_fused():
                                    rtol=2e-4, atol=2e-5)
 
 
+def test_train_step_exposes_the_function_its_program_is_jitted_from():
+    """``run.step`` is the pure function behind the program: jitted and
+    lowered by a caller at the arguments' avals (tools/step_ops.py does so
+    for a described chip) it gives the text ``run.lower`` gives."""
+    import jax
+    import jax.numpy as jnp
+
+    data = sym.Variable("data")
+    net = sym.FullyConnected(data, num_hidden=8, name="fc1")
+    net = sym.BatchNorm(net, name="bn")  # an aux state through the step
+    net = sym.SoftmaxOutput(net, name="softmax")
+    exe = net.simple_bind(mx.cpu(), data=(4, 6), softmax_label=(4,))
+    inputs = ("data", "softmax_label")
+    params = {n: a._data for n, a in exe.arg_dict.items() if n not in inputs}
+    feed = {n: exe.arg_dict[n]._data for n in inputs}
+
+    def sgd(params, grads, states, lr):
+        return ({n: params[n] - lr * grads[n] for n in params}, states)
+
+    run = exe.make_train_step(sgd)
+    lr = jnp.float32(0.1)
+    want = run.lower(params, None, feed, lr).as_text()
+
+    def aval(a):  # shape, dtype and placement: what run.lower lowers at
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=a.sharding)
+
+    aux = {n: a._data for n, a in exe.aux_dict.items()}
+    got = jax.jit(run.step).lower(
+        jax.tree_util.tree_map(aval, params), None, aux, exe._next_rng(),
+        feed, lr).as_text()
+    assert got == want
+    assert "stablehlo.dot_general" in want
+
+
 def test_make_train_step_chained_matches_sequential():
     """chain=k runs k optimizer sub-steps in ONE device program
-    (lax.scan bulk execution, bench.py BENCH_CHAIN): 1 call at chain=4
+    (lax.scan bulk execution): 1 call at chain=4
     must land on the same params as 4 calls at chain=1, including the
     BatchNorm aux-state threading through the scan carry."""
     import numpy as np

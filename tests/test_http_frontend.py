@@ -102,7 +102,7 @@ def _lm_frontend(spec=False, max_new_tokens=8, slots=2):
         prefill_buckets=(4, 8), max_new_tokens=max_new_tokens,
         queue_depth=16, paged=False,
         spec=spec, spec_tokens=3, spec_draft="self",
-        kv_dtype="f32", quant_weights="", capture=False)
+        kv_dtype="f32", quant_weights="")
     srv = serving.InferenceServer(
         _lm_symbol(), _lm_params(),
         {"data": (8,), "softmax_label": (8,)},

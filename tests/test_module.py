@@ -4,6 +4,7 @@ import os
 import tempfile
 
 import numpy as np
+import pytest
 
 import mxnet_tpu as mx
 from mxnet_tpu import ndarray as nd
@@ -273,7 +274,7 @@ def test_fused_fit_lockstep_counts_materialize():
     assert opt.num_update == 6
     assert set(opt._index_update_count.values()) == {6}
 
-    # set_lr_mult must NOT tear down the fused state (it only bumps the
+    # set_lr_mult must NOT tear down the fused state (it only changes the
     # lw fingerprint — a hyper-key invalidation would recompile seconds)
     fs_before = mod._fused_fit
     mod.fit_step(batch)
@@ -288,6 +289,46 @@ def test_fused_fit_lockstep_counts_materialize():
     mod.bind(data_shapes=[("data", (16, 6))],
              label_shapes=[("softmax_label", (16,))], force_rebind=True)
     assert set(opt._index_update_count.values()) == {n_before + 1}
+
+
+@pytest.mark.parametrize("which, value", [("lr_mult", 0.0),
+                                          ("wd_mult", 30.0)])
+def test_fused_fit_sees_a_mult_entry_changed_in_place(which, value,
+                                                      monkeypatch):
+    """An existing ``lr_mult`` / ``wd_mult`` entry assigned in place, not
+    through the setter, reaches the fused step's cached lr/wd arrays: the
+    run matches the unfused path given the same assignment."""
+    rng = np.random.RandomState(2)
+    batch = mx.io.DataBatch(
+        data=[mx.nd.array(rng.uniform(-1, 1, (16, 6)).astype(np.float32))],
+        label=[mx.nd.array(rng.randint(0, 2, (16,)).astype(np.float32))])
+    net = mx.sym.SoftmaxOutput(
+        mx.sym.FullyConnected(mx.sym.Variable("data"), num_hidden=2,
+                              name="fc"), name="softmax")
+
+    def run(fused):
+        monkeypatch.setenv("MXNET_FUSED_FIT", "1" if fused else "0")
+        mx.random.seed(3)
+        mod = mx.mod.Module(net, context=mx.cpu())
+        mod.bind(data_shapes=[("data", (16, 6))],
+                 label_shapes=[("softmax_label", (16,))])
+        mod.init_params(mx.initializer.Xavier())
+        mod.init_optimizer(optimizer="sgd", optimizer_params={
+            "learning_rate": 0.1, "wd": 0.01})
+        opt = mod._optimizer
+        getattr(opt, "set_" + which)({"fc_weight": 1.0})
+        for _ in range(2):
+            mod.fit_step(batch)
+        getattr(opt, which)["fc_weight"] = value
+        for _ in range(2):
+            mod.fit_step(batch)
+        assert mod.fit_step_path == ("fused" if fused else "unfused")
+        return {k: v.asnumpy() for k, v in mod.get_params()[0].items()}
+
+    got, want = run(True), run(False)
+    for k in want:
+        np.testing.assert_allclose(got[k], want[k], rtol=2e-4, atol=2e-5,
+                                   err_msg=k)
 
 
 def test_fused_fit_then_score_and_checkpoint(tmp_path):

@@ -322,16 +322,6 @@ def test_telemetry_counters_exported(cache_dir):
         assert name in lines, name
 
 
-def test_fused_key_deterministic_and_text_sensitive():
-    k1 = progcache.fused_key("sig", "module @m {}")
-    assert k1 == progcache.fused_key("sig", "module @m {}")
-    assert k1 != progcache.fused_key("sig", "module @other {}")
-    assert k1 != progcache.fused_key("sig2", "module @m {}")
-    # explicit per-op fingerprints skip the lowered text entirely
-    assert progcache.fused_key("sig") == progcache.fused_key("sig")
-    assert progcache.fused_key("sig") != k1
-
-
 def test_bytes_by_kind_splits_and_survives_manifest_rebuild(cache_dir):
     import jax
     import jax.numpy as jnp
@@ -342,17 +332,17 @@ def test_bytes_by_kind_splits_and_survives_manifest_rebuild(cache_dir):
             jnp.zeros((2, 2), jnp.float32)).compile()
 
     assert progcache.store("kindtest_pred", compiled(2.0), kind="predictor")
-    assert progcache.store("kindtest_fused", compiled(3.0), kind="fused")
+    assert progcache.store("kindtest_step", compiled(3.0), kind="train_step")
     assert progcache.store("kindtest_legacy", compiled(4.0))  # no kind
     bk = progcache.bytes_by_kind()
-    assert bk["predictor"] > 0 and bk["fused"] > 0
+    assert bk["predictor"] > 0 and bk["train_step"] > 0
     assert bk.get("", 0) > 0  # pre-kind entries collect under ""
     assert sum(bk.values()) == progcache.bytes_in_use()
     # per-kind gauges register lazily, only for kinds actually in use
     lines = {l.split()[0] for l in telemetry.registry.exposition()
              .splitlines() if l and not l.startswith("#")}
     assert "progcache_bytes_kind_predictor" in lines
-    assert "progcache_bytes_kind_fused" in lines
+    assert "progcache_bytes_kind_train_step" in lines
     # kill the manifest: the rebuild-from-scan must recover each entry's
     # kind from its meta header, not collapse everything into ""
     os.remove(os.path.join(cache_dir, progcache.MANIFEST))

@@ -11,8 +11,7 @@ rate while the program set stays at ladder + 2 (paged; unpaged rides its
 standalone admit along at ladder + 3); (d) rewind is a refcount-safe
 block-table/length edit — ``truncate()`` under copy-on-write sharing
 never frees another sequence's prefix blocks and is idempotent; (e) spec
-composes with engine capture, ``stop(drain=True)`` and per-stream
-deadlines; (f) sampled streams are seed-deterministic and the
+composes with ``stop(drain=True)`` and per-stream deadlines; (f) sampled streams are seed-deterministic and the
 ``decode_spec_accept_rate`` / ``decode_tokens_per_step`` gauges plus the
 ``decode.draft``/``decode.verify`` spans are live.
 """
@@ -367,28 +366,7 @@ def test_unpaged_truncate_is_length_rollback():
     assert cache.length(plan.slot) == n0
 
 
-# --- (e) composition: capture / drain / deadline ---------------------------
-
-def test_spec_composes_with_capture():
-    """MXNET_DECODE_CAPTURE: the one-op-per-replica iteration has a
-    stable (name, vars) signature, so the captured sequence compiles and
-    replays — with identical tokens."""
-    model = _decode_model()
-    prompt = [3, 7, 1, 9, 4]
-    ref, _ = _run(model, [prompt], paged=True, max_new_tokens=14,
-                  max_context=32, spec=True, spec_tokens=2)
-    sched = DecodeScheduler(model, _config(
-        paged=True, max_new_tokens=14, max_context=32, spec=True,
-        spec_tokens=2, capture=True))
-    sched.start()
-    try:
-        out = list(sched.submit(prompt))
-        cs = sched._captures[0]
-    finally:
-        sched.stop(drain=True)
-    assert out == ref[0]
-    assert cs is not None and cs.replays > 0
-
+# --- (e) composition: drain / deadline -------------------------------------
 
 def test_spec_drain_and_deadline():
     """stop(drain=True) finishes mid-flight speculative streams; a

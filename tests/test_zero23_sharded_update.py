@@ -18,11 +18,6 @@ XLA_FLAGS=--xla_force_host_platform_device_count=8). Covers:
 - the layout byte model (``stage_train_bytes``) behind the
   ``train_param_bytes``/``train_grad_bytes{stage=}`` gauges, plus the
   gauges and the step span's ``stage``/``gather_bytes`` themselves;
-- capture/fuse composition: stages 2/3 under MXNET_ENGINE_CAPTURE
-  match eager bitwise, and MXNET_ENGINE_FUSE now stages the sharded
-  step into the ONE donated fused program (the committed carry
-  placement rides the staged avals; ISSUE 20) — fused weights stay
-  bitwise with the replay arm;
 - ZeRO-3 checkpoints: local-write snapshot (no device re-replication)
   bitwise-equal to the synced exec values, dp=4 -> 2 -> 4 resharding
   round-trip bitwise INCLUDING momentum state, restore resumes
@@ -291,34 +286,6 @@ def test_stage3_gauges_and_step_span(monkeypatch):
              if ev[1] == "executor.train_step"]
     assert len(steps) == 2
     assert all(a["stage"] == 3 and a["gather_bytes"] > 0 for a in steps)
-
-
-# --- capture / fuse composition ---------------------------------------------
-
-@pytest.mark.parametrize("stage", [2, 3])
-def test_stage_capture_fuse_runs_fused_bitwise(monkeypatch, stage):
-    """MXNET_ENGINE_FUSE at stages 2/3 stages the sharded step into the
-    one donated fused program (no bail: the committed carry placement is
-    part of the staged avals) and the fused weights are BITWISE equal to
-    the uncaptured run."""
-    monkeypatch.delenv("MXNET_ENGINE_CAPTURE", raising=False)
-    monkeypatch.delenv("MXNET_ENGINE_FUSE", raising=False)
-    eager = _train_mlp(monkeypatch, stage)
-    w_eager = {n: a.asnumpy().copy()
-               for n, a in eager.get_params()[0].items()}
-
-    monkeypatch.setenv("MXNET_ENGINE_CAPTURE", "1")
-    monkeypatch.setenv("MXNET_ENGINE_FUSE", "1")
-    mod = _train_mlp(monkeypatch, stage)
-    cap = mod._fused_fit.get("capture")
-    assert cap is not None
-    seq = cap.seq
-    assert seq._fuse_state == "staged"
-    assert seq.fused_runs > 0
-    assert seq.fuse_bails == 0
-    w_cap = {n: a.asnumpy().copy() for n, a in mod.get_params()[0].items()}
-    for n in w_eager:
-        assert np.array_equal(w_eager[n], w_cap[n]), n
 
 
 # --- ZeRO-3 checkpoints -----------------------------------------------------

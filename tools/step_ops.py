@@ -89,7 +89,7 @@ def compile_step(cfg, traffic):
         return ({n: p for n, (p, _) in new.items()},
                 {n: s for n, (_, s) in new.items()})
 
-    step = exe.make_train_step(update).fuse["step"]
+    step = exe.make_train_step(update).step
     params = {n: args[n]._data for n in leaves}
     key = mxrandom.next_key()
     per_leaf = described((len(leaves),), "float32")
