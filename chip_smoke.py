@@ -171,9 +171,12 @@ def check_flash(sizes, seq, batch, heads, kv_heads, ref_batch):
                                       has_aux=True))
     if not sizes.interpret:
         text = step.lower(q, k, v, w).as_text()
-        assert text.count(PALLAS_CALL) >= 3, (
-            "flash fwd + dq + dkv not all selected at seq %d: %d Pallas "
-            "calls in the lowered program" % (seq, text.count(PALLAS_CALL)))
+        # forward and backward: one fused kernel, or dq and dkv where its
+        # accumulators do not fit VMEM
+        assert text.count(PALLAS_CALL) >= 2, (
+            "flash forward and backward not both selected at seq %d: %d "
+            "Pallas calls in the lowered program"
+            % (seq, text.count(PALLAS_CALL)))
     (_, out), grads = step(q, k, v, w)
     r = slice(0, ref_batch)
     (_, want), want_grads = jax.jit(jax.value_and_grad(
@@ -376,8 +379,8 @@ def phase_train(sizes, ctx, prefix, meter):
 
     facts, _ = train(sizes, [ctx], prefix, meter)
     if pallas.on_tpu():
-        # 3 kernels (fwd, dq, dkv) per layer
-        assert facts["pallas_calls"] >= 3 * sizes.layers, \
+        # at least 2 kernels (fwd, fused backward) per layer
+        assert facts["pallas_calls"] >= 2 * sizes.layers, \
             "Pallas flash attention missing from the lowered train step " \
             "(%d calls)" % facts["pallas_calls"]
     return facts

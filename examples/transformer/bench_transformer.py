@@ -15,9 +15,11 @@ where it is selected):
    blocks with a scalar-loss head; head_dim 128 so the flash path is
    selected), flash on vs off in the SAME training program.
 
-3. kernels (``--kernels``, alone): the three flash kernels' milliseconds
-   (forward, dq, dkv), each alone, at the shape of the benchmark cell
-   ``lm_train_4k`` — the measurement PERF.md's PR-26 findings start from.
+3. kernels (``--kernels``, alone): the flash kernels' milliseconds
+   (forward; dq and dkv, the two-kernel backward; the fused backward that
+   replaces them where its accumulators fit VMEM), each alone, at the
+   shape of the benchmark cell ``lm_train_4k`` — the measurement
+   PERF.md's findings for PRs 26 and 32 start from.
 
     python examples/transformer/bench_transformer.py
     python examples/transformer/bench_transformer.py --kernels
@@ -144,14 +146,16 @@ def micro(args):
 
 
 def kernel_times(fa, batch, heads, kv_heads, seq, head_dim, causal=True,
-                 reps=20, seed=0, only=("fwd", "dq", "dkv")):
-    """Milliseconds of each of the three flash kernels alone (forward with
-    lse, dq, dkv) at one shape, bfloat16: each closure keeps ONE of the
-    kernels (XLA drops a pallas_call whose results nobody reads; checked
-    in the compiled text), runs ``reps`` times back to back and is read
-    once; the least of three such blocks. ``fa`` is the kernel module.
-    Returns ({"fwd": ms, "dq": ms, "dkv": ms}, {"fwd": (o, lse), "dq": dq,
-    "dkv": (dk, dv)}) for the kernels in ``only``."""
+                 reps=20, seed=0, only=("fwd", "dq", "dkv", "fused")):
+    """Milliseconds of each flash kernel alone (forward with lse; the
+    two-kernel backward's dq and dkv; the fused backward) at one shape,
+    bfloat16: each closure keeps ONE of the kernels (XLA drops a
+    pallas_call whose results nobody reads; checked in the compiled text),
+    runs ``reps`` times back to back and is read once; the least of three
+    such blocks. The backward arms call the two paths directly, whatever
+    `_fa_backward` would choose at the shape. ``fa`` is the kernel module.
+    Returns ({"fwd": ms, ...}, {"fwd": (o, lse), "dq": dq, "dkv": (dk,
+    dv), "fused": (dq, dk, dv)}) for the kernels in ``only``."""
     import numpy as np
     import jax
     import jax.numpy as jnp
@@ -170,13 +174,18 @@ def kernel_times(fa, batch, heads, kv_heads, seq, head_dim, causal=True,
     fwd = jax.jit(lambda q, k, v: fa._fa_forward(
         q, k, v, causal, scale, interp, with_lse=True))
     o, lse = fwd(q, k, v)
+    # flash's row sums D, the XLA pass `_fa_backward` makes before either
+    # path: outside the timed kernels
+    bwd_args = (q, k, v, do, lse, fa._row_sums(o, do))
 
-    def bwd(q, k, v, o, lse, do):
-        return fa._fa_backward(q, k, v, o, lse, do, causal, scale, interp)
+    def split(*a):
+        return fa._fa_backward_split(a, causal, scale, interp)
 
     arms = {"fwd": (fwd, (q, k, v)),
-            "dq": (jax.jit(lambda *a: bwd(*a)[0]), (q, k, v, o, lse, do)),
-            "dkv": (jax.jit(lambda *a: bwd(*a)[1:]), (q, k, v, o, lse, do))}
+            "dq": (jax.jit(lambda *a: split(*a)[0]), bwd_args),
+            "dkv": (jax.jit(lambda *a: split(*a)[1:]), bwd_args),
+            "fused": (jax.jit(lambda *a: fa._fa_backward_fused(
+                a, causal, scale, interp)), bwd_args)}
     ms, outs = {}, {}
     for name in only:
         f, xs = arms[name]
@@ -198,21 +207,34 @@ def kernel_times(fa, batch, heads, kv_heads, seq, head_dim, causal=True,
 
 
 def kernels(args):
-    """The three flash kernels' milliseconds at the benchmark cell's shape
+    """The flash kernels' milliseconds at the benchmark cell's shape
     (lm_train_4k: batch 2, 24 heads over 2 KV heads, 4096, 128, causal,
-    bfloat16): one layer's calls. PERF.md (Findings, PR 26) has the
-    readings this repeats."""
+    bfloat16): one layer's calls, the fused backward beside the dq and dkv
+    kernels it replaces there, and how far its three results lie from
+    theirs (norm of the difference over the norm). PERF.md (Findings, PRs
+    26 and 32) has the readings this repeats."""
+    import numpy as np
     import jax
     from mxnet_tpu.ops.pallas import flash_attention as fa
 
     on_cpu = jax.default_backend() == "cpu"
     shape = (1, 2, 1, 256, 128) if on_cpu else (2, 24, 2, 4096, 128)
-    ms, _ = kernel_times(fa, *shape, causal=args.causal,
-                         reps=1 if on_cpu else 50)
+    ms, outs = kernel_times(fa, *shape, causal=args.causal,
+                            reps=1 if on_cpu else 50)
     print("kernels B=%d H=%d HKV=%d S=%d D=%d causal=%s: fwd %.3f ms  "
-          "dq %.3f ms  dkv %.3f ms  sum %.3f ms"
+          "dq %.3f ms  dkv %.3f ms  fused %.3f ms  fused under dq+dkv by "
+          "%.3f ms"
           % (shape + (args.causal, ms["fwd"], ms["dq"], ms["dkv"],
-                      sum(ms.values()))))
+                      ms["fused"], ms["dq"] + ms["dkv"] - ms["fused"])))
+
+    def gap(a, b):
+        a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+        return float(np.linalg.norm(a - b) / np.linalg.norm(b))
+
+    split = (outs["dq"],) + tuple(outs["dkv"])
+    print("fused against dq/dkv, |difference| / |dq/dkv|: "
+          + "  ".join("%s %.3g" % (n, gap(a, b)) for n, a, b in
+                      zip(("dq", "dk", "dv"), outs["fused"], split)))
     return ms
 
 
@@ -522,7 +544,7 @@ def main():
     p.add_argument("--gqa", action="store_true",
                    help="run ONLY the grouped-query attention micro")
     p.add_argument("--kernels", action="store_true",
-                   help="run ONLY the three flash kernels' timing at the "
+                   help="run ONLY the flash kernels' timing at the "
                         "lm_train_4k cell's shape")
     p.add_argument("--long", action="store_true",
                    help="run ONLY the long-context 16k/32k LM headline")

@@ -7,12 +7,12 @@ block (one key block in the dK/dV kernel), walks the other side's tiles
 through VMEM, and keeps the softmax statistics and every accumulator in
 f32 — the standard memory-bandwidth-optimal formulation for the MXU.
 
-Three kernels (forward, dq, dkv), each ONE grid over (batch*kv-head, ...,
-block, superblock): the walked side arrives one superblock per grid step,
-an in-kernel loop walks the superblock's tiles, and the state (acc / running
-max / running sum, or dq, or dk and dv) lives in VMEM scratch that the tile
-body updates in place, so it crosses tiles and superblocks the same way.
-The superblock is sized per shape:
+Four kernels. Three of them (forward, dq, dkv) are each ONE grid over
+(batch*kv-head, ..., block, superblock): the walked side arrives one
+superblock per grid step, an in-kernel loop walks the superblock's tiles,
+and the state (acc / running max / running sum, or dq, or dk and dv) lives
+in VMEM scratch that the tile body updates in place, so it crosses tiles
+and superblocks the same way. The superblock is sized per shape:
 
 - **resident** (seq <= _RESIDENT_MAX): one superblock, the whole K/V (or,
   in the dK/dV kernel, Q/dO) sequence in VMEM per grid cell — fewest grid
@@ -22,9 +22,22 @@ The superblock is sized per shape:
   grid's innermost dim. Nothing in VMEM scales with total sequence
   length, so 16k/32k+ train in the same footprint as 4k.
 
+The fourth is the **fused backward** (`_fa_bwd_fused_kernel`): dq, dk and
+dv from one walk over the live tiles, so s, p, dp and ds are rebuilt once
+and a tile costs five products where dq and dkv together cost seven, and
+q, dO, lse and D are fetched once a head and not once a key block. It
+keeps all three accumulators whole in VMEM (f32: (tq + 2 tk) x d x 4
+bytes), so `_fa_backward` takes it where a byte count from the shapes
+fits a kernel's scoped VMEM (`_SCOPED_VMEM`: 4096 x 4096 in bfloat16 at
+head_dim 128, not 8192) and the dq and dkv kernels otherwise. One
+algorithm, two regimes read from the input, like resident against
+streaming. Measured on a v5e (PERF.md, Findings, PR 32): 3.51 ms against
+2.13 + 2.78 at 4096 causal, 12 heads a KV head, and no slower at any
+shape it fits.
+
 What the tile loop costs besides its matrix products decides the speed
-(PERF.md, Findings, PR 26; measured on a v5e at 4096 causal, 12 query
-heads a KV head):
+(PERF.md, Findings, PRs 26 and 32; measured on a v5e at 4096 causal, 12
+query heads a KV head):
 
 - the state is updated IN VMEM, not carried through the loop: a carry of
   96-128 vregs (acc, and m and l at one row per sublane) does not fit the
@@ -33,16 +46,21 @@ heads a KV head):
 - dK/dV are computed in TRANSPOSED form (scores as (BK, BQ) tiles: k q^T,
   p^T dO, v dO^T, ds^T q), so every product contracts over the last dim
   of one side, no tile goes through the XLU, and lse and D are used in
-  the (1, Tq) layout they are stored in;
+  the (1, Tq) layout they are stored in; the fused kernel adds dq's
+  product to that body, (ds^T)^T k, the one tile that is transposed;
 - 512 x 512 tiles: the MXU loads a 128 x 128 weight tile in the time it
   multiplies 128 rows, so the rows streamed per weight tile (BQ; BK in
-  dkv) set how much of its time goes to products.
+  dkv) set how much of its time goes to products;
+- the loops are bound by the MXU, not by their bundles: a fused tile is
+  2489 bundles where dq's is 1637 and dkv's 2048, and the chip reads 84%
+  of the MXU's peak for its five products (dkv 88% for its four).
 
 Operands go to the MXU as f32 and are rounded there to bf16 in its one
 pass (measured: the product of f32 operands equals that of their bf16
 roundings to 6e-8), so widening the stored bf16 costs the MXU nothing and
 rounds p and ds for free; feeding the stored dtype and masking only the
-tiles on the diagonal were measured too and gave nothing (same place).
+tiles on the diagonal were measured too and gave nothing (same place;
+bf16 operands in the fused kernel: 3.475 against 3.517 ms, PR 32).
 
 Falls back to the XLA reference math off-TPU or for non-tile-aligned
 shapes, exactly as the reference falls back from cuDNN to the mshadow
@@ -59,14 +77,18 @@ from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import PartitionSpec as P
 
 from ...parallel.mesh import partition_mesh
+from ...telemetry.metrics import registry
 
-# Preferred tiles of all three kernels (`_pick_block` falls back to a
+# Preferred tiles of all four kernels (`_pick_block` falls back to a
 # divisor of the length). PR-26 sweep on v5e, bq x bk over {256,512,1024}
 # x {128..1024}, causal S=4096 D=128 group 12, ms a call (PERF.md,
 # Findings, PR 26): forward 256x512 2.25, 512x256 2.81, 512x512 2.06,
 # 512x1024 2.30, 1024x512 2.20; dq 256x512 2.68, 512x512 2.41, 1024x512
 # 2.48, 1024x1024 2.42; dkv 256x512 3.86, 512x512 3.03, 512x1024 3.17,
 # 1024x512 3.17. The backward kernels are NOT flat in them any more.
+# Fused backward, PR-32 sweep at the same shape: 512x512 3.52, 1024x512
+# 3.62, 512x1024 3.57, 256x512 4.37, 512x256 4.29, 256x1024 4.02,
+# 1024x256 3.97.
 BLOCK_Q = 512
 BLOCK_K = 512
 # Selection gate (the cudnn-autotune "must not lose" contract): measured
@@ -110,10 +132,11 @@ def _superblocks(t, block):
     return (t, 1) if t <= _RESIDENT_MAX else _split_super(t, block)
 
 
-# --- what the three tile bodies share -----------------------------------------
+# --- what the four tile bodies share ------------------------------------------
 
 _NT = (((1,), (1,)), ((), ()))     # a @ b.T
 _NN = (((1,), (0,)), ((), ()))     # a @ b
+_TN = (((0,), (0,)), ((), ()))     # a.T @ b
 
 
 def _dot(a, b, dims):
@@ -253,12 +276,11 @@ def _kv_stream_idx(block_q, super_k, offset, causal):
     return idx
 
 
-def _compiler_params(interpret):
+def _compiler_params(interpret, grid_dims=4):
     if interpret:
         return {}
     return {"compiler_params": pltpu.CompilerParams(
-        dimension_semantics=("parallel", "arbitrary", "arbitrary",
-                             "arbitrary"))}
+        dimension_semantics=("parallel",) + ("arbitrary",) * (grid_dims - 1))}
 
 
 def _fa_forward(q, k, v, causal, scale, interpret, with_lse=False):
@@ -423,24 +445,166 @@ def _fa_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dvec_ref,
         dv_ref[0] = dv_acc_ref[...].astype(dv_ref.dtype)
 
 
-def _fa_backward(q, k, v, o, lse, do, causal, scale, interpret,
-                 g_lse=None):
-    """q/o/do: (B*Hkv, G, Tq, D); k/v: (B*Hkv, Tk, D); lse: (B*Hkv, G, 1,
-    Tq). Returns (dq like q, dk/dv like k/v) — dk/dv already summed over
-    the query-head group inside the kernel."""
+def _fa_bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dvec_ref,
+                         dq_ref, dk_ref, dv_ref, dq_acc_ref, dk_acc_ref,
+                         dv_acc_ref, *, causal, scale, block_q, offset, g,
+                         num_k):
+    """dQ, dK and dV for one (batch*kv-head, group head, k-block): every
+    live tile builds s, p, dp and ds ONCE and spends five products on
+    them, where the dq and dkv kernels together spend seven. q, dO, lse
+    and D of the head are whole in VMEM (fetched once a head, not once a
+    key block), K and V arrive one block a grid step, and the in-kernel
+    loop walks the head's block_q tiles from the diagonal on.
+
+    The tile body is the dkv kernel's (transposed scores, lse and D as
+    the rows they are stored in) plus ``dq[rows] += ds k``, whose left
+    side is the one tile of the five products that is transposed. All
+    three accumulators are whole-sequence f32 VMEM scratch: dq's is
+    zeroed at the head's first key block and written, scaled, at its
+    last; a key block's rows of dk's and dv's are zeroed at the group's
+    first head and written, in the output dtype, at its last — the GQA
+    sum stays an f32 sum inside the kernel."""
+    bk = k_ref.shape[1]
+    tq = q_ref.shape[2]
+    gi = pl.program_id(1)
+    ki = pl.program_id(2)
+    k0 = ki * bk
+    krows = pl.ds(pl.multiple_of(k0, bk), bk)
+
+    @_when(num_k == 1, ki == 0)
+    def _init_dq():
+        dq_acc_ref[...] = jnp.zeros_like(dq_acc_ref)
+
+    @_when(g == 1, gi == 0)
+    def _init_dkv():
+        dk_acc_ref[krows, :] = jnp.zeros((bk, dk_acc_ref.shape[1]),
+                                         jnp.float32)
+        dv_acc_ref[krows, :] = jnp.zeros((bk, dv_acc_ref.shape[1]),
+                                         jnp.float32)
+
+    k, v = k_ref[0], v_ref[0]                          # (BK, D)
+
+    def tile(qb):
+        rows = pl.ds(pl.multiple_of(qb * block_q, block_q), block_q)
+        q, do = q_ref[0, 0, rows, :], do_ref[0, 0, rows, :]
+        st = _dot(k, q, _NT) * scale                   # (BK, BQ)
+        if causal:
+            st = jnp.where(_keep(st.shape, offset + qb * block_q, k0, 1),
+                           st, _NEG_INF)
+        pt = jnp.exp(st - lse_ref[0, 0, :, rows])
+        dv_acc_ref[krows, :] += _dot(pt, do, _NN)
+        dst = pt * (_dot(v, do, _NT) - dvec_ref[0, 0, :, rows])
+        dk_acc_ref[krows, :] += _dot(dst, q, _NN)
+        dq_acc_ref[rows, :] += _dot(dst, k, _TN)
+
+    n = tq // block_q
+    # causal: tiles whose last (offset) query position precedes this k
+    # block's start contribute nothing (every entry masked)
+    _walk(tile, jnp.clip((k0 - offset) // block_q, 0, n) if causal else 0,
+          n)
+
+    @_when(num_k == 1, ki == num_k - 1)
+    def _write_dq():
+        dq_ref[0, 0] = (dq_acc_ref[...] * scale).astype(dq_ref.dtype)
+
+    @_when(g == 1, gi == g - 1)
+    def _write_dkv():
+        dk_ref[0] = (dk_acc_ref[krows, :] * scale).astype(dk_ref.dtype)
+        dv_ref[0] = dv_acc_ref[krows, :].astype(dv_ref.dtype)
+
+
+# The scoped VMEM a Mosaic kernel gets without asking. The fused backward
+# lives in it like the other kernels and takes the shapes that fit: a
+# `vmem_limit_bytes` on the call, even one of these same 16 MiB, changes
+# how XLA builds OTHER fusions of the program around it (measured, PR 32:
+# with a 32 MiB limit on this kernel the head's dW fusion of lm_train_4k
+# went from 13.5 to 18.4 ms a step, most of what the kernel had won).
+_SCOPED_VMEM = 16 * 2 ** 20
+
+
+def _fused_bwd_vmem_bytes(tq, tk, d, itemsize):
+    """What the fused backward holds in VMEM, from the shapes alone:
+    every input and output block twice (the pipeline's two buffers), the
+    three f32 accumulators, and two f32 score-sized tiles for what the
+    loop spills (the compiler asks for 1.0-1.5 at 512 x 512: the cell's
+    shape, 15.5 MiB by this count, compiles from 14.5-15 MiB up)."""
+    block_q, block_k = _pick_block(tq, BLOCK_Q), _pick_block(tk, BLOCK_K)
+    row = 8 * tq * 4                    # a (1, tq) f32 row pads to 8 sublanes
+    blocks = ((2 * tq * d + 2 * block_k * d) * itemsize + 2 * row  # in
+              + (tq * d + 2 * block_k * d) * itemsize)             # out
+    acc = (tq + 2 * tk) * d * 4
+    tiles = 2 * block_q * block_k * 4
+    return 2 * blocks + acc + tiles
+
+
+def _fa_backward_fused(args, causal, scale, interpret):
+    """dq, dk, dv from ONE kernel over a (batch*kv-head, group head,
+    k-block) grid: `_fa_bwd_fused_kernel`."""
+    q, k, v = args[:3]
+    bkv, g, tq, d = q.shape
+    tk = k.shape[1]
+    block_q = _pick_block(tq, BLOCK_Q)
+    block_k = _pick_block(tk, BLOCK_K)
+    num_k = tk // block_k
+    q_spec = pl.BlockSpec((1, 1, tq, d), lambda b, gi, ki: (b, gi, 0, 0))
+    qrow_spec = pl.BlockSpec((1, 1, 1, tq), lambda b, gi, ki: (b, gi, 0, 0))
+    kv_spec = pl.BlockSpec((1, block_k, d), lambda b, gi, ki: (b, ki, 0))
+    # dk/dv blocks are written during the group's LAST head only: until
+    # then the index stays put, and Pallas writes a block back when its
+    # index changes, so no block leaves before it is written
+    dkv_spec = pl.BlockSpec(
+        (1, block_k, d),
+        lambda b, gi, ki: (b, jnp.where(gi == g - 1, ki, 0), 0))
+    return pl.pallas_call(
+        functools.partial(_fa_bwd_fused_kernel, causal=causal, scale=scale,
+                          block_q=block_q, offset=tk - tq, g=g,
+                          num_k=num_k),
+        grid=(bkv, g, num_k),
+        in_specs=[q_spec, kv_spec, kv_spec, q_spec, qrow_spec, qrow_spec],
+        out_specs=[q_spec, dkv_spec, dkv_spec],
+        out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
+                   jax.ShapeDtypeStruct(k.shape, k.dtype),
+                   jax.ShapeDtypeStruct(v.shape, v.dtype)],
+        scratch_shapes=[pltpu.VMEM((tq, d), jnp.float32),
+                        pltpu.VMEM((tk, d), jnp.float32),
+                        pltpu.VMEM((tk, d), jnp.float32)],
+        cost_estimate=pl.CostEstimate(
+            # 5 matmuls per (q,k) tile pair: s^T, p^T@dO, v@dO^T, ds^T@q,
+            # ds@k
+            flops=10 * bkv * g * tq * tk * d,
+            bytes_accessed=_bwd_in_bytes(args),
+            transcendentals=bkv * g * tq * tk),
+        interpret=interpret,
+        **_compiler_params(interpret, grid_dims=3),
+    )(*args)
+
+
+def _row_sums(o, do, g_lse=None):
+    """D_i = rowsum(dO * O), (bkv, g, 1, tq) f32: one cheap fused XLA pass
+    before either backward path. A cotangent on the logsumexp output
+    folds in here: d(lse)/ds = p, so ds gains +g_lse*p, i.e. D := D -
+    g_lse (ring attention's merge differentiates through lse)."""
+    dvec = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
+                   axis=-1)[:, :, None, :]
+    if g_lse is not None:
+        dvec = dvec - g_lse.astype(jnp.float32)
+    return dvec
+
+
+def _bwd_in_bytes(args):
+    q, k, v, do = args[:4]
+    return (q.size + k.size + v.size + do.size) * q.dtype.itemsize
+
+
+def _fa_backward_split(args, causal, scale, interpret):
+    """dq from one kernel, dk and dv from another: two walks over the
+    same tiles, seven products a tile pair, nothing in VMEM that grows
+    with the sequence beyond a superblock."""
+    q, k, v = args[:3]
     bkv, g, tq, d = q.shape
     tk = k.shape[1]
     offset = tk - tq
-    # D_i = rowsum(dO * O): one cheap fused XLA pass. A cotangent on the
-    # logsumexp output folds in here: d(lse)/ds = p, so ds gains
-    # +g_lse*p, i.e. D := D - g_lse (ring attention's merge
-    # differentiates through lse).
-    dvec = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
-                   axis=-1)[:, :, None, :]         # (bkv, g, 1, tq)
-    if g_lse is not None:
-        dvec = dvec - g_lse.astype(jnp.float32)
-    args = (q, k, v, do, lse, dvec)
-    in_bytes = (q.size + k.size + v.size + do.size) * q.dtype.itemsize
+    in_bytes = _bwd_in_bytes(args)
 
     block_q = _pick_block(tq, BLOCK_Q)
     block_k = _pick_block(tk, BLOCK_K)
@@ -506,6 +670,31 @@ def _fa_backward(q, k, v, o, lse, do, causal, scale, interpret,
         **_compiler_params(interpret),
     )(*args)
     return dq, dk, dv
+
+
+def _fa_backward(q, k, v, o, lse, do, causal, scale, interpret,
+                 g_lse=None):
+    """q/o/do: (B*Hkv, G, Tq, D); k/v: (B*Hkv, Tk, D); lse: (B*Hkv, G, 1,
+    Tq). Returns (dq like q, dk/dv like k/v) — dk/dv already summed over
+    the query-head group inside the kernel.
+
+    One algorithm, two regimes read from the shapes: the fused kernel
+    where its whole-sequence accumulators fit a kernel's scoped VMEM
+    (`_fused_bwd_vmem_bytes` against `_SCOPED_VMEM`), the dq and dkv
+    kernels otherwise (long sequences, wide dtypes). Which was built is
+    counted as the program is traced:
+    ``flash_backward_built_total{path="fused"|"split"}``."""
+    args = (q, k, v, do, lse, _row_sums(o, do, g_lse))
+    fused = _fused_bwd_vmem_bytes(q.shape[2], k.shape[1], q.shape[3],
+                                  q.dtype.itemsize) <= _SCOPED_VMEM
+    registry.counter(
+        "flash_backward_built_total",
+        labels={"path": "fused" if fused else "split"},
+        help="flash-attention backward passes traced into a program, by "
+             "the kernels chosen from the shapes").inc()
+    if fused:
+        return _fa_backward_fused(args, causal, scale, interpret)
+    return _fa_backward_split(args, causal, scale, interpret)
 
 
 def _aligned(t, block):

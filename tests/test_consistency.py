@@ -433,6 +433,10 @@ def test_pallas_flash_streaming_regime_matches_xla(monkeypatch):
     assert fa.pltpu is not None, "pltpu missing; streaming path untestable"
     monkeypatch.setattr(fa, "_RESIDENT_MAX", 256)
     monkeypatch.setattr(fa, "SUPER_TARGET", 512)
+    # at real sizes a streaming shape's accumulators do not fit the scoped
+    # VMEM the fused backward lives in; at CI sizes the byte count has to
+    # be told so
+    monkeypatch.setattr(fa, "_SCOPED_VMEM", 0)
     rng = np.random.RandomState(13)
     B, D = 1, 8
     # (h, hkv, tq, tk, causal): all > 256 shapes take the streaming path.
@@ -519,26 +523,59 @@ _FLASH_CASES = {
 }
 
 
-def _flash_case_setup(monkeypatch, fa, blocks, streaming):
+def _flash_case_setup(monkeypatch, fa, blocks, streaming, path="split"):
+    """Tiles, regime and backward path of one case. The path follows from
+    the shapes alone (`_fused_bwd_vmem_bytes` against `_SCOPED_VMEM`),
+    and every CI-sized shape fits: "split" tells the byte count that
+    nothing does, which is what a streaming shape reads at its real size."""
     if blocks:
         monkeypatch.setattr(fa, "BLOCK_Q", blocks[0])
         monkeypatch.setattr(fa, "BLOCK_K", blocks[1])
     if streaming:
         monkeypatch.setattr(fa, "_RESIDENT_MAX", streaming[0])
         monkeypatch.setattr(fa, "SUPER_TARGET", streaming[1])
+    if path == "split":
+        monkeypatch.setattr(fa, "_SCOPED_VMEM", 0)
 
 
-@pytest.mark.parametrize("case", sorted(_FLASH_CASES))
-def test_pallas_flash_cases_match_xla(case, monkeypatch):
+def _flash_backward_built():
+    """{path: flash_backward_built_total{path=...}} as it stands."""
+    from mxnet_tpu import telemetry
+
+    return {p: telemetry.registry.counter("flash_backward_built_total",
+                                          labels={"path": p}).value
+            for p in ("split", "fused")}
+
+
+def _assert_built_one(before, path):
+    """One backward was traced since ``before``, on ``path``."""
+    want = dict(before)
+    want[path] += 1
+    assert _flash_backward_built() == want
+
+
+def _case_paths(cases, is_streaming):
+    """(case, path) pairs: every case through the dq and dkv kernels, the
+    resident ones through the fused kernel too."""
+    return [(c, p) for c in sorted(cases) for p in ("split", "fused")
+            if p == "split" or not is_streaming(cases[c])]
+
+
+@pytest.mark.parametrize(
+    "case,path", _case_paths(_FLASH_CASES, lambda c: c[7] is not None))
+def test_pallas_flash_cases_match_xla(case, path, monkeypatch):
     """Forward and all three gradients of the flash kernels against the
     XLA reference, one case per way the tile walk can go: which tiles a
     block visits, where the diagonal crosses them, the group's sum in
-    dk/dv, the superblock regime, and bfloat16 operands."""
+    dk/dv, the superblock regime, and bfloat16 operands; the backward
+    through the two kernels and through the fused one, and the counter
+    says which was built."""
     from mxnet_tpu.ops.pallas import flash_attention as fa
     from mxnet_tpu.ops.attention import _grouped_attention
 
     h, hkv, tq, tk, causal, dtype, blocks, streaming = _FLASH_CASES[case]
-    _flash_case_setup(monkeypatch, fa, blocks, streaming)
+    _flash_case_setup(monkeypatch, fa, blocks, streaming, path)
+    built = _flash_backward_built()
     rng = np.random.RandomState(sorted(_FLASH_CASES).index(case))
     B, D = 1, 8
     q = jnp.asarray(rng.randn(B, h, tq, D).astype(np.float32), dtype)
@@ -572,23 +609,28 @@ def test_pallas_flash_cases_match_xla(case, monkeypatch):
         np.testing.assert_allclose(np.asarray(a, np.float32),
                                    np.asarray(b, np.float32),
                                    err_msg="d" + name, **tol[1])
+    _assert_built_one(built, path)
 
 
-@pytest.mark.parametrize("case", ["resident_causal_gqa",
-                                  "resident_noncausal",
-                                  "streaming_causal_offset"])
-def test_pallas_flash_with_lse_cotangent(case, monkeypatch):
+_FLASH_LSE_CASES = {
+    # (group, tq, tk, causal, streaming)
+    "resident_causal_gqa": (2, 512, 512, True, None),
+    "resident_noncausal": (1, 256, 512, False, None),
+    "streaming_causal_offset": (2, 512, 1024, True, (256, 512)),
+}
+
+
+@pytest.mark.parametrize(
+    "case,path", _case_paths(_FLASH_LSE_CASES, lambda c: c[4] is not None))
+def test_pallas_flash_with_lse_cotangent(case, path, monkeypatch):
     """`_flash_with_lse` (ring attention's per-shard call): lse is a real
-    output, and a non-zero cotangent on it folds into D in the backward.
-    A loss over BOTH outputs against the same loss through plain jnp."""
+    output, and a non-zero cotangent on it folds into D in the backward,
+    before either backward path. A loss over BOTH outputs against the same
+    loss through plain jnp."""
     from mxnet_tpu.ops.pallas import flash_attention as fa
 
-    g, tq, tk, causal, streaming = {
-        "resident_causal_gqa": (2, 512, 512, True, None),
-        "resident_noncausal": (1, 256, 512, False, None),
-        "streaming_causal_offset": (2, 512, 1024, True, (256, 512)),
-    }[case]
-    _flash_case_setup(monkeypatch, fa, (256, 256), streaming)
+    g, tq, tk, causal, streaming = _FLASH_LSE_CASES[case]
+    _flash_case_setup(monkeypatch, fa, (256, 256), streaming, path)
     rng = np.random.RandomState(3)
     rows, D = 2, 8
     scale = 0.4
@@ -626,6 +668,99 @@ def test_pallas_flash_with_lse_cotangent(case, monkeypatch):
     for name, a, b in zip("qkv", gk, gr):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=2e-3,
                                    atol=5e-4, err_msg="d" + name)
+
+
+# (rows, group, tq, tk, causal, dtype, blocks)
+_FUSED_CASES = {
+    # 3 x 3 tiles: key block 0 walks a diagonal tile and two interior
+    # ones, key block 2 the diagonal one alone
+    "causal_tiles_on_and_off_the_diagonal":
+        (2, 2, 768, 768, True, "float32", (256, 256)),
+    # offset 384 = tk - tq: the diagonal crosses the middle of a key tile
+    "causal_offset_tq_under_tk": (1, 2, 128, 512, True, "float32",
+                                  (256, 256)),
+    "causal_offset_rectangular_tiles":
+        (2, 1, 256, 1024, True, "float32", (256, 512)),
+    "group1_default_tiles": (1, 1, 1024, 1024, True, "float32", None),
+    "group3_causal": (2, 3, 512, 512, True, "float32", (256, 256)),
+    "mqa_noncausal": (1, 4, 256, 768, False, "float32", (256, 256)),
+    "noncausal_default_tiles": (2, 1, 512, 1024, False, "float32", None),
+    "bf16_causal_gqa": (2, 2, 512, 512, True, "bfloat16", (256, 256)),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_FUSED_CASES))
+def test_pallas_flash_fused_backward_equals_two_kernel_path(case,
+                                                            monkeypatch):
+    """The fused backward against the dq and dkv kernels on the SAME
+    inputs (q, k, v, dO, lse, D), both called directly: dk and dv walk
+    the same tiles in the same order and are equal to the last bit; dq
+    adds the same per-tile products, from transposed scores."""
+    from mxnet_tpu.ops.pallas import flash_attention as fa
+
+    rows, g, tq, tk, causal, dtype, blocks = _FUSED_CASES[case]
+    _flash_case_setup(monkeypatch, fa, blocks, None, "fused")
+    rng = np.random.RandomState(sorted(_FUSED_CASES).index(case))
+    D, scale = 8, 0.35
+
+    def rand(*shape):
+        return jnp.asarray(rng.randn(*shape).astype(np.float32), dtype)
+
+    q, do = rand(rows, g, tq, D), rand(rows, g, tq, D)
+    k, v = rand(rows, tk, D), rand(rows, tk, D)
+    o, lse = fa._fa_forward(q, k, v, causal, scale, True, with_lse=True)
+    args = (q, k, v, do, lse, fa._row_sums(o, do))
+    fused = fa._fa_backward_fused(args, causal, scale, True)
+    split = fa._fa_backward_split(args, causal, scale, True)
+    for name, a, b in zip(("dq", "dk", "dv"), fused, split):
+        assert a.shape == b.shape and a.dtype == b.dtype, name
+    np.testing.assert_array_equal(np.asarray(fused[1], np.float32),
+                                  np.asarray(split[1], np.float32))
+    np.testing.assert_array_equal(np.asarray(fused[2], np.float32),
+                                  np.asarray(split[2], np.float32))
+    tol = dict(rtol=1e-5, atol=1e-6) if dtype == "float32" \
+        else dict(rtol=1e-2, atol=1e-2)
+    np.testing.assert_allclose(np.asarray(fused[0], np.float32),
+                               np.asarray(split[0], np.float32), **tol)
+
+
+@pytest.mark.parametrize("shape,want", [
+    # (rows, group, tq, tk, dtype): the benchmark cell lm_train_4k, 15.5
+    # MiB of the 16 a kernel gets ...
+    ((4, 12, 4096, 4096, "bfloat16"), "fused"),
+    # ... and the same lengths in float32, 22.5
+    ((4, 12, 4096, 4096, "float32"), "split"),
+    ((1, 2, 8192, 8192, "bfloat16"), "split"),
+    ((1, 2, 16384, 16384, "bfloat16"), "split"),
+    # few queries against a long cache: K and V arrive block by block, so
+    # only dk's and dv's accumulators grow with it (15.1 MiB)
+    ((2, 12, 1024, 10240, "bfloat16"), "fused"),
+    ((2, 12, 512, 4096, "bfloat16"), "fused"),
+    ((2, 1, 768, 1280, "float32"), "fused"),
+])
+def test_pallas_flash_backward_path_follows_from_the_shapes(shape, want):
+    """`_fa_backward` takes the fused kernel exactly where the byte count
+    of its blocks, accumulators and tiles fits a kernel's scoped VMEM, and
+    counts the path it built as the program is traced (nothing runs
+    here: the real shapes are only traced)."""
+    from mxnet_tpu.ops.pallas import flash_attention as fa
+
+    rows, g, tq, tk, dtype = shape
+    d = 128
+    fits = fa._fused_bwd_vmem_bytes(
+        tq, tk, d, jnp.dtype(dtype).itemsize) <= fa._SCOPED_VMEM
+    assert fits == (want == "fused")
+    q = jax.ShapeDtypeStruct((rows, g, tq, d), jnp.dtype(dtype))
+    kv = jax.ShapeDtypeStruct((rows, tk, d), jnp.dtype(dtype))
+    lse = jax.ShapeDtypeStruct((rows, g, 1, tq), jnp.float32)
+    built = _flash_backward_built()
+    dq, dk, dv = jax.eval_shape(
+        lambda q, k, v, o, lse, do: fa._fa_backward(
+            q, k, v, o, lse, do, True, d ** -0.5, True),
+        q, kv, kv, q, lse, q)
+    assert (dq.shape, dq.dtype) == (q.shape, q.dtype)
+    assert (dk.shape, dv.shape, dk.dtype) == (kv.shape, kv.shape, kv.dtype)
+    _assert_built_one(built, want)
 
 
 @pytest.mark.parametrize("t,pref,want", [

@@ -255,6 +255,18 @@ def test_transformer_bench_example():
     assert "micro" in out and "flash-vs-plain" in out
 
 
+def test_transformer_bench_kernels_arm():
+    """``--kernels`` times forward, dq, dkv and the fused backward from one
+    command and compares the fused kernel's three results with the
+    two-kernel path's (interpret mode here, one tile: equal to the bit;
+    the milliseconds count on the chip alone — PERF.md)."""
+    out = _run("examples/transformer/bench_transformer.py", "--kernels")
+    line = [l for l in out.splitlines() if l.startswith("kernels ")][0]
+    for arm in ("fwd", "dq", "dkv", "fused", "fused under dq+dkv by"):
+        assert " %s " % arm in line, line
+    assert "fused against dq/dkv" in out and "dq 0  dk 0  dv 0" in out, out
+
+
 def test_neural_style_example():
     """Pretrained-model surgery (get_internals feature taps, frozen
     weights, grad only on the image) + imperative-autograd TV term."""
