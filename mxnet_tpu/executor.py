@@ -38,6 +38,7 @@ from .base import MXNetError
 from .context import Context, default_context
 from .ndarray import NDArray
 from .ops.matrix import gathered_rows_as
+from .ops.moe import built_layers
 
 
 def _cast_floats(tree, dtype, src=None, skip=()):
@@ -73,6 +74,53 @@ def _gathered_only(symbol):
                 only[child.name] = only.get(child.name, True) and gathered
     heads = {node.name for node, _ in symbol._entries if node.is_var}
     return frozenset(n for n, ok in only.items() if ok and n not in heads)
+
+
+def _expert_attrs(layers):
+    """The ``executor.train_step`` span's static attributes for a step with
+    ``ExpertFFN`` layers (``ops/moe.py`` ``built_layers``); none without:
+    how many, and of one layer the experts held, the choices a token, the
+    rows of its sorted-assignment buffer as allocated and the assignments
+    expected under uniform routing."""
+    if not layers:
+        return {}
+    one = layers[0]
+    return {"moe_layers": len(layers), "moe_experts_held": one["experts_held"],
+            "moe_top_k": one["top_k"], "moe_buffer_rows": one["buffer_rows"],
+            "moe_expected_rows": one["expected_rows"]}
+
+
+def _relaid(tree, formats):
+    """``tree`` put into ``formats``, the arrays it was copied from freed:
+    (the tree, the formats its leaves hold, whether every leaf took the
+    format asked for). The step consumes what it is passed (the donation
+    contract), but a relayout copies, and the caller's originals would
+    otherwise live beside the copies until the first step returns: 2.1 GB
+    that the chip did not have for SmallThinker's expert matrices (PERF.md,
+    PR 33). A copy that does not REPORT the layout asked for is not used:
+    that leaf stays the caller's array in the layout it has, and the step
+    is then jitted for that (a float32[16,2560,768] leaf came back so, and
+    a step jitted for what the copy reported read it permuted)."""
+    flat, treedef = jax.tree_util.tree_flatten(tree)
+    out, had, whole = [], [], True
+    for old, fmt in zip(flat, treedef.flatten_up_to(formats)):
+        new = jax.device_put(old, fmt)
+        want = getattr(fmt, "layout", None)
+        if want is not None and new.format.layout != want:
+            logging.getLogger("mxnet_tpu").warning(
+                "train step: a %s%s leaf did not take its learned layout; "
+                "it keeps the layout it has", old.dtype, list(old.shape))
+            new, fmt, whole = old, old.format, False
+        elif new is not old and isinstance(old, jax.Array) and \
+                old.is_fully_addressable and not old.is_deleted() and \
+                {s.data.unsafe_buffer_pointer()
+                 for s in old.addressable_shards}.isdisjoint(
+                     s.data.unsafe_buffer_pointer()
+                     for s in new.addressable_shards):
+            old.delete()
+        out.append(new)
+        had.append(fmt)
+    return treedef.unflatten(out), treedef.unflatten(had), whole
 
 
 def _under_mesh(eval_fn, mesh):
@@ -326,6 +374,7 @@ class Executor:
         cd = self._compute_dtype
         tables = _gathered_only(self._symbol) if cd is not None else ()
         chain = max(1, int(chain))
+        experts = built_layers()  # what each ExpertFFN allocates, as traced
         from .parallel import collectives as _coll
         stage = _coll.sharded_stage(mesh, shard_axis)
         sharded = stage >= 1
@@ -356,7 +405,8 @@ class Executor:
                 if cd is not None:
                     av = _cast_floats(av, cd, skip=tables)
                     auxv = _cast_floats(auxv, cd)
-                with gathered_rows_as(cd):
+                del experts.layers[:]  # a retrace lists them again
+                with gathered_rows_as(cd), experts:
                     outs, aux_up = eval_fn(av, auxv, True, rng)
                 if cd is not None:
                     outs = _cast_floats(outs, jnp.float32, src=cd)
@@ -497,10 +547,17 @@ class Executor:
                     from jax.experimental.layout import Format, Layout
 
                     def spec(tree):
-                        # AUTO only for >=2D leaves (conv/fc weights —
-                        # where the per-step layout copies live); vectors
-                        # keep the default layout. Under the ZeRO-1
-                        # sharded update the Format also pins each
+                        # AUTO only for matrices (2-D leaves: where the
+                        # LM's per-step layout copies live); vectors keep
+                        # the default layout, and so do leaves of more
+                        # dimensions: a learned layout does not survive
+                        # `device_put` for them. A float32[16,2560,768]
+                        # expert matrix came back REPORTING the default
+                        # layout, which jit refused, and, jitted for the
+                        # layout it reported, read permuted (PERF.md,
+                        # Findings, PR 33); 1x1 conv weights are refused
+                        # on a warm cache (section 7, item 3). Under the
+                        # ZeRO-1 sharded update the Format also pins each
                         # leaf's NamedSharding so the learned layouts
                         # apply to the 1/N shards.
                         def one(a):
@@ -508,8 +565,8 @@ class Executor:
                                 sh = _coll.zero1_sharding(
                                     mesh, a.shape, shard_axis)
                                 return (Format(Layout.AUTO, sh)
-                                        if a.ndim >= 2 else sh)
-                            return Format(Layout.AUTO) if a.ndim >= 2 else None
+                                        if a.ndim == 2 else sh)
+                            return Format(Layout.AUTO) if a.ndim == 2 else None
                         return jax.tree_util.tree_map(one, tree)
 
                     nextra = (None,) * len(extra)
@@ -541,14 +598,7 @@ class Executor:
                     pf, sf = (learned.input_formats[0][0],
                               learned.input_formats[0][1])
                     aot["informats"] = (pf, sf)
-                    if chain == 1:
-                        aot["jit"] = jax.jit(
-                            step, donate_argnums=(0, 1),
-                            in_shardings=(pf, sf, None, None, None)
-                            + nextra,
-                            out_shardings=(None, pf, sf, None))
-                    else:
-                        aot["jit"] = learned
+                    aot["learned"] = learned
                 # relayout to the learned formats; only needed until the
                 # caller threads returned (already-relaid) arrays back
                 # in — re-issuing device_put on matching arrays is
@@ -556,8 +606,19 @@ class Executor:
                 if not aot.get("relaid"):
                     pf, sf = aot["informats"]
                     with _build("relayout"):
-                        params = jax.device_put(params, pf)
-                        states = jax.device_put(states, sf)
+                        params, pf, took_p = _relaid(params, pf)
+                        states, sf, took_s = _relaid(states, sf)
+                    learned = aot.pop("learned")
+                    if chain == 1 or not (took_p and took_s):
+                        # for the formats the arrays HAVE: the learned
+                        # ones, but for a leaf that did not take its own
+                        aot["jit"] = jax.jit(
+                            step, donate_argnums=(0, 1),
+                            in_shardings=(pf, sf, None, None, None)
+                            + (None,) * len(extra),
+                            out_shardings=(None, pf, sf, None))
+                    else:
+                        aot["jit"] = learned
                     aot["relaid"] = True
                 with _dispatch():
                     outs, new_params, new_states, aux_up = aot["jit"](
@@ -625,17 +686,21 @@ class Executor:
             # compile listener says on both whether, and for how long, jit
             # traced, lowered, compiled or read its cache inside them
             self._train_steps += 1
-            # both byte counts are reckoned on the first call
+            # both byte counts are reckoned on the first call, and the
+            # expert layers are listed as that call traces the step
             counts = ("gather_bytes", "uncast_table_bytes")
             known = {k: aot[k] for k in counts if k in aot}
             with _telemetry.span("executor.train_step", domain="executor",
                                  step=self._train_steps, chain=chain,
                                  stage=stage,
-                                 **(known or dict.fromkeys(counts, 0))) as sp:
+                                 **(known or dict.fromkeys(counts, 0)),
+                                 **(_expert_attrs(experts.layers)
+                                    if known else {})) as sp:
                 out = _run_impl(params, states, data_values, *extra)
                 if not known:
                     for k in counts:
                         sp.add(k, aot.get(k, 0))
+                    sp.annotate(**_expert_attrs(experts.layers))
                 return out
 
         run.lower = lower

@@ -19,5 +19,6 @@ from . import sequence  # noqa: F401
 from . import shape_rules  # noqa: F401
 from . import rnn_fused  # noqa: F401
 from . import attention  # noqa: F401
+from . import moe  # noqa: F401
 from . import contrib  # noqa: F401
 from . import custom  # noqa: F401

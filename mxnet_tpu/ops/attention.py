@@ -65,7 +65,19 @@ def rope(x, positions=None, base=10000.0):
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
 
 
-def dot_product_attention(q, k, v, causal=False, scale=None, mask=None):
+def _causal_mask(tq, tk, window=0):
+    """(tq, tk) bool: query i (at position i + tk - tq, so that kv may be
+    longer than q) sees the keys up to its own; with a ``window``, the
+    last ``window`` of them."""
+    idx_q = jnp.arange(tq)[:, None] + (tk - tq)
+    idx_k = jnp.arange(tk)[None, :]
+    if window:
+        return (idx_q >= idx_k) & (idx_k > idx_q - window)
+    return idx_q >= idx_k
+
+
+def dot_product_attention(q, k, v, causal=False, scale=None, mask=None,
+                          window=0):
     """Reference attention math on (B, H, T, D) tensors.
 
     Computed in float32 accumulation regardless of input dtype (MXU-friendly:
@@ -76,9 +88,7 @@ def dot_product_attention(q, k, v, causal=False, scale=None, mask=None):
     logits = jnp.einsum("bhqd,bhkd->bhqk", q, k,
                         preferred_element_type=jnp.float32) * scale
     if causal:
-        tq, tk = logits.shape[-2], logits.shape[-1]
-        idx_q = jnp.arange(tq)[:, None] + (tk - tq)  # support kv longer than q
-        cmask = idx_q >= jnp.arange(tk)[None, :]
+        cmask = _causal_mask(logits.shape[-2], logits.shape[-1], window)
         logits = jnp.where(cmask, logits, jnp.finfo(jnp.float32).min)
     if mask is not None:
         logits = jnp.where(mask, logits, jnp.finfo(jnp.float32).min)
@@ -90,7 +100,8 @@ def dot_product_attention(q, k, v, causal=False, scale=None, mask=None):
     "MultiHeadAttention",
     arg_names=("query", "key", "value"),
     param_spec={"num_heads": 1, "num_kv_heads": 0, "causal": False,
-                "use_rope": False, "use_flash": True},
+                "use_rope": False, "use_flash": True, "window": 0,
+                "rope_base": 10000.0},
 )
 def _multi_head_attention(attrs, query, key, value):
     """Fused multi-head attention on (B, T, H*D) projected inputs.
@@ -107,6 +118,11 @@ def _multi_head_attention(attrs, query, key, value):
     groups over the VMEM-resident kv block, the XLA path uses a grouped
     einsum — so KV HBM bandwidth shrinks by h/hkv along with the
     projection params/FLOPs. 0 (default) = standard MHA.
+
+    ``window`` > 0 (with ``causal``) lets query i see keys j with
+    i - window < j <= i only; 0 is no window. ``rope_base`` is the base
+    of the rotary frequencies where ``use_rope`` is set; a layer without
+    ``use_rope`` has no position encoding at all.
     """
     h = int(attrs["num_heads"])
     hkv = int(attrs["num_kv_heads"]) or h
@@ -117,6 +133,10 @@ def _multi_head_attention(attrs, query, key, value):
     tk = key.shape[1]
     d = dm // h
     causal = bool(attrs["causal"])
+    window = int(attrs["window"])
+    if window < 0 or (window and not causal):
+        raise ValueError("window %d: a band is causal and not negative"
+                         % window)
 
     def split(x, t, heads):
         return x.reshape(b, t, heads, d).transpose(0, 2, 1, 3)
@@ -124,7 +144,8 @@ def _multi_head_attention(attrs, query, key, value):
     q = split(query, tq, h)
     k, v = split(key, tk, hkv), split(value, tk, hkv)
     if attrs["use_rope"]:
-        q, k = rope(q), rope(k)
+        base = float(attrs["rope_base"])
+        q, k = rope(q, base=base), rope(k, base=base)
     if attrs["use_flash"]:
         # flash_attention owns the selection gate (on-TPU + block
         # contract + MIN_SEQ) and takes narrow (B, Hkv, Tk, D) k/v
@@ -132,15 +153,16 @@ def _multi_head_attention(attrs, query, key, value):
         # einsum / reference math itself, so the predicate lives in ONE
         # place and the two layers cannot drift
         from .pallas import flash_attention as _fa
-        out = _fa.flash_attention(q, k, v, causal=causal)
+        out = _fa.flash_attention(q, k, v, causal=causal, window=window)
     elif hkv != h:
-        out = _grouped_attention(q, k, v, hkv, causal)
+        out = _grouped_attention(q, k, v, hkv, causal, window=window)
     else:
-        out = dot_product_attention(q, k, v, causal=causal)
+        out = dot_product_attention(q, k, v, causal=causal, window=window)
     return out.transpose(0, 2, 1, 3).reshape(b, tq, dm)
 
 
-def _grouped_attention(q, k, v, hkv, causal, scale=None, mask=None):
+def _grouped_attention(q, k, v, hkv, causal, scale=None, mask=None,
+                       window=0):
     """GQA without materializing repeated kv: q (B, H, Tq, D) grouped as
     (B, Hkv, G, Tq, D) against k/v (B, Hkv, Tk, D) — kv streams once per
     GROUP, which is the bandwidth/KV-cache saving GQA exists for.
@@ -154,9 +176,7 @@ def _grouped_attention(q, k, v, hkv, causal, scale=None, mask=None):
     logits = jnp.einsum("bkgqd,bkld->bkgql", q5, k,
                         preferred_element_type=jnp.float32) * scale
     if causal:
-        tk = logits.shape[-1]
-        idx_q = jnp.arange(tq)[:, None] + (tk - tq)
-        cmask = idx_q >= jnp.arange(tk)[None, :]
+        cmask = _causal_mask(tq, logits.shape[-1], window)
         logits = jnp.where(cmask, logits, jnp.finfo(jnp.float32).min)
     if mask is not None:
         logits = jnp.where(mask[:, None, None, None, :], logits,

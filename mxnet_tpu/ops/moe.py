@@ -1,0 +1,225 @@
+"""Sparse expert feed-forward: one chip's share of a top-k expert layer.
+
+``ExpertFFN`` routes every token over ALL ``num_experts`` (the router keeps
+its published width), keeps the ``top_k`` largest, and computes the part of
+the layer's result that the ``experts_held`` experts it was given
+(``first_expert`` onward) contribute. What the other experts would add is
+another chip's; on one chip nothing is exchanged and nothing stands in for
+the absent chips. Expert parallelism asks exactly this of a layer, so the
+same op is what an ``expert`` mesh axis above 1 will shard.
+
+Nothing is dropped. Assignments are sorted by expert and the three expert
+matrices are GROUPED products over the ragged, sorted batches
+(``jax.lax.ragged_dot``, which the TPU compiler builds as one grouped-matmul
+kernel over row tiles; its transposes are ragged products too), never a
+dense product over all held experts with a mask and never a one-hot
+dispatch (``parallel/moe.py``'s Switch layer is that, with a capacity that
+clips). Shapes are static, so the sorted rows live in a buffer that holds
+the worst case (every token's every choice held here), and the products
+walk ALL of it whatever the routing: the last held expert's group is
+stretched over the rows no assignment took, which are zero and add nothing
+to any product. A step's time then does not follow its routing. That is a
+choice, and it costs: with untrained weights a layer's held experts drew
+0.05 to 2.97 times the expected load, and a first buffer of twice the
+expectation with the worst case as an exact overflow path under a
+``lax.cond`` took 240-254 ms a step by the seed (0.6 ms per 1000 held rows,
+the overflow path in 4 seeds of 6) where this takes 301 on every seed
+(PERF.md, Findings, PR 33).
+
+Every move between token order and sorted-row order is a row GATHER in
+both directions (a permutation read forwards or backwards), because the
+transpose of a gather that autodiff would write is a scatter-add, which the
+TPU runs row by row (PERF.md, section 7, item 8). The backward is
+autodiff's: a ``custom_vjp`` that recomputed the forward from the layer's
+inputs compiled to the same program, XLA merging the second forward with
+the first.
+"""
+from __future__ import annotations
+
+import threading
+
+import jax
+import jax.numpy as jnp
+
+from ..telemetry.metrics import registry
+from .registry import defop
+
+_ACTS = {"relu": jax.nn.relu, "silu": jax.nn.silu, "gelu": jax.nn.gelu}
+
+_tracing = threading.local()
+
+
+def buffer_rows(tokens, top_k, held, experts):
+    """(rows of the sorted-assignment buffer, assignments expected): the
+    buffer holds the worst case, every token's every choice among the held
+    experts; the expectation is under uniform routing."""
+    return tokens * min(top_k, held), tokens * top_k * held / experts
+
+
+class built_layers:
+    """Entered around the trace of a graph: every ``ExpertFFN`` traced
+    under it appends what it allocated to ``self.layers`` (a dict each).
+    Trace-time Python state only: nothing here reaches the program."""
+
+    def __init__(self):
+        self.layers = []
+
+    def __enter__(self):
+        self._prev = getattr(_tracing, "into", None)
+        _tracing.into = self.layers
+        return self
+
+    def __exit__(self, *exc):
+        _tracing.into = self._prev
+        return False
+
+
+# --- moves between token order and sorted-row order ---------------------------
+# A plan (dict of int/bool arrays) describes one permutation both ways:
+#   tok (R,), order (R,), valid (R,): row r holds assignment order[r], of
+#       token tok[r]; rows past the held assignments are not valid;
+#   slot (N, k), ok (N, k): assignment (t, j) sits in row slot[t, j], if ok.
+
+def _take(src, index, mask):
+    out = jnp.take(src, index, axis=0)
+    return jnp.where(mask.reshape(mask.shape + (1,) * (src.ndim - 1)), out, 0)
+
+
+@jax.custom_vjp
+def _spread(x, plan):
+    """Tokens (N, d) -> sorted rows (R, d)."""
+    return _take(x, plan["tok"], plan["valid"])
+
+
+@jax.custom_vjp
+def _collect(rows, plan):
+    """Sorted rows (R, d) -> tokens (N, d): the sum of a token's rows."""
+    got = _take(rows, plan["slot"], plan["ok"])            # (N, k, d)
+    return jnp.sum(got, axis=1, dtype=jnp.float32).astype(rows.dtype)
+
+
+@jax.custom_vjp
+def _row_weights(w, plan):
+    """Routing weights (N, k) -> one a sorted row (R,)."""
+    return _take(w.reshape(-1), plan["order"], plan["valid"])
+
+
+_spread.defvjp(lambda x, plan: (_spread(x, plan), plan),
+               lambda plan, g: (_collect(g, plan), None))
+_collect.defvjp(lambda rows, plan: (_collect(rows, plan), plan),
+                lambda plan, g: (_spread(g, plan), None))
+_row_weights.defvjp(
+    lambda w, plan: (_row_weights(w, plan), plan),
+    lambda plan, g: (_take(g, plan["slot"], plan["ok"]), None))
+
+
+def _sort_assignments(idx, first, held):
+    """idx (N, k): the experts each token chose. Returns the assignments'
+    order by held expert (those of other chips' experts last), its inverse,
+    and how many each held expert took."""
+    local = idx.reshape(-1) - first
+    key = jnp.where((local >= 0) & (local < held), local, held)
+    order = jnp.argsort(key, stable=True).astype(jnp.int32)
+    inv = jnp.argsort(order).astype(jnp.int32)
+    ends = jnp.searchsorted(key[order], jnp.arange(held + 1, dtype=key.dtype))
+    sizes = jnp.diff(ends).astype(jnp.int32)
+    return order, inv, sizes
+
+
+def _plan(order, inv, total, rows, top_k):
+    r = jnp.arange(rows, dtype=jnp.int32)
+    inv = inv.reshape(-1, top_k)
+    return {"tok": order[:rows] // top_k, "order": order[:rows],
+            "valid": r < total, "slot": jnp.minimum(inv, rows - 1),
+            "ok": inv < total}
+
+
+def _grouped(x, w, sizes):
+    """x (R, in) by w (E, out, in), rows grouped by ``sizes``: (R, out)."""
+    return jax.lax.ragged_dot(x, jnp.swapaxes(w, 1, 2), sizes,
+                              preferred_element_type=x.dtype)
+
+
+def _held_part(rows, top_k, act, x, w, wg, wu, wd, order, inv, sizes):
+    """The held experts' part of the layer through a buffer of ``rows``
+    sorted rows, all of which the products walk."""
+    total = jnp.sum(sizes)
+    plan = _plan(order, inv, total, rows, top_k)
+    walked = sizes.at[-1].add(rows - total)  # the empty rows: zeros
+    xs = _spread(x, plan)
+    a = act(_grouped(xs, wg, walked)) * _grouped(xs, wu, walked)
+    ys = _grouped(a, wd, walked)
+    ys = (ys * _row_weights(w, plan)[:, None]).astype(ys.dtype)
+    return _collect(ys, plan)
+
+
+def route(router_data, router_weight, top_k, norm_topk):
+    """Float32 router over all the experts: (weights (N, k) float32, which
+    experts (N, k) int32). The choice carries no gradient, the weights
+    do."""
+    hp = jax.lax.Precision.HIGHEST
+    logits = jnp.einsum("nd,ed->ne", router_data.astype(jnp.float32),
+                        router_weight.astype(jnp.float32), precision=hp)
+    if norm_topk:
+        top, idx = jax.lax.top_k(logits, top_k)
+        return jax.nn.softmax(top, axis=-1), idx
+    _, idx = jax.lax.top_k(logits, top_k)
+    return jnp.take_along_axis(jax.nn.softmax(logits, axis=-1), idx, 1), idx
+
+
+@defop(
+    "ExpertFFN",
+    arg_names=("data", "router_data", "router_weight", "gate_weight",
+               "up_weight", "down_weight"),
+    num_outputs=2,
+    output_names=("output", "expert_tokens"),
+    param_spec={"num_experts": 1, "experts_held": 0, "first_expert": 0,
+                "top_k": 1, "norm_topk": True, "act_type": "relu"},
+)
+def _expert_ffn(attrs, data, router_data, router_weight, gate_weight,
+                up_weight, down_weight):
+    """Top-k gated expert feed-forward, this chip's share.
+
+    ``data`` (B, T, d) feeds the experts; the router reads ``router_data``
+    (B, T, d), which may be another tensor (SmallThinker routes on the
+    attention's input): ``softmax`` of the ``top_k`` largest of
+    ``router_data @ router_weight.T`` over all ``num_experts``, in float32
+    (``norm_topk`` False: the softmax over all experts, not renormalised).
+    ``gate_weight``/``up_weight`` (E_held, f, d) and ``down_weight``
+    (E_held, d, f) are experts ``first_expert`` .. ``first_expert +
+    experts_held - 1`` (``experts_held`` 0: all of them). Output 0: the sum
+    over a token's chosen experts AMONG THOSE HELD of
+    ``weight * down(act(gate x) * up x)``; the weights stay normalised over
+    all ``top_k`` chosen. Output 1 (float32, no gradient): how many
+    assignments each held expert took, for a load-balance metric. No token
+    is dropped, whatever the routing."""
+    experts = int(attrs["num_experts"])
+    held = int(attrs["experts_held"]) or experts
+    first, top_k = int(attrs["first_expert"]), int(attrs["top_k"])
+    if gate_weight.shape[0] != held or router_weight.shape[0] != experts:
+        raise ValueError("ExpertFFN: %d expert matrices for experts_held=%d, "
+                         "router of %d for num_experts=%d" % (
+                             gate_weight.shape[0], held,
+                             router_weight.shape[0], experts))
+    if not 0 <= first <= experts - held or not 0 < top_k <= experts:
+        raise ValueError("ExpertFFN: experts %d..%d of %d, top_k %d"
+                         % (first, first + held - 1, experts, top_k))
+    act = _ACTS[attrs["act_type"]]
+    d = data.shape[-1]
+    x = data.reshape(-1, d)
+    w, idx = route(router_data.reshape(-1, d), router_weight, top_k,
+                   bool(attrs["norm_topk"]))
+    order, inv, sizes = _sort_assignments(idx, first, held)
+    rows, expected = buffer_rows(x.shape[0], top_k, held, experts)
+    registry.counter(
+        "expert_layer_built_total", labels={"path": "ragged_dot"},
+        help="expert layers traced into a program, by the grouped-product "
+             "path they were built with").inc()
+    into = getattr(_tracing, "into", None)
+    if into is not None:
+        into.append({"experts_held": held, "top_k": top_k,
+                     "buffer_rows": rows, "expected_rows": expected})
+    y = _held_part(rows, top_k, act, x, w, gate_weight, up_weight,
+                   down_weight, order, inv, sizes)
+    counts = jax.lax.stop_gradient(sizes.astype(jnp.float32))
+    return y.reshape(data.shape), counts

@@ -146,15 +146,18 @@ def _dot(a, b, dims):
                                dims, preferred_element_type=jnp.float32)
 
 
-def _keep(shape, q0, k0, q_axis):
+def _keep(shape, q0, k0, q_axis, window=0):
     """Causal keep-mask of one score tile whose first query sits at
     position q0 and first key at k0, queries along ``q_axis``. Positions
     are absolute and a query's includes ``offset`` = tk - tq: causal
     masking aligns the LAST query with the last key (kv-cache decode),
     matching the XLA paths' (tk - tq) query offset (attention.py
-    dot_product_attention / _grouped_attention)."""
+    dot_product_attention / _grouped_attention). A ``window`` keeps the
+    last ``window`` keys up to the query's own: q - window < k <= q."""
     q_pos = q0 + jax.lax.broadcasted_iota(jnp.int32, shape, q_axis)
     k_pos = k0 + jax.lax.broadcasted_iota(jnp.int32, shape, 1 - q_axis)
+    if window:
+        return (q_pos >= k_pos) & (k_pos > q_pos - window)
     return q_pos >= k_pos
 
 
@@ -175,12 +178,32 @@ def _when(known, cond):
     return (lambda body: body()) if known else pl.when(cond)
 
 
-def _key_tiles(causal, q0, bq, k_base, block_k, n):
-    """How many of the n key tiles from position k_base the query block
-    at q0 walks: a causal one stops at the (offset) diagonal."""
+def _key_tiles(causal, q0, bq, k_base, block_k, n, window=0):
+    """(lo, hi): which of the n key tiles from position k_base the query
+    block at q0 walks. A causal one stops at the (offset) diagonal; a
+    band starts at the tile that holds the first key its first query
+    sees, q0 - window + 1."""
     if not causal:
-        return n
-    return jnp.clip(pl.cdiv(q0 + bq - k_base, block_k), 0, n)
+        return 0, n
+    hi = jnp.clip(pl.cdiv(q0 + bq - k_base, block_k), 0, n)
+    if not window:
+        return 0, hi
+    lo = jnp.maximum(q0 - window + 1 - k_base, 0) // block_k
+    return jnp.minimum(lo, hi), hi
+
+
+def _query_tiles(causal, k0, bk, q_base, block_q, n, window=0):
+    """(lo, hi): which of the n query tiles from (offset) position q_base
+    the key block at k0 is walked by, the transposed ``_key_tiles``: from
+    the tile on the diagonal to the tile that holds the last query whose
+    band reaches the block, k0 + bk + window - 2."""
+    if not causal:
+        return 0, n
+    lo = jnp.clip((k0 - q_base) // block_q, 0, n)
+    if not window:
+        return lo, n
+    hi = jnp.clip(pl.cdiv(k0 + bk + window - 1 - q_base, block_q), 0, n)
+    return jnp.minimum(lo, hi), hi
 
 
 def _walk(tile, lo, hi):
@@ -196,7 +219,7 @@ def _walk(tile, lo, hi):
 # --- forward -------------------------------------------------------------------
 
 def _fa_kernel(q_ref, k_ref, v_ref, o_ref, *rest, causal, scale, block_k,
-               offset, with_lse, num_super):
+               offset, with_lse, num_super, window=0):
     """One (batch*kv-head, group, q-block, k-superblock) grid cell: the
     online softmax of the query block over the superblock's block_k
     tiles, its state (acc, running max, running sum; the last two one
@@ -232,8 +255,8 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, *rest, causal, scale, block_k,
             rows = pl.ds(kb * block_k, block_k)
             s = _dot(q, k_ref[0, rows, :], _NT)        # (BQ, BK)
             if causal:
-                s = jnp.where(_keep(s.shape, q0, k_base + kb * block_k, 0),
-                              s, _NEG_INF)
+                s = jnp.where(_keep(s.shape, q0, k_base + kb * block_k, 0,
+                                    window), s, _NEG_INF)
             m_prev = m_ref[...]
             m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
             p = jnp.exp(s - _lanes(m_new, block_k))
@@ -244,14 +267,15 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, *rest, causal, scale, block_k,
             acc_ref[...] = (acc_ref[...] * _lanes(alpha, d)
                             + _dot(p, v_ref[0, rows, :], _NN))
 
-        _walk(tile, 0, _key_tiles(causal, q0, bq, k_base, block_k,
-                                  sk // block_k))
+        _walk(tile, *_key_tiles(causal, q0, bq, k_base, block_k,
+                                sk // block_k, window))
 
-    # causal: supersteps strictly right of the diagonal contribute nothing:
-    # skip the compute (their K/V fetch is also elided — the index map
-    # clamps to the diagonal superblock, and Pallas only issues a DMA when
-    # the block index CHANGES)
-    _when(one or not causal, k_base <= q0 + bq - 1)(_compute)
+    # causal: supersteps strictly right of the diagonal contribute nothing,
+    # nor do those wholly left of a band: skip the compute (their K/V fetch
+    # is also elided — the index map clamps to the live superblocks, and
+    # Pallas only issues a DMA when the block index CHANGES)
+    _when(one or not causal, _super_live(k_base, sk, q0, bq, window))(
+        _compute)
 
     @_when(one, ski == num_super - 1)
     def _finalize():
@@ -261,17 +285,31 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, *rest, causal, scale, block_k,
             lse_ref[0, 0, 0] = (m_ref[...] + jnp.log(l))[:, 0]
 
 
-def _kv_stream_idx(block_q, super_k, offset, causal):
+def _super_live(k_base, sk, q0, bq, window):
+    """Whether the key superblock [k_base, k_base + sk) holds a key that
+    the causal query block at (offset) position q0 sees."""
+    live = k_base <= q0 + bq - 1
+    if window:
+        live &= k_base + sk - 1 >= q0 - window + 1
+    return live
+
+
+def _kv_stream_idx(block_q, super_k, offset, causal, window=0):
     """Index map for K/V superblocks streamed under a (b, g, qi, ski)
     grid. Causal grids clamp ski to this q-block's diagonal superblock so
     the fully-masked tail re-addresses the same superblock (no DMA) while
-    the kernel skips its compute."""
+    the kernel skips its compute; a band clamps it from below too, to the
+    superblock of the first key the block's first query sees."""
     if not causal:
         return lambda b, gi, qi, ski: (b, ski, 0)
 
     def idx(b, gi, qi, ski):
         hi = jax.lax.div(qi * block_q + block_q - 1 + offset, super_k)
-        return (b, jnp.minimum(ski, hi), 0)
+        ski = jnp.minimum(ski, hi)
+        if window:
+            ski = jnp.maximum(ski, jax.lax.div(jnp.maximum(
+                qi * block_q + offset - window + 1, 0), super_k))
+        return (b, ski, 0)
 
     return idx
 
@@ -283,10 +321,12 @@ def _compiler_params(interpret, grid_dims=4):
         dimension_semantics=("parallel",) + ("arbitrary",) * (grid_dims - 1))}
 
 
-def _fa_forward(q, k, v, causal, scale, interpret, with_lse=False):
+def _fa_forward(q, k, v, causal, scale, interpret, with_lse=False,
+                window=0):
     """q: (B*Hkv, G, Tq, D); k/v: (B*Hkv, Tk, D). Returns (B*Hkv, G, Tq,
     D) [+ lse (B*Hkv, G, 1, Tq) — the singleton keeps the last two block
-    dims TPU-tileable]."""
+    dims TPU-tileable]. ``window`` > 0 (causal only) bands the scores:
+    the tile walk starts where the band does."""
     bkv, g, tq, d = q.shape
     tk = k.shape[1]
     block_q = _pick_block(tq, BLOCK_Q)
@@ -297,7 +337,8 @@ def _fa_forward(q, k, v, causal, scale, interpret, with_lse=False):
     # k/v block index ignores (gi, i): Pallas re-fetches only on index
     # change, so resident K/V stream from HBM once per KV head
     kv_spec = pl.BlockSpec((1, super_k, d),
-                           _kv_stream_idx(block_q, super_k, tk - tq, causal))
+                           _kv_stream_idx(block_q, super_k, tk - tq, causal,
+                                          window))
     out_specs = [q_spec]
     out_shape = [jax.ShapeDtypeStruct((bkv, g, tq, d), q.dtype)]
     if with_lse:
@@ -311,7 +352,8 @@ def _fa_forward(q, k, v, causal, scale, interpret, with_lse=False):
     res = pl.pallas_call(
         functools.partial(_fa_kernel, causal=causal, scale=scale,
                           block_k=block_k, offset=tk - tq,
-                          with_lse=with_lse, num_super=num_super),
+                          with_lse=with_lse, num_super=num_super,
+                          window=window),
         grid=(bkv, g, tq // block_q, num_super),
         in_specs=[q_spec, kv_spec, kv_spec],
         out_specs=out_specs,
@@ -335,7 +377,7 @@ def _fa_forward(q, k, v, causal, scale, interpret, with_lse=False):
 
 def _fa_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dvec_ref,
                       dq_ref, dq_acc_ref, *, causal, scale, block_k, offset,
-                      num_super):
+                      num_super, window=0):
     """dQ for one (batch*kv-head, group, q-block, k-superblock): rebuild p
     from the saved logsumexp tile by tile, dq += (p * (dO v^T - D)) @ k in
     VMEM scratch, scaled and written on the last superstep."""
@@ -359,8 +401,8 @@ def _fa_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dvec_ref,
             k, v = k_ref[0, rows, :], v_ref[0, rows, :]
             s = _dot(q, k, _NT) * scale
             if causal:
-                s = jnp.where(_keep(s.shape, q0, k_base + kb * block_k, 0),
-                              s, _NEG_INF)
+                s = jnp.where(_keep(s.shape, q0, k_base + kb * block_k, 0,
+                                    window), s, _NEG_INF)
             # the two columns are spread across lanes HERE, tile by tile:
             # spread once before the loop they hold 2 x BQ/8 vregs through
             # it, and the kernel measured 9% slower
@@ -368,10 +410,11 @@ def _fa_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dvec_ref,
             ds = p * (_dot(do, v, _NT) - dvec[:, None])
             dq_acc_ref[...] += _dot(ds, k, _NN)
 
-        _walk(tile, 0, _key_tiles(causal, q0, bq, k_base, block_k,
-                                  sk // block_k))
+        _walk(tile, *_key_tiles(causal, q0, bq, k_base, block_k,
+                                sk // block_k, window))
 
-    _when(one or not causal, k_base <= q0 + bq - 1)(_compute)
+    _when(one or not causal, _super_live(k_base, sk, q0, bq, window))(
+        _compute)
 
     @_when(one, ski == num_super - 1)
     def _finalize():
@@ -380,7 +423,7 @@ def _fa_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dvec_ref,
 
 def _fa_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dvec_ref,
                        dk_ref, dv_ref, dk_acc_ref, dv_acc_ref, *, causal,
-                       scale, block_q, offset, g, num_super):
+                       scale, block_q, offset, g, num_super, window=0):
     """dK/dV for one (batch*kv-head, k-block): q/dO/lse/D arrive through
     the two inner grid dims (group head, then q-superblock, whose block_q
     tiles the loop walks) while K/V stay put, and dk/dv accumulate across
@@ -422,22 +465,25 @@ def _fa_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dvec_ref,
             st = _dot(k, q, _NT) * scale               # (BK, BQ)
             if causal:
                 st = jnp.where(
-                    _keep(st.shape, q_base + qb * block_q, k0, 1), st,
-                    _NEG_INF)
+                    _keep(st.shape, q_base + qb * block_q, k0, 1, window),
+                    st, _NEG_INF)
             pt = jnp.exp(st - lse_ref[0, 0, :, rows])
             dv_acc_ref[...] += _dot(pt, do, _NN)
             dst = pt * (_dot(v, do, _NT) - dvec_ref[0, 0, :, rows])
             dk_acc_ref[...] += _dot(dst, q, _NN)
 
-        n = sq // block_q
         # causal: tiles whose last (offset) query position precedes this
-        # k block's start contribute nothing (every entry masked)
-        _walk(tile, jnp.clip((k0 - q_base) // block_q, 0, n) if causal
-              else 0, n)
+        # k block's start contribute nothing (every entry masked), nor do
+        # those whose band has passed the block
+        _walk(tile, *_query_tiles(causal, k0, bk, q_base, block_q,
+                                  sq // block_q, window))
 
-    # causal: q superblocks entirely above the diagonal are skipped; their
-    # q-side fetches are elided by the clamped index map
-    _when(one or not causal, q_base + sq - 1 >= k0)(_compute)
+    # causal: q superblocks entirely above the diagonal, or past the band,
+    # are skipped; their q-side fetches are elided by the clamped index map
+    live = q_base + sq - 1 >= k0
+    if window:
+        live &= q_base <= k0 + bk + window - 2
+    _when(one or not causal, live)(_compute)
 
     @_when(one and g == 1, (gi == g - 1) & (qsi == num_super - 1))
     def _finalize():
@@ -448,7 +494,7 @@ def _fa_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dvec_ref,
 def _fa_bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dvec_ref,
                          dq_ref, dk_ref, dv_ref, dq_acc_ref, dk_acc_ref,
                          dv_acc_ref, *, causal, scale, block_q, offset, g,
-                         num_k):
+                         num_k, window=0):
     """dQ, dK and dV for one (batch*kv-head, group head, k-block): every
     live tile builds s, p, dp and ds ONCE and spends five products on
     them, where the dq and dkv kernels together spend seven. q, dO, lse
@@ -489,19 +535,19 @@ def _fa_bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dvec_ref,
         q, do = q_ref[0, 0, rows, :], do_ref[0, 0, rows, :]
         st = _dot(k, q, _NT) * scale                   # (BK, BQ)
         if causal:
-            st = jnp.where(_keep(st.shape, offset + qb * block_q, k0, 1),
-                           st, _NEG_INF)
+            st = jnp.where(_keep(st.shape, offset + qb * block_q, k0, 1,
+                                 window), st, _NEG_INF)
         pt = jnp.exp(st - lse_ref[0, 0, :, rows])
         dv_acc_ref[krows, :] += _dot(pt, do, _NN)
         dst = pt * (_dot(v, do, _NT) - dvec_ref[0, 0, :, rows])
         dk_acc_ref[krows, :] += _dot(dst, q, _NN)
         dq_acc_ref[rows, :] += _dot(dst, k, _TN)
 
-    n = tq // block_q
     # causal: tiles whose last (offset) query position precedes this k
-    # block's start contribute nothing (every entry masked)
-    _walk(tile, jnp.clip((k0 - offset) // block_q, 0, n) if causal else 0,
-          n)
+    # block's start contribute nothing (every entry masked), nor do those
+    # whose band has passed the block
+    _walk(tile, *_query_tiles(causal, k0, bk, offset, block_q,
+                              tq // block_q, window))
 
     @_when(num_k == 1, ki == num_k - 1)
     def _write_dq():
@@ -537,7 +583,7 @@ def _fused_bwd_vmem_bytes(tq, tk, d, itemsize):
     return 2 * blocks + acc + tiles
 
 
-def _fa_backward_fused(args, causal, scale, interpret):
+def _fa_backward_fused(args, causal, scale, interpret, window=0):
     """dq, dk, dv from ONE kernel over a (batch*kv-head, group head,
     k-block) grid: `_fa_bwd_fused_kernel`."""
     q, k, v = args[:3]
@@ -558,7 +604,7 @@ def _fa_backward_fused(args, causal, scale, interpret):
     return pl.pallas_call(
         functools.partial(_fa_bwd_fused_kernel, causal=causal, scale=scale,
                           block_q=block_q, offset=tk - tq, g=g,
-                          num_k=num_k),
+                          num_k=num_k, window=window),
         grid=(bkv, g, num_k),
         in_specs=[q_spec, kv_spec, kv_spec, q_spec, qrow_spec, qrow_spec],
         out_specs=[q_spec, dkv_spec, dkv_spec],
@@ -596,7 +642,7 @@ def _bwd_in_bytes(args):
     return (q.size + k.size + v.size + do.size) * q.dtype.itemsize
 
 
-def _fa_backward_split(args, causal, scale, interpret):
+def _fa_backward_split(args, causal, scale, interpret, window=0):
     """dq from one kernel, dk and dv from another: two walks over the
     same tiles, seven products a tile pair, nothing in VMEM that grows
     with the sequence beyond a superblock."""
@@ -614,11 +660,12 @@ def _fa_backward_split(args, causal, scale, interpret):
     qrow_spec = pl.BlockSpec((1, 1, 1, block_q),
                              lambda b, gi, i, ski: (b, gi, 0, i))
     kv_spec = pl.BlockSpec((1, super_k, d),
-                           _kv_stream_idx(block_q, super_k, offset, causal))
+                           _kv_stream_idx(block_q, super_k, offset, causal,
+                                          window))
     dq = pl.pallas_call(
         functools.partial(_fa_bwd_dq_kernel, causal=causal, scale=scale,
                           block_k=block_k, offset=offset,
-                          num_super=num_super),
+                          num_super=num_super, window=window),
         grid=(bkv, g, tq // block_q, num_super),
         in_specs=[q_spec, kv_spec, kv_spec, q_spec, qrow_spec, qrow_spec],
         out_specs=q_spec,
@@ -634,24 +681,33 @@ def _fa_backward_split(args, causal, scale, interpret):
     super_q, num_super = _superblocks(tq, block_q)
 
     # causal: q superblocks strictly above this k block's diagonal are
-    # fully masked; clamp their index so the dead steps re-address the
-    # first live superblock (no DMA) while the kernel skips their compute
+    # fully masked, as are those past a band; clamp their index so the
+    # dead steps re-address a live superblock (no DMA) while the kernel
+    # skips their compute
     def first_live(i):
         if not causal:
             return 0
         return jax.lax.div(jax.lax.max(i * block_k - offset, 0), super_q)
 
+    def live(i, qsi):
+        qsi = jnp.maximum(qsi, first_live(i))
+        if window:
+            last = (i + 1) * block_k + window - 2 - offset
+            qsi = jnp.minimum(qsi, jax.lax.div(jax.lax.max(last, 0),
+                                               super_q))
+        return qsi
+
     q_spec = pl.BlockSpec(
         (1, 1, super_q, d),
-        lambda b, i, gi, qsi: (b, gi, jnp.maximum(qsi, first_live(i)), 0))
+        lambda b, i, gi, qsi: (b, gi, live(i, qsi), 0))
     qrow_spec = pl.BlockSpec(
         (1, 1, 1, super_q),
-        lambda b, i, gi, qsi: (b, gi, 0, jnp.maximum(qsi, first_live(i))))
+        lambda b, i, gi, qsi: (b, gi, 0, live(i, qsi)))
     kv_spec = pl.BlockSpec((1, block_k, d), lambda b, i, gi, qsi: (b, i, 0))
     dk, dv = pl.pallas_call(
         functools.partial(_fa_bwd_dkv_kernel, causal=causal, scale=scale,
                           block_q=block_q, offset=offset, g=g,
-                          num_super=num_super),
+                          num_super=num_super, window=window),
         grid=(bkv, tk // block_k, g, num_super),
         in_specs=[q_spec, kv_spec, kv_spec, q_spec, qrow_spec, qrow_spec],
         out_specs=[kv_spec, kv_spec],
@@ -673,7 +729,7 @@ def _fa_backward_split(args, causal, scale, interpret):
 
 
 def _fa_backward(q, k, v, o, lse, do, causal, scale, interpret,
-                 g_lse=None):
+                 g_lse=None, window=0):
     """q/o/do: (B*Hkv, G, Tq, D); k/v: (B*Hkv, Tk, D); lse: (B*Hkv, G, 1,
     Tq). Returns (dq like q, dk/dv like k/v) — dk/dv already summed over
     the query-head group inside the kernel.
@@ -693,8 +749,8 @@ def _fa_backward(q, k, v, o, lse, do, causal, scale, interpret,
         help="flash-attention backward passes traced into a program, by "
              "the kernels chosen from the shapes").inc()
     if fused:
-        return _fa_backward_fused(args, causal, scale, interpret)
-    return _fa_backward_split(args, causal, scale, interpret)
+        return _fa_backward_fused(args, causal, scale, interpret, window)
+    return _fa_backward_split(args, causal, scale, interpret, window)
 
 
 def _aligned(t, block):
@@ -734,25 +790,26 @@ def kernel_qualifies(tq, tk, d, compiled=True, causal=False):
             and (not compiled or d % 128 == 0))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
-def _flash(q3, k3, v3, causal, scale, interpret):
-    return _fa_forward(q3, k3, v3, causal, scale, interpret)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _flash(q3, k3, v3, causal, scale, interpret, window=0):
+    return _fa_forward(q3, k3, v3, causal, scale, interpret, window=window)
 
 
-def _flash_fwd(q3, k3, v3, causal, scale, interpret):
+def _flash_fwd(q3, k3, v3, causal, scale, interpret, window):
     out, lse = _fa_forward(q3, k3, v3, causal, scale, interpret,
-                           with_lse=True)
+                           with_lse=True, window=window)
     return out, (q3, k3, v3, out, lse)
 
 
-def _flash_bwd(causal, scale, interpret, res, g):
+def _flash_bwd(causal, scale, interpret, window, res, g):
     # Blocked FlashAttention-2 backward: rebuilds p per tile from the
     # saved logsumexp — never materializes the (Tq, Tk) score matrix, so
     # long-sequence TRAINING scales like the forward (docs/perf.md
     # attention section; previously this was recompute-through-the-
     # reference-math and the S^2 backward dominated at seq >= 4096).
     q3, k3, v3, o3, lse = res
-    return _fa_backward(q3, k3, v3, o3, lse, g, causal, scale, interpret)
+    return _fa_backward(q3, k3, v3, o3, lse, g, causal, scale, interpret,
+                        window=window)
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
@@ -783,9 +840,14 @@ def _flash_with_lse_bwd(causal, scale, interpret, res, g):
 _flash_with_lse.defvjp(_flash_with_lse_fwd, _flash_with_lse_bwd)
 
 
-def flash_attention(q, k, v, causal=False, scale=None, interpret=None):
+def flash_attention(q, k, v, causal=False, scale=None, interpret=None,
+                    window=0):
     """Attention over q (B, H, T, D). Pallas on TPU, XLA reference
     otherwise.
+
+    ``window`` > 0 (causal only) bands the scores: query i sees keys j
+    with i - window < j <= i, and the kernels walk only the key tiles the
+    band reaches, so a band costs its own pairs and not the triangle's.
 
     k/v may carry FEWER heads (B, Hkv, Tk, D) with Hkv dividing H
     (grouped-query / multi-query attention): the kernel grids the query
@@ -803,13 +865,19 @@ def flash_attention(q, k, v, causal=False, scale=None, interpret=None):
                          % (h, hkv))
     if scale is None:
         scale = 1.0 / (d ** 0.5)
+    window = int(window)
+    if window < 0 or (window and not causal):
+        raise ValueError("window %d: a band is causal and not negative"
+                         % window)
+    if window >= tk:
+        window = 0  # the band holds every causal pair
 
     def fallback():
         if hkv != h:
             return _att._grouped_attention(q, k, v, hkv, causal,
-                                           scale=scale)
+                                           scale=scale, window=window)
         return _att.dot_product_attention(q, k, v, causal=causal,
-                                          scale=scale)
+                                          scale=scale, window=window)
 
     # kernel_qualifies = the correctness contract; MIN_SEQ = the measured
     # perf threshold (auto mode only)
@@ -827,11 +895,18 @@ def flash_attention(q, k, v, causal=False, scale=None, interpret=None):
         return fallback()
 
     g = h // hkv
+    if causal:
+        registry.counter(
+            "flash_window_built_total",
+            labels={"band": "window" if window else "causal"},
+            help="causal flash-attention calls traced into a program, by "
+                 "whether a window bands the tile walk").inc()
 
     def run(q, k, v):
         rows = q.shape[0] * hkv
         out = _flash(q.reshape(rows, g, tq, d), k.reshape(rows, tk, d),
-                     v.reshape(rows, tk, d), causal, scale, interpret)
+                     v.reshape(rows, tk, d), causal, scale, interpret,
+                     window)
         return out.reshape(q.shape)
 
     mesh = partition_mesh()

@@ -147,6 +147,15 @@ class DecodeModel:
             n_layers += 1
         if n_layers == 0:
             raise ServingError("decode model: no layer0_q_weight in params")
+        other = [k for k in ("layer0_router_weight", "layer0_gate_weight")
+                 if k in arg_params]
+        if other or "layer0_ln1_beta" not in arg_params:
+            raise ServingError(
+                "decode model: the decode builders build the dense LayerNorm "
+                "block of models/transformer.py alone; this checkpoint is of "
+                "another kind (%s): RMSNorm, expert and window layers train "
+                "through Symbol.simple_bind + make_train_step and are not "
+                "served yet" % (", ".join(other) or "no layer0_ln1_beta"))
         stacked: Dict[str, list] = {k: [] for k in (
             "ln1_g", "ln1_b", "wq", "wk", "wv", "wo", "ln2_g", "ln2_b",
             "w1", "b1", "w2", "b2")}

@@ -45,6 +45,9 @@ BF16_SKIP = {
     "argsort": "sort order of values closer than one bf16 ulp is "
                "legitimately unstable across compute dtypes",
     "topk": "same tie instability as argsort",
+    "ExpertFFN": "which experts a token takes is a top-k over the router's "
+                 "logits: one bf16 ulp flips a near-tied choice "
+                 "(tests/test_smallthinker.py holds it to a dense loop)",
     "_random_uniform": "PRNG bits are generated in the compute dtype: "
                        "sequences differ by design (freshness is tested "
                        "in test_random.py)",

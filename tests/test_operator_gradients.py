@@ -333,6 +333,20 @@ _case("MultiHeadAttention:", lambda: (
     sym.MultiHeadAttention(V("query"), V("key"), V("value"), num_heads=2),
     {"query": _u((1, 3, 4)), "key": _u((1, 3, 4)), "value": _u((1, 3, 4))},
     {"numeric_eps": 1e-2, "rtol": 0.12, "atol": 3e-2}))
+_case("ExpertFFN:", lambda: (
+    # a router whose logits lie far apart: which experts a token takes is
+    # piecewise constant, and a numeric step must not cross a tie
+    sym.ExpertFFN(V("data"), V("router_data"), V("router_weight"),
+                  V("gate_weight"), V("up_weight"), V("down_weight"),
+                  num_experts=4, experts_held=2, first_expert=1,
+                  top_k=2)[0],
+    {"data": _u((1, 3, 4)), "router_data": _u((1, 3, 4), 0.5, 1.0),
+     "router_weight": np.array([[2.0] * 4, [1.0] * 4, [-1.0] * 4,
+                                [-2.0] * 4], np.float32)
+     + _u((4, 4), -0.1, 0.1),
+     "gate_weight": _u((2, 3, 4)), "up_weight": _u((2, 3, 4)),
+     "down_weight": _u((2, 4, 3))},
+    {"numeric_eps": 1e-2, "rtol": 0.12, "atol": 3e-2}))
 for _sop in ("SequenceMask", "SequenceReverse", "SequenceLast"):
     _case("%s:lens" % _sop,
           lambda n=_sop: (getattr(sym, n)(V("data"), V("sl"),

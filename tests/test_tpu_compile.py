@@ -9,6 +9,7 @@ library and keeps it, and no other may (on-chip-measurement guide, section
 """
 import importlib.util
 import os
+import re
 
 import pytest
 
@@ -36,6 +37,13 @@ def one_chip():
 _FLASH_SHAPES = {
     # the benchmark cell lm_train_4k: 2 x 2 KV heads, 12 query heads each
     "lm_train_4k": (4, 12, 4096, 4096, True, "bfloat16", 1),
+    # the cell smallthinker_train_8k: 1 x 4 KV heads, 7 query heads each; a
+    # global layer, and a window layer (a trailing 8th field: the window)
+    "smallthinker_8k_global": (4, 7, 8192, 8192, True, "bfloat16", 2),
+    "smallthinker_8k_window": (4, 7, 8192, 8192, True, "bfloat16", 2, 4096),
+    # a band through the fused backward, and through streamed superblocks
+    "window_1024_of_4096": (4, 12, 4096, 4096, True, "bfloat16", 1, 1024),
+    "window_4096_of_16384": (1, 2, 16384, 16384, True, "bfloat16", 2, 4096),
     # the longest resident sequence, in the widest dtype: the VMEM wall
     "resident_8192_f32": (1, 2, 8192, 8192, False, "float32", 2),
     "streaming_16384": (1, 2, 16384, 16384, True, "bfloat16", 2),
@@ -50,7 +58,8 @@ _FLASH_SHAPES = {
 def test_flash_kernels_compile_for_v5e(name, one_chip):
     from mxnet_tpu.ops.pallas import flash_attention as fa
 
-    rows, g, tq, tk, causal, dtype, bwd_kernels = _FLASH_SHAPES[name]
+    rows, g, tq, tk, causal, dtype, bwd_kernels, *band = _FLASH_SHAPES[name]
+    window = band[0] if band else 0
     d = 128
 
     def sds(shape, dt=dtype):
@@ -61,17 +70,28 @@ def test_flash_kernels_compile_for_v5e(name, one_chip):
     scale = d ** -0.5
 
     def fwd(q, k, v):
-        return (fa._fa_forward(q, k, v, causal, scale, False),
-                fa._fa_forward(q, k, v, causal, scale, False, with_lse=True))
+        return (fa._fa_forward(q, k, v, causal, scale, False, window=window),
+                fa._fa_forward(q, k, v, causal, scale, False, with_lse=True,
+                               window=window))
 
     def bwd(q, k, v, o, lse, do):
-        return fa._fa_backward(q, k, v, o, lse, do, causal, scale, False)
+        return fa._fa_backward(q, k, v, o, lse, do, causal, scale, False,
+                               window=window)
 
     text = jax.jit(fwd).lower(q, kv, kv).compile().as_text()
     assert text.count('custom_call_target="tpu_custom_call"') == 2
     text = jax.jit(bwd).lower(q, kv, kv, q, lse, q).compile().as_text()
     # which backward `_fa_backward` builds follows from the shapes alone
     assert text.count('custom_call_target="tpu_custom_call"') == bwd_kernels
+
+
+def _step_ops():
+    spec = importlib.util.spec_from_file_location("step_ops", os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        "tools", "step_ops.py"))
+    step_ops = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(step_ops)
+    return step_ops
 
 
 def test_fused_lm_step_makes_no_needless_pass_over_the_vocabulary(one_chip):
@@ -82,11 +102,7 @@ def test_fused_lm_step_makes_no_needless_pass_over_the_vocabulary(one_chip):
     embedding table, which is gathered from in its master dtype (the head's
     weight is cast inside the fusions that multiply by it). The two flash
     kernels (forward, fused backward) are there."""
-    spec = importlib.util.spec_from_file_location("step_ops", os.path.join(
-        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-        "tools", "step_ops.py"))
-    step_ops = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(step_ops)
+    step_ops = _step_ops()
     cfg = {"vocab_size": 49152, "hidden_size": 3072, "num_hidden_layers": 1,
            "intermediate_size": 12288, "num_attention_heads": 24,
            "num_key_value_heads": 2}
@@ -103,3 +119,34 @@ def test_fused_lm_step_makes_no_needless_pass_over_the_vocabulary(one_chip):
     groups = {o["group"] for o in ops}
     assert {"head and loss", "embedding", "feed-forward", "flash",
             "attention projections", "norms"} <= groups
+
+
+def test_expert_layer_step_compiles_to_grouped_kernels(one_chip):
+    """The fused step of ``smallthinker_train_8k`` at one layer, as the
+    chip's compiler builds it: the expert matrices are the compiler's
+    grouped-matmul kernel over the sorted rows (``ragged-dot``: forward,
+    dX and dW of the three matrices, over the worst-case buffer), there is no
+    dense product over all sixteen held experts, and the one window-free
+    layer's flash kernels are there."""
+    import json
+
+    step_ops = _step_ops()
+    with open(os.path.join(step_ops.ROOT, "benchmark", "configs",
+                           "smallthinker-21b-a3b.train.json")) as f:
+        cfg = dict(json.load(f), num_hidden_layers=1)
+    traffic = {"batch": 1, "seq_len": 8192, "compute_dtype": "bfloat16",
+               "optimizer": {"learning_rate": 0.01, "momentum": 0.9}}
+    compiled, sym = step_ops.compile_step(cfg, traffic)
+    text = compiled.as_text()
+    ops = step_ops.device_ops(text, step_ops.node_groups(sym))
+    # 8192 tokens x 6 choices = 49152 rows, the worst case (12288 expected)
+    grouped = [o for o in ops if o["name"].startswith("ragged-dot-none")]
+    assert len(grouped) == 9, [o["name"] for o in grouped]
+    assert {o["result"] for o in grouped} == {
+        "bf16[49152,768]", "bf16[49152,2560]", "bf16[16,2560,768]",
+        "bf16[16,768,2560]"}
+    # no product, mask or one-hot with an axis over the held experts
+    assert not re.findall(r"(?:bf16|f32)\[16,(?:24576|49152|8192),", text)
+    assert sum(o["kernel"] and o["group"] == "flash" for o in ops) == 3
+    assert {"expert products", "expert routing", "flash"} <= {
+        o["group"] for o in ops}

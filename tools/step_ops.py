@@ -16,10 +16,12 @@ too high where only some rows of an operand are read, as in a gather).
 Grouped by what the node is for. The times are the compiler's guesses: they
 were off by up to 50% either way against the chip (PERF.md, PR 30), so they
 rank nothing alone; they say what the program DOES, and where to look in a
-trace. Only the ``transformer_lm`` family is known here.
+trace. The symbol is the one the configuration's family file builds
+(``benchmark/families/<family>.py``).
 """
 import argparse
 import collections
+import importlib.util
 import json
 import os
 import re
@@ -33,14 +35,27 @@ CLOCK_HZ, HBM_BYTES_PER_S = 1.5e9, 819e9
 FREE = ("parameter", "constant", "tuple", "get-tuple-element", "bitcast")
 ITEM = {"f32": 4, "s32": 4, "u32": 4, "bf16": 2, "f16": 2, "s8": 1, "u8": 1,
         "pred": 1, "f64": 8, "s64": 8, "u64": 8}
-GROUPS = ("head and loss", "embedding", "feed-forward",
-          "attention projections", "attention glue", "flash", "norms",
-          "residual adds", "updates and casts")
+GROUPS = ("head and loss", "embedding", "feed-forward", "expert products",
+          "expert routing", "attention projections", "attention glue",
+          "flash", "norms", "residual adds", "updates and casts")
+
+
+def family_symbol(cfg):
+    """The training symbol as the configuration's family file builds it."""
+    bench = os.path.join(ROOT, "benchmark")
+    if bench not in sys.path:
+        sys.path.insert(0, bench)  # the family imports lib.*
+    spec = importlib.util.spec_from_file_location(
+        "family", os.path.join(bench, "families",
+                               cfg.get("family", "transformer_lm") + ".py"))
+    family = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(family)
+    return family.symbol(cfg, True)
 
 
 def compile_step(cfg, traffic):
-    """The compiled fused step of ``transformer-lm`` at ``cfg``'s sizes, and
-    the symbol: lowered from described arrays, so nothing is allocated."""
+    """The compiled fused step of ``cfg``'s model at its sizes, and the
+    symbol: lowered from described arrays, so nothing is allocated."""
     import jax
     import jax.numpy as jnp
     from jax.experimental import topologies
@@ -48,7 +63,6 @@ def compile_step(cfg, traffic):
 
     import mxnet_tpu as mx
     import mxnet_tpu.ops.pallas as pallas
-    from mxnet_tpu import models
     from mxnet_tpu import random as mxrandom
     from mxnet_tpu.executor import Executor
     from mxnet_tpu.ndarray import NDArray
@@ -56,12 +70,7 @@ def compile_step(cfg, traffic):
     topo = topologies.get_topology_desc(platform="tpu",
                                         topology_name="v5e:2x2")
     chip = SingleDeviceSharding(topo.devices[0])
-    sym = models.get_symbol(
-        "transformer-lm", num_classes=cfg["vocab_size"],
-        num_layers=cfg["num_hidden_layers"],
-        num_heads=cfg["num_attention_heads"], model_dim=cfg["hidden_size"],
-        ffn_dim=cfg["intermediate_size"],
-        num_kv_heads=cfg["num_key_value_heads"], scalar_loss=True)
+    sym = family_symbol(cfg)
     inputs = {n: (traffic["batch"], traffic["seq_len"])
               for n in ("data", "softmax_label")}
     shapes, _, _ = sym.infer_shape(**inputs)
@@ -113,6 +122,7 @@ def node_groups(sym):
         for child, _ in n.inputs:
             feeds[id(child)].add(n.op.name)
     by_op = {"Embedding": "embedding", "LayerNorm": "norms",
+             "RMSNorm": "norms", "ExpertFFN": "expert routing",
              "MultiHeadAttention": "attention glue",
              "Activation": "feed-forward", "elemwise_add": "residual adds",
              "_plus": "residual adds"}
@@ -189,6 +199,10 @@ def device_ops(text, groups):
         kernel = "tpu_custom_call" in line
         if group == "attention glue" and kernel:
             group = "flash"
+        if name.startswith("ragged-dot"):
+            # the compiler's grouped-matmul kernel and its tile
+            # metadata: they carry no graph node's name
+            group = "expert products"
         ops.append({"name": name, "opcode": opcode, "result": types[name],
                     "operands": operands, "kernel": kernel,
                     "node": node, "group": group,
@@ -222,6 +236,11 @@ def main():
         with open(args.text, "w") as f:
             f.write(text)
     ops = device_ops(text, node_groups(sym))
+    mem = compiled.memory_analysis()
+    print("the program's arguments %.2f GB, outputs %.2f GB (%.2f GB of them "
+          "in the arguments' place), temporaries %.2f GB" % (
+              mem.argument_size_in_bytes / 1e9, mem.output_size_in_bytes / 1e9,
+              mem.alias_size_in_bytes / 1e9, mem.temp_size_in_bytes / 1e9))
     print("%d device operations; est = XLA's estimated_cycles at 1.5 GHz, "
           "hbm = operands and result once over 819 GB/s; compiler's "
           "guesses, not times" % len(ops))
