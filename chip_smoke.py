@@ -68,6 +68,9 @@ class Sizes:
     stream_seq: int = 16384
     lstm_n: int = 128
     lstm_h: int = 512
+    # the expert layer's grouped products: sorted rows, each expert's width
+    expert_rows: int = 8192
+    expert_ffn: int = 512
     # True: Pallas interpreter (the CPU test); False: compiled for the chip
     interpret: bool = False
 
@@ -226,6 +229,45 @@ def check_lstm(sizes, ctx):
     return errs
 
 
+def check_grouped(sizes):
+    """The expert layer's grouped products (``ops/pallas/grouped_matmul``:
+    forward, dX and dW over sorted rows cut into eight uneven groups, one
+    of them empty) against ``jax.lax.ragged_dot``, bf16; on the chip the
+    layer's own gate must pick them at this shape."""
+    import jax
+    import jax.numpy as jnp
+    from mxnet_tpu.ops import moe
+    from mxnet_tpu.ops.pallas import grouped_matmul as gm
+
+    rows, k, n, e = sizes.expert_rows, sizes.d_model, sizes.expert_ffn, 8
+    rng = np.random.RandomState(4)
+    x = jnp.asarray(rng.randn(rows, k), jnp.bfloat16)
+    w = jnp.asarray(rng.randn(e, n, k) * k ** -0.5, jnp.bfloat16)
+    ct = jnp.asarray(rng.randn(rows, n), jnp.bfloat16)
+    cuts = np.sort(rng.randint(0, rows // 4, e - 2))
+    groups = np.diff(np.concatenate([[0], cuts, [rows // 4, rows // 4]]))
+    groups[-1] = rows - rows // 4  # stretched over the empty rows
+    groups = jnp.asarray(groups, jnp.int32)
+    path = moe.product_path(rows, k, n, x.dtype)
+    assert path == ("ragged_dot" if sizes.interpret else "pallas"), path
+
+    def grads(product):
+        def loss(x, w):
+            y = product(x, w)
+            return jnp.sum(y.astype(jnp.float32) * ct.astype(jnp.float32)), y
+        (_, y), g = jax.jit(jax.value_and_grad(
+            loss, argnums=(0, 1), has_aux=True))(x, w)
+        return (y,) + g
+
+    got = grads(lambda x, w: gm.grouped_matmul(x, w, groups,
+                                               sizes.interpret))
+    want = grads(lambda x, w: moe._ragged(x, w, groups))
+    errs = {name: _rel_err(a, b)
+            for name, a, b in zip(("out", "dx", "dw"), got, want)}
+    assert max(errs.values()) <= BF16_TOL, errs
+    return errs
+
+
 def check_rtc(ctx):
     """One runtime-compiled user Pallas kernel (mx.rtc), compiled for the
     chip when there is one."""
@@ -288,6 +330,7 @@ def phase_kernels(sizes, ctx):
         facts["flash_streaming"] = check_flash(
             sizes, sizes.stream_seq, 1, 2, 1, ref_batch=1)
     facts["lstm"] = check_lstm(sizes, ctx)
+    facts["grouped_matmul"] = check_grouped(sizes)
     facts["rtc"] = check_rtc(ctx)
     return facts
 

@@ -9,12 +9,30 @@ the absent chips. Expert parallelism asks exactly this of a layer, so the
 same op is what an ``expert`` mesh axis above 1 will shard.
 
 Nothing is dropped. Assignments are sorted by expert and the three expert
-matrices are GROUPED products over the ragged, sorted batches
-(``jax.lax.ragged_dot``, which the TPU compiler builds as one grouped-matmul
-kernel over row tiles; its transposes are ragged products too), never a
+matrices are GROUPED products over the ragged, sorted batches, never a
 dense product over all held experts with a mask and never a one-hot
 dispatch (``parallel/moe.py``'s Switch layer is that, with a capacity that
-clips). Shapes are static, so the sorted rows live in a buffer that holds
+clips). Which implementation multiplies follows from what the code can
+observe (``product_path``; counter ``expert_layer_built_total{path=}``),
+with no option to choose it:
+
+- on the TPU, where both widths are whole lanes, the buffer whole row
+  tiles and the blocks fit a kernel's VMEM, the repo's own Pallas kernels
+  (``ops/pallas/grouped_matmul.py``): ``gmm`` for the forward and, with the
+  matrices read the other way, for dX; ``tgmm`` for dW; joined by a
+  ``custom_vjp``. An expert's matrix stays in VMEM while its row tiles
+  pass, and the matrices are read in the (E, out, in) layout the op holds
+  them, so the step makes no transposed copy of them either: 1.05-1.15 ms
+  a forward or dX product and 1.22-1.28 a dW product in the benchmark
+  cell's step, 88% and 79% of the MXU over the rows walked (PERF.md,
+  Findings, PR 34);
+- elsewhere (the CPU's tests, odd widths) ``jax.lax.ragged_dot``, which the
+  TPU compiler builds as a grouped-matmul kernel over 512 x 512 x 256
+  tiles that fetches a matrix again for every row tile (1.87-2.16 ms the
+  same products, 45-52%, beside transposed copies of the matrices); its
+  transposes are autodiff's.
+
+Shapes are static, so the sorted rows live in a buffer that holds
 the worst case (every token's every choice held here), and the products
 walk ALL of it whatever the routing: the last held expert's group is
 stretched over the rows no assignment took, which are zero and add nothing
@@ -23,16 +41,16 @@ choice, and it costs: with untrained weights a layer's held experts drew
 0.05 to 2.97 times the expected load, and a first buffer of twice the
 expectation with the worst case as an exact overflow path under a
 ``lax.cond`` took 240-254 ms a step by the seed (0.6 ms per 1000 held rows,
-the overflow path in 4 seeds of 6) where this takes 301 on every seed
+the overflow path in 4 seeds of 6) where this took 301 on every seed
 (PERF.md, Findings, PR 33).
 
 Every move between token order and sorted-row order is a row GATHER in
 both directions (a permutation read forwards or backwards), because the
 transpose of a gather that autodiff would write is a scatter-add, which the
-TPU runs row by row (PERF.md, section 7, item 8). The backward is
-autodiff's: a ``custom_vjp`` that recomputed the forward from the layer's
-inputs compiled to the same program, XLA merging the second forward with
-the first.
+TPU runs row by row (PERF.md, section 7, item 8). Around the products the
+backward is autodiff's: a ``custom_vjp`` that recomputed the forward from
+the layer's inputs compiled to the same program, XLA merging the second
+forward with the first.
 """
 from __future__ import annotations
 
@@ -134,23 +152,40 @@ def _plan(order, inv, total, rows, top_k):
             "ok": inv < total}
 
 
-def _grouped(x, w, sizes):
+def product_path(rows, d, f, dtype):
+    """Which implementation the grouped products of a layer of these
+    shapes take: the repo's own kernels (``"pallas"``) on the TPU where
+    the widths are whole lanes, the buffer whole row tiles and the blocks
+    fit a kernel's VMEM; the compiler's ``jax.lax.ragged_dot``
+    (``"ragged_dot"``) elsewhere. One algorithm, chosen from what the code
+    can observe, as ``flash_attention`` chooses."""
+    from .pallas import grouped_matmul, on_tpu
+    if on_tpu() and grouped_matmul.fits(rows, d, f, jnp.dtype(dtype).itemsize):
+        return "pallas"
+    return "ragged_dot"
+
+
+def _ragged(x, w, sizes):
     """x (R, in) by w (E, out, in), rows grouped by ``sizes``: (R, out)."""
     return jax.lax.ragged_dot(x, jnp.swapaxes(w, 1, 2), sizes,
                               preferred_element_type=x.dtype)
 
 
-def _held_part(rows, top_k, act, x, w, wg, wu, wd, order, inv, sizes):
+def _held_part(rows, top_k, act, grouped, x, w, wg, wu, wd, order, inv,
+               sizes):
     """The held experts' part of the layer through a buffer of ``rows``
-    sorted rows, all of which the products walk."""
+    sorted rows, all of which the products (``grouped``) walk."""
     total = jnp.sum(sizes)
     plan = _plan(order, inv, total, rows, top_k)
     walked = sizes.at[-1].add(rows - total)  # the empty rows: zeros
     xs = _spread(x, plan)
-    a = act(_grouped(xs, wg, walked)) * _grouped(xs, wu, walked)
-    ys = _grouped(a, wd, walked)
-    ys = (ys * _row_weights(w, plan)[:, None]).astype(ys.dtype)
-    return _collect(ys, plan)
+    a = act(grouped(xs, wg, walked)) * grouped(xs, wu, walked)
+    # a row's routing weight scales the down product's INPUT (f wide),
+    # not its result (d wide): the same product, a third of the elements
+    # to scale, and the backward keeps no (rows, d) result for the
+    # weights' gradient
+    a = (a * _row_weights(w, plan)[:, None]).astype(a.dtype)
+    return _collect(grouped(a, wd, walked), plan)
 
 
 def route(router_data, router_weight, top_k, norm_topk):
@@ -211,15 +246,19 @@ def _expert_ffn(attrs, data, router_data, router_weight, gate_weight,
                    bool(attrs["norm_topk"]))
     order, inv, sizes = _sort_assignments(idx, first, held)
     rows, expected = buffer_rows(x.shape[0], top_k, held, experts)
+    path = product_path(rows, d, gate_weight.shape[1], x.dtype)
     registry.counter(
-        "expert_layer_built_total", labels={"path": "ragged_dot"},
+        "expert_layer_built_total", labels={"path": path},
         help="expert layers traced into a program, by the grouped-product "
              "path they were built with").inc()
     into = getattr(_tracing, "into", None)
     if into is not None:
         into.append({"experts_held": held, "top_k": top_k,
                      "buffer_rows": rows, "expected_rows": expected})
-    y = _held_part(rows, top_k, act, x, w, gate_weight, up_weight,
+    grouped = _ragged
+    if path == "pallas":
+        from .pallas.grouped_matmul import grouped_matmul as grouped
+    y = _held_part(rows, top_k, act, grouped, x, w, gate_weight, up_weight,
                    down_weight, order, inv, sizes)
     counts = jax.lax.stop_gradient(sizes.astype(jnp.float32))
     return y.reshape(data.shape), counts

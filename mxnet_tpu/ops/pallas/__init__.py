@@ -11,6 +11,7 @@ cudnn_algoreg-inl.h).
 import jax
 
 from . import flash_attention  # noqa: F401
+from . import grouped_matmul  # noqa: F401
 from . import lstm  # noqa: F401
 
 

@@ -287,6 +287,35 @@ def test_pallas_lstm_step_matches_plain():
     np.testing.assert_allclose(np.asarray(h2), h_want, rtol=1e-5, atol=1e-5)
 
 
+def test_pallas_grouped_matmul_matches_plain():
+    """The expert layer's grouped products, interpreted, against one plain
+    product a group (an empty group, a group boundary inside a row tile);
+    tests/test_grouped_matmul.py has the rest."""
+    from mxnet_tpu.ops.pallas import grouped_matmul as gm
+
+    rng = np.random.RandomState(2)
+    sizes = [5, 0, 20, 39]
+    x = rng.randn(64, 128).astype(np.float32)
+    w = rng.randn(4, 256, 128).astype(np.float32)
+    ct = rng.randn(64, 256).astype(np.float32)
+    at = np.concatenate([[0], np.cumsum(sizes)])
+    groups = [slice(a, b) for a, b in zip(at[:-1], at[1:])]
+    s = jnp.asarray(sizes, jnp.int32)
+    with jax.default_matmul_precision("highest"):
+        y = gm.gmm(jnp.asarray(x), jnp.asarray(w), s, tm=16, chunk=128,
+                   interpret=True)
+        dx = gm.gmm(jnp.asarray(ct), jnp.asarray(w), s, transposed=False,
+                    tm=16, chunk=128, interpret=True)
+        dw = gm.tgmm(jnp.asarray(ct), jnp.asarray(x), s, tm=16,
+                     interpret=True)
+    for got, want in (
+            (y, np.concatenate([x[g] @ w[i].T for i, g in enumerate(groups)])),
+            (dx, np.concatenate([ct[g] @ w[i] for i, g in enumerate(groups)])),
+            (dw, np.stack([ct[g].T @ x[g] for g in groups]))):
+        np.testing.assert_allclose(np.asarray(got), want, rtol=1e-4,
+                                   atol=1e-4)
+
+
 def test_pallas_kernel_coverage_is_complete():
     """Every public Pallas kernel entry point must have an interpret-vs-
     plain consistency test above (fails when a kernel is added without
@@ -297,9 +326,11 @@ def test_pallas_kernel_coverage_is_complete():
 
     from mxnet_tpu.ops import pallas
 
-    tested = {"flash_attention", "lstm_step"}
-    helpers = {"on_tpu", "use_for",
-               "kernel_qualifies"}  # selection predicates, not kernels
+    tested = {"flash_attention", "lstm_step", "gmm", "tgmm",
+              "grouped_matmul"}
+    helpers = {"on_tpu", "use_for", "kernel_qualifies", "fits",
+               # selection predicates and what they count, not kernels
+               "gmm_vmem_bytes", "tgmm_vmem_bytes", "group_visits"}
     public = set()
     # enumerate the PACKAGE, not a hardcoded list, so a kernel added in a
     # new ops/pallas module cannot escape the gate
@@ -307,8 +338,10 @@ def test_pallas_kernel_coverage_is_complete():
         mod = __import__("mxnet_tpu.ops.pallas.%s" % info.name,
                          fromlist=[info.name])
         for name, fn in vars(mod).items():
-            if (inspect.isfunction(fn) and not name.startswith("_")
-                    and fn.__module__ == mod.__name__):
+            # a kernel's entry point may be wrapped (jax.jit, custom_vjp)
+            if (inspect.isfunction(inspect.unwrap(fn))
+                    and not name.startswith("_")
+                    and getattr(fn, "__module__", None) == mod.__name__):
                 public.add(name)
     missing = public - tested - helpers
     assert not missing, (

@@ -121,13 +121,58 @@ def test_fused_lm_step_makes_no_needless_pass_over_the_vocabulary(one_chip):
             "attention projections", "norms"} <= groups
 
 
+# the grouped products of one ExpertFFN layer of smallthinker_train_8k
+# (49152 sorted rows, 16 held experts, 2560 x 768): (kernel, the two
+# arrays' shapes, transposed)
+_GROUPED = {
+    "forward_gate_up": ("gmm", (49152, 2560), (16, 768, 2560), True),
+    "forward_down": ("gmm", (49152, 768), (16, 2560, 768), True),
+    "dx_gate_up": ("gmm", (49152, 768), (16, 768, 2560), False),
+    "dx_down": ("gmm", (49152, 2560), (16, 2560, 768), False),
+    "dw_gate_up": ("tgmm", (49152, 768), (49152, 2560), None),
+    "dw_down": ("tgmm", (49152, 2560), (49152, 768), None),
+}
+
+
+@pytest.mark.parametrize("name", sorted(_GROUPED))
+def test_grouped_matmul_kernels_compile_for_v5e(name, one_chip):
+    """Each at the cell's shape, in the 16 MiB of VMEM a kernel gets
+    unasked (a block over it fails HERE), one Mosaic call named for what it
+    is, its tiling under the compiler's own key; a 512-row tile beside the
+    whole matrix twice, which `fits` counts out, is refused."""
+    from mxnet_tpu.ops.pallas import grouped_matmul as gm
+
+    kernel, a, b, transposed = _GROUPED[name]
+
+    def sds(shape, dt="bfloat16"):
+        return jax.ShapeDtypeStruct(shape, jnp.dtype(dt), sharding=one_chip)
+
+    args = (sds(a), sds(b), sds((16,), "int32"))
+    if kernel == "gmm":
+        def f(x, w, s, **kw):
+            return gm.gmm(x, w, s, transposed=transposed, **kw)
+    else:
+        f = gm.tgmm
+    text = jax.jit(f).lower(*args).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert "%expert_" + kernel in text and "ragged_dot_tiling" in text
+    assert '"scoped_memory_configs":[{' not in text  # no vmem_limit_bytes
+    if kernel == "gmm" and 2560 in a:
+        assert gm.gmm_vmem_bytes(512, 2560, 768, 2) > 16 * 2 ** 20
+        with pytest.raises(Exception, match="vmem"):
+            jax.jit(lambda x, w, s: f(x, w, s, tm=512)).lower(
+                *args).compile()
+
+
 def test_expert_layer_step_compiles_to_grouped_kernels(one_chip):
     """The fused step of ``smallthinker_train_8k`` at one layer, as the
-    chip's compiler builds it: the expert matrices are the compiler's
-    grouped-matmul kernel over the sorted rows (``ragged-dot``: forward,
-    dX and dW of the three matrices, over the worst-case buffer), there is no
-    dense product over all sixteen held experts, and the one window-free
-    layer's flash kernels are there."""
+    chip's compiler builds it: the expert matrices' nine products (forward,
+    dX and dW of gate, up and down) are the repo's own kernels
+    (``expert_gmm`` / ``expert_tgmm``, each line saying its tiling under
+    ``ragged_dot_tiling``) over the worst-case buffer and the matrices in
+    the layout the op holds them, none is left to the compiler's
+    ``ragged-dot``, there is no dense product over all sixteen held
+    experts, and the one window-free layer's flash kernels are there."""
     import json
 
     step_ops = _step_ops()
@@ -139,12 +184,27 @@ def test_expert_layer_step_compiles_to_grouped_kernels(one_chip):
     compiled, sym = step_ops.compile_step(cfg, traffic)
     text = compiled.as_text()
     ops = step_ops.device_ops(text, step_ops.node_groups(sym))
+    lines = {line.split(" = ")[0].strip().lstrip("%"): line
+             for line in step_ops.instruction_lines(text)}
     # 8192 tokens x 6 choices = 49152 rows, the worst case (12288 expected)
-    grouped = [o for o in ops if o["name"].startswith("ragged-dot-none")]
-    assert len(grouped) == 9, [o["name"] for o in grouped]
-    assert {o["result"] for o in grouped} == {
-        "bf16[49152,768]", "bf16[49152,2560]", "bf16[16,2560,768]",
-        "bf16[16,768,2560]"}
+    grouped = [o for o in ops if o["group"] == "expert products"]
+    assert sorted(o["name"].split(".")[0] for o in grouped) == (
+        ["expert_gmm"] * 6 + ["expert_tgmm"] * 3), [o["name"] for o in grouped]
+    assert all(o["kernel"] and "ragged_dot_tiling" in lines[o["name"]]
+               for o in grouped)
+    assert sorted(o["result"] for o in grouped) == sorted(
+        ["bf16[49152,768]"] * 3 + ["bf16[49152,2560]"] * 3
+        + ["bf16[16,768,2560]"] * 2 + ["bf16[16,2560,768]"])
+    # every product reads (49152, ...) rows and (16, ...) matrices, and no
+    # transposed copy of a matrix is made for it
+    for o in grouped:
+        assert [t for t in o["operands"] if t.startswith("bf16[49152,")], o
+        if o["name"].startswith("expert_gmm"):
+            assert [t for t in o["operands"] if t.startswith("bf16[16,")], o
+    assert not [o["name"] for o in ops if o["opcode"] in ("copy", "transpose")
+                and re.match(r"(?:bf16|f32)\[16,(?:768,2560|2560,768)\]",
+                             o["result"])]
+    assert "ragged-dot" not in text
     # no product, mask or one-hot with an axis over the held experts
     assert not re.findall(r"(?:bf16|f32)\[16,(?:24576|49152|8192),", text)
     assert sum(o["kernel"] and o["group"] == "flash" for o in ops) == 3

@@ -10,9 +10,10 @@ route.
 
     chiprun -- python tools/probe_expert_step.py --seeds 11,12,13 --steps 24
 
-One line of JSON a seed, a summary last (the spread of the seeds' median
-milliseconds, and their slope over the held rows); with ``--out`` the steps
-too.
+One line of JSON a seed (the first says which grouped-product path the
+layers were traced with: ``expert_layer_built_total{path=}``), a summary
+last (the spread of the seeds' median milliseconds, and their slope over the
+held rows); with ``--out`` the steps too.
 """
 import argparse
 import gc
@@ -25,6 +26,15 @@ import types
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 BENCH = os.path.join(ROOT, "benchmark")
+
+
+def built_paths():
+    """{path: layers traced with it}: ``expert_layer_built_total``."""
+    from mxnet_tpu.telemetry.metrics import registry
+    paths = {p: registry.counter("expert_layer_built_total",
+                                 labels={"path": p}).value
+             for p in ("pallas", "ragged_dot")}
+    return {p: n for p, n in paths.items() if n}
 
 
 def main(argv=None):
@@ -125,6 +135,8 @@ def main(argv=None):
                 "layer_steps": len(flat),
                 "layer_steps_over_twice_expected": sum(
                     r > 2 * expected for r in flat)}
+        if not lines:
+            line["product_paths"] = built_paths()
         lines.append(line)
         print(json.dumps(line), flush=True)
     if len(lines) > 2:

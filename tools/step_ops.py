@@ -153,12 +153,39 @@ def _bytes(type_text):
     return total
 
 
+def instruction_lines(text):
+    """The program's lines, each instruction whole on one: the printer
+    breaks a Mosaic call's ``kernel_metadata`` (a JSON object among the
+    frontend attributes) over lines of its own."""
+    out = []
+    for line in text.splitlines():
+        if out and (line.startswith('"')
+                    or line.startswith("}") and line.strip() != "}"):
+            out[-1] += line
+        else:
+            out.append(line)
+    return out
+
+
+def group_of(groups, name, node, kernel):
+    """The group of the operation ``name`` traced from graph node ``node``
+    (``kernel``: a Mosaic call)."""
+    if name.startswith(("ragged-dot", "expert_gmm", "expert_tgmm")):
+        # the grouped-matmul kernels: the repo's own (a Mosaic call is
+        # named for its kernel) or, where the layer's gate leaves the
+        # products to it, the compiler's with its tile metadata, which
+        # carries no graph node's name
+        return "expert products"
+    group = groups.get(node, "updates and casts")
+    return "flash" if kernel and group == "attention glue" else group
+
+
 def device_ops(text, groups):
     """One dict for each operation of the entry computation that does
     work: name, opcode, result and operands (types without their layouts),
     kernel (a Mosaic call), node, group, est_ms, hbm_ms."""
     comps, name = {}, None
-    for line in text.splitlines():
+    for line in instruction_lines(text):
         if line and not line[0].isspace() and "{" in line and "(" in line:
             name = line.split()[1 if line.startswith("ENTRY") else 0]
             name = name.lstrip("%")
@@ -195,14 +222,8 @@ def device_ops(text, groups):
         moved = sum(_bytes(t) for t in [types[name]] + operands)
         found = sorted(scopes(line), key=lambda t: -t[0])
         node = found[0][1] if found else ""
-        group = groups.get(node, "updates and casts")
         kernel = "tpu_custom_call" in line
-        if group == "attention glue" and kernel:
-            group = "flash"
-        if name.startswith("ragged-dot"):
-            # the compiler's grouped-matmul kernel and its tile
-            # metadata: they carry no graph node's name
-            group = "expert products"
+        group = group_of(groups, name, node, kernel)
         ops.append({"name": name, "opcode": opcode, "result": types[name],
                     "operands": operands, "kernel": kernel,
                     "node": node, "group": group,
