@@ -66,6 +66,9 @@ class Sizes:
     # the resident regime (0 skips it), LSTM step
     flash_batch: int = 32
     stream_seq: int = 16384
+    # flash at head size 64 (64-lane blocks), 4 query heads a KV head:
+    # a sequence the fused backward takes, and one it leaves to dq and dkv
+    head64_seqs: tuple = (2048, 8192)
     lstm_n: int = 128
     lstm_h: int = 512
     # the expert layer's grouped products: sorted rows, each expert's width
@@ -140,17 +143,19 @@ def _peak_hbm(devices):
 BF16_TOL = 3e-2  # max |kernel - XLA| over max |XLA|, bf16 operands
 
 
-def check_flash(sizes, seq, batch, heads, kv_heads, ref_batch):
+def check_flash(sizes, seq, batch, heads, kv_heads, ref_batch,
+                head_dim=None):
     """Flash attention forward and backward, causal GQA, bf16, against the
     grouped-einsum XLA math. The kernel runs at the full (batch, heads, seq)
     shape; the reference, whose score matrix is quadratic in seq, on the
-    first ``ref_batch`` batch rows (rows are independent grid cells)."""
+    first ``ref_batch`` batch rows (rows are independent grid cells).
+    ``head_dim``: another head size than the model's."""
     import jax
     import jax.numpy as jnp
     from mxnet_tpu.ops import attention
     from mxnet_tpu.ops.pallas import flash_attention as fa
 
-    d = sizes.head_dim
+    d = head_dim or sizes.head_dim
     rng = np.random.RandomState(seq)
     q = jnp.asarray(rng.randn(batch, heads, seq, d), jnp.bfloat16)
     k = jnp.asarray(rng.randn(batch, kv_heads, seq, d), jnp.bfloat16)
@@ -268,6 +273,41 @@ def check_grouped(sizes):
     return errs
 
 
+def check_short_conv(sizes):
+    """The gated short convolution's elementwise part
+    (``ops/pallas/short_conv``: one pass forward, one backward, an
+    eight-row halo a tile) against the XLA formulation, bf16, two
+    sequences; on the chip the op's own gate must pick the kernels."""
+    import jax
+    import jax.numpy as jnp
+    from mxnet_tpu.ops import shortconv
+    from mxnet_tpu.ops.pallas import short_conv
+
+    d, taps = sizes.d_model, 3
+    seq = max(min(sizes.seq, 2048), 2 * short_conv.ROWS)  # tiles with a halo
+    rng = np.random.RandomState(6)
+    u = jnp.asarray(rng.randn(2, seq, 3 * d), jnp.bfloat16)
+    k = jnp.asarray(rng.randn(d, taps) * 0.5, jnp.bfloat16)
+    w = jnp.asarray(rng.randn(2, seq, d), jnp.bfloat16)
+    path = shortconv.conv_path((2, seq, d), taps)
+    assert path == ("xla" if sizes.interpret else "pallas"), path
+
+    def grads(conv):
+        def loss(u, k):
+            y = conv(u, k)
+            return jnp.sum(y.astype(jnp.float32) * w.astype(jnp.float32)), y
+        (_, y), g = jax.jit(jax.value_and_grad(
+            loss, argnums=(0, 1), has_aux=True))(u, k)
+        return (y,) + g
+
+    got = grads(lambda u, k: short_conv.gated_conv(u, k, sizes.interpret))
+    want = grads(shortconv._gated_conv)
+    errs = {name: _rel_err(a, b)
+            for name, a, b in zip(("out", "du", "dk"), got, want)}
+    assert max(errs.values()) <= BF16_TOL, errs
+    return errs
+
+
 def check_rtc(ctx):
     """One runtime-compiled user Pallas kernel (mx.rtc), compiled for the
     chip when there is one."""
@@ -329,8 +369,12 @@ def phase_kernels(sizes, ctx):
         assert sizes.stream_seq > fa._RESIDENT_MAX
         facts["flash_streaming"] = check_flash(
             sizes, sizes.stream_seq, 1, 2, 1, ref_batch=1)
+    for seq in sizes.head64_seqs:
+        facts["flash_head64_%d" % seq] = check_flash(
+            sizes, seq, 1, 8, 2, ref_batch=1, head_dim=64)
     facts["lstm"] = check_lstm(sizes, ctx)
     facts["grouped_matmul"] = check_grouped(sizes)
+    facts["short_conv"] = check_short_conv(sizes)
     facts["rtc"] = check_rtc(ctx)
     return facts
 

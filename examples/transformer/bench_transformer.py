@@ -212,7 +212,9 @@ def kernels(args):
     bfloat16): one layer's calls, the fused backward beside the dq and dkv
     kernels it replaces there, and how far its three results lie from
     theirs (norm of the difference over the norm). PERF.md (Findings, PRs
-    26 and 32) has the readings this repeats."""
+    26 and 32) has the readings this repeats. Then lfm2_train_8k's calls
+    at head size 64, beside a head of 128 at the same shape (Findings, PR
+    35)."""
     import numpy as np
     import jax
     from mxnet_tpu.ops.pallas import flash_attention as fa
@@ -235,6 +237,19 @@ def kernels(args):
     print("fused against dq/dkv, |difference| / |dq/dkv|: "
           + "  ".join("%s %.3g" % (n, gap(a, b)) for n, a, b in
                       zip(("dq", "dk", "dv"), outs["fused"], split)))
+    # a head of 64 (lfm2_train_8k: 32 heads over 8 KV heads, 8192 tokens,
+    # the split backward) in its 64-lane blocks, beside what the same heads
+    # padded with zeros to 128 would cost: the same kernels at 128
+    shape = (1, 8, 2, 256) if on_cpu else (1, 32, 8, 8192)
+    for head_dim in (64, 128):
+        m, _ = kernel_times(fa, *shape, head_dim, causal=args.causal,
+                            reps=1 if on_cpu else 20,
+                            only=("fwd", "dq", "dkv"))
+        print("kernels B=%d H=%d HKV=%d S=%d D=%d causal=%s: fwd %.3f ms  "
+              "dq %.3f ms  dkv %.3f ms" % (
+                  shape + (head_dim, args.causal, m["fwd"], m["dq"],
+                           m["dkv"])))
+        ms["d%d" % head_dim] = m
     return ms
 
 

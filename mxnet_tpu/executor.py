@@ -38,7 +38,7 @@ from .base import MXNetError
 from .context import Context, default_context
 from .ndarray import NDArray
 from .ops.matrix import gathered_rows_as
-from .ops.moe import built_layers
+from .ops.registry import built_layers
 
 
 def _cast_floats(tree, dtype, src=None, skip=()):
@@ -76,18 +76,47 @@ def _gathered_only(symbol):
     return frozenset(n for n, ok in only.items() if ok and n not in heads)
 
 
-def _expert_attrs(layers):
-    """The ``executor.train_step`` span's static attributes for a step with
-    ``ExpertFFN`` layers (``ops/moe.py`` ``built_layers``); none without:
-    how many, and of one layer the experts held, the choices a token, the
-    rows of its sorted-assignment buffer as allocated and the assignments
-    expected under uniform routing."""
-    if not layers:
-        return {}
-    one = layers[0]
-    return {"moe_layers": len(layers), "moe_experts_held": one["experts_held"],
-            "moe_top_k": one["top_k"], "moe_buffer_rows": one["buffer_rows"],
-            "moe_expected_rows": one["expected_rows"]}
+def _float32_state(symbol):
+    """The auxiliary states that stay float32 under a compute dtype: those
+    their op declares (``OpDef.float32_aux``; ``ExpertFFN``'s
+    ``expert_bias`` chooses experts, and rounded to bfloat16 it chooses
+    others)."""
+    keep = set()
+    for node in symbol._nodes():
+        if node.is_var or not node.op.float32_aux:
+            continue
+        aux = node.op.get_aux_names(node.attrs)
+        states = node.inputs[len(node.inputs) - len(aux):]
+        keep.update(child.name for name, (child, _) in zip(aux, states)
+                    if name in node.op.float32_aux)
+    return frozenset(keep)
+
+
+def _layer_attrs(layers):
+    """The ``executor.train_step`` span's static attributes for what the
+    step's layers told of themselves as it was traced (``ops/registry.py``
+    ``built_layers``). With ``ExpertFFN`` layers: how many, and of one
+    layer the experts held, the choices a token, the rows of its
+    sorted-assignment buffer as allocated, the assignments expected under
+    uniform routing, and the route where it is not the softmax. With
+    ``ShortConv`` layers: how many. With attention: its head size."""
+    by_op = {}
+    for layer in layers:
+        by_op.setdefault(layer["op"], []).append(layer)
+    out = {}
+    if "ExpertFFN" in by_op:
+        one = by_op["ExpertFFN"][0]
+        out.update(moe_layers=len(by_op["ExpertFFN"]),
+                   moe_experts_held=one["experts_held"],
+                   moe_top_k=one["top_k"], moe_buffer_rows=one["buffer_rows"],
+                   moe_expected_rows=one["expected_rows"])
+        if one["route"] != "softmax":
+            out["moe_route"] = one["route"]
+    if "ShortConv" in by_op:
+        out["conv_layers"] = len(by_op["ShortConv"])
+    if "MultiHeadAttention" in by_op:
+        out["attn_head_dim"] = by_op["MultiHeadAttention"][0]["head_dim"]
+    return out
 
 
 def _relaid(tree, formats):
@@ -179,6 +208,9 @@ class Executor:
         self._compute_dtype = (jnp.dtype(compute_dtype)
                                if compute_dtype not in (None, "", "float32")
                                else None)
+        # auxiliary states the compute dtype leaves alone
+        self._state32 = (_float32_state(symbol)
+                         if self._compute_dtype is not None else ())
         self._ctx = ctx if isinstance(ctx, Context) else (ctx[0] if ctx else default_context())
         self._group2ctx = group2ctx
         arg_names = symbol.list_arguments()
@@ -250,12 +282,12 @@ class Executor:
         fn = self._fwd_cache.get(is_train)
         if fn is None:
             eval_fn = self._eval_fn
-            cd = self._compute_dtype
+            cd, state32 = self._compute_dtype, self._state32
 
             def fwd(arg_values, aux_values, rng):
                 if cd is not None:
                     arg_values = _cast_floats(arg_values, cd)
-                    aux_values = _cast_floats(aux_values, cd)
+                    aux_values = _cast_floats(aux_values, cd, skip=state32)
                 outs, aux_up = eval_fn(arg_values, aux_values, is_train, rng)
                 if cd is not None:
                     outs = _cast_floats(outs, jnp.float32, src=cd)
@@ -274,7 +306,7 @@ class Executor:
             grad_names = [n for n in self._arg_names if self.grad_req.get(n) != "null"]
             reqs = tuple(self.grad_req[n] for n in grad_names)
 
-            cd = self._compute_dtype
+            cd, state32 = self._compute_dtype, self._state32
 
             def fwd_bwd(arg_values, aux_values, rng, head_grads, old_grads):
                 grad_vals = [arg_values[n] for n in grad_names]
@@ -288,7 +320,7 @@ class Executor:
                         # bf16 compute; vjp of the cast returns f32 grads
                         # (transpose of convert_element_type casts back).
                         av = _cast_floats(av, cd)
-                        auxv = _cast_floats(auxv, cd)
+                        auxv = _cast_floats(auxv, cd, skip=state32)
                     outs, aux_up = eval_fn(av, auxv, True, rng)
                     if cd is not None:
                         outs = _cast_floats(outs, jnp.float32, src=cd)
@@ -374,7 +406,8 @@ class Executor:
         cd = self._compute_dtype
         tables = _gathered_only(self._symbol) if cd is not None else ()
         chain = max(1, int(chain))
-        experts = built_layers()  # what each ExpertFFN allocates, as traced
+        state32 = self._state32
+        built = built_layers()  # what the layers tell of themselves, as traced
         from .parallel import collectives as _coll
         stage = _coll.sharded_stage(mesh, shard_axis)
         sharded = stage >= 1
@@ -404,9 +437,9 @@ class Executor:
                 auxv = aux_values
                 if cd is not None:
                     av = _cast_floats(av, cd, skip=tables)
-                    auxv = _cast_floats(auxv, cd)
-                del experts.layers[:]  # a retrace lists them again
-                with gathered_rows_as(cd), experts:
+                    auxv = _cast_floats(auxv, cd, skip=state32)
+                del built.layers[:]  # a retrace lists them again
+                with gathered_rows_as(cd), built:
                     outs, aux_up = eval_fn(av, auxv, True, rng)
                 if cd is not None:
                     outs = _cast_floats(outs, jnp.float32, src=cd)
@@ -694,13 +727,13 @@ class Executor:
                                  step=self._train_steps, chain=chain,
                                  stage=stage,
                                  **(known or dict.fromkeys(counts, 0)),
-                                 **(_expert_attrs(experts.layers)
+                                 **(_layer_attrs(built.layers)
                                     if known else {})) as sp:
                 out = _run_impl(params, states, data_values, *extra)
                 if not known:
                     for k in counts:
                         sp.add(k, aot.get(k, 0))
-                    sp.annotate(**_expert_attrs(experts.layers))
+                    sp.annotate(**_layer_attrs(built.layers))
                 return out
 
         run.lower = lower

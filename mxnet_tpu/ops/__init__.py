@@ -20,5 +20,6 @@ from . import shape_rules  # noqa: F401
 from . import rnn_fused  # noqa: F401
 from . import attention  # noqa: F401
 from . import moe  # noqa: F401
+from . import shortconv  # noqa: F401
 from . import contrib  # noqa: F401
 from . import custom  # noqa: F401

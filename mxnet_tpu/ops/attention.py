@@ -17,7 +17,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from .registry import defop
+from .registry import defop, get_op, note_built
 
 
 @defop(
@@ -96,14 +96,22 @@ def dot_product_attention(q, k, v, causal=False, scale=None, mask=None,
     return jnp.einsum("bhqk,bhkd->bhqd", probs.astype(v.dtype), v)
 
 
+def _mha_arg_names(attrs):
+    if attrs.get("qk_norm"):
+        return ("query", "key", "value", "q_norm_gamma", "k_norm_gamma")
+    return ("query", "key", "value")
+
+
 @defop(
     "MultiHeadAttention",
-    arg_names=("query", "key", "value"),
+    arg_names=_mha_arg_names,
     param_spec={"num_heads": 1, "num_kv_heads": 0, "causal": False,
                 "use_rope": False, "use_flash": True, "window": 0,
-                "rope_base": 10000.0},
+                "rope_base": 10000.0, "qk_norm": False,
+                "qk_norm_eps": 1e-6},
 )
-def _multi_head_attention(attrs, query, key, value):
+def _multi_head_attention(attrs, query, key, value, q_norm_gamma=None,
+                          k_norm_gamma=None):
     """Fused multi-head attention on (B, T, H*D) projected inputs.
 
     Splits heads, optionally applies RoPE, runs (flash) attention, and
@@ -123,6 +131,11 @@ def _multi_head_attention(attrs, query, key, value):
     i - window < j <= i only; 0 is no window. ``rope_base`` is the base
     of the rotary frequencies where ``use_rope`` is set; a layer without
     ``use_rope`` has no position encoding at all.
+
+    ``qk_norm`` adds two inputs, ``q_norm_gamma`` and ``k_norm_gamma``
+    (head size,): every query head and every key head is RMS-normalised
+    over its own head size (``qk_norm_eps``) and scaled, one scale shared
+    by the heads, BEFORE the rotation.
     """
     h = int(attrs["num_heads"])
     hkv = int(attrs["num_kv_heads"]) or h
@@ -143,6 +156,10 @@ def _multi_head_attention(attrs, query, key, value):
 
     q = split(query, tq, h)
     k, v = split(key, tk, hkv), split(value, tk, hkv)
+    note_built({"op": "MultiHeadAttention", "head_dim": d})
+    if attrs["qk_norm"]:
+        q = _head_norm(q, q_norm_gamma, attrs["qk_norm_eps"])
+        k = _head_norm(k, k_norm_gamma, attrs["qk_norm_eps"])
     if attrs["use_rope"]:
         base = float(attrs["rope_base"])
         q, k = rope(q, base=base), rope(k, base=base)
@@ -159,6 +176,26 @@ def _multi_head_attention(attrs, query, key, value):
     else:
         out = dot_product_attention(q, k, v, causal=causal, window=window)
     return out.transpose(0, 2, 1, 3).reshape(b, tq, dm)
+
+
+def _head_norm(x, gamma, eps):
+    """RMSNorm over the head size of (B, H, T, D), float32 statistics."""
+    x32 = x.astype(jnp.float32)
+    ms = jnp.mean(jnp.square(x32), axis=-1, keepdims=True)
+    return (x32 * jax.lax.rsqrt(ms + eps)
+            * gamma.astype(jnp.float32)).astype(x.dtype)
+
+
+def _mha_infer(attrs, shapes):
+    """The two head-norm scales: (head size,), from the query's width."""
+    if len(shapes) > 3 and shapes[0] is not None:
+        d = shapes[0][-1] // int(attrs["num_heads"])
+        shapes[3] = shapes[3] or (d,)
+        shapes[4] = shapes[4] or (d,)
+    return shapes
+
+
+get_op("MultiHeadAttention").infer_params = _mha_infer
 
 
 def _grouped_attention(q, k, v, hkv, causal, scale=None, mask=None,

@@ -17,7 +17,8 @@ observe (``product_path``; counter ``expert_layer_built_total{path=}``),
 with no option to choose it:
 
 - on the TPU, where both widths are whole lanes, the buffer whole row
-  tiles and the blocks fit a kernel's VMEM, the repo's own Pallas kernels
+  tiles and the blocks fit a kernel's VMEM (a matrix that does not stand
+  there twice, 2048 x 1536, in column blocks), the repo's own Pallas kernels
   (``ops/pallas/grouped_matmul.py``): ``gmm`` for the forward and, with the
   matrices read the other way, for dX; ``tgmm`` for dW; joined by a
   ``custom_vjp``. An expert's matrix stays in VMEM while its row tiles
@@ -54,17 +55,13 @@ forward with the first.
 """
 from __future__ import annotations
 
-import threading
-
 import jax
 import jax.numpy as jnp
 
 from ..telemetry.metrics import registry
-from .registry import defop
+from .registry import defop, get_op, note_built
 
 _ACTS = {"relu": jax.nn.relu, "silu": jax.nn.silu, "gelu": jax.nn.gelu}
-
-_tracing = threading.local()
 
 
 def buffer_rows(tokens, top_k, held, experts):
@@ -72,24 +69,6 @@ def buffer_rows(tokens, top_k, held, experts):
     buffer holds the worst case, every token's every choice among the held
     experts; the expectation is under uniform routing."""
     return tokens * min(top_k, held), tokens * top_k * held / experts
-
-
-class built_layers:
-    """Entered around the trace of a graph: every ``ExpertFFN`` traced
-    under it appends what it allocated to ``self.layers`` (a dict each).
-    Trace-time Python state only: nothing here reaches the program."""
-
-    def __init__(self):
-        self.layers = []
-
-    def __enter__(self):
-        self._prev = getattr(_tracing, "into", None)
-        _tracing.into = self.layers
-        return self
-
-    def __exit__(self, *exc):
-        _tracing.into = self._prev
-        return False
 
 
 # --- moves between token order and sorted-row order ---------------------------
@@ -188,13 +167,33 @@ def _held_part(rows, top_k, act, grouped, x, w, wg, wu, wd, order, inv,
     return _collect(grouped(a, wd, walked), plan)
 
 
-def route(router_data, router_weight, top_k, norm_topk):
+ROUTES = ("softmax", "sigmoid_bias")
+
+
+def route(router_data, router_weight, top_k, norm_topk, bias=None,
+          norm_eps=0.0, scale=1.0):
     """Float32 router over all the experts: (weights (N, k) float32, which
     experts (N, k) int32). The choice carries no gradient, the weights
-    do."""
+    do.
+
+    Without ``bias`` the scores are a softmax: over the ``top_k`` largest
+    logits (``norm_topk``) or over all the experts, not renormalised.
+    With ``bias`` (E,) float32, state and no parameter, they are
+    ``sigmoid(logits)``; the experts CHOSEN are the ``top_k`` largest of
+    score + bias, their weights the scores alone (the bias moves the
+    choice and no weight), over their sum + ``norm_eps`` where
+    ``norm_topk``, times ``scale``."""
     hp = jax.lax.Precision.HIGHEST
     logits = jnp.einsum("nd,ed->ne", router_data.astype(jnp.float32),
                         router_weight.astype(jnp.float32), precision=hp)
+    if bias is not None:
+        scores = jax.nn.sigmoid(logits)
+        _, idx = jax.lax.top_k(
+            scores + jax.lax.stop_gradient(bias.astype(jnp.float32)), top_k)
+        w = jnp.take_along_axis(scores, idx, 1)
+        if norm_topk:
+            w = w / (jnp.sum(w, axis=-1, keepdims=True) + norm_eps)
+        return w * scale, idx
     if norm_topk:
         top, idx = jax.lax.top_k(logits, top_k)
         return jax.nn.softmax(top, axis=-1), idx
@@ -202,17 +201,24 @@ def route(router_data, router_weight, top_k, norm_topk):
     return jnp.take_along_axis(jax.nn.softmax(logits, axis=-1), idx, 1), idx
 
 
+def _aux_names(attrs):
+    return ("expert_bias",) if attrs.get("route") == "sigmoid_bias" else ()
+
+
 @defop(
     "ExpertFFN",
     arg_names=("data", "router_data", "router_weight", "gate_weight",
                "up_weight", "down_weight"),
+    aux_names=_aux_names,
+    float32_aux=("expert_bias",),  # added to float32 scores to CHOOSE
     num_outputs=2,
     output_names=("output", "expert_tokens"),
     param_spec={"num_experts": 1, "experts_held": 0, "first_expert": 0,
-                "top_k": 1, "norm_topk": True, "act_type": "relu"},
+                "top_k": 1, "norm_topk": True, "act_type": "relu",
+                "route": "softmax", "norm_eps": 0.0, "scale": 1.0},
+    simple=False,
 )
-def _expert_ffn(attrs, data, router_data, router_weight, gate_weight,
-                up_weight, down_weight):
+def _expert_ffn(attrs, inputs, aux, ctx):
     """Top-k gated expert feed-forward, this chip's share.
 
     ``data`` (B, T, d) feeds the experts; the router reads ``router_data``
@@ -220,6 +226,12 @@ def _expert_ffn(attrs, data, router_data, router_weight, gate_weight,
     attention's input): ``softmax`` of the ``top_k`` largest of
     ``router_data @ router_weight.T`` over all ``num_experts``, in float32
     (``norm_topk`` False: the softmax over all experts, not renormalised).
+    ``route`` "sigmoid_bias" scores with a sigmoid instead and takes an
+    auxiliary state ``expert_bias`` (``num_experts``,) float32: the experts
+    chosen are the ``top_k`` largest of score + bias, weighted by the score
+    alone over the chosen scores' sum + ``norm_eps`` (``norm_topk``), times
+    ``scale``. The bias is state: no gradient, no optimizer, and this op
+    never writes it.
     ``gate_weight``/``up_weight`` (E_held, f, d) and ``down_weight``
     (E_held, d, f) are experts ``first_expert`` .. ``first_expert +
     experts_held - 1`` (``experts_held`` 0: all of them). Output 0: the sum
@@ -228,6 +240,13 @@ def _expert_ffn(attrs, data, router_data, router_weight, gate_weight,
     all ``top_k`` chosen. Output 1 (float32, no gradient): how many
     assignments each held expert took, for a load-balance metric. No token
     is dropped, whatever the routing."""
+    del ctx
+    (data, router_data, router_weight, gate_weight, up_weight,
+     down_weight) = inputs
+    if attrs["route"] not in ROUTES:
+        raise ValueError("ExpertFFN: route %r (known: %s)"
+                         % (attrs["route"], ", ".join(ROUTES)))
+    bias = aux[0] if aux else None
     experts = int(attrs["num_experts"])
     held = int(attrs["experts_held"]) or experts
     first, top_k = int(attrs["first_expert"]), int(attrs["top_k"])
@@ -243,7 +262,8 @@ def _expert_ffn(attrs, data, router_data, router_weight, gate_weight,
     d = data.shape[-1]
     x = data.reshape(-1, d)
     w, idx = route(router_data.reshape(-1, d), router_weight, top_k,
-                   bool(attrs["norm_topk"]))
+                   bool(attrs["norm_topk"]), bias, float(attrs["norm_eps"]),
+                   float(attrs["scale"]))
     order, inv, sizes = _sort_assignments(idx, first, held)
     rows, expected = buffer_rows(x.shape[0], top_k, held, experts)
     path = product_path(rows, d, gate_weight.shape[1], x.dtype)
@@ -251,14 +271,23 @@ def _expert_ffn(attrs, data, router_data, router_weight, gate_weight,
         "expert_layer_built_total", labels={"path": path},
         help="expert layers traced into a program, by the grouped-product "
              "path they were built with").inc()
-    into = getattr(_tracing, "into", None)
-    if into is not None:
-        into.append({"experts_held": held, "top_k": top_k,
-                     "buffer_rows": rows, "expected_rows": expected})
+    note_built({"op": "ExpertFFN", "experts_held": held, "top_k": top_k,
+                "buffer_rows": rows, "expected_rows": expected,
+                "route": attrs["route"]})
     grouped = _ragged
     if path == "pallas":
         from .pallas.grouped_matmul import grouped_matmul as grouped
     y = _held_part(rows, top_k, act, grouped, x, w, gate_weight, up_weight,
                    down_weight, order, inv, sizes)
     counts = jax.lax.stop_gradient(sizes.astype(jnp.float32))
-    return y.reshape(data.shape), counts
+    return (y.reshape(data.shape), counts), ()
+
+
+def _expert_bias_infer(attrs, shapes):
+    """The auxiliary state's shape: one number an expert of the router."""
+    if len(shapes) > 6:
+        shapes[6] = shapes[6] or (int(attrs["num_experts"]),)
+    return shapes
+
+
+get_op("ExpertFFN").infer_params = _expert_bias_infer

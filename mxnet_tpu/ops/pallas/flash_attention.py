@@ -108,6 +108,18 @@ _RESIDENT_MAX = 8192
 SUPER_TARGET = 4096
 _NEG_INF = float(jnp.finfo(jnp.float32).min)
 _LANES = 128
+# A head of 64 runs in 64-lane blocks (`kernel_qualifies`). On a 128 x 128
+# MXU neither score product can be full at that head size, whatever the
+# layout: q k^T contracts over 64 of the array's 128 rows, and p v fills 64
+# of its 128 columns, so a tile takes the time of a head of 128 for half
+# the FLOPs, and 50% of the peak is these kernels' ceiling. Two heads side
+# by side in 128 lanes change nothing of that (their scores differ, so the
+# products stay two, each half empty; only the accumulators' elementwise
+# updates would fill their vregs), and heads padded with zeros to 128 do
+# the same MXU work while q, k, v, o and their gradients cross HBM at twice
+# the size. What the 64-lane blocks cost in VMEM is a head of 128's: a row
+# of 64 takes a whole vreg row (`_fused_bwd_vmem_bytes` counts it so).
+_HALF_LANES = 64
 
 
 def _split_super(t, block, target=None):
@@ -166,6 +178,8 @@ def _lanes(x, n):
     vregs are reused, nothing moves."""
     if n == x.shape[1]:
         return x
+    if n < x.shape[1]:
+        return x[:, :n]                 # a head of 64: the vreg's first half
     if n % x.shape[1] == 0:
         return pltpu.repeat(x, n // x.shape[1], 1)
     return jnp.broadcast_to(x[:, :1], (x.shape[0], n))
@@ -575,6 +589,7 @@ def _fused_bwd_vmem_bytes(tq, tk, d, itemsize):
     loop spills (the compiler asks for 1.0-1.5 at 512 x 512: the cell's
     shape, 15.5 MiB by this count, compiles from 14.5-15 MiB up)."""
     block_q, block_k = _pick_block(tq, BLOCK_Q), _pick_block(tk, BLOCK_K)
+    d = -(-d // _LANES) * _LANES        # a row of 64 takes a whole vreg row
     row = 8 * tq * 4                    # a (1, tq) f32 row pads to 8 sublanes
     blocks = ((2 * tq * d + 2 * block_k * d) * itemsize + 2 * row  # in
               + (tq * d + 2 * block_k * d) * itemsize)             # out
@@ -779,7 +794,11 @@ def kernel_qualifies(tq, tk, d, compiled=True, causal=False):
     whole blocks (a ragged final block would read padding into the
     softmax) at the finest `_MIN_TILE` granularity (the actual tiles
     are picked per shape by `_pick_block`); the compiled path
-    additionally needs a lane-aligned head_dim; causal calls need
+    additionally needs a head_dim of whole lanes, or of HALF a vreg's 128
+    (64: the blocks are then 64 lanes wide, q, k, v and every accumulator
+    fill the first half of each vreg row, and the MXU contracts over 64
+    for the scores and writes 64 columns for the values; `_HALF_LANES`
+    says what that costs and what else was weighed); causal calls need
     tq <= tk (with tq > tk the first tk-tq query rows are FULLY masked —
     the XLA path's finfo.min masking degrades to uniform attention
     there, while the kernel's l=0 would produce NaN). Shared by
@@ -787,7 +806,7 @@ def kernel_qualifies(tq, tk, d, compiled=True, causal=False):
     two paths cannot drift."""
     return (_aligned(tq, _MIN_TILE) and _aligned(tk, _MIN_TILE)
             and (not causal or tq <= tk)
-            and (not compiled or d % 128 == 0))
+            and (not compiled or d % _LANES == 0 or d == _HALF_LANES))
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
@@ -895,6 +914,10 @@ def flash_attention(q, k, v, causal=False, scale=None, interpret=None,
         return fallback()
 
     g = h // hkv
+    registry.counter(
+        "flash_head_dim_built_total", labels={"head_dim": str(d)},
+        help="flash-attention calls traced into a program, by head "
+             "size").inc()
     if causal:
         registry.counter(
             "flash_window_built_total",

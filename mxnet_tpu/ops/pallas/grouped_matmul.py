@@ -38,9 +38,15 @@ unwritten.
 
 Everything lives in the 16 MiB of VMEM a kernel gets unasked
 (``flash_attention._SCOPED_VMEM``: a ``vmem_limit_bytes`` on a Mosaic call
-changes how XLA builds other fusions of the step, PR 32); ``fits`` counts
-the bytes from the shapes and the caller takes ``jax.lax.ragged_dot``
-where they do not fit.
+changes how XLA builds other fusions of the step, PR 32). A matrix that
+does not stand there twice beside the tiles (2048 x 1536 in bfloat16: 6.3
+MB, 18.5 MiB in all) is taken in column blocks: ``gmm`` computes its
+result's columns in two halves where both fit (``gmm_column_blocks``; a
+second walk over the rows), ``tgmm`` halves its output block
+(``tgmm_wide``). Shapes that fit whole are built as PR 34 built them.
+``fits`` counts the bytes from the shapes and the caller takes
+``jax.lax.ragged_dot`` where neither fits: no other blocking has been
+timed against it.
 """
 from __future__ import annotations
 
@@ -70,6 +76,13 @@ from .flash_attention import _SCOPED_VMEM
 ROWS = 256
 GMM_CHUNK = 256
 TGMM_WIDE = 1280
+# the most column blocks `gmm` takes a matrix in, and the narrowest output
+# block of `tgmm`: what the chip has timed against the compiler's own kernel
+# (2048 x 1536 in bfloat16: 2.34-2.36 and 2.64 ms against 2.94-3.26,
+# PERF.md, PR 35). Past them nothing is measured, and the caller takes
+# `ragged_dot` as before
+MAX_COLUMN_BLOCKS = 2
+TGMM_NARROW = 640
 # what the kernels' own temporaries took beside the pipeline's buffers as
 # the compiler counted them for a described v5e (gmm 2.97-3.08 MiB at 512
 # rows; tgmm 1.5-1.9 MiB and the copies counted in `tgmm_vmem_bytes`)
@@ -114,10 +127,10 @@ def _mine(tile_ref, lo_ref, hi_ref, v, tm):
 
 
 def _gmm_kernel(group_ref, tile_ref, lo_ref, hi_ref, x_ref, w_ref, o_ref, *,
-                chunk, transposed):
+                chunk, transposed, visit_axis=0):
     del group_ref  # the index maps' only
     tm, n = o_ref.shape
-    mine = _mine(tile_ref, lo_ref, hi_ref, pl.program_id(0), tm)
+    mine = _mine(tile_ref, lo_ref, hi_ref, pl.program_id(visit_axis), tm)
     for j in range(0, n, chunk):
         w = w_ref[j:j + chunk, :] if transposed else w_ref[:, j:j + chunk]
         y = jax.lax.dot_general(x_ref[...], w, _NT if transposed else _NN,
@@ -132,8 +145,24 @@ def _gmm_kernel(group_ref, tile_ref, lo_ref, hi_ref, x_ref, w_ref, o_ref, *,
 def gmm_vmem_bytes(tm, k, n, itemsize):
     """What ``gmm`` holds in VMEM, from the shapes alone: the matrix, a
     row tile and a result tile twice each (the pipeline's two buffers),
-    and the kernel's temporaries."""
+    and the kernel's temporaries. ``n``: the result's columns computed a
+    grid step, the whole width or a column block of it."""
     return 2 * (k * n + tm * k + tm * n) * itemsize + _TEMPORARIES
+
+
+def gmm_column_blocks(tm, k, n, itemsize):
+    """In how many column blocks ``gmm`` computes a result of ``n``
+    columns so that a block of the matrix fits VMEM twice beside the
+    tiles: 1 wherever the whole matrix does (the kernel PR 34 measured),
+    else ``MAX_COLUMN_BLOCKS`` = 2 halves of whole lanes (PR 35 measured)
+    where those do; 0 where they do not. A block more is one more pass
+    over the rows, for a matrix that still crosses HBM once a group and
+    block."""
+    for parts in range(1, MAX_COLUMN_BLOCKS + 1):
+        if n % (parts * _LANES) == 0 and gmm_vmem_bytes(
+                tm, k, n // parts, itemsize) <= _SCOPED_VMEM:
+            return parts
+    return 0
 
 
 def _params(interpret, semantics):
@@ -155,30 +184,56 @@ def gmm(x, w, sizes, transposed=True, tm=ROWS, chunk=GMM_CHUNK,
     if w.shape[2 if transposed else 1] != k or rows % tm:
         raise ValueError("gmm: x %s by w %s (transposed=%s), row tile %d"
                          % (x.shape, w.shape, transposed, tm))
-    chunk = min(chunk, n)
     visits = group_visits(sizes, rows, tm)
     e = w.shape[0]
+    # a matrix too large to stand in VMEM twice goes in `parts` column
+    # blocks, an outer grid dimension, each a walk over all the visits; one
+    # block is the grid of visits alone, as PR 34 built it
+    parts = gmm_column_blocks(tm, k, n, x.dtype.itemsize) or 1
+    blocked = parts > 1
+    nc = n // parts
+    chunk = min(chunk, nc)
+
+    def at(ids):
+        """(column block, visit, group of, tile of) from an index map's
+        arguments: the grid's indices, then the four prefetched arrays."""
+        return (ids[0] if blocked else 0), ids[-5], ids[-4], ids[-3]
+
+    def rows_of(*ids):
+        _, v, _, t = at(ids)
+        return t[v], 0
+
+    def matrix_of(*ids):
+        c, v, g, _ = at(ids)
+        return (g[v], c, 0) if transposed else (g[v], 0, c)
+
+    def result_of(*ids):
+        c, v, _, t = at(ids)
+        return t[v], c
+
+    visit_grid = (rows // tm + e - 1,)
     return pl.pallas_call(
-        functools.partial(_gmm_kernel, chunk=chunk, transposed=transposed),
+        functools.partial(_gmm_kernel, chunk=chunk, transposed=transposed,
+                          visit_axis=int(blocked)),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=4,
-            grid=(rows // tm + e - 1,),
+            grid=(parts,) + visit_grid if blocked else visit_grid,
             in_specs=[
-                pl.BlockSpec((tm, k), lambda v, g, t, lo, hi: (t[v], 0)),
-                pl.BlockSpec((None,) + w.shape[1:],
-                             lambda v, g, t, lo, hi: (g[v], 0, 0)),
+                pl.BlockSpec((tm, k), rows_of),
+                pl.BlockSpec((None, nc, k) if transposed else (None, k, nc),
+                             matrix_of),
             ],
-            out_specs=pl.BlockSpec((tm, n),
-                                   lambda v, g, t, lo, hi: (t[v], 0)),
+            out_specs=pl.BlockSpec((tm, nc), result_of),
         ),
         out_shape=jax.ShapeDtypeStruct((rows, n), x.dtype),
         cost_estimate=pl.CostEstimate(
             flops=2 * rows * k * n, transcendentals=0,
-            bytes_accessed=(x.size + w.size + rows * n) * x.dtype.itemsize),
+            bytes_accessed=(x.size * parts + w.size + rows * n)
+            * x.dtype.itemsize),
         name="expert_gmm",
         metadata={"ragged_dot_tiling": "%d,%d,%d" % (tm, k, chunk)},
         interpret=interpret,
-        **_params(interpret, ("arbitrary",)),
+        **_params(interpret, ("arbitrary",) * (1 + blocked)),
     )(*visits, x, w)
 
 
@@ -222,6 +277,19 @@ def _tgmm_blocks(ka, nb, wide=TGMM_WIDE):
     return (cut(ka), nb) if ka > nb else (ka, cut(nb))
 
 
+def tgmm_wide(tm, ka, nb, itemsize, out_itemsize):
+    """The most columns of ``tgmm``'s output block: ``TGMM_WIDE`` wherever
+    that fits VMEM (the kernel PR 34 measured), else its half,
+    ``TGMM_NARROW`` (PR 35 measured); 0 where that does not."""
+    wide = TGMM_WIDE
+    while wide >= TGMM_NARROW:
+        if tgmm_vmem_bytes(tm, ka, nb, itemsize, out_itemsize,
+                           wide) <= _SCOPED_VMEM:
+            return wide
+        wide //= 2
+    return 0
+
+
 def tgmm_vmem_bytes(tm, ka, nb, itemsize, out_itemsize, wide=TGMM_WIDE):
     """What ``tgmm`` holds in VMEM: both row tiles and the output block
     twice, the float32 accumulator, a's tile transposed (masked on the
@@ -235,7 +303,7 @@ def tgmm_vmem_bytes(tm, ka, nb, itemsize, out_itemsize, wide=TGMM_WIDE):
 
 @functools.partial(jax.jit, static_argnames=("tm", "wide", "out_dtype",
                                              "interpret"))
-def tgmm(a, b, sizes, tm=ROWS, wide=TGMM_WIDE, out_dtype=None,
+def tgmm(a, b, sizes, tm=ROWS, wide=None, out_dtype=None,
          interpret=False):
     """a (R, ka) and b (R, nb), rows grouped by ``sizes`` (E,): (E, ka,
     nb), group g's block ``a[its rows]^T b[its rows]`` (zeros for an empty
@@ -246,8 +314,11 @@ def tgmm(a, b, sizes, tm=ROWS, wide=TGMM_WIDE, out_dtype=None,
         raise ValueError("tgmm: a %s, b %s, row tile %d"
                          % (a.shape, b.shape, tm))
     e = sizes.shape[0]
-    ta, tb = _tgmm_blocks(ka, nb, wide)
     out_dtype = jnp.dtype(out_dtype or a.dtype)
+    if wide is None:
+        wide = tgmm_wide(tm, ka, nb, a.dtype.itemsize,
+                         out_dtype.itemsize) or TGMM_WIDE
+    ta, tb = _tgmm_blocks(ka, nb, wide)
     visits = group_visits(sizes, rows, tm)
     return pl.pallas_call(
         functools.partial(_tgmm_kernel, mask_a=ta <= tb),
@@ -282,9 +353,9 @@ def fits(rows, k, n, itemsize):
     the shape half of the gate (``ops/moe.py`` asks ``on_tpu`` for the
     other). Widths are whole lanes, rows whole tiles."""
     return (k % _LANES == 0 and n % _LANES == 0 and rows % ROWS == 0
-            and max(gmm_vmem_bytes(ROWS, k, n, itemsize),
-                    tgmm_vmem_bytes(ROWS, n, k, itemsize, itemsize))
-            <= _SCOPED_VMEM)
+            and gmm_column_blocks(ROWS, k, n, itemsize) > 0    # forward
+            and gmm_column_blocks(ROWS, n, k, itemsize) > 0    # dX
+            and tgmm_wide(ROWS, n, k, itemsize, itemsize) > 0)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))

@@ -26,6 +26,7 @@ as the reference generates its Python API from the C op registry
 from __future__ import annotations
 
 import dataclasses
+import threading
 from typing import Any, Callable, Dict, Optional, Sequence, Tuple
 
 from ..base import MXNetError, coerce_attr
@@ -34,6 +35,36 @@ OP_REGISTRY: Dict[str, "OpDef"] = {}
 
 # A required parameter (no default) in a param_spec.
 REQUIRED = object()
+
+_tracing = threading.local()
+
+
+class built_layers:
+    """Entered around the trace of a graph: an op that tells what it built
+    (``note_built``: ``ExpertFFN`` its buffer, ``ShortConv`` and
+    ``MultiHeadAttention`` that they are there) appends a dict to
+    ``self.layers``. Trace-time Python state only: nothing here reaches
+    the program."""
+
+    def __init__(self):
+        self.layers = []
+
+    def __enter__(self):
+        self._prev = getattr(_tracing, "into", None)
+        _tracing.into = self.layers
+        return self
+
+    def __exit__(self, *exc):
+        _tracing.into = self._prev
+        return False
+
+
+def note_built(record):
+    """Append ``record`` (a dict with the op's name under ``"op"``) to the
+    ``built_layers`` this trace runs under, if any."""
+    into = getattr(_tracing, "into", None)
+    if into is not None:
+        into.append(record)
 
 
 @dataclasses.dataclass
@@ -58,6 +89,8 @@ class OpDef:
     uses_train: bool = False
     variadic: bool = False  # takes arbitrary list of inputs (Concat, add_n)
     no_grad_inputs: Sequence[str] = ()  # e.g. labels
+    # auxiliary states a compute dtype leaves float32 (the executor's casts)
+    float32_aux: Sequence[str] = ()
     doc: str = ""
     py_name: Optional[str] = None  # name exposed in nd/sym namespaces
     output_names: Any = None  # list or fn(attrs)->list; default [name_output]
@@ -177,6 +210,7 @@ def defop(
     output_names=None,
     simple=True,
     param_docs=None,
+    float32_aux=(),
 ):
     """Decorator registering an operator implementation.
 
@@ -204,6 +238,7 @@ def defop(
             uses_train=uses_train,
             variadic=variadic,
             no_grad_inputs=no_grad_inputs,
+            float32_aux=float32_aux,
             doc=fn.__doc__ or "",
             py_name=py_name or name,
             output_names=output_names,

@@ -7,7 +7,9 @@ span readers on a canned ring, and the toy LM cell end to end (GQA, scalar
 loss, ``simple_bind`` + ``make_train_step``) against its float32 reference,
 its control and its planted faults; and the same for the toy SmallThinker
 cell (window and NoPE layers, the held experts' share), with its pinned
-counts and its metric readers.
+counts and its metric readers; and for the toy LFM2 cell (short-convolution
+and attention layers, a dense SwiGLU layer, the sigmoid-and-bias route, the
+tied head).
 """
 import importlib.util
 import os
@@ -29,7 +31,7 @@ def _load(name):
 
 
 for _name in ("test_benchmark", "test_span_readers",
-              "test_smallthinker_cell"):
+              "test_smallthinker_cell", "test_lfm2_cell"):
     # tests, fixtures and the helpers they name
     globals().update({k: v for k, v in vars(_load(_name)).items()
                       if not k.startswith("_")})

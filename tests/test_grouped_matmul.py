@@ -88,6 +88,31 @@ def test_gmm_against_a_loop_over_the_groups(case, transposed, dtype):
     np.testing.assert_allclose(np.asarray(got, np.float32), want, atol=tol)
 
 
+@pytest.mark.parametrize("transposed", [True, False])
+@pytest.mark.parametrize("case", sorted(SIZES))
+def test_gmm_in_column_blocks_against_a_loop(case, transposed, monkeypatch):
+    """A matrix that does not stand in VMEM twice (here: a VMEM shrunk
+    until this one does not): its columns in two blocks, each a walk over
+    all the visits, the same result; one that does not fit in halves
+    either is the caller's to take elsewhere."""
+    sizes, k, n = SIZES[case], 128, 512
+    monkeypatch.setattr(gm, "_TEMPORARIES", 0)
+    monkeypatch.setattr(gm, "_SCOPED_VMEM", gm.gmm_vmem_bytes(
+        TM, k, n // 4, 4))
+    assert gm.gmm_column_blocks(TM, k, n, 4) == 0
+    monkeypatch.setattr(gm, "_SCOPED_VMEM", gm.gmm_vmem_bytes(
+        TM, k, n // 2, 4))
+    assert gm.gmm_column_blocks(TM, k, n, 4) == 2
+    x = _rows((ROWS, k), "float32", 11)
+    w = _rows((4, n, k) if transposed else (4, k, n), "float32", 12)
+    with jax.default_matmul_precision("highest"):
+        got = gm.gmm(jnp.asarray(x), jnp.asarray(w),
+                     jnp.asarray(sizes, jnp.int32), transposed=transposed,
+                     tm=TM, chunk=128, interpret=True)
+    np.testing.assert_allclose(got, _loop_gmm(x, w, sizes, transposed),
+                               atol=1e-4)
+
+
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
 @pytest.mark.parametrize("ka,nb", [(128, 256), (256, 128)])
 @pytest.mark.parametrize("case", sorted(SIZES))
@@ -238,5 +263,37 @@ def test_vmem_count_at_the_cells_shapes():
     assert (gm.tgmm_vmem_bytes(256, f, d, 2, 2)
             < gm.tgmm_vmem_bytes(256, d, f, 2, 2) < limit)
     assert gm.tgmm_vmem_bytes(256, f, d, 2, 2, wide=2560) > limit
-    # float32 models: the matrices alone are 15.7 MB twice
+    assert gm.gmm_column_blocks(256, d, f, 2) == 1
+    assert gm.gmm_column_blocks(256, f, d, 2) == 1
+    assert gm.tgmm_wide(256, f, d, 2, 2) == gm.TGMM_WIDE
+    # float32 models: the matrices alone are 15.7 MB twice, and in halves
+    # the forward's still does not fit: the compiler's kernel, as before
+    assert gm.gmm_column_blocks(256, d, f, 4) == 0
     assert not gm.fits(rows, d, f, 4)
+
+
+def test_vmem_count_at_the_second_cells_shapes():
+    """``lfm2_train_8k``: 32768 rows, 2048 x 1536, bfloat16. An expert's
+    matrix is 6.3 MB, and twice beside the tiles 18.5 MiB: ``gmm`` takes
+    its columns in two blocks both ways, ``tgmm`` halves its output block
+    to 640 columns (512 of this shape's), and all of it fits the 16 MiB a
+    kernel gets unasked (the compile for a described v5e is the proof:
+    ``test_tpu_compile.py``). That is the blocking the chip has timed
+    against ``ragged_dot``; what it does not fit stays the compiler's."""
+    rows, d, f = 32768, 2048, 1536
+    limit = 16 * 2 ** 20
+    assert gm.gmm_vmem_bytes(256, d, f, 2) > limit
+    assert gm.gmm_column_blocks(256, d, f, 2) == 2      # gate, up; dX of down
+    assert gm.gmm_column_blocks(256, f, d, 2) == 2      # down; dX of gate, up
+    assert gm.gmm_vmem_bytes(256, d, f // 2, 2) < limit
+    assert gm.tgmm_vmem_bytes(256, f, d, 2, 2) > limit
+    assert gm.tgmm_wide(256, f, d, 2, 2) == gm.tgmm_wide(256, d, f, 2, 2) == 640
+    assert gm._tgmm_blocks(f, d, 640) == (1536, 512)
+    assert gm._tgmm_blocks(d, f, 640) == (512, 1536)
+    assert gm.tgmm_vmem_bytes(256, f, d, 2, 2, wide=640) < limit
+    assert gm.fits(rows, d, f, 2) and gm.fits(2 * rows, d, f, 2)
+    assert gm.MAX_COLUMN_BLOCKS == 2 and gm.TGMM_NARROW == 640
+    assert gm.gmm_column_blocks(256, d, 2 * f, 2) == 0   # three blocks: no
+    assert not gm.fits(rows, d, 2 * f, 2)
+    assert gm.gmm_column_blocks(256, 8192, 4096, 2) == 0
+    assert not gm.fits(512, 8192, 4096, 2)

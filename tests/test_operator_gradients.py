@@ -347,6 +347,12 @@ _case("ExpertFFN:", lambda: (
      "gate_weight": _u((2, 3, 4)), "up_weight": _u((2, 3, 4)),
      "down_weight": _u((2, 4, 3))},
     {"numeric_eps": 1e-2, "rtol": 0.12, "atol": 3e-2}))
+_case("ShortConv:", lambda: (
+    sym.ShortConv(V("data"), V("in_weight"), V("conv_weight"),
+                  V("out_weight"), kernel=3),
+    {"data": _u((2, 5, 4)), "in_weight": _u((12, 4)),
+     "conv_weight": _u((4, 3)), "out_weight": _u((4, 4))},
+    {"numeric_eps": 1e-2, "rtol": 0.12, "atol": 3e-2}))
 for _sop in ("SequenceMask", "SequenceReverse", "SequenceLast"):
     _case("%s:lens" % _sop,
           lambda n=_sop: (getattr(sym, n)(V("data"), V("sl"),

@@ -54,13 +54,34 @@ _FLASH_SHAPES = {
 }
 
 
+# the same at head size 64, in 64-lane blocks (lfm2_train_8k: 1 x 8 KV
+# heads, 4 query heads each, the split backward at 8192; the fused one
+# where it fits, whose accumulators take a head of 128's VMEM)
+_FLASH_HEAD64 = {
+    "lfm2_8k": (8, 4, 8192, 8192, True, "bfloat16", 2),
+    "lfm2_8k_two_sequences": (16, 4, 8192, 8192, True, "bfloat16", 2),
+    "fused_4096": (8, 4, 4096, 4096, True, "bfloat16", 1),
+    "window_1024_of_4096": (8, 4, 4096, 4096, True, "bfloat16", 1, 1024),
+    "streaming_16384": (1, 2, 16384, 16384, True, "bfloat16", 2),
+    "prefill_512_of_4096": (2, 4, 512, 4096, True, "bfloat16", 1),
+}
+
+
 @pytest.mark.parametrize("name", sorted(_FLASH_SHAPES))
 def test_flash_kernels_compile_for_v5e(name, one_chip):
+    _compile_flash(one_chip, 128, *_FLASH_SHAPES[name])
+
+
+@pytest.mark.parametrize("name", sorted(_FLASH_HEAD64))
+def test_flash_kernels_compile_for_v5e_at_head_size_64(name, one_chip):
+    _compile_flash(one_chip, 64, *_FLASH_HEAD64[name])
+
+
+def _compile_flash(one_chip, d, rows, g, tq, tk, causal, dtype, bwd_kernels,
+                   window=0):
     from mxnet_tpu.ops.pallas import flash_attention as fa
 
-    rows, g, tq, tk, causal, dtype, bwd_kernels, *band = _FLASH_SHAPES[name]
-    window = band[0] if band else 0
-    d = 128
+    assert fa.kernel_qualifies(tq, tk, d, causal=causal)
 
     def sds(shape, dt=dtype):
         return jax.ShapeDtypeStruct(shape, jnp.dtype(dt), sharding=one_chip)
@@ -134,8 +155,21 @@ _GROUPED = {
 }
 
 
+# and of lfm2_train_8k (32768 sorted rows, 8 held experts, 2048 x 1536):
+# a matrix of 6.3 MB does not stand in VMEM twice, so gmm takes its columns
+# in two blocks and tgmm halves its output block
+_GROUPED.update({
+    "lfm2_forward_gate_up": ("gmm", (32768, 2048), (8, 1536, 2048), True),
+    "lfm2_forward_down": ("gmm", (32768, 1536), (8, 2048, 1536), True),
+    "lfm2_dx_gate_up": ("gmm", (32768, 1536), (8, 1536, 2048), False),
+    "lfm2_dx_down": ("gmm", (32768, 2048), (8, 2048, 1536), False),
+    "lfm2_dw_gate_up": ("tgmm", (32768, 1536), (32768, 2048), None),
+    "lfm2_dw_down": ("tgmm", (32768, 2048), (32768, 1536), None),
+})
+
+
 @pytest.mark.parametrize("name", sorted(_GROUPED))
-def test_grouped_matmul_kernels_compile_for_v5e(name, one_chip):
+def test_grouped_matmul_kernels_compile_for_v5e(name, one_chip, monkeypatch):
     """Each at the cell's shape, in the 16 MiB of VMEM a kernel gets
     unasked (a block over it fails HERE), one Mosaic call named for what it
     is, its tiling under the compiler's own key; a 512-row tile beside the
@@ -147,7 +181,8 @@ def test_grouped_matmul_kernels_compile_for_v5e(name, one_chip):
     def sds(shape, dt="bfloat16"):
         return jax.ShapeDtypeStruct(shape, jnp.dtype(dt), sharding=one_chip)
 
-    args = (sds(a), sds(b), sds((16,), "int32"))
+    groups = b[0] if kernel == "gmm" else 16 if a[0] == 49152 else 8
+    args = (sds(a), sds(b), sds((groups,), "int32"))
     if kernel == "gmm":
         def f(x, w, s, **kw):
             return gm.gmm(x, w, s, transposed=transposed, **kw)
@@ -158,10 +193,38 @@ def test_grouped_matmul_kernels_compile_for_v5e(name, one_chip):
     assert "%expert_" + kernel in text and "ragged_dot_tiling" in text
     assert '"scoped_memory_configs":[{' not in text  # no vmem_limit_bytes
     if kernel == "gmm" and 2560 in a:
+        # the count is the compiler's: with the matrix whole (no column
+        # blocks) a 512-row tile is over it, and does not compile
         assert gm.gmm_vmem_bytes(512, 2560, 768, 2) > 16 * 2 ** 20
+        monkeypatch.setattr(gm, "MAX_COLUMN_BLOCKS", 1)
         with pytest.raises(Exception, match="vmem"):
             jax.jit(lambda x, w, s: f(x, w, s, tm=512)).lower(
                 *args).compile()
+
+
+@pytest.mark.parametrize("batch", [1, 2])
+def test_short_conv_kernels_compile_for_v5e(batch, one_chip):
+    """``lfm2_train_8k``'s gated short convolution: (batch, 8192, 3 x 2048)
+    in bfloat16, 3 taps, forward and backward, one Mosaic call each, named
+    for what it is, in the VMEM a kernel gets unasked."""
+    from mxnet_tpu.ops.pallas import short_conv as kernels
+
+    def sds(shape, dt="bfloat16"):
+        return jax.ShapeDtypeStruct(shape, jnp.dtype(dt), sharding=one_chip)
+
+    t, d = 8192, 2048
+    assert kernels.fits(t, d, 3)
+    u, k, dy = sds((batch, t, 3 * d)), sds((d, 3)), sds((batch, t, d))
+    for name, f, args in (
+            ("short_conv_fwd", lambda u, k: kernels._forward(u, k, False),
+             (u, k)),
+            ("short_conv_bwd",
+             lambda u, k, dy: kernels._backward(u, k, dy, False),
+             (u, k, dy))):
+        text = jax.jit(f).lower(*args).compile().as_text()
+        assert text.count('custom_call_target="tpu_custom_call"') == 1
+        assert "%" + name in text
+        assert '"scoped_memory_configs":[{' not in text
 
 
 def test_expert_layer_step_compiles_to_grouped_kernels(one_chip):

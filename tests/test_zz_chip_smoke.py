@@ -20,7 +20,7 @@ TINY = chip_smoke.Sizes(
     batch=4, seq=64, steps=3,
     prompts=(5, 12, 20), prefill_buckets=(8, 16, 32), new_tokens=6,
     slots=2, block_tokens=8,
-    flash_batch=2, stream_seq=0, lstm_n=8, lstm_h=128, expert_rows=512,
+    flash_batch=2, stream_seq=0, head64_seqs=(256,), lstm_n=8, lstm_h=128, expert_rows=512,
     expert_ffn=128, interpret=True)
 
 

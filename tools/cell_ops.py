@@ -113,6 +113,12 @@ def main(argv=None):
         os.makedirs(os.path.dirname(os.path.abspath(args.out)), exist_ok=True)
         with open(args.out, "w") as f:
             json.dump({"steps": steps, "groups": table,
+                       # a few operations' names as the trace gives them
+                       # (whole lines), the longest-running first: what a
+                       # traffic file's `kernels` patterns are matched with
+                       "lines": [line[:1500] for line, _ in sorted(
+                           kept["op_seconds"].items(),
+                           key=lambda kv: -kv[1])[:40]],
                        "op_ms_a_step": {
                            n.split(" = ")[0].strip().lstrip("%"):
                            1e3 * s / steps

@@ -37,7 +37,8 @@ ITEM = {"f32": 4, "s32": 4, "u32": 4, "bf16": 2, "f16": 2, "s8": 1, "u8": 1,
         "pred": 1, "f64": 8, "s64": 8, "u64": 8}
 GROUPS = ("head and loss", "embedding", "feed-forward", "expert products",
           "expert routing", "attention projections", "attention glue",
-          "flash", "norms", "residual adds", "updates and casts")
+          "flash", "short conv", "norms", "residual adds",
+          "updates and casts")
 
 
 def family_symbol(cfg):
@@ -73,7 +74,7 @@ def compile_step(cfg, traffic):
     sym = family_symbol(cfg)
     inputs = {n: (traffic["batch"], traffic["seq_len"])
               for n in ("data", "softmax_label")}
-    shapes, _, _ = sym.infer_shape(**inputs)
+    shapes, _, aux_shapes = sym.infer_shape(**inputs)
     names = sym.list_arguments()
 
     def described(shape, dtype):
@@ -82,9 +83,11 @@ def compile_step(cfg, traffic):
 
     args = {n: NDArray(described(s, "int32" if n in inputs else "float32"))
             for n, s in zip(names, shapes)}
+    aux = {n: NDArray(described(s, "float32"))
+           for n, s in zip(sym.list_auxiliary_states(), aux_shapes)}
     exe = Executor(sym, mx.cpu(), args, dict(args),
                    {n: "null" if n in inputs else "write" for n in names},
-                   compute_dtype=traffic["compute_dtype"])
+                   aux, compute_dtype=traffic["compute_dtype"])
     leaves = [n for n in names if n not in inputs]
     o = traffic["optimizer"]
     rule = mx.optimizer.create(
@@ -106,7 +109,8 @@ def compile_step(cfg, traffic):
     pallas.on_tpu = lambda: True  # the flash gate, answered for the chip
     try:
         lowered = jax.jit(step, donate_argnums=(0, 1)).lower(
-            params, dict(params), {}, described(key.shape, key.dtype),
+            params, dict(params), {n: a._data for n, a in aux.items()},
+            described(key.shape, key.dtype),
             {n: args[n]._data for n in inputs}, per_leaf, per_leaf)
     finally:
         pallas.on_tpu = on_tpu
@@ -123,7 +127,7 @@ def node_groups(sym):
             feeds[id(child)].add(n.op.name)
     by_op = {"Embedding": "embedding", "LayerNorm": "norms",
              "RMSNorm": "norms", "ExpertFFN": "expert routing",
-             "MultiHeadAttention": "attention glue",
+             "MultiHeadAttention": "attention glue", "ShortConv": "short conv",
              "Activation": "feed-forward", "elemwise_add": "residual adds",
              "_plus": "residual adds"}
     out = {}
@@ -131,7 +135,10 @@ def node_groups(sym):
         near = feeds[id(n)] | {c.op.name for c, _ in n.inputs if not c.is_var}
         if n.op.name == "FullyConnected" and "MultiHeadAttention" in near:
             out[n.name] = "attention projections"
-        elif n.op.name == "FullyConnected" and "Activation" in near:
+        elif n.op.name == "FullyConnected" and near & {"Activation",
+                                                       "broadcast_mul"}:
+            out[n.name] = "feed-forward"  # a gated one's up and down too
+        elif n.op.name == "broadcast_mul" and "Activation" in near:
             out[n.name] = "feed-forward"
         else:
             out[n.name] = by_op.get(n.op.name, "head and loss")
