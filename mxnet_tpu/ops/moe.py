@@ -48,10 +48,33 @@ the overflow path in 4 seeds of 6) where this took 301 on every seed
 Every move between token order and sorted-row order is a row GATHER in
 both directions (a permutation read forwards or backwards), because the
 transpose of a gather that autodiff would write is a scatter-add, which the
-TPU runs row by row (PERF.md, section 7, item 8). Around the products the
-backward is autodiff's: a ``custom_vjp`` that recomputed the forward from
-the layer's inputs compiled to the same program, XLA merging the second
-forward with the first.
+TPU runs row by row (PERF.md, section 7, item 8). Every such gather takes a
+ONE-dimensional index, gives a two-dimensional ``(rows, d)`` result and is
+told that its indices lie inside the table (``_rows_at``); no array has
+``top_k`` on its second-minor dimension. The combine fetches a token's k
+rows CHOICE-major, into k slabs of ``(N, d)`` that one pass sums. With an
+``(N, k)`` index the same rows landed in an ``(N, k, d)`` array whose k
+rows pad a tile of sixteen sublanes, which cost a relayout (0.9 ms at the
+cells' shapes) and a sum over the padded array after the gather, and
+``jnp.take``'s default, which fills what lies outside the table with NaN,
+a ``select`` pass over every gather's result (0.76-0.82 ms). What decides
+a gather's own time is where its TABLE lies (PERF.md, Findings, PR 36): one
+of N rows (42-67 MB) XLA keeps in VMEM, and the gather writes at the HBM's
+bandwidth (0.39 ms for 49152 x 2560 or 65536 x 2048 rows); one of R rows
+(251-268 MB) stays in HBM and is read at 33-41 ns a row (2.0-2.2 ms),
+whatever the index's shape or order. Around the products the backward is
+autodiff's: a ``custom_vjp`` that recomputed the forward from the layer's
+inputs compiled to the same program, XLA merging the second forward with
+the first.
+
+Where the ``valid`` mask lives: in ``_row_weights``, always (a row past the
+held assignments gets weight 0, which multiplies the down product's input
+and every gradient that returns through it), and in the combine's ``ok``
+(such a row is never summed). ``_spread`` masks its rows only where the
+buffer is smaller than the assignments (``experts_held < top_k``); with
+one row an assignment the two masks above already make what ``_spread``
+writes there immaterial, and its ``select`` pass over ``(rows, d)`` is not
+made (``_plan``).
 """
 from __future__ import annotations
 
@@ -75,24 +98,49 @@ def buffer_rows(tokens, top_k, held, experts):
 # A plan (dict of int/bool arrays) describes one permutation both ways:
 #   tok (R,), order (R,), valid (R,): row r holds assignment order[r], of
 #       token tok[r]; rows past the held assignments are not valid;
+#   spread_mask: valid, or None where what _spread writes into a row that
+#       is not valid cannot reach any result (_plan);
 #   slot (N, k), ok (N, k): assignment (t, j) sits in row slot[t, j], if ok.
 
+def _rows_at(src, index):
+    """``src[index]`` along axis 0, the gather alone. Every index a plan
+    holds lies inside its table (``_plan``), and the gather is told so:
+    ``jnp.take``'s default fills what lies outside with NaN, a ``select``
+    pass over the whole result after the gather."""
+    return src.at[index].get(mode="promise_in_bounds")
+
+
 def _take(src, index, mask):
-    out = jnp.take(src, index, axis=0)
+    out = _rows_at(src, index)
+    if mask is None:
+        return out
     return jnp.where(mask.reshape(mask.shape + (1,) * (src.ndim - 1)), out, 0)
 
 
 @jax.custom_vjp
 def _spread(x, plan):
-    """Tokens (N, d) -> sorted rows (R, d)."""
-    return _take(x, plan["tok"], plan["valid"])
+    """Tokens (N, d) -> sorted rows (R, d): one gather, index (R,)."""
+    return _take(x, plan["tok"], plan["spread_mask"])
 
 
 @jax.custom_vjp
 def _collect(rows, plan):
-    """Sorted rows (R, d) -> tokens (N, d): the sum of a token's rows."""
-    got = _take(rows, plan["slot"], plan["ok"])            # (N, k, d)
-    return jnp.sum(got, axis=1, dtype=jnp.float32).astype(rows.dtype)
+    """Sorted rows (R, d) -> tokens (N, d): the sum of a token's rows.
+
+    ONE flat gather, choice-major: the index is ``slot.T`` read as
+    ``(k * N,)``, so the result is ``(k * N, d)``, the layout ``_spread``'s
+    gather writes, and its k slabs of ``(N, d)`` (a view: N is whole tiles)
+    are summed over the MAJOR axis in float32 and cast once, the ``ok``
+    mask a predicate inside that one pass. (Written as a reduction: as k
+    explicit adds of ``got[j]`` XLA sliced every slab out and copied it
+    into a transposed layout, 40 copies in ``smallthinker_train_8k``'s
+    step.) The gather with the ``(N, k)`` index gave ``(N, k, d)``, k
+    padding a tile's sublanes: a relayout and a sum over the padded array
+    after it (PERF.md, Findings, PR 36)."""
+    n, k = plan["slot"].shape
+    got = _rows_at(rows, plan["slot"].T.reshape(-1)).reshape(k, n, -1)
+    got = jnp.where(plan["ok"].T[:, :, None], got, 0)
+    return jnp.sum(got, axis=0, dtype=jnp.float32).astype(rows.dtype)
 
 
 @jax.custom_vjp
@@ -124,11 +172,24 @@ def _sort_assignments(idx, first, held):
 
 
 def _plan(order, inv, total, rows, top_k):
-    r = jnp.arange(rows, dtype=jnp.int32)
+    """``spread_mask`` is None where the buffer has a row for every
+    assignment (``rows == N * top_k``, a fact of the shapes): a row past
+    ``total`` is then the row of an assignment to another chip's expert,
+    ``_row_weights`` gives it weight 0, and that zero multiplies the down
+    product's input and every gradient that returns through it, so the
+    down product's result, dgate, dup, both dX products and all three dW
+    are zero on such rows whatever ``_spread`` wrote there, and
+    ``_collect`` and ``_row_weights``' backward read such rows only through
+    slots that are not ``ok``. (It holds a real token's row through the
+    last held expert's matrices: no likelier to overflow, and so to turn
+    its zero weight into a NaN, than a row that holds an assignment.)
+    A smaller buffer (``experts_held < top_k``) keeps the mask."""
+    valid = jnp.arange(rows, dtype=jnp.int32) < total
     inv = inv.reshape(-1, top_k)
     return {"tok": order[:rows] // top_k, "order": order[:rows],
-            "valid": r < total, "slot": jnp.minimum(inv, rows - 1),
-            "ok": inv < total}
+            "valid": valid,
+            "spread_mask": None if rows == inv.size else valid,
+            "slot": jnp.minimum(inv, rows - 1), "ok": inv < total}
 
 
 def product_path(rows, d, f, dtype):

@@ -270,6 +270,93 @@ def test_expert_layer_refuses_wrong_shares():
         _expert_op(8, 4, 6, 2)(*args)
 
 
+def _moves_at_the_parent():
+    """``_spread`` and ``_collect`` as they stood before PR 36, the plain
+    reference: the combine takes with the ``(N, k)`` index into an
+    ``(N, k, d)`` array, masks it and sums over axis 1; ``_spread`` masks
+    its rows always."""
+    def take(src, index, mask):
+        out = jnp.take(src, index, axis=0)
+        return jnp.where(mask.reshape(mask.shape + (1,) * (src.ndim - 1)),
+                         out, 0)
+
+    @jax.custom_vjp
+    def spread(x, plan):
+        return take(x, plan["tok"], plan["valid"])
+
+    @jax.custom_vjp
+    def collect(rows, plan):
+        got = take(rows, plan["slot"], plan["ok"])
+        return jnp.sum(got, axis=1, dtype=jnp.float32).astype(rows.dtype)
+
+    spread.defvjp(lambda x, plan: (spread(x, plan), plan),
+                  lambda plan, g: (collect(g, plan), None))
+    collect.defvjp(lambda rows, plan: (collect(rows, plan), plan),
+                   lambda plan, g: (spread(g, plan), None))
+    return spread, collect
+
+
+@pytest.mark.parametrize("dtype,tol", [("float32", 2e-6), ("bfloat16", 0.03)])
+@pytest.mark.parametrize("tokens,top_k,held,e,prefers", [
+    (48, 3, 4, 8, "any"),      # a row an assignment: _spread unmasked
+    (64, 6, 16, 64, "any"),    # smallthinker_train_8k's ratios
+    (32, 4, 8, 64, "any"),     # lfm2_train_8k's
+    (48, 3, 2, 8, "any"),      # experts_held < top_k: clamped slots
+    (40, 4, 1, 8, "any"),      # one held expert, a buffer of N rows
+    (40, 3, 4, 16, "held"),    # every assignment held: total == rows
+    (40, 3, 4, 16, "others"),  # none: the products walk no assignment
+    (40, 3, 2, 16, "held"),    # a full buffer and every slot past it clamped
+])
+def test_expert_moves_against_the_parents_formulation(
+        tokens, top_k, held, e, prefers, dtype, tol, monkeypatch):
+    """The flat choice-major combine, and ``_spread`` without its mask
+    where a row stands for every assignment, against the moves as the
+    parent wrote them: the output and the gradients of ``data``, the
+    router matrix and all three expert matrices, in float32 equal to
+    rounding and in bfloat16 within the op's gate
+    (``test_expert_layer_down_the_kernel_path``)."""
+    d, f = 16, 24
+    x, xr, wr, wg, wu, wd = _expert_args(e, held, d, f, tokens,
+                                         seed=tokens + top_k + held)
+    if prefers != "any":  # a router that only ever prefers the held, or never
+        sign = jnp.where(jnp.arange(e) < held, 1.0, -1.0)
+        xr, wr = jnp.abs(xr), jnp.abs(wr) * (
+            sign if prefers == "held" else -sign)[:, None]
+    args = tuple(a.astype(dtype) for a in (x, xr)) + (wr,) + tuple(
+        a.astype(dtype) for a in (wg, wu, wd))
+    ct = jnp.asarray(np.random.RandomState(1).randn(*x.shape), dtype)
+    rows = moe.buffer_rows(tokens, top_k, held, e)[0]
+    layer = _expert_op(e, held, 0, top_k)
+
+    def run():
+        with jax.default_matmul_precision("highest"):
+            (y, counts), vjp = jax.vjp(layer, *args)
+            grads = vjp((ct, jnp.zeros_like(counts)))
+        return (y, counts) + tuple(grads[i] for i in (0, 2, 3, 4, 5))
+
+    got = run()
+    # the mask is gone from _spread exactly where a row stands for every
+    # assignment, a fact of the shapes
+    every = jnp.arange(tokens * top_k, dtype=jnp.int32)
+    plan = moe._plan(every, every, 0, rows, top_k)
+    assert (plan["spread_mask"] is None) == (rows == tokens * top_k)
+    assert plan["slot"].shape == (tokens, top_k)
+    for name, move in zip(("_spread", "_collect"), _moves_at_the_parent()):
+        monkeypatch.setattr(moe, name, move)
+    want = run()
+    total = float(want[1].sum())
+    assert prefers == "any" or total == (
+        tokens * min(top_k, held) if prefers == "held" else 0)
+    assert prefers != "held" or total == rows
+    np.testing.assert_array_equal(got[1], want[1])
+    for a, b in zip(got, want):
+        assert a.dtype == b.dtype and a.shape == b.shape
+        b = np.asarray(b, np.float32)
+        assert np.isfinite(b).all()
+        np.testing.assert_allclose(np.asarray(a, np.float32), b, rtol=0,
+                                   atol=tol * max(1.0, np.abs(b).max()))
+
+
 # --- the block builder -----------------------------------------------------------
 
 def test_todays_transformer_lm_graph_is_unchanged():

@@ -227,15 +227,11 @@ def test_short_conv_kernels_compile_for_v5e(batch, one_chip):
         assert '"scoped_memory_configs":[{' not in text
 
 
-def test_expert_layer_step_compiles_to_grouped_kernels(one_chip):
+@pytest.fixture(scope="module")
+def expert_layer_step(one_chip):
     """The fused step of ``smallthinker_train_8k`` at one layer, as the
-    chip's compiler builds it: the expert matrices' nine products (forward,
-    dX and dW of gate, up and down) are the repo's own kernels
-    (``expert_gmm`` / ``expert_tgmm``, each line saying its tiling under
-    ``ragged_dot_tiling``) over the worst-case buffer and the matrices in
-    the layout the op holds them, none is left to the compiler's
-    ``ragged-dot``, there is no dense product over all sixteen held
-    experts, and the one window-free layer's flash kernels are there."""
+    chip's compiler builds it: (its text, its device operations, its
+    instructions' lines by name). One compile for the tests below."""
     import json
 
     step_ops = _step_ops()
@@ -249,6 +245,18 @@ def test_expert_layer_step_compiles_to_grouped_kernels(one_chip):
     ops = step_ops.device_ops(text, step_ops.node_groups(sym))
     lines = {line.split(" = ")[0].strip().lstrip("%"): line
              for line in step_ops.instruction_lines(text)}
+    return text, ops, lines
+
+
+def test_expert_layer_step_compiles_to_grouped_kernels(expert_layer_step):
+    """The expert matrices' nine products (forward, dX and dW of gate, up
+    and down) are the repo's own kernels (``expert_gmm`` / ``expert_tgmm``,
+    each line saying its tiling under ``ragged_dot_tiling``) over the
+    worst-case buffer and the matrices in the layout the op holds them,
+    none is left to the compiler's ``ragged-dot``, there is no dense
+    product over all sixteen held experts, and the one window-free layer's
+    flash kernels are there."""
+    text, ops, lines = expert_layer_step
     # 8192 tokens x 6 choices = 49152 rows, the worst case (12288 expected)
     grouped = [o for o in ops if o["group"] == "expert products"]
     assert sorted(o["name"].split(".")[0] for o in grouped) == (
@@ -273,3 +281,33 @@ def test_expert_layer_step_compiles_to_grouped_kernels(one_chip):
     assert sum(o["kernel"] and o["group"] == "flash" for o in ops) == 3
     assert {"expert products", "expert routing", "flash"} <= {
         o["group"] for o in ops}
+
+
+def test_expert_layer_moves_are_flat_gathers(expert_layer_step):
+    """Every move between token order and sorted-row order of that step is
+    a gather with a one-dimensional index and a two-dimensional result: the
+    four of a layer (``_spread`` and the combine, forward and backward) give
+    ``bf16[49152,2560]``, no gather anywhere has a three-dimensional
+    result, no array has the six choices on its second-minor dimension,
+    the k slabs ``[6,8192,2560]`` are a view of the gather's result that
+    ONE fusion a combine sums (no slab is sliced out or copied on the
+    way), and no ``select`` pass over ``[49152,2560]`` stands between a
+    gather and the product that reads it (``_spread`` without its mask;
+    the gather told its indices lie inside the table)."""
+    text, ops, _ = expert_layer_step
+    gathers = re.findall(r"= (\w+)\[([\d,]*)\]\S* gather\(", text)
+    assert gathers.count(("bf16", "49152,2560")) == 4, gathers
+    assert not [g for g in gathers if g[1].count(",") > 1], gathers
+    assert "8192,6,2560]" not in text
+    routing = [o for o in ops if o["group"] == "expert routing"]
+    rows = [o for o in routing if o["result"] == "bf16[49152,2560]"]
+    # the four gathers and the add of the two dX products, nothing else
+    assert sorted(o["opcode"] for o in rows) == ["add"] + ["fusion"] * 4, [
+        (o["name"], o["opcode"]) for o in rows]
+    slabs = [o for o in ops if "bf16[6,8192,2560]" in o["operands"]
+             or o["result"].startswith("bf16[6,8192,2560]")]
+    assert [(o["opcode"], o["result"]) for o in slabs] == [
+        ("fusion", "bf16[8192,2560]")] * 2, slabs
+    assert not [o["name"] for o in ops if o["opcode"] in ("copy", "slice")
+                and o["result"] in ("bf16[1,8192,2560]", "bf16[49152,2560]")
+                and o["node"] == "layer0_experts"]
