@@ -1,0 +1,11 @@
+"""Attention but for its kernels: the q, k, v and o projections, the rotation,
+the q and k head norms and the relayouts around the kernels. Device
+milliseconds a step of the operations the program's record
+(``telemetry.programs()``) puts in the group ``attention_rest``
+(lib/groups.py), joined to the trace by their own names (lib/programs.py);
+the ``step.ms.*`` metrics add up to ``step.device_ms``. Device trace."""
+from lib import programs
+
+
+def read(run):
+    return programs.group_ms(run, "attention_rest")

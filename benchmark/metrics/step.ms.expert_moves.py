@@ -1,0 +1,11 @@
+"""Everything under an ``ExpertFFN`` node but its grouped products: the
+router, the sorts, the gathers both ways, the masks, the activation, the
+combine. Device milliseconds a step of the operations the program's record
+(``telemetry.programs()``) puts in the group ``expert_moves``
+(lib/groups.py), joined to the trace by their own names (lib/programs.py);
+the ``step.ms.*`` metrics add up to ``step.device_ms``. Device trace."""
+from lib import programs
+
+
+def read(run):
+    return programs.group_ms(run, "expert_moves")

@@ -39,6 +39,8 @@ from .context import Context, default_context
 from .ndarray import NDArray
 from .ops.matrix import gathered_rows_as
 from .ops.registry import built_layers
+from .telemetry.programs import graph_nodes as _graph_nodes
+from .telemetry.programs import note as _note_program
 
 
 def _cast_floats(tree, dtype, src=None, skip=()):
@@ -93,30 +95,20 @@ def _float32_state(symbol):
 
 
 def _layer_attrs(layers):
-    """The ``executor.train_step`` span's static attributes for what the
-    step's layers told of themselves as it was traced (``ops/registry.py``
-    ``built_layers``). With ``ExpertFFN`` layers: how many, and of one
-    layer the experts held, the choices a token, the rows of its
-    sorted-assignment buffer as allocated, the assignments expected under
-    uniform routing, and the route where it is not the softmax. With
-    ``ShortConv`` layers: how many. With attention: its head size."""
-    by_op = {}
-    for layer in layers:
-        by_op.setdefault(layer["op"], []).append(layer)
-    out = {}
-    if "ExpertFFN" in by_op:
-        one = by_op["ExpertFFN"][0]
-        out.update(moe_layers=len(by_op["ExpertFFN"]),
-                   moe_experts_held=one["experts_held"],
-                   moe_top_k=one["top_k"], moe_buffer_rows=one["buffer_rows"],
-                   moe_expected_rows=one["expected_rows"])
-        if one["route"] != "softmax":
-            out["moe_route"] = one["route"]
-    if "ShortConv" in by_op:
-        out["conv_layers"] = len(by_op["ShortConv"])
-    if "MultiHeadAttention" in by_op:
-        out["attn_head_dim"] = by_op["MultiHeadAttention"][0]["head_dim"]
-    return out
+    """The ``executor.train_step`` span's static attributes for the
+    ``ExpertFFN`` layers among what the step's layers told of themselves as
+    it was traced (``ops/registry.py`` ``built_layers``): how many, and of
+    one layer the experts held, the choices a token, the rows of its
+    sorted-assignment buffer as allocated and the assignments expected
+    under uniform routing. The rest of what the layers say is the program
+    record's (``telemetry.programs()``: ``layers``)."""
+    moe = [layer for layer in layers if layer["op"] == "ExpertFFN"]
+    if not moe:
+        return {}
+    one = moe[0]
+    return dict(moe_layers=len(moe), moe_experts_held=one["experts_held"],
+                moe_top_k=one["top_k"], moe_buffer_rows=one["buffer_rows"],
+                moe_expected_rows=one["expected_rows"])
 
 
 def _relaid(tree, formats):
@@ -413,6 +405,15 @@ class Executor:
         sharded = stage >= 1
 
         def one_step(params, states, aux_values, rng, data_values, *extra):
+            # a (re)trace lists the layers again, and the backward's
+            # choices with them: a custom VJP's backward is traced when
+            # the VJP is applied, after the forward's evaluation
+            del built.layers[:]
+            with built:
+                return _one_step(params, states, aux_values, rng,
+                                 data_values, *extra)
+
+        def _one_step(params, states, aux_values, rng, data_values, *extra):
             # Stage 1/2: params arrive 1/N-sharded; gather the whole tree
             # replicated up front for forward/backward (vjp's transpose of
             # the gather, fused with the data-parallel psum, is exactly
@@ -438,8 +439,7 @@ class Executor:
                 if cd is not None:
                     av = _cast_floats(av, cd, skip=tables)
                     auxv = _cast_floats(auxv, cd, skip=state32)
-                del built.layers[:]  # a retrace lists them again
-                with gathered_rows_as(cd), built:
+                with gathered_rows_as(cd):
                     outs, aux_up = eval_fn(av, auxv, True, rng)
                 if cd is not None:
                     outs = _cast_floats(outs, jnp.float32, src=cd)
@@ -519,6 +519,20 @@ class Executor:
         def _dispatch():
             return _telemetry.span("executor.train_step.dispatch",
                                    domain="executor")
+
+        def _built_one(sp):
+            # the compile listener marks the spans a program was built
+            # (compiled, or read from the compilation cache) under
+            return bool((getattr(sp, "args", None) or {}).get("compiled"))
+
+        def _note(compiled=None, under=None):
+            """The program just built says what it is
+            (``telemetry.programs()``); the compiled step is not kept."""
+            _note_program(
+                "train_step", step=self._train_steps,
+                build=getattr(under, "id", None), layers=built.layers,
+                nodes=_graph_nodes(self._symbol), compiled=compiled,
+                uncast_table_bytes=aot.get("uncast_table_bytes", 0))
 
         def _run_impl(params, states, data_values, *extra):
             rng = self._next_rng()
@@ -621,7 +635,7 @@ class Executor:
                     # Format above carries the sharding): a concrete
                     # jax.Array has a layout of its own, which jit
                     # refuses next to Layout.AUTO
-                    with _build("auto_layout_learn"):
+                    with _build("auto_layout_learn") as learn:
                         learned = jf.lower(_avals(params, sharding=False),
                                            _avals(states, sharding=False),
                                            aux_values, rng, dv,
@@ -631,17 +645,18 @@ class Executor:
                     pf, sf = (learned.input_formats[0][0],
                               learned.input_formats[0][1])
                     aot["informats"] = (pf, sf)
-                    aot["learned"] = learned
+                    aot["learned"] = learned, learn
                 # relayout to the learned formats; only needed until the
                 # caller threads returned (already-relaid) arrays back
                 # in — re-issuing device_put on matching arrays is
                 # avoided entirely after the first call
+                learn = None
                 if not aot.get("relaid"):
                     pf, sf = aot["informats"]
                     with _build("relayout"):
                         params, pf, took_p = _relaid(params, pf)
                         states, sf, took_s = _relaid(states, sf)
-                    learned = aot.pop("learned")
+                    learned, learn = aot.pop("learned")
                     if chain == 1 or not (took_p and took_s):
                         # for the formats the arrays HAVE: the learned
                         # ones, but for a leaf that did not take its own
@@ -652,10 +667,31 @@ class Executor:
                             out_shardings=(None, pf, sf, None))
                     else:
                         aot["jit"] = learned
+                        _note(learned, learn)
+                        learn = None  # noted: nothing is re-jitted
                     aot["relaid"] = True
-                with _dispatch():
+                with _dispatch() as sp:
                     outs, new_params, new_states, aux_up = aot["jit"](
                         params, states, aux_values, rng, dv, *extra)
+                if learn is not None:
+                    # the program that RUNS is the one jit just compiled
+                    # for the learned formats, and it names its
+                    # instructions otherwise than `learned` does (PERF.md,
+                    # Findings, PR 37). jit keeps its lowering and its
+                    # executable: asked again at the same arguments'
+                    # shapes and formats it lowers and compiles nothing
+                    try:
+                        running = aot["jit"].lower(
+                            new_params, new_states, aux_values, rng, dv,
+                            *extra).compile()
+                    except Exception:
+                        logging.getLogger("mxnet_tpu").warning(
+                            "train step: jit did not hand its program back; "
+                            "the program record has no ops", exc_info=True)
+                        running = None
+                    _note(running, learn)
+                elif _built_one(sp):  # another program (new shapes), with
+                    _note()           # no compiled object in hand to read
             else:
                 if _progcache.enabled() and "exec" not in aot:
                     # Persistent program cache for the fused step: key by
@@ -664,7 +700,7 @@ class Executor:
                     # key could collide across optimizer rules). Donation
                     # is part of the key and survives serialization. Any
                     # failure pins the plain-jit path for this step fn.
-                    with _build("progcache"):
+                    with _build("progcache") as sp:
                         try:
                             lowered = jitted.lower(params, states, aux_values,
                                                    rng, dv, *extra)
@@ -679,12 +715,13 @@ class Executor:
                                 _progcache.store(key, exe, note="train_step",
                                                  kind="train_step")
                             aot["exec"] = exe
+                            _note(exe, sp)
                         except Exception:
                             logging.getLogger("mxnet_tpu").warning(
                                 "progcache: train-step AOT path failed; "
                                 "using plain jit", exc_info=True)
                             aot["exec"] = None
-                with _dispatch():
+                with _dispatch() as sp:
                     if aot.get("exec") is not None:
                         try:
                             outs, new_params, new_states, aux_up = \
@@ -705,6 +742,8 @@ class Executor:
                     else:
                         outs, new_params, new_states, aux_up = jitted(
                             params, states, aux_values, rng, dv, *extra)
+                if _built_one(sp):  # jit built it: no compiled object
+                    _note()         # in hand to read it from
             for n, v in aux_up.items():
                 self.aux_dict[n]._data = v
             self.outputs = [NDArray(o) for o in outs]
@@ -719,20 +758,18 @@ class Executor:
             # compile listener says on both whether, and for how long, jit
             # traced, lowered, compiled or read its cache inside them
             self._train_steps += 1
-            # both byte counts are reckoned on the first call, and the
-            # expert layers are listed as that call traces the step
-            counts = ("gather_bytes", "uncast_table_bytes")
-            known = {k: aot[k] for k in counts if k in aot}
+            # the bytes are reckoned on the first call, and the expert
+            # layers are listed as that call traces the step
+            known = "gather_bytes" in aot
             with _telemetry.span("executor.train_step", domain="executor",
                                  step=self._train_steps, chain=chain,
                                  stage=stage,
-                                 **(known or dict.fromkeys(counts, 0)),
+                                 gather_bytes=aot.get("gather_bytes", 0),
                                  **(_layer_attrs(built.layers)
                                     if known else {})) as sp:
                 out = _run_impl(params, states, data_values, *extra)
                 if not known:
-                    for k in counts:
-                        sp.add(k, aot.get(k, 0))
+                    sp.add("gather_bytes", aot.get("gather_bytes", 0))
                     sp.annotate(**_layer_attrs(built.layers))
                 return out
 

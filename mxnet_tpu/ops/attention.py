@@ -156,7 +156,10 @@ def _multi_head_attention(attrs, query, key, value, q_norm_gamma=None,
 
     q = split(query, tq, h)
     k, v = split(key, tk, hkv), split(value, tk, hkv)
-    note_built({"op": "MultiHeadAttention", "head_dim": d})
+    # the kernels' gate and their backward add to this record what they
+    # build (flash_attention.py); without them it stands as it is
+    note_built({"op": "MultiHeadAttention", "head_dim": d, "window": None,
+                "kernel": False, "backward": None})
     if attrs["qk_norm"]:
         q = _head_norm(q, q_norm_gamma, attrs["qk_norm_eps"])
         k = _head_norm(k, k_norm_gamma, attrs["qk_norm_eps"])

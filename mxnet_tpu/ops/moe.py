@@ -13,7 +13,7 @@ matrices are GROUPED products over the ragged, sorted batches, never a
 dense product over all held experts with a mask and never a one-hot
 dispatch (``parallel/moe.py``'s Switch layer is that, with a capacity that
 clips). Which implementation multiplies follows from what the code can
-observe (``product_path``; counter ``expert_layer_built_total{path=}``),
+observe (``product_path``; the layer's ``note_built`` record says it),
 with no option to choose it:
 
 - on the TPU, where both widths are whole lanes, the buffer whole row
@@ -81,7 +81,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from ..telemetry.metrics import registry
 from .registry import defop, get_op, note_built
 
 _ACTS = {"relu": jax.nn.relu, "silu": jax.nn.silu, "gelu": jax.nn.gelu}
@@ -328,13 +327,9 @@ def _expert_ffn(attrs, inputs, aux, ctx):
     order, inv, sizes = _sort_assignments(idx, first, held)
     rows, expected = buffer_rows(x.shape[0], top_k, held, experts)
     path = product_path(rows, d, gate_weight.shape[1], x.dtype)
-    registry.counter(
-        "expert_layer_built_total", labels={"path": path},
-        help="expert layers traced into a program, by the grouped-product "
-             "path they were built with").inc()
     note_built({"op": "ExpertFFN", "experts_held": held, "top_k": top_k,
                 "buffer_rows": rows, "expected_rows": expected,
-                "route": attrs["route"]})
+                "route": attrs["route"], "path": path})
     grouped = _ragged
     if path == "pallas":
         from .pallas.grouped_matmul import grouped_matmul as grouped

@@ -77,7 +77,7 @@ from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import PartitionSpec as P
 
 from ...parallel.mesh import partition_mesh
-from ...telemetry.metrics import registry
+from ..registry import current_node, note_built
 
 # Preferred tiles of all four kernels (`_pick_block` falls back to a
 # divisor of the length). PR-26 sweep on v5e, bq x bk over {256,512,1024}
@@ -744,7 +744,7 @@ def _fa_backward_split(args, causal, scale, interpret, window=0):
 
 
 def _fa_backward(q, k, v, o, lse, do, causal, scale, interpret,
-                 g_lse=None, window=0):
+                 g_lse=None, window=0, node=""):
     """q/o/do: (B*Hkv, G, Tq, D); k/v: (B*Hkv, Tk, D); lse: (B*Hkv, G, 1,
     Tq). Returns (dq like q, dk/dv like k/v) — dk/dv already summed over
     the query-head group inside the kernel.
@@ -752,17 +752,14 @@ def _fa_backward(q, k, v, o, lse, do, causal, scale, interpret,
     One algorithm, two regimes read from the shapes: the fused kernel
     where its whole-sequence accumulators fit a kernel's scoped VMEM
     (`_fused_bwd_vmem_bytes` against `_SCOPED_VMEM`), the dq and dkv
-    kernels otherwise (long sequences, wide dtypes). Which was built is
-    counted as the program is traced:
-    ``flash_backward_built_total{path="fused"|"split"}``."""
+    kernels otherwise (long sequences, wide dtypes). Which was built goes
+    into the record of the graph node ``node`` whose forward this is the
+    backward of (``note_built``: ``backward`` ``"fused"`` or ``"split"``)."""
     args = (q, k, v, do, lse, _row_sums(o, do, g_lse))
     fused = _fused_bwd_vmem_bytes(q.shape[2], k.shape[1], q.shape[3],
                                   q.dtype.itemsize) <= _SCOPED_VMEM
-    registry.counter(
-        "flash_backward_built_total",
-        labels={"path": "fused" if fused else "split"},
-        help="flash-attention backward passes traced into a program, by "
-             "the kernels chosen from the shapes").inc()
+    note_built({"op": "MultiHeadAttention",
+                "backward": "fused" if fused else "split"}, node=node)
     if fused:
         return _fa_backward_fused(args, causal, scale, interpret, window)
     return _fa_backward_split(args, causal, scale, interpret, window)
@@ -809,18 +806,21 @@ def kernel_qualifies(tq, tk, d, compiled=True, causal=False):
             and (not compiled or d % _LANES == 0 or d == _HALF_LANES))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
-def _flash(q3, k3, v3, causal, scale, interpret, window=0):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+def _flash(q3, k3, v3, causal, scale, interpret, window=0, node=""):
+    """``node``: the graph node being traced, for the backward's record
+    (a custom VJP's backward is traced when the VJP is applied, outside
+    the node)."""
     return _fa_forward(q3, k3, v3, causal, scale, interpret, window=window)
 
 
-def _flash_fwd(q3, k3, v3, causal, scale, interpret, window):
+def _flash_fwd(q3, k3, v3, causal, scale, interpret, window, node):
     out, lse = _fa_forward(q3, k3, v3, causal, scale, interpret,
                            with_lse=True, window=window)
     return out, (q3, k3, v3, out, lse)
 
 
-def _flash_bwd(causal, scale, interpret, window, res, g):
+def _flash_bwd(causal, scale, interpret, window, node, res, g):
     # Blocked FlashAttention-2 backward: rebuilds p per tile from the
     # saved logsumexp — never materializes the (Tq, Tk) score matrix, so
     # long-sequence TRAINING scales like the forward (docs/perf.md
@@ -828,7 +828,7 @@ def _flash_bwd(causal, scale, interpret, window, res, g):
     # reference-math and the S^2 backward dominated at seq >= 4096).
     q3, k3, v3, o3, lse = res
     return _fa_backward(q3, k3, v3, o3, lse, g, causal, scale, interpret,
-                        window=window)
+                        window=window, node=node)
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
@@ -914,22 +914,16 @@ def flash_attention(q, k, v, causal=False, scale=None, interpret=None,
         return fallback()
 
     g = h // hkv
-    registry.counter(
-        "flash_head_dim_built_total", labels={"head_dim": str(d)},
-        help="flash-attention calls traced into a program, by head "
-             "size").inc()
-    if causal:
-        registry.counter(
-            "flash_window_built_total",
-            labels={"band": "window" if window else "causal"},
-            help="causal flash-attention calls traced into a program, by "
-                 "whether a window bands the tile walk").inc()
+    node = current_node()
+    # ``window``: the band that bounds the tile walk of all four kernels
+    note_built({"op": "MultiHeadAttention", "head_dim": d, "kernel": True,
+                "window": window or None})
 
     def run(q, k, v):
         rows = q.shape[0] * hkv
         out = _flash(q.reshape(rows, g, tq, d), k.reshape(rows, tk, d),
                      v.reshape(rows, tk, d), causal, scale, interpret,
-                     window)
+                     window, node)
         return out.reshape(q.shape)
 
     mesh = partition_mesh()

@@ -25,6 +25,7 @@ as the reference generates its Python API from the C op registry
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import threading
 from typing import Any, Callable, Dict, Optional, Sequence, Tuple
@@ -41,10 +42,10 @@ _tracing = threading.local()
 
 class built_layers:
     """Entered around the trace of a graph: an op that tells what it built
-    (``note_built``: ``ExpertFFN`` its buffer, ``ShortConv`` and
-    ``MultiHeadAttention`` that they are there) appends a dict to
-    ``self.layers``. Trace-time Python state only: nothing here reaches
-    the program."""
+    (``note_built``: ``ExpertFFN`` its buffer and its products' path,
+    ``ShortConv`` its path, ``MultiHeadAttention`` its head size, band and
+    kernels) leaves a dict in ``self.layers``, one a graph node. Trace-time
+    Python state only: nothing here reaches the program."""
 
     def __init__(self):
         self.layers = []
@@ -59,12 +60,38 @@ class built_layers:
         return False
 
 
-def note_built(record):
-    """Append ``record`` (a dict with the op's name under ``"op"``) to the
-    ``built_layers`` this trace runs under, if any."""
+@contextlib.contextmanager
+def at_node(name):
+    """Entered by the graph's evaluator around one node's ``impl``: an op
+    does not know its node, so ``note_built`` reads the name from here."""
+    prev, _tracing.node = current_node(), name
+    try:
+        yield
+    finally:
+        _tracing.node = prev
+
+
+def current_node():
+    """The graph node whose ``impl`` is being traced, or ``""``: what a
+    custom VJP hands its backward, which is traced outside the node."""
+    return getattr(_tracing, "node", "")
+
+
+def note_built(record, node=None):
+    """Leave ``record`` (a dict with the op's name under ``"op"``) in the
+    ``built_layers`` this trace runs under, if any, under ``"node"`` the
+    graph node being traced (``node``: another's, a backward's). What a
+    node's op says in several places (the attention op and its kernels'
+    gate; a backward) is one record."""
     into = getattr(_tracing, "into", None)
-    if into is not None:
-        into.append(record)
+    if into is None:
+        return
+    node = current_node() if node is None else node
+    for seen in into:
+        if node and seen["node"] == node and seen["op"] == record["op"]:
+            seen.update(record)
+            return
+    into.append(dict(record, node=node))
 
 
 @dataclasses.dataclass

@@ -27,7 +27,7 @@ tiles and the width whole lanes the TPU takes the repo's own Pallas kernels
 result, ``short_conv_bwd`` reads it and the incoming gradient once and
 writes the three gradients, 1.13 ms a layer, and keeps 0.27 GB less of the
 step's temporaries. Which of the two follows from what the code can observe
-(``conv_path``; counter ``short_conv_built_total{path=}``), with no option
+(``conv_path``; the layer's ``note_built`` record says it), with no option
 to choose it.
 """
 from __future__ import annotations
@@ -35,7 +35,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from ..telemetry.metrics import registry
 from .registry import defop, get_op, note_built
 
 
@@ -129,11 +128,7 @@ def _short_conv(attrs, data, in_weight, conv_weight, out_weight):
             % (d, 3 * d, d, d, taps, d, d, in_weight.shape,
                conv_weight.shape, out_weight.shape))
     path = conv_path(data.shape, taps)
-    registry.counter(
-        "short_conv_built_total", labels={"path": path},
-        help="gated short-convolution layers traced into a program, by the "
-             "implementation of their elementwise part").inc()
-    note_built({"op": "ShortConv", "kernel": taps})
+    note_built({"op": "ShortConv", "kernel": taps, "path": path})
     u = jnp.dot(data, in_weight.T)
     return jnp.dot(gated_conv(u, conv_weight, path), out_weight.T)
 

@@ -25,6 +25,7 @@ import numpy as np
 from . import attribute, name as _name_mod
 from .base import MXNetError
 from .ops import OP_REGISTRY, OpContext, OpDef, get_op
+from .ops.registry import at_node
 
 # Monotonic id for ephemeral Symbol.grad ops (never reused, unlike id()).
 _GRAD_OP_COUNTER = 0
@@ -568,7 +569,7 @@ class Symbol:
                     node_rng = jax.random.fold_in(rng, ni)
                 # the node's name on every device operation traced from it:
                 # the profiler and the compiled text say whose a fusion is
-                with jax.named_scope(node.name):
+                with jax.named_scope(node.name), at_node(node.name):
                     outs, aux_out = op.impl(
                         attrs, tuple(vals[:n_args]), tuple(vals[n_args:]),
                         OpContext(is_train, node_rng),
@@ -666,7 +667,8 @@ class Symbol:
                                 [(id(c), i) in tags for c, i in node.inputs])
                         node_rng = (jax.random.fold_in(c_rng, ni)
                                     if op.needs_rng else None)
-                        with jax.named_scope(node.name):
+                        with jax.named_scope(node.name), \
+                                at_node(node.name):
                             outs, aux_out = op.impl(
                                 attrs, tuple(vals[:n_args]),
                                 tuple(vals[n_args:]),

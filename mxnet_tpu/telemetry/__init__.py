@@ -1,6 +1,6 @@
 """mxnet_tpu.telemetry — process-wide tracing + metrics (ISSUE 4).
 
-Four pieces, all with branch-and-return disabled paths:
+Five pieces, all with branch-and-return disabled paths:
 
 - **tracing** (:mod:`.tracer`): per-thread ring-buffer span recorder.
   Domains are OFF by default; enable them with
@@ -24,6 +24,10 @@ Four pieces, all with branch-and-return disabled paths:
   compile-after-steady, drain, ``MXNET_SLOW_REQUEST_MS``) write
   diagnostic bundles to ``MXNET_FLIGHT_DIR``.
 
+- **program records** (:mod:`.programs`): what each step program the
+  executor built is (its device operations by graph node, the kernels
+  its layers built, the bytes it wants), read through :func:`programs`.
+
 See docs/observability.md. Instrumentation must live OUTSIDE
 jitted/shard_mapped functions — enforced by
 ``mxnet_tpu.analysis.trace_purity`` (rule ``telemetry-in-jit``), which
@@ -32,19 +36,31 @@ also flags ``current_context()`` reads inside jitted code.
 from .tracer import (STEP_PATH, begin, chrome_events, clock_ns, complete,
                      disable_spans, drain_events, dump_ring, enable_spans,
                      enabled, enabled_domains, end, instant, open_spans,
-                     reset, set_span_sink, span)
+                     set_span_sink, span)
+from .tracer import reset as _reset_ring
 from .metrics import (CONTENT_TYPE_LATEST, Counter, Gauge, Histogram,
                       Registry, registry)
+# the name is the function from here on: the module's other names are
+# imported from it (``from mxnet_tpu.telemetry.programs import note``)
+from .programs import clear as _clear_programs, programs
 from . import compiles
 from . import context
 from . import flight
 from .context import TraceContext, current_context
 
+
+
+def reset():
+    """Drop every buffered event and every program record."""
+    _reset_ring()
+    _clear_programs()
+
+
 __all__ = [
     "span", "begin", "end", "complete", "instant", "open_spans",
     "STEP_PATH", "enabled", "enable_spans", "disable_spans", "enabled_domains",
     "drain_events", "chrome_events", "clock_ns", "reset", "dump_ring",
-    "set_span_sink",
+    "set_span_sink", "programs",
     "registry", "Registry", "Counter", "Gauge", "Histogram",
     "CONTENT_TYPE_LATEST",
     "context", "flight", "TraceContext", "current_context",

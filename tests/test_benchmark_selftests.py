@@ -1,6 +1,6 @@
 """The yardstick's own tests (``benchmark/tests``), run in tier-1.
 
-``benchmark/`` is closed to edits, so its two test files are loaded from
+``benchmark/`` is closed to edits, so its test files are loaded from
 where they are and their tests and fixtures re-exported here: hand counts
 against ``lib/counts.py``, the trace reduction on a recorded trace, the
 span readers on a canned ring, and the toy LM cell end to end (GQA, scalar
@@ -9,7 +9,8 @@ its control and its planted faults; and the same for the toy SmallThinker
 cell (window and NoPE layers, the held experts' share), with its pinned
 counts and its metric readers; and for the toy LFM2 cell (short-convolution
 and attention layers, a dense SwiGLU layer, the sigmoid-and-bias route, the
-tied head).
+tied head); and the ``step.ms.*`` metrics on a hand-made record and trace for
+each toy cell's graph (``test_program_groups``).
 """
 import importlib.util
 import os
@@ -31,7 +32,8 @@ def _load(name):
 
 
 for _name in ("test_benchmark", "test_span_readers",
-              "test_smallthinker_cell", "test_lfm2_cell"):
+              "test_smallthinker_cell", "test_lfm2_cell",
+              "test_program_groups"):
     # tests, fixtures and the helpers they name
     globals().update({k: v for k, v in vars(_load(_name)).items()
                       if not k.startswith("_")})

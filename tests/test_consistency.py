@@ -577,20 +577,19 @@ def _flash_case_setup(monkeypatch, fa, blocks, streaming, path="split"):
         monkeypatch.setattr(fa, "_SCOPED_VMEM", 0)
 
 
-def _flash_backward_built():
-    """{path: flash_backward_built_total{path=...}} as it stands."""
-    from mxnet_tpu import telemetry
+@pytest.fixture
+def built():
+    """What the kernels traced inside the test tell of themselves
+    (``note_built``), the backward's choice among it."""
+    from mxnet_tpu.ops.registry import built_layers
 
-    return {p: telemetry.registry.counter("flash_backward_built_total",
-                                          labels={"path": p}).value
-            for p in ("split", "fused")}
+    with built_layers() as into:
+        yield into.layers
 
 
-def _assert_built_one(before, path):
-    """One backward was traced since ``before``, on ``path``."""
-    want = dict(before)
-    want[path] += 1
-    assert _flash_backward_built() == want
+def _assert_built_one(built, path):
+    """One backward was traced, on ``path``."""
+    assert [r["backward"] for r in built if r.get("backward")] == [path]
 
 
 def _case_paths(cases, is_streaming):
@@ -602,19 +601,18 @@ def _case_paths(cases, is_streaming):
 
 @pytest.mark.parametrize(
     "case,path", _case_paths(_FLASH_CASES, lambda c: c[7] is not None))
-def test_pallas_flash_cases_match_xla(case, path, monkeypatch):
+def test_pallas_flash_cases_match_xla(case, path, monkeypatch, built):
     """Forward and all three gradients of the flash kernels against the
     XLA reference, one case per way the tile walk can go: which tiles a
     block visits, where the diagonal crosses them, the group's sum in
     dk/dv, the superblock regime, and bfloat16 operands; the backward
-    through the two kernels and through the fused one, and the counter
-    says which was built."""
+    through the two kernels and through the fused one, and the kernels'
+    own record says which was built."""
     from mxnet_tpu.ops.pallas import flash_attention as fa
     from mxnet_tpu.ops.attention import _grouped_attention
 
     h, hkv, tq, tk, causal, dtype, blocks, streaming = _FLASH_CASES[case]
     _flash_case_setup(monkeypatch, fa, blocks, streaming, path)
-    built = _flash_backward_built()
     rng = np.random.RandomState(sorted(_FLASH_CASES).index(case))
     B, D = 1, 8
     q = jnp.asarray(rng.randn(B, h, tq, D).astype(np.float32), dtype)
@@ -777,10 +775,11 @@ def test_pallas_flash_fused_backward_equals_two_kernel_path(case,
     ((2, 12, 512, 4096, "bfloat16"), "fused"),
     ((2, 1, 768, 1280, "float32"), "fused"),
 ])
-def test_pallas_flash_backward_path_follows_from_the_shapes(shape, want):
+def test_pallas_flash_backward_path_follows_from_the_shapes(shape, want,
+                                                            built):
     """`_fa_backward` takes the fused kernel exactly where the byte count
     of its blocks, accumulators and tiles fits a kernel's scoped VMEM, and
-    counts the path it built as the program is traced (nothing runs
+    says which path it built as the program is traced (nothing runs
     here: the real shapes are only traced)."""
     from mxnet_tpu.ops.pallas import flash_attention as fa
 
@@ -792,7 +791,6 @@ def test_pallas_flash_backward_path_follows_from_the_shapes(shape, want):
     q = jax.ShapeDtypeStruct((rows, g, tq, d), jnp.dtype(dtype))
     kv = jax.ShapeDtypeStruct((rows, tk, d), jnp.dtype(dtype))
     lse = jax.ShapeDtypeStruct((rows, g, 1, tq), jnp.float32)
-    built = _flash_backward_built()
     dq, dk, dv = jax.eval_shape(
         lambda q, k, v, o, lse, do: fa._fa_backward(
             q, k, v, o, lse, do, True, d ** -0.5, True),

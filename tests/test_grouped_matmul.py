@@ -13,8 +13,7 @@ from jax.experimental.pallas import tpu as pltpu
 import mxnet_tpu.ops.pallas as pallas
 from mxnet_tpu.ops import moe
 from mxnet_tpu.ops.pallas import grouped_matmul as gm
-from mxnet_tpu.ops.registry import get_op
-from mxnet_tpu.telemetry.metrics import registry
+from mxnet_tpu.ops.registry import built_layers, get_op
 
 ROWS, TM = 64, 16
 # sizes of four groups over 64 rows in tiles of 16
@@ -180,9 +179,8 @@ def _expert_layer(e, held, first, top_k):
     return f
 
 
-def _built(path):
-    return registry.counter("expert_layer_built_total",
-                            labels={"path": path}).value
+def _built(layers):
+    return [r["path"] for r in layers if r["op"] == "ExpertFFN"]
 
 
 @pytest.mark.parametrize("dtype,tol", [("float32", 1e-4), ("bfloat16", 0.03)])
@@ -190,7 +188,7 @@ def test_expert_layer_down_the_kernel_path(dtype, tol, monkeypatch):
     """``ExpertFFN`` where its gate says "pallas" (the platform answered
     for the chip, the kernels interpreted) against the same layer through
     ``jax.lax.ragged_dot``: the output and all six gradients, and the
-    counter says which path each trace took."""
+    layer's own record says which path each trace took."""
     e, held, first, top_k, tokens, d, f = 8, 4, 2, 2, 256, 128, 256
     rng = np.random.RandomState(11)
 
@@ -207,11 +205,11 @@ def test_expert_layer_down_the_kernel_path(dtype, tol, monkeypatch):
     layer = _expert_layer(e, held, first, top_k)
 
     def both(path):
-        before = _built(path)
-        with jax.default_matmul_precision("highest"):
+        with jax.default_matmul_precision("highest"), \
+                built_layers() as built:
             (y, counts), vjp = jax.vjp(layer, *args)
             grads = vjp((ct, jnp.zeros_like(counts)))
-        assert _built(path) == before + 1
+        assert _built(built.layers) == [path]
         return (y,) + tuple(grads)
 
     assert moe.product_path(rows, d, f, dtype) == "ragged_dot"  # the CPU

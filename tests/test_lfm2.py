@@ -240,9 +240,10 @@ def test_flash_kernels_at_head_size_64_interpreted(t, regime, monkeypatch):
     64 (64-lane blocks: no padding) in interpret mode against the einsum
     path, 4 query heads a KV head as the benchmark cell has them: output
     and the gradients of q, k and v within the tolerance the tests hold a
-    head of 128 to. The counter says what head size was traced."""
+    head of 128 to. The kernels' record says what head size and which
+    backward were traced."""
     from mxnet_tpu.ops.pallas import flash_attention as fa
-    from mxnet_tpu.telemetry.metrics import registry
+    from mxnet_tpu.ops.registry import built_layers
 
     monkeypatch.setattr(fa, "BLOCK_Q", 256)
     monkeypatch.setattr(fa, "BLOCK_K", 256)
@@ -250,17 +251,15 @@ def test_flash_kernels_at_head_size_64_interpreted(t, regime, monkeypatch):
         monkeypatch.setattr(fa, "_RESIDENT_MAX", 256)
         monkeypatch.setattr(fa, "SUPER_TARGET", 512)
         monkeypatch.setattr(fa, "_SCOPED_VMEM", 0)
-    built = registry.counter("flash_backward_built_total",
-                             labels={"path": regime})
-    sized = registry.counter("flash_head_dim_built_total",
-                             labels={"head_dim": "64"})
-    before = built.value, sized.value
     rng = np.random.RandomState(t)
     q, k, v = (_rand(rng, 1, n, t, 64) for n in (8, 2, 2))
-    _close(lambda q, k, v: fa.flash_attention(q, k, v, causal=True,
-                                              interpret=True),
-           _plain_attention, (q, k, v), tol=3e-5)
-    assert built.value > before[0] and sized.value > before[1]
+    with built_layers() as built:
+        _close(lambda q, k, v: fa.flash_attention(q, k, v, causal=True,
+                                                  interpret=True),
+               _plain_attention, (q, k, v), tol=3e-5)
+    assert {r["backward"] for r in built.layers if "backward" in r} == {
+        regime}
+    assert {r["head_dim"] for r in built.layers if r.get("kernel")} == {64}
 
 
 def test_flash_gate_takes_a_head_of_64_and_counts_its_vmem_at_128():
@@ -504,10 +503,9 @@ def test_fused_step_keeps_the_bias_float32_and_untouched(fam, toy):
     """``make_train_step`` under ``compute_dtype="bfloat16"``: the loss
     falls, ``expert_bias`` reaches the router in float32 (a bias that
     bfloat16 cannot hold still decides), is the same bits after the steps,
-    and the step's span and counters say what was traced."""
+    and the step's span and the program's record say what was traced."""
     from mxnet_tpu import telemetry
     from mxnet_tpu.executor import _float32_state
-    from mxnet_tpu.telemetry.metrics import registry
 
     sym = fam.symbol(toy, True)
     assert _float32_state(sym) == set(sym.list_auxiliary_states())
@@ -523,8 +521,7 @@ def test_fused_step_keeps_the_bias_float32_and_untouched(fam, toy):
                     *rest)
 
     moe.route = spy
-    conv = registry.counter("short_conv_built_total", labels={"path": "xla"})
-    before = conv.value
+    telemetry.reset()
     try:
         step = exe.make_train_step(lambda p, g, s: (
             {n: p[n] - 0.3 * g[n] for n in p}, s))
@@ -541,14 +538,19 @@ def test_fused_step_keeps_the_bias_float32_and_untouched(fam, toy):
     assert seen and all(d == jnp.float32 for d in seen)
     for n, b in bias.items():
         np.testing.assert_array_equal(exe.aux_dict[n].asnumpy(), b)
-    assert conv.value >= before + 4
+    (rec,) = telemetry.programs()
+    by_op = {}
+    for layer in rec["layers"]:
+        by_op.setdefault(layer["op"], []).append(layer)
+    assert [c["path"] for c in by_op["ShortConv"]] == ["xla"] * 4
+    assert [a["head_dim"] for a in by_op["MultiHeadAttention"]] == [16]
+    assert {e["route"] for e in by_op["ExpertFFN"]} == {"sigmoid_bias"}
+    assert all(layer["node"] in rec["nodes"] for layer in rec["layers"])
     spans = [args for ph, name, _d, _t, _dur, args, *_ in
              telemetry.drain_events(clear=False)
              if name == "executor.train_step"]
     assert len(spans) == 6
     for args in spans:
-        assert args["conv_layers"] == 4 and args["attn_head_dim"] == 16
-        assert args["moe_route"] == "sigmoid_bias"
         assert args["moe_layers"] == 4 and args["moe_experts_held"] == 4
         assert args["moe_buffer_rows"] == 32 * 2
         assert args["moe_expected_rows"] == 32 * 2 * 4 / 8
@@ -594,8 +596,8 @@ def test_init_params_raises_where_the_bias_cannot_be_seeded(fam, toy):
 
 
 def test_dense_steps_span_names_the_head_size_and_no_more():
-    """Today's dense model: the span gains ``attn_head_dim`` and none of
-    the keys of layers it does not have."""
+    """Today's dense model: the program's record names the head size, and
+    the span gains none of the keys of layers it does not have."""
     from mxnet_tpu import telemetry
 
     sym = models.get_symbol("transformer-lm", num_classes=50, num_layers=1,
@@ -609,12 +611,16 @@ def test_dense_steps_span_names_the_head_size_and_no_more():
               if n not in ("data", "softmax_label")}
     step = exe.make_train_step(lambda p, g, s: (p, s))
     ids = rng.randint(0, 50, (2, 9)).astype(np.int32)
-    telemetry.drain_events()
+    telemetry.reset()
     for _ in range(2):
         _, params, _ = step(params, {}, {"data": ids[:, :-1],
                                          "softmax_label": ids[:, 1:]})
     spans = [args for ph, name, _d, _t, _dur, args, *_ in
              telemetry.drain_events(clear=False)
              if name == "executor.train_step"]
-    assert [a["attn_head_dim"] for a in spans] == [4, 4]
-    assert not {"conv_layers", "moe_layers", "moe_route"} & set(spans[-1])
+    (rec,) = telemetry.programs()
+    assert [(r["op"], r["head_dim"], r["node"]) for r in rec["layers"]] == [
+        ("MultiHeadAttention", 4, "layer0_attn")]
+    assert len(spans) == 2 and not {
+        "conv_layers", "moe_layers", "moe_route", "attn_head_dim",
+        "uncast_table_bytes"} & set(spans[-1])

@@ -106,7 +106,7 @@ def test_windowed_flash_kernels_interpreted(t, window, regime, monkeypatch):
     the fused backward, and streamed superblocks with the split one, whose
     index maps clamp to the band from both sides."""
     from mxnet_tpu.ops.pallas import flash_attention as fa
-    from mxnet_tpu.telemetry.metrics import registry
+    from mxnet_tpu.ops.registry import built_layers
 
     monkeypatch.setattr(fa, "BLOCK_Q", 256)
     monkeypatch.setattr(fa, "BLOCK_K", 256)
@@ -114,15 +114,14 @@ def test_windowed_flash_kernels_interpreted(t, window, regime, monkeypatch):
         monkeypatch.setattr(fa, "_RESIDENT_MAX", 256)
         monkeypatch.setattr(fa, "SUPER_TARGET", 512)
         monkeypatch.setattr(fa, "_SCOPED_VMEM", 0)
-    band = "window" if 0 < window < t else "causal"
-    counter = registry.counter("flash_window_built_total",
-                               labels={"band": band})
-    before = counter.value
+    band = window if 0 < window < t else None
     q, k, v, w = _qkv(t, 4, 2, 8, t + window)
-    _close(lambda q, k, v: fa.flash_attention(q, k, v, causal=True,
-                                              interpret=True, window=window),
-           lambda q, k, v: _plain_attention(q, k, v, window), (q, k, v), w)
-    assert counter.value > before
+    with built_layers() as built:
+        _close(lambda q, k, v: fa.flash_attention(
+            q, k, v, causal=True, interpret=True, window=window),
+            lambda q, k, v: _plain_attention(q, k, v, window), (q, k, v), w)
+    bands = [r["window"] for r in built.layers if r.get("kernel")]
+    assert bands and set(bands) == {band}
 
 
 def test_band_tile_walk_skips_what_the_band_does_not_reach():

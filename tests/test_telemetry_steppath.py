@@ -7,7 +7,12 @@ one ``executor.train_step`` a step, the first compiled with ``build``
 children, a reshape compiles once more and the record names the step;
 (d) ``Module.fit``: the ``module.*`` children in order under one ``step``;
 (e) ``MXNET_TELEMETRY=0`` records none of it; (f) every ``per_layer`` entry
-of ``BENCHMARK.json`` has its reader file.
+of ``BENCHMARK.json`` has its reader file. The program's record of the step
+it built (``telemetry.programs()``): (g) through the program cache, every
+working instruction under its graph node and the compiled program's bytes;
+(h) the plain path, without them; (i) a reshape's second record; (j) the
+master kill and ``reset``; (k) noting prints nothing, the first read parses
+once.
 """
 import glob
 import importlib.util
@@ -379,5 +384,137 @@ def test_every_per_layer_metric_has_its_reader(metric):
     assert "def read(run)" in src
     assert entry["source"] in ("device_trace", "program_span",
                                "program_counter", "host_clock")
-    # a reader of the program's spans names no device trace, and back
-    assert ("lib import spans" in src) == (entry["source"] == "program_span")
+    # a reader of the program's spans or of its record's memory names no
+    # device trace, and back
+    assert ("lib import spans" in src or "programs.memory(" in src) == (
+        entry["source"] == "program_span")
+
+
+# --- (g)-(k) the program's record of its step -------------------------------
+
+def _toy_lm_step(batch=2):
+    from mxnet_tpu import models
+
+    sym = models.get_symbol("transformer-lm", num_classes=50, num_layers=1,
+                            num_heads=4, model_dim=16, ffn_dim=32,
+                            scalar_loss=True)
+    inputs = {"data": (batch, 8), "softmax_label": (batch, 8)}
+    exe = sym.simple_bind(mx.cpu(), grad_req={
+        n: "null" if n in inputs else "write" for n in sym.list_arguments()},
+        type_dict=dict.fromkeys(inputs, "int32"), **inputs)
+    rng = np.random.RandomState(0)
+    params = {n: jnp.asarray(rng.uniform(-0.1, 0.1, a.shape), jnp.float32)
+              for n, a in exe.arg_dict.items() if n not in inputs}
+    return exe, exe.make_train_step(lambda p, g, s: (
+        {n: p[n] - 0.1 * g[n] for n in p}, s)), params
+
+
+def _lm_feed(batch=2):
+    ids = np.random.RandomState(1).randint(0, 50, (batch, 9)).astype(np.int32)
+    return {"data": ids[:, :-1], "softmax_label": ids[:, 1:]}
+
+
+def _programs_module():
+    import importlib
+
+    # ``telemetry.programs`` is the function; this is its module
+    return importlib.import_module("mxnet_tpu.telemetry.programs")
+
+
+def test_record_through_the_program_cache(monkeypatch, tmp_path):
+    """(g) and (k): one record, hung on the ``progcache`` build; its ``ops``
+    are the entry computation's working instructions, each under the node
+    its text names; its bytes are the compiled program's; the text is
+    parsed at the first read, once."""
+    from mxnet_tpu import executor
+
+    monkeypatch.setenv("MXNET_PROGCACHE_DIR", str(tmp_path))
+    mod, compiled, parses = _programs_module(), [], []
+    store, parse = executor._progcache.store, mod.device_ops
+    monkeypatch.setattr(executor._progcache, "store", lambda key, exe, **kw: (
+        compiled.append(exe), store(key, exe, **kw))[1])
+    monkeypatch.setattr(mod, "device_ops",
+                        lambda text: (parses.append(1), parse(text))[1])
+    exe, step, params = _toy_lm_step()
+    for _ in range(2):
+        _, params, _ = step(params, {}, _lm_feed())
+    assert len(compiled) == 1 and parses == []
+    (rec,) = telemetry.programs()
+    telemetry.programs()
+    assert parses == [1] and rec["read_s"]["parse"] > 0
+    (build,) = [r for r in _records("executor.train_step.build")
+                if r["args"]["phase"] == "progcache"]
+    assert (rec["program"], rec["step"], rec["build"]) == (
+        "train_step", 1, build["args"]["id"])
+
+    text = compiled[0].as_text()
+    comps, entry = mod.computations(text)
+    working = {}
+    for line in comps[entry]:
+        name, _, opcode, _ = mod.INSTR.match(line).groups()
+        if opcode not in mod.FREE + mod.UMBRELLAS and not opcode.endswith(
+                ("-start", "-done")):
+            working[name] = line
+    ops = {op["name"]: op for op in rec["ops"]}
+    assert working and set(working) <= set(ops)
+    for name, line in working.items():
+        called = mod.re.search(r"calls=%(\S+?)[,\s]", line)
+        inside = line + "".join(comps.get(called.group(1), ())
+                                if called else ())
+        node = ops[name]["node"]
+        assert ("jvp(%s)" % node in inside) if node else (
+            "jvp(" not in inside), name
+        assert ops[name]["opcode"] == mod.INSTR.match(line).group(3)
+        assert ops[name]["kernel"] is False  # no Mosaic call on the CPU
+    named = {op["node"] for op in rec["ops"]}
+    assert {"embed", "layer0_attn", "layer0_ffn1", "layer0_ffn2"} <= named
+    assert "" in named  # the update, traced outside every node
+    assert named - {""} <= set(rec["nodes"])
+    assert rec["nodes"]["layer0_attn"] == {
+        "op": "MultiHeadAttention",
+        "inputs": ["layer0_q", "layer0_k", "layer0_v"]}
+    stats = compiled[0].memory_analysis()
+    assert rec["memory"] == {
+        "argument": stats.argument_size_in_bytes,
+        "output": stats.output_size_in_bytes,
+        "alias": stats.alias_size_in_bytes,
+        "temp": stats.temp_size_in_bytes,
+        "generated_code": stats.generated_code_size_in_bytes,
+        "uncast_table_bytes": 0}
+    assert rec["layers"] == [{
+        "op": "MultiHeadAttention", "node": "layer0_attn", "head_dim": 4,
+        "window": None, "kernel": False, "backward": None}]
+
+
+def test_record_of_a_plainly_jitted_step_and_of_a_reshape():
+    """(h) and (i): no compiled object in hand, so ``ops`` and the
+    program's bytes are None and the layers are there; a reshape builds a
+    second record and the first is kept."""
+    exe, step, params = _toy_lm_step()
+    for _ in range(3):
+        _, params, _ = step(params, {}, _lm_feed())
+    (rec,) = telemetry.programs()
+    assert rec["ops"] is None and rec["read_s"] is None
+    assert rec["build"] is None and rec["step"] == 1
+    assert rec["memory"]["temp"] is None
+    assert [(r["op"], r["node"], r["head_dim"]) for r in rec["layers"]] == [
+        ("MultiHeadAttention", "layer0_attn", 4)]
+    assert rec["nodes"]["embed"]["op"] == "Embedding"
+    for _ in range(2):
+        _, params, _ = step(params, {}, _lm_feed(batch=4))
+    first, second = telemetry.programs()
+    assert first is rec and second["step"] == 4
+    assert second["layers"] == first["layers"]
+
+
+def test_record_under_the_master_kill_and_after_reset(monkeypatch):
+    """(j)."""
+    exe, step, params = _toy_lm_step()
+    _, params, _ = step(params, {}, _lm_feed())
+    assert len(telemetry.programs()) == 1
+    telemetry.reset()
+    assert telemetry.programs() == []
+    monkeypatch.setenv("MXNET_TELEMETRY", "0")
+    exe, step, params = _toy_lm_step(batch=3)
+    step(params, {}, _lm_feed(batch=3))
+    assert telemetry.programs() == []

@@ -16,6 +16,8 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+from mxnet_tpu.telemetry.programs import graph_nodes, instruction_lines
+
 
 @pytest.fixture(scope="module")
 def one_chip():
@@ -130,7 +132,7 @@ def test_fused_lm_step_makes_no_needless_pass_over_the_vocabulary(one_chip):
     traffic = {"batch": 2, "seq_len": 4096, "compute_dtype": "bfloat16",
                "optimizer": {"learning_rate": 0.01, "momentum": 0.9}}
     compiled, sym = step_ops.compile_step(cfg, traffic)
-    ops = step_ops.device_ops(compiled.as_text(), step_ops.node_groups(sym))
+    ops = step_ops.device_ops(compiled.as_text(), graph_nodes(sym))
     over_vocab = [o["name"] for o in ops if "[8192,49152]" in o["result"]]
     assert len(over_vocab) == 2, over_vocab
     casts = [o["name"] for o in ops if o["result"] == "bf16[49152,3072]"
@@ -138,8 +140,8 @@ def test_fused_lm_step_makes_no_needless_pass_over_the_vocabulary(one_chip):
     assert not casts, casts
     assert sum(o["kernel"] for o in ops) == 2
     groups = {o["group"] for o in ops}
-    assert {"head and loss", "embedding", "feed-forward", "flash",
-            "attention projections", "norms"} <= groups
+    assert {"head_loss", "embedding", "feed_forward", "flash",
+            "attention_rest", "rest", "update"} <= groups
 
 
 # the grouped products of one ExpertFFN layer of smallthinker_train_8k
@@ -242,9 +244,9 @@ def expert_layer_step(one_chip):
                "optimizer": {"learning_rate": 0.01, "momentum": 0.9}}
     compiled, sym = step_ops.compile_step(cfg, traffic)
     text = compiled.as_text()
-    ops = step_ops.device_ops(text, step_ops.node_groups(sym))
+    ops = step_ops.device_ops(text, graph_nodes(sym))
     lines = {line.split(" = ")[0].strip().lstrip("%"): line
-             for line in step_ops.instruction_lines(text)}
+             for line in instruction_lines(text)}
     return text, ops, lines
 
 
@@ -258,7 +260,7 @@ def test_expert_layer_step_compiles_to_grouped_kernels(expert_layer_step):
     flash kernels are there."""
     text, ops, lines = expert_layer_step
     # 8192 tokens x 6 choices = 49152 rows, the worst case (12288 expected)
-    grouped = [o for o in ops if o["group"] == "expert products"]
+    grouped = [o for o in ops if o["group"] == "expert_products"]
     assert sorted(o["name"].split(".")[0] for o in grouped) == (
         ["expert_gmm"] * 6 + ["expert_tgmm"] * 3), [o["name"] for o in grouped]
     assert all(o["kernel"] and "ragged_dot_tiling" in lines[o["name"]]
@@ -279,7 +281,7 @@ def test_expert_layer_step_compiles_to_grouped_kernels(expert_layer_step):
     # no product, mask or one-hot with an axis over the held experts
     assert not re.findall(r"(?:bf16|f32)\[16,(?:24576|49152|8192),", text)
     assert sum(o["kernel"] and o["group"] == "flash" for o in ops) == 3
-    assert {"expert products", "expert routing", "flash"} <= {
+    assert {"expert_products", "expert_moves", "flash"} <= {
         o["group"] for o in ops}
 
 
@@ -299,7 +301,7 @@ def test_expert_layer_moves_are_flat_gathers(expert_layer_step):
     assert gathers.count(("bf16", "49152,2560")) == 4, gathers
     assert not [g for g in gathers if g[1].count(",") > 1], gathers
     assert "8192,6,2560]" not in text
-    routing = [o for o in ops if o["group"] == "expert routing"]
+    routing = [o for o in ops if o["group"] == "expert_moves"]
     rows = [o for o in routing if o["result"] == "bf16[49152,2560]"]
     # the four gathers and the add of the two dX products, nothing else
     assert sorted(o["opcode"] for o in rows) == ["add"] + ["fusion"] * 4, [

@@ -215,8 +215,8 @@ def test_fused_lm_step_is_unchanged_without_a_compute_dtype():
     text = step.lower(params, states, feed).as_text()
     assert "bf16" not in text
     step(params, states, feed)
-    (rec,) = _step_records()
-    assert rec["uncast_table_bytes"] == 0
+    (rec,) = telemetry.programs()
+    assert rec["memory"]["uncast_table_bytes"] == 0
 
 
 def _conv_net():
@@ -237,8 +237,8 @@ def test_fused_conv_step_casts_every_float32_leaf_as_before():
     for name, a in params.items():
         assert _converts(text, a.shape) >= 1, name
     step(params, states, feed)
-    (rec,) = _step_records()
-    assert rec["uncast_table_bytes"] == 0
+    (rec,) = telemetry.programs()
+    assert rec["memory"]["uncast_table_bytes"] == 0
 
 
 def _tied_lm(vocab=56, dm=16):
@@ -288,5 +288,6 @@ def test_uncast_table_bytes_on_the_train_step_record():
                                              types=_INT)
     for _ in range(2):
         outs, params, states = step(params, states, feed)
-    assert [r["uncast_table_bytes"] for r in _step_records()] == [
-        4 * vocab * _LM["model_dim"]] * 2
+    (rec,) = telemetry.programs()  # static: the program's, not a step's
+    assert rec["memory"]["uncast_table_bytes"] == 4 * vocab * _LM["model_dim"]
+    assert len(_step_records()) == 2

@@ -11,7 +11,7 @@ route.
     chiprun -- python tools/probe_expert_step.py --seeds 11,12,13 --steps 24
 
 One line of JSON a seed (the first says which grouped-product path the
-layers were traced with: ``expert_layer_built_total{path=}``), a summary
+layers were traced with: the program record's ``layers``), a summary
 last (the spread of the seeds' median milliseconds, and their slope over the
 held rows); with ``--out`` the steps too.
 """
@@ -29,12 +29,13 @@ BENCH = os.path.join(ROOT, "benchmark")
 
 
 def built_paths():
-    """{path: layers traced with it}: ``expert_layer_built_total``."""
-    from mxnet_tpu.telemetry.metrics import registry
-    paths = {p: registry.counter("expert_layer_built_total",
-                                 labels={"path": p}).value
-             for p in ("pallas", "ragged_dot")}
-    return {p: n for p, n in paths.items() if n}
+    """{path: layers traced with it}, from the newest step's record."""
+    import collections
+
+    from mxnet_tpu import telemetry
+    return dict(collections.Counter(
+        layer["path"] for layer in telemetry.programs()[-1]["layers"]
+        if layer["op"] == "ExpertFFN"))
 
 
 def main(argv=None):

@@ -20,7 +20,6 @@ trace. The symbol is the one the configuration's family file builds
 (``benchmark/families/<family>.py``).
 """
 import argparse
-import collections
 import importlib.util
 import json
 import os
@@ -30,24 +29,17 @@ import sys
 os.environ.setdefault("TPU_LOG_DIR", "disabled")
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
+sys.path.insert(0, os.path.join(ROOT, "benchmark"))  # lib.*, the families
 
 CLOCK_HZ, HBM_BYTES_PER_S = 1.5e9, 819e9
-FREE = ("parameter", "constant", "tuple", "get-tuple-element", "bitcast")
 ITEM = {"f32": 4, "s32": 4, "u32": 4, "bf16": 2, "f16": 2, "s8": 1, "u8": 1,
         "pred": 1, "f64": 8, "s64": 8, "u64": 8}
-GROUPS = ("head and loss", "embedding", "feed-forward", "expert products",
-          "expert routing", "attention projections", "attention glue",
-          "flash", "short conv", "norms", "residual adds",
-          "updates and casts")
 
 
 def family_symbol(cfg):
     """The training symbol as the configuration's family file builds it."""
-    bench = os.path.join(ROOT, "benchmark")
-    if bench not in sys.path:
-        sys.path.insert(0, bench)  # the family imports lib.*
     spec = importlib.util.spec_from_file_location(
-        "family", os.path.join(bench, "families",
+        "family", os.path.join(ROOT, "benchmark", "families",
                                cfg.get("family", "transformer_lm") + ".py"))
     family = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(family)
@@ -117,37 +109,7 @@ def compile_step(cfg, traffic):
     return lowered.compile(), sym
 
 
-def node_groups(sym):
-    """Graph node name -> group, from each node's operator and, for a
-    FullyConnected, from what it feeds or is fed by."""
-    nodes = [n for n in sym._nodes() if not n.is_var]
-    feeds = collections.defaultdict(set)  # node -> operators that read it
-    for n in nodes:
-        for child, _ in n.inputs:
-            feeds[id(child)].add(n.op.name)
-    by_op = {"Embedding": "embedding", "LayerNorm": "norms",
-             "RMSNorm": "norms", "ExpertFFN": "expert routing",
-             "MultiHeadAttention": "attention glue", "ShortConv": "short conv",
-             "Activation": "feed-forward", "elemwise_add": "residual adds",
-             "_plus": "residual adds"}
-    out = {}
-    for n in nodes:
-        near = feeds[id(n)] | {c.op.name for c, _ in n.inputs if not c.is_var}
-        if n.op.name == "FullyConnected" and "MultiHeadAttention" in near:
-            out[n.name] = "attention projections"
-        elif n.op.name == "FullyConnected" and near & {"Activation",
-                                                       "broadcast_mul"}:
-            out[n.name] = "feed-forward"  # a gated one's up and down too
-        elif n.op.name == "broadcast_mul" and "Activation" in near:
-            out[n.name] = "feed-forward"
-        else:
-            out[n.name] = by_op.get(n.op.name, "head and loss")
-    return out
-
-
-_INSTR = re.compile(r"^\s*(?:ROOT )?%(\S+) = (.*?) ([a-z][a-z\-]*)\((.*)$")
 _SHAPE = re.compile(r"\b([a-z]+\d*)\[([\d,]*)\]")
-_SCOPE = re.compile(r'op_name="[^"]*?jvp\(([^()]+)\)')
 
 
 def _bytes(type_text):
@@ -160,83 +122,36 @@ def _bytes(type_text):
     return total
 
 
-def instruction_lines(text):
-    """The program's lines, each instruction whole on one: the printer
-    breaks a Mosaic call's ``kernel_metadata`` (a JSON object among the
-    frontend attributes) over lines of its own."""
-    out = []
-    for line in text.splitlines():
-        if out and (line.startswith('"')
-                    or line.startswith("}") and line.strip() != "}"):
-            out[-1] += line
-        else:
-            out.append(line)
-    return out
+def device_ops(text, nodes):
+    """One dict for each operation of the program that does work, as the
+    program's own record lists them (``mxnet_tpu/telemetry/programs.py``:
+    name, opcode, kernel, node) and as the benchmark groups them
+    (``benchmark/lib/groups.py``), with its result and operands (types
+    without their layouts), est_ms and hbm_ms. ``nodes``: the record's
+    ``nodes`` (``graph_nodes(symbol)``)."""
+    from lib import groups
+    from mxnet_tpu.telemetry.programs import INSTR, computations
+    from mxnet_tpu.telemetry.programs import device_ops as record_ops
 
-
-def group_of(groups, name, node, kernel):
-    """The group of the operation ``name`` traced from graph node ``node``
-    (``kernel``: a Mosaic call)."""
-    if name.startswith(("ragged-dot", "expert_gmm", "expert_tgmm")):
-        # the grouped-matmul kernels: the repo's own (a Mosaic call is
-        # named for its kernel) or, where the layer's gate leaves the
-        # products to it, the compiler's with its tile metadata, which
-        # carries no graph node's name
-        return "expert products"
-    group = groups.get(node, "updates and casts")
-    return "flash" if kernel and group == "attention glue" else group
-
-
-def device_ops(text, groups):
-    """One dict for each operation of the entry computation that does
-    work: name, opcode, result and operands (types without their layouts),
-    kernel (a Mosaic call), node, group, est_ms, hbm_ms."""
-    comps, name = {}, None
-    for line in instruction_lines(text):
-        if line and not line[0].isspace() and "{" in line and "(" in line:
-            name = line.split()[1 if line.startswith("ENTRY") else 0]
-            name = name.lstrip("%")
-            comps[name] = []
-            if line.startswith("ENTRY"):
-                entry = name
-        elif name and _INSTR.match(line):
-            comps[name].append(line)
-
-    def scopes(line, depth=0):
-        """(weight, node) of the instruction and of what it calls: the node
-        of a matmul or a kernel inside names the fusion."""
-        opcode = _INSTR.match(line).group(3)
-        weight = 2 if opcode in ("convolution", "custom-call") else 0
-        found = [(weight, m) for m in _SCOPE.findall(line)]
-        called = re.search(r"calls=%(\S+?)[,\s]", line)
-        if called and depth < 4:
-            for inner in comps.get(called.group(1), ()):
-                found += scopes(inner, depth + 1)
-        return found
-
-    types = {}
-    for line in comps[entry]:
-        m = _INSTR.match(line)
-        types[m.group(1)] = re.sub(r"\{[^}]*\}", "", m.group(2))
+    lines, types = {}, {}
+    for comp in computations(text)[0].values():
+        for line in comp:
+            m = INSTR.match(line)
+            lines[m.group(1)] = line
+            types[m.group(1)] = re.sub(r"\{[^}]*\}", "", m.group(2))
+    by_node = groups.node_groups(nodes)
     ops = []
-    for line in comps[entry]:
-        name, _, opcode, rest = _INSTR.match(line).groups()
-        if opcode in FREE or opcode.endswith(("-start", "-done")):
-            continue  # the second: asynchronous copies, beside the work
+    for op in record_ops(text):
+        line = lines[op["name"]]
         cycles = re.search(r'"estimated_cycles":"(\d+)"', line)
-        operands = [types.get(o, "") for o in
-                    re.findall(r"%([\w.\-]+)", rest.split("), ")[0])]
-        moved = sum(_bytes(t) for t in [types[name]] + operands)
-        found = sorted(scopes(line), key=lambda t: -t[0])
-        node = found[0][1] if found else ""
-        kernel = "tpu_custom_call" in line
-        group = group_of(groups, name, node, kernel)
-        ops.append({"name": name, "opcode": opcode, "result": types[name],
-                    "operands": operands, "kernel": kernel,
-                    "node": node, "group": group,
-                    "est_ms": (1e3 * int(cycles.group(1)) / CLOCK_HZ
-                               if cycles else None),
-                    "hbm_ms": 1e3 * moved / HBM_BYTES_PER_S})
+        operands = [types.get(o, "") for o in re.findall(
+            r"%([\w.\-]+)", INSTR.match(line).group(4).split("), ")[0])]
+        moved = sum(_bytes(t) for t in [types[op["name"]]] + operands)
+        ops.append(dict(op, group=groups.group_of(nodes, by_node, op),
+                        result=types[op["name"]], operands=operands,
+                        est_ms=(1e3 * int(cycles.group(1)) / CLOCK_HZ
+                                if cycles else None),
+                        hbm_ms=1e3 * moved / HBM_BYTES_PER_S))
     return ops
 
 
@@ -263,7 +178,10 @@ def main():
     if args.text:
         with open(args.text, "w") as f:
             f.write(text)
-    ops = device_ops(text, node_groups(sym))
+    from lib.groups import GROUPS
+    from mxnet_tpu.telemetry.programs import graph_nodes
+
+    ops = device_ops(text, graph_nodes(sym))
     mem = compiled.memory_analysis()
     print("the program's arguments %.2f GB, outputs %.2f GB (%.2f GB of them "
           "in the arguments' place), temporaries %.2f GB" % (
