@@ -16,7 +16,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..base import MXNetError
-from .registry import defop, alias
+from .registry import alias, current_node, defop, note_built
 
 
 @defop("dot", arg_names=("lhs", "rhs"), param_spec={"transpose_a": False, "transpose_b": False})
@@ -291,26 +291,126 @@ def gathered_rows_as(dtype):
         _tracing.rows = prev
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
-def _rows_as(weight, ids, dtype):
+# What XLA's memory-space assignment gives a scatter on the v5e: the table
+# stands in VMEM (``S(1)`` in the compiled text) where it and the rows added
+# into it fit 112 MiB together, the chip's 128 less the 16 a kernel may
+# scope. Read from compiles for a described v5e (PERF.md, Findings, PR 38):
+# with 8192 rows of 40 / 48 / 64 / 16 MiB added, the largest table held was
+# 71.8 / 63.9 / 47.8 / 96.0 MiB. Into a table there a sorted scatter-add
+# costs 0.09-0.15 us a row; into one in HBM every add is a read-modify-write
+# of a row there, 0.5-1.9 us.
+_SCATTER_VMEM_BYTES = 112 * 2 ** 20
+# XLA's scatter adds a row in whole (8, 128) registers where its width is
+# whole pieces of 1024 numbers; a row that is not costs four times as much,
+# bfloat16 or float32, in VMEM as in HBM (8192 rows into a table of 37984:
+# 2.99 ms at 2048 wide, 4.44 at 3072, 16.04 at 2560). Wider rows than one
+# piece are padded to whole pieces for the adds.
+_SCATTER_ROW_LANES = 1024
+
+
+def _scatter_width(d):
+    return d + -d % _SCATTER_ROW_LANES if d > _SCATTER_ROW_LANES else d
+
+
+def _direct_cotangent(g, ids, rows):
+    """``jnp.take``'s own transpose: a scatter-add of the ids' rows into
+    the ``(rows, d)`` table. XLA sorts the ids and adds row by row."""
+    table = jax.ShapeDtypeStruct((rows,) + g.shape[ids.ndim:], g.dtype)
+    (dw,) = jax.linear_transpose(
+        lambda w: jnp.take(w, ids, axis=0), table)(g)
+    return dw
+
+
+def _compact_plan(ids, rows):
+    """(order, slot, at) of the N ids: their stable order by id (ids that
+    ``jnp.take`` reads as another row, the negative ones, as that row);
+    the slot of each sorted id, one a run of equal ids, in id order; and
+    where a run's first id names its slot in an ``int32[rows]`` array:
+    the id itself, and past the end (dropped) at every other position and
+    for an id outside the table, so that no two are equal."""
+    flat = ids.reshape(-1)
+    flat = jnp.where(flat < 0, flat + rows, flat)
+    order = jnp.argsort(flat, stable=True)
+    key = flat[order]
+    first = jnp.concatenate([jnp.ones((1,), bool), key[1:] != key[:-1]])
+    slot = jnp.cumsum(first, dtype=jnp.int32) - 1
+    at = jnp.where(first & (key >= 0) & (key < rows), key,
+                   rows + jnp.arange(flat.size, dtype=key.dtype))
+    return order, slot, at
+
+
+def _compact_table(g, ids, rows):
+    """(compact, slot, at): equal ids' rows of ``g`` summed, in id order
+    and then in the order they came, into a table of N + 1 rows (N the
+    number of ids), one slot a distinct id, row N left zero; and the plan's
+    ``slot`` and ``at``. The rows are fetched in id order by one gather
+    and added by one sorted scatter-add into a table small enough for
+    VMEM, today's arithmetic add for add (``_scatter_width``: over
+    padded rows where the width asks for it)."""
+    n, d = ids.size, g.shape[-1]
+    order, slot, at = _compact_plan(ids, rows)
+    got = g.reshape(n, d).at[order].get(mode="promise_in_bounds")
+    got = jnp.pad(got, ((0, 0), (0, _scatter_width(d) - d)))
+    compact = jnp.zeros((n + 1, got.shape[1]), g.dtype).at[slot].add(
+        got, indices_are_sorted=True, mode="promise_in_bounds")
+    return compact[:, :d], slot, at
+
+
+def _compact_cotangent(g, ids, rows):
+    """The direct form's sums with no row of the ``(rows, d)`` result
+    written twice and nothing added into an array of that height: the
+    compact table, an ``int32[rows]`` array that says which slot each row
+    of the result reads (N: none, the zero row), and ONE gather told its
+    indices are in bounds that writes the result once. Every piece has a
+    static shape and walks all N rows whatever the ids hold. Ids outside
+    the table are dropped, as ``jnp.take``'s transpose drops them."""
+    compact, slot, at = _compact_table(g, ids, rows)
+    slot_of = jnp.full((rows,), ids.size, jnp.int32).at[at].set(
+        slot, unique_indices=True, mode="drop")
+    return compact.at[slot_of].get(mode="promise_in_bounds")
+
+
+def cotangent_path(rows, n, d, itemsize):
+    """Which way the table's cotangent is built, from the shapes alone:
+    the table's height, the ids' count, a row's width and the bytes of a
+    number in the gradient's dtype. ``"direct"`` where the table itself
+    stands in VMEM beside the n rows added into it: the compact form does
+    the same adds and more (``lfm2_train_8k``'s 16384 ids into 8192 rows:
+    1.53 ms against 2.03). ``"compact"`` where only the table of n + 1
+    rows does (``smallthinker_train_8k`` and ``lm_train_4k``: 8192 ids
+    into 37984 and 49152 rows). ``"direct"`` again where neither does:
+    both add into HBM then, and the compact form's gathers come on top
+    (16384 ids, 8 KB a row: 7.3 ms against 9.8, in four column pieces
+    10.8). The readings: PERF.md, Findings, PR 38."""
+    def in_vmem(table_rows, width):
+        return (table_rows + n) * width * itemsize <= _SCATTER_VMEM_BYTES
+
+    compact = (n and in_vmem(n + 1, _scatter_width(d))
+               and not in_vmem(rows, d))
+    return "compact" if compact else "direct"
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))
+def _rows_as(weight, ids, dtype, node):
     return jnp.take(weight, ids, axis=0).astype(dtype)
 
 
-def _rows_as_fwd(weight, ids, dtype):
+def _rows_as_fwd(weight, ids, dtype, node):
     # the table itself is no residual: an empty slice carries its height
-    return _rows_as(weight, ids, dtype), (ids, weight[:, :0])
+    return _rows_as(weight, ids, dtype, node), (ids, weight[:, :0])
 
 
-def _rows_as_bwd(dtype, res, g):
-    # the rows' cotangent scatter-adds into a table of the compute dtype,
-    # as it did when the table itself was cast, and widens where the
-    # update reads it
+def _rows_as_bwd(dtype, node, res, g):
+    # the rows' cotangent is summed into a table of the compute dtype, as
+    # it was when the table itself was cast, and widens where the update
+    # reads it
     ids, height = res
-    table = jax.ShapeDtypeStruct(
-        height.shape[:1] + g.shape[ids.ndim:], g.dtype)
-    (dw,) = jax.linear_transpose(
-        lambda w: jnp.take(w, ids, axis=0), table)(g)
-    return dw.astype(height.dtype), None
+    rows = height.shape[0]
+    path = cotangent_path(rows, ids.size, g.shape[-1], g.dtype.itemsize)
+    note_built({"op": "Embedding", "rows": rows, "ids": ids.size,
+                "backward": path}, node=node)
+    build = _compact_cotangent if path == "compact" else _direct_cotangent
+    return build(g, ids, rows).astype(height.dtype), None
 
 
 _rows_as.defvjp(_rows_as_fwd, _rows_as_bwd)
@@ -323,13 +423,17 @@ _rows_as.defvjp(_rows_as_fwd, _rows_as_bwd)
     no_grad_inputs=("data",),
 )
 def _embedding(attrs, data, weight):
-    """Table lookup; backward is a scatter-add handled by jax.vjp of take
-    (reference indexing_op.cc Embedding + EmbeddingOpBackward)."""
+    """Table lookup (reference indexing_op.cc Embedding +
+    EmbeddingOpBackward). A float32 table the executor left in its master
+    dtype gives its rows in the compute dtype (``gathered_rows_as``); the
+    backward of either is ``_rows_as_bwd``."""
     ids = data.astype(jnp.int32)
     dtype = getattr(_tracing, "rows", None)
-    if dtype is not None and weight.dtype == jnp.float32:
-        return _rows_as(weight, ids, dtype)
-    return jnp.take(weight, ids, axis=0)
+    if dtype is None or weight.dtype != jnp.float32:
+        dtype = weight.dtype
+    if not jnp.issubdtype(weight.dtype, jnp.floating):
+        return jnp.take(weight, ids, axis=0)  # no cotangent to build
+    return _rows_as(weight, ids, jnp.dtype(dtype), current_node())
 
 
 @defop("take", arg_names=("a", "indices"), param_spec={"axis": 0, "mode": "clip"}, no_grad_inputs=("indices",))

@@ -44,7 +44,8 @@ class built_layers:
     """Entered around the trace of a graph: an op that tells what it built
     (``note_built``: ``ExpertFFN`` its buffer and its products' path,
     ``ShortConv`` its path, ``MultiHeadAttention`` its head size, band and
-    kernels) leaves a dict in ``self.layers``, one a graph node. Trace-time
+    kernels, ``Embedding`` how its backward sums the table's gradient)
+    leaves a dict in ``self.layers``, one a graph node. Trace-time
     Python state only: nothing here reaches the program."""
 
     def __init__(self):

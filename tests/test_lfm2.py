@@ -619,8 +619,8 @@ def test_dense_steps_span_names_the_head_size_and_no_more():
              telemetry.drain_events(clear=False)
              if name == "executor.train_step"]
     (rec,) = telemetry.programs()
-    assert [(r["op"], r["head_dim"], r["node"]) for r in rec["layers"]] == [
-        ("MultiHeadAttention", 4, "layer0_attn")]
+    assert [(r["op"], r.get("head_dim"), r["node"]) for r in rec["layers"]] == [
+        ("MultiHeadAttention", 4, "layer0_attn"), ("Embedding", None, "embed")]
     assert len(spans) == 2 and not {
         "conv_layers", "moe_layers", "moe_route", "attn_head_dim",
         "uncast_table_bytes"} & set(spans[-1])

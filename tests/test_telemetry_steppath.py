@@ -481,9 +481,12 @@ def test_record_through_the_program_cache(monkeypatch, tmp_path):
         "temp": stats.temp_size_in_bytes,
         "generated_code": stats.generated_code_size_in_bytes,
         "uncast_table_bytes": 0}
-    assert rec["layers"] == [{
-        "op": "MultiHeadAttention", "node": "layer0_attn", "head_dim": 4,
-        "window": None, "kernel": False, "backward": None}]
+    assert rec["layers"] == [
+        {"op": "MultiHeadAttention", "node": "layer0_attn", "head_dim": 4,
+         "window": None, "kernel": False, "backward": None},
+        # the backward's choice lands in the record too (16 ids, 50 rows)
+        {"op": "Embedding", "node": "embed", "rows": 50, "ids": 16,
+         "backward": "direct"}]
 
 
 def test_record_of_a_plainly_jitted_step_and_of_a_reshape():
@@ -497,14 +500,16 @@ def test_record_of_a_plainly_jitted_step_and_of_a_reshape():
     assert rec["ops"] is None and rec["read_s"] is None
     assert rec["build"] is None and rec["step"] == 1
     assert rec["memory"]["temp"] is None
-    assert [(r["op"], r["node"], r["head_dim"]) for r in rec["layers"]] == [
-        ("MultiHeadAttention", "layer0_attn", 4)]
+    assert [(r["op"], r["node"], r.get("head_dim")) for r in rec["layers"]] == [
+        ("MultiHeadAttention", "layer0_attn", 4), ("Embedding", "embed", None)]
     assert rec["nodes"]["embed"]["op"] == "Embedding"
     for _ in range(2):
         _, params, _ = step(params, {}, _lm_feed(batch=4))
     first, second = telemetry.programs()
     assert first is rec and second["step"] == 4
-    assert second["layers"] == first["layers"]
+    # the same layers, traced again at the new shape: twice the ids
+    assert second["layers"] == [dict(r, ids=32) if r["op"] == "Embedding"
+                                else r for r in first["layers"]]
 
 
 def test_record_under_the_master_kill_and_after_reset(monkeypatch):

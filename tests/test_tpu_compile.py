@@ -117,22 +117,34 @@ def _step_ops():
     return step_ops
 
 
-def test_fused_lm_step_makes_no_needless_pass_over_the_vocabulary(one_chip):
-    """The fused step of ``lm_train_4k`` at one layer, as the chip's compiler
-    builds it (``tools/step_ops.py``): besides the logits and their
-    gradient, written once, no operation writes a (rows, vocabulary)
-    array: no one-hot, no log_softmax. No operation casts the
-    embedding table, which is gathered from in its master dtype (the head's
-    weight is cast inside the fusions that multiply by it). The two flash
-    kernels (forward, fused backward) are there."""
+_SGD = {"learning_rate": 0.01, "momentum": 0.9}
+
+
+@pytest.fixture(scope="module")
+def dense_lm_step(one_chip):
+    """The fused step of ``lm_train_4k`` at one layer, as the chip's
+    compiler builds it (``tools/step_ops.py``): (its text, its device
+    operations). One compile for the tests below."""
     step_ops = _step_ops()
     cfg = {"vocab_size": 49152, "hidden_size": 3072, "num_hidden_layers": 1,
            "intermediate_size": 12288, "num_attention_heads": 24,
            "num_key_value_heads": 2}
     traffic = {"batch": 2, "seq_len": 4096, "compute_dtype": "bfloat16",
-               "optimizer": {"learning_rate": 0.01, "momentum": 0.9}}
+               "optimizer": _SGD}
     compiled, sym = step_ops.compile_step(cfg, traffic)
-    ops = step_ops.device_ops(compiled.as_text(), graph_nodes(sym))
+    text = compiled.as_text()
+    return text, step_ops.device_ops(text, graph_nodes(sym))
+
+
+def test_fused_lm_step_makes_no_needless_pass_over_the_vocabulary(
+        dense_lm_step):
+    """Besides the logits and their gradient, written once, no operation
+    writes a (rows, vocabulary) array: no one-hot, no log_softmax. No
+    operation casts the embedding table, which is gathered from in its
+    master dtype (the head's weight is cast inside the fusions that
+    multiply by it). The two flash kernels (forward, fused backward) are
+    there."""
+    _, ops = dense_lm_step
     over_vocab = [o["name"] for o in ops if "[8192,49152]" in o["result"]]
     assert len(over_vocab) == 2, over_vocab
     casts = [o["name"] for o in ops if o["result"] == "bf16[49152,3072]"
@@ -142,6 +154,62 @@ def test_fused_lm_step_makes_no_needless_pass_over_the_vocabulary(one_chip):
     groups = {o["group"] for o in ops}
     assert {"head_loss", "embedding", "feed_forward", "flash",
             "attention_rest", "rest", "update"} <= groups
+
+
+def _scatters(text):
+    """(result type with its layout, line) of every scatter of a compiled
+    program. A scatter works in place: its result is the table it adds
+    into or writes."""
+    return [(m.group(1), line) for line in text.splitlines()
+            for m in [re.search(r" = (\S+) scatter\(", line)] if m]
+
+
+def _assert_compact_backward(text, ops, vocab, d, ids, added=None):
+    """No scatter's result is the ``[vocab, d]`` table; the ids' rows are
+    added into a table of ``ids + 1`` rows (``added`` wide: ``d``, or ``d``
+    padded to whole pieces of 1024 numbers) that lies in VMEM (``S(1)`` in
+    its layout), as does the ``int32[vocab]`` array of slots; one gather
+    under the embedding's node writes ``bf16[vocab, d]`` once."""
+    scatters = _scatters(text)
+    assert not [r for r, _ in scatters if "[%d,%d]" % (vocab, d) in r], (
+        scatters)
+    (compact,) = [r for r, _ in scatters
+                  if r.startswith("bf16[%d,%d]" % (ids + 1, added or d))]
+    assert "S(1)" in compact, compact
+    (slots,) = [r for r, _ in scatters if r.startswith("s32[%d]" % vocab)]
+    assert "S(1)" in slots, slots
+    written = [o for o in ops if o["group"] == "embedding"
+               and o["result"] == "bf16[%d,%d]" % (vocab, d)]
+    assert [o["opcode"] for o in written] == ["fusion"], written
+
+
+def test_dense_lm_step_sums_the_tables_gradient_in_a_compact_table(
+        dense_lm_step):
+    """StarCoder2's vocabulary and width: 8192 ids into 49152 rows."""
+    text, ops = dense_lm_step
+    _assert_compact_backward(text, ops, 49152, 3072, 8192)
+
+
+def test_a_tied_table_that_fits_vmem_keeps_the_direct_scatter_add(one_chip):
+    """``lfm2_train_8k``'s ends alone (16384 ids, a table of 8192 x 2048
+    tied to the head): the rule reads more ids than rows and a table of
+    33.5 MB, so the sorted scatter-add stays, into a ``bf16[8192,2048]``
+    that XLA holds in VMEM."""
+    from mxnet_tpu import symbol as S
+
+    vocab, d = 8192, 2048
+    table = S.Variable("table_weight")
+    x = S.Embedding(data=S.Variable("data"), weight=table, input_dim=vocab,
+                    output_dim=d, name="embed")
+    x = S.FullyConnected(data=S.Reshape(x, shape=(-1, d)), weight=table,
+                         num_hidden=vocab, no_bias=True, name="pred")
+    label = S.Reshape(data=S.Variable("softmax_label"), shape=(-1,))
+    net = S.MakeLoss(S.softmax_cross_entropy(x, label), name="loss")
+    traffic = {"batch": 2, "seq_len": 8192, "compute_dtype": "bfloat16",
+               "optimizer": _SGD}
+    compiled, _ = _step_ops().compile_step({}, traffic, sym=net)
+    (table_,) = [r for r, _ in _scatters(compiled.as_text())]
+    assert table_.startswith("bf16[8192,2048]") and "S(1)" in table_, table_
 
 
 # the grouped products of one ExpertFFN layer of smallthinker_train_8k
@@ -241,7 +309,7 @@ def expert_layer_step(one_chip):
                            "smallthinker-21b-a3b.train.json")) as f:
         cfg = dict(json.load(f), num_hidden_layers=1)
     traffic = {"batch": 1, "seq_len": 8192, "compute_dtype": "bfloat16",
-               "optimizer": {"learning_rate": 0.01, "momentum": 0.9}}
+               "optimizer": _SGD}
     compiled, sym = step_ops.compile_step(cfg, traffic)
     text = compiled.as_text()
     ops = step_ops.device_ops(text, graph_nodes(sym))
@@ -313,3 +381,11 @@ def test_expert_layer_moves_are_flat_gathers(expert_layer_step):
     assert not [o["name"] for o in ops if o["opcode"] in ("copy", "slice")
                 and o["result"] in ("bf16[1,8192,2560]", "bf16[49152,2560]")
                 and o["node"] == "layer0_experts"]
+
+
+def test_expert_lm_step_sums_the_tables_gradient_in_a_compact_table(
+        expert_layer_step):
+    """SmallThinker's vocabulary share and width: 8192 ids into 37984
+    rows, whose 2560 numbers are padded to 3072 for the adds."""
+    text, ops, _ = expert_layer_step
+    _assert_compact_backward(text, ops, 37984, 2560, 8192, added=3072)

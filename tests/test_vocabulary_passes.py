@@ -7,7 +7,10 @@ it replaced, built here; (c) ``make_train_step(compute_dtype="bfloat16")``
 leaves a table that only ``Embedding`` gathers from in its master dtype,
 casts everything else as before, and its lowered text holds neither a
 one-hot nor a log_softmax array; (d) ``uncast_table_bytes`` on the
-``executor.train_step`` record.
+``executor.train_step`` record; (e) the backward of ``Embedding`` (ISSUE
+38): the table's cotangent from a compact table of the ids' runs, against
+``jax.grad`` of ``jnp.take``, in both branches of the operator, and the
+``layers`` record that names the path.
 """
 import re
 
@@ -21,7 +24,8 @@ from mxnet_tpu import models, telemetry
 from mxnet_tpu import symbol as sym
 from mxnet_tpu.executor import _gathered_only
 from mxnet_tpu.models import transformer
-from mxnet_tpu.ops.registry import OpContext, get_op
+from mxnet_tpu.ops import matrix
+from mxnet_tpu.ops.registry import OpContext, built_layers, get_op
 
 INPUTS = ("data", "softmax_label")
 
@@ -171,6 +175,22 @@ def _converts(text, shape, src="f32", dst="bf16"):
         % (dims, src, dims, dst), text))
 
 
+def _scatter_results(text):
+    """The result type of every ``stablehlo.scatter`` of a lowered text
+    (the operation spans lines: its region comes before its type)."""
+    return re.findall(r"stablehlo\.scatter.*?\}\) : \([^\n]*\) -> tensor<(\w+)>",
+                      text, flags=re.S)
+
+
+@pytest.fixture
+def toy_vmem(monkeypatch):
+    """VMEM scaled down to the toy LM's shapes, so that its table (56 rows
+    of 32 bytes, 24 ids) stands where the benchmark cells' tables do: too
+    large beside its rows, the compact table of 25 rows not."""
+    monkeypatch.setattr(matrix, "_SCATTER_VMEM_BYTES", 2048)
+    assert matrix.cotangent_path(56, 24, 16, 2) == "compact"
+
+
 _LM_SHAPES = {"data": (3, 8), "softmax_label": (3, 8)}
 _INT = {"data": "int32", "softmax_label": "int32"}
 
@@ -180,7 +200,7 @@ def _toy_lm(vocab=56):
                              scalar_loss=True, **_LM)
 
 
-def test_fused_lm_step_gathers_from_the_master_table():
+def test_fused_lm_step_gathers_from_the_master_table(toy_vmem):
     vocab, dm, rows = 56, _LM["model_dim"], 24
     net = _toy_lm(vocab)
     assert _gathered_only(net) == {"embed_weight"}
@@ -188,10 +208,15 @@ def test_fused_lm_step_gathers_from_the_master_table():
     text = step.lower(params, states, feed).as_text()
     # the head's weight is cast whole, for the MXU; the table no longer
     assert _converts(text, (vocab, dm)) == 1
-    # its rows are cast after the gather, and their cotangent scatters into
-    # a table of the compute dtype that widens for the update, as before
+    # its rows are cast after the gather, and their cotangent is summed in
+    # the compute dtype (since ISSUE 38 in a compact table of 24 + 1 rows,
+    # which one gather reads the table's gradient out of) and widens for
+    # the update, as before: the table's and the head's
     assert _converts(text, (3, 8, dm)) >= 1
     assert _converts(text, (vocab, dm), "bf16", "f32") == 2
+    scattered = set(_scatter_results(text))
+    assert "25x%dxbf16" % dm in scattered
+    assert not [r for r in scattered if r.startswith("%dx%dx" % (vocab, dm))]
     # over (rows, vocabulary): the row maximum and the sum of exp, and no
     # other reduction of a row; one column index, the backward's; no log
     over_rows = [line for line in text.splitlines()
@@ -291,3 +316,195 @@ def test_uncast_table_bytes_on_the_train_step_record():
     (rec,) = telemetry.programs()  # static: the program's, not a step's
     assert rec["memory"]["uncast_table_bytes"] == 4 * vocab * _LM["model_dim"]
     assert len(_step_records()) == 2
+
+
+# --- (e) the table's cotangent -------------------------------------------------------
+
+_VOCAB, _DM = 37, 8
+_IDS = {
+    "distinct": lambda rng: rng.permutation(_VOCAB)[:11],
+    "uniform_with_repeats": lambda rng: rng.randint(0, _VOCAB, 29),
+    "all_equal": lambda rng: np.full(13, 5),
+    "more_ids_than_rows": lambda rng: rng.randint(0, _VOCAB, 4 * _VOCAB),
+    "two_dimensional": lambda rng: rng.randint(0, _VOCAB, (3, 9)),
+    "three_dimensional": lambda rng: rng.randint(0, _VOCAB, (2, 3, 5)),
+    # jnp.take reads -1 as the last row and -37 as the first, and drops
+    # what lies outside the table after that: -38, 37, 100
+    "outside_the_table": lambda rng: np.array(
+        [-1, -_VOCAB, -_VOCAB - 1, _VOCAB - 1, _VOCAB, 100, 0, 0, 5, 5]),
+}
+
+
+@pytest.fixture
+def always_compact(monkeypatch):
+    """The operator's backward through the compact table at any shape
+    (the rule sends these toy tables down the direct path)."""
+    monkeypatch.setattr(matrix, "cotangent_path", lambda *shape: "compact")
+
+
+def _embed(weight, ids, rows_as=None):
+    """The operator itself, under ``gathered_rows_as`` where asked."""
+    op = get_op("Embedding")
+    attrs = op.parse_attrs({"input_dim": weight.shape[0],
+                            "output_dim": weight.shape[1]})
+    with matrix.gathered_rows_as(rows_as):
+        (out,), _ = op.impl(attrs, (ids, weight), (), OpContext())
+    return out
+
+
+def _table_grads(ids, dtype, branch):
+    """(the operator's table gradient, ``jnp.take``'s own in ``dtype``, and
+    in float32 from the same rounded cotangent): ``branch`` ``"master"`` is
+    a float32 table under ``gathered_rows_as(dtype)``, ``"cast"`` a table
+    that reaches the operator in ``dtype``."""
+    rng = np.random.RandomState(3)
+    ids = jnp.asarray(ids, jnp.int32)
+    w = jnp.asarray(rng.normal(0, 1, (_VOCAB, _DM)), jnp.float32)
+    g = jnp.asarray(rng.normal(0, 1, ids.shape + (_DM,)), dtype)
+    if branch == "master":
+        table, rows_as = w, dtype
+    else:
+        table, rows_as = w.astype(dtype), None
+    with built_layers() as built:
+        out, vjp = jax.vjp(lambda t: _embed(t, ids, rows_as), table)
+        (got,) = vjp(g)
+    assert [la["backward"] for la in built.layers] == ["compact"]
+    assert out.dtype == jnp.dtype(dtype)
+    assert got.dtype == table.dtype and got.shape == table.shape
+
+    def plain(t, g):
+        return jax.vjp(lambda t: jnp.take(t, ids, axis=0), t)[1](g)[0]
+
+    return (np.asarray(got, np.float32),
+            np.asarray(plain(w.astype(dtype), g), np.float32),
+            np.asarray(plain(w, g.astype(jnp.float32))))
+
+
+@pytest.mark.parametrize("branch", ["master", "cast"])
+@pytest.mark.parametrize("ids", sorted(_IDS))
+def test_embedding_backward_equals_takes_in_float32(ids, branch,
+                                                    always_compact):
+    got, _, want = _table_grads(_IDS[ids](np.random.RandomState(0)),
+                                "float32", branch)
+    np.testing.assert_allclose(got, want, rtol=1e-6, atol=1e-6)
+
+
+@pytest.mark.parametrize("branch", ["master", "cast"])
+@pytest.mark.parametrize("ids", sorted(_IDS))
+def test_embedding_backward_in_bfloat16_is_no_further_from_float32(
+        ids, branch, always_compact):
+    """Equal ids' rows are added in bfloat16, in id order and then in the
+    order they came, as the scatter-add it replaces added them: no further
+    from the float32 sum than that one, and equal to it where no id
+    repeats."""
+    got, today, exact = _table_grads(_IDS[ids](np.random.RandomState(0)),
+                                     "bfloat16", branch)
+    assert np.abs(got - exact).max() <= np.abs(today - exact).max() + 1e-6
+    if ids == "distinct":
+        np.testing.assert_array_equal(got, today)
+
+
+def test_compact_cotangent_under_jit_on_every_case():
+    rng = np.random.RandomState(1)
+    for name in sorted(_IDS):
+        ids = jnp.asarray(_IDS[name](rng), jnp.int32)
+        g = jnp.asarray(rng.normal(0, 1, ids.shape + (_DM,)), jnp.float32)
+        np.testing.assert_allclose(
+            jax.jit(matrix._compact_cotangent, static_argnums=2)(
+                g, ids, _VOCAB),
+            matrix._direct_cotangent(g, ids, _VOCAB),
+            rtol=1e-6, atol=1e-6, err_msg=name)
+
+
+@pytest.mark.parametrize("rows,ids,d,itemsize,path", [
+    (37984, 8192, 2560, 2, "compact"),    # smallthinker_train_8k
+    (49152, 8192, 3072, 2, "compact"),    # lm_train_4k
+    # lfm2_train_8k: the table itself stands in VMEM beside its 16384 rows
+    (8192, 16384, 2048, 2, "direct"),
+    (10000, 1120, 256, 4, "direct"),      # models/lstm_lm.py on PTB
+    (56, 24, 16, 2, "direct"),            # the toy step above
+    # neither the table nor the compact table stands in VMEM
+    (65536, 16384, 4096, 2, "direct"),
+    (37984, 8192, 2560, 4, "direct"),     # SmallThinker's in float32
+    # the largest compact table the described v5e's compiler held, and
+    # the first it did not (55.8 MiB of 3072 bfloat16 numbers a row); rows
+    # of 2560 are padded to 3072 for the adds and count as such
+    (10 ** 6, 9552, 3072, 2, "compact"),
+    (10 ** 6, 9600, 3072, 2, "direct"),
+    (10 ** 6, 9552, 2560, 2, "compact"),
+    (10 ** 6, 9600, 2560, 2, "direct"),
+])
+def test_cotangent_path_reads_shapes_alone(rows, ids, d, itemsize, path):
+    assert matrix.cotangent_path(rows, ids, d, itemsize) == path
+
+
+def test_rows_are_padded_to_whole_pieces_for_the_adds():
+    """A width over 1024 that is no multiple of it is padded for the
+    compact table's scatter-add and sliced back: the sums are the same."""
+    assert [matrix._scatter_width(d) for d in (16, 1024, 1536, 2560, 3072)
+            ] == [16, 1024, 2048, 3072, 3072]
+    rng = np.random.RandomState(2)
+    ids = jnp.asarray(rng.randint(0, _VOCAB, (3, 9)), jnp.int32)
+    g = jnp.asarray(rng.normal(0, 1, (3, 9, 1536)), jnp.float32)
+    compact, _, _ = matrix._compact_table(g, ids, _VOCAB)
+    assert compact.shape == (28, 1536)
+    np.testing.assert_allclose(
+        matrix._compact_cotangent(g, ids, _VOCAB),
+        matrix._direct_cotangent(g, ids, _VOCAB), rtol=1e-6, atol=1e-6)
+
+
+def test_embedding_records_the_path_its_backward_took(monkeypatch):
+    # 37 rows of 32 bytes: beside 9 rows the compact table fits, the table
+    # does not; beside 111 neither
+    monkeypatch.setattr(matrix, "_SCATTER_VMEM_BYTES", 1024)
+    w = jnp.zeros((_VOCAB, _DM), jnp.float32)
+    for n, path in ((9, "compact"), (3 * _VOCAB, "direct")):
+        ids = jnp.arange(n, dtype=jnp.int32) % _VOCAB
+        with built_layers() as built:
+            jax.grad(lambda t: jnp.sum(_embed(t, ids)))(w)
+        assert built.layers == [{"op": "Embedding", "rows": _VOCAB, "ids": n,
+                                 "backward": path, "node": ""}]
+
+
+@pytest.mark.parametrize("path", ["compact", "direct"])
+def test_fused_steps_name_the_embeddings_path_in_layers(path, request):
+    """The toy LM's step (a master table, 24 ids into 56 rows) and the tied
+    one's (a cast table): each program's record holds one ``Embedding``
+    layer under its node, with the path the rule gave it."""
+    if path == "compact":
+        request.getfixturevalue("toy_vmem")
+    for net in (_toy_lm(), _tied_lm()):
+        telemetry.reset()
+        exe, step, params, states, feed = _fused(net, _LM_SHAPES, types=_INT)
+        step(params, states, feed)
+        (rec,) = telemetry.programs()
+        (layer,) = [la for la in rec["layers"] if la["op"] == "Embedding"]
+        assert layer == {"op": "Embedding", "node": "embed", "rows": 56,
+                         "ids": 24, "backward": path}
+
+
+@pytest.mark.parametrize("net", ["master_table", "tied_table"])
+@pytest.mark.parametrize("compute_dtype", [None, "bfloat16"])
+def test_fused_step_equals_the_scatter_adds_arithmetic(net, compute_dtype,
+                                                      toy_vmem, monkeypatch):
+    """One fused step of the toy LM with the compact backward and with the
+    scatter-add it replaced (the parent's arithmetic): every parameter
+    after the step is equal. In float32 the sums are the same numbers in
+    the same order; in bfloat16 too, here."""
+    def one_step():
+        telemetry.reset()
+        sym_ = _toy_lm() if net == "master_table" else _tied_lm()
+        exe, step, params, states, feed = _fused(
+            sym_, _LM_SHAPES, compute_dtype=compute_dtype, types=_INT)
+        # eight ids out of 56 rows: every id repeats
+        outs, params, states = step(params, states, feed)
+        return {n: np.asarray(a) for n, a in params.items()}
+
+    new = one_step()
+    monkeypatch.setattr(matrix, "_compact_cotangent",
+                        matrix._direct_cotangent)
+    old = one_step()
+    assert sorted(new) == sorted(old)
+    for n in new:
+        np.testing.assert_allclose(new[n], old[n], rtol=1e-6, atol=1e-7,
+                                   err_msg=n)

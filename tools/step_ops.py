@@ -46,9 +46,10 @@ def family_symbol(cfg):
     return family.symbol(cfg, True)
 
 
-def compile_step(cfg, traffic):
-    """The compiled fused step of ``cfg``'s model at its sizes, and the
-    symbol: lowered from described arrays, so nothing is allocated."""
+def compile_step(cfg, traffic, sym=None):
+    """The compiled fused step of ``cfg``'s model (of ``sym``, where one is
+    given) at its sizes, and the symbol: lowered from described arrays, so
+    nothing is allocated."""
     import jax
     import jax.numpy as jnp
     from jax.experimental import topologies
@@ -63,7 +64,7 @@ def compile_step(cfg, traffic):
     topo = topologies.get_topology_desc(platform="tpu",
                                         topology_name="v5e:2x2")
     chip = SingleDeviceSharding(topo.devices[0])
-    sym = family_symbol(cfg)
+    sym = family_symbol(cfg) if sym is None else sym
     inputs = {n: (traffic["batch"], traffic["seq_len"])
               for n in ("data", "softmax_label")}
     shapes, _, aux_shapes = sym.infer_shape(**inputs)
