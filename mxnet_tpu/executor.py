@@ -94,21 +94,51 @@ def _float32_state(symbol):
     return frozenset(keep)
 
 
-def _layer_attrs(layers):
+def _layer_attrs(layers, symbol):
     """The ``executor.train_step`` span's static attributes for the
     ``ExpertFFN`` layers among what the step's layers told of themselves as
     it was traced (``ops/registry.py`` ``built_layers``): how many, and of
     one layer the experts held, the choices a token, the rows of its
     sorted-assignment buffer as allocated and the assignments expected
-    under uniform routing. The rest of what the layers say is the program
+    under uniform routing; and for a stack run several times
+    (``_loop_attrs``). The rest of what the layers say is the program
     record's (``telemetry.programs()``: ``layers``)."""
+    attrs = _loop_attrs(layers, symbol)
     moe = [layer for layer in layers if layer["op"] == "ExpertFFN"]
     if not moe:
-        return {}
+        return attrs
     one = moe[0]
-    return dict(moe_layers=len(moe), moe_experts_held=one["experts_held"],
-                moe_top_k=one["top_k"], moe_buffer_rows=one["buffer_rows"],
+    return dict(attrs, moe_layers=len(moe),
+                moe_experts_held=one["experts_held"], moe_top_k=one["top_k"],
+                moe_buffer_rows=one["buffer_rows"],
                 moe_expected_rows=one["expected_rows"])
+
+
+def _loop_attrs(layers, symbol):
+    """Where a ``LoopExitLoss`` was traced: ``loop_exits``, its exits;
+    ``loop_layers``, the layers held, told apart by the leaves their mixer
+    reads (its own and its projections'); ``loop_steps``, the mixers traced
+    over the layers held: the times the step runs each layer."""
+    exits = [layer for layer in layers if layer["op"] == "LoopExitLoss"]
+    if not exits:
+        return {}
+    nodes = {n.name: n for n in symbol._nodes() if not n.is_var}
+
+    def leaves(node, depth=2):
+        out = set()
+        for child, _ in node.inputs:
+            if child.is_var:
+                out.add(child.name)
+            elif depth > 1:
+                out |= leaves(child, depth - 1)
+        return frozenset(out)
+
+    runs = [leaves(nodes[layer["node"]]) for layer in layers
+            if layer["op"] in ("MultiHeadAttention", "ShortConv")
+            and layer["node"] in nodes]
+    held = len(set(runs))
+    return dict(loop_exits=exits[0]["exits"], loop_layers=held,
+                loop_steps=len(runs) / held if held else 0.0)
 
 
 def _relaid(tree, formats):
@@ -765,12 +795,12 @@ class Executor:
                                  step=self._train_steps, chain=chain,
                                  stage=stage,
                                  gather_bytes=aot.get("gather_bytes", 0),
-                                 **(_layer_attrs(built.layers)
+                                 **(_layer_attrs(built.layers, self._symbol)
                                     if known else {})) as sp:
                 out = _run_impl(params, states, data_values, *extra)
                 if not known:
                     sp.add("gather_bytes", aot.get("gather_bytes", 0))
-                    sp.annotate(**_layer_attrs(built.layers))
+                    sp.annotate(**_layer_attrs(built.layers, self._symbol))
                 return out
 
         run.lower = lower

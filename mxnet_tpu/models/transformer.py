@@ -9,16 +9,46 @@ mxnet_tpu.parallel.transformer.
 from .. import symbol as sym
 
 
-def _norm(x, kind, dm, name, eps=None):
-    """LayerNorm (gamma, beta) or RMSNorm (a plain scale); ``eps`` None
-    leaves each op its own default (1e-5 and 1e-6)."""
-    gamma = sym.Variable(name + '_gamma', shape=(dm,))
+class _Names:
+    """Where a layer's nodes and leaves get their names. Alone (``shared``
+    None) each leaf is named after its node and made where it is read, as
+    the builder always did. In a loop ``shared`` holds the model's leaves,
+    named after the layer (``leaf``) and made once, and the nodes are named
+    after the pass (``node``): every pass reads the one set."""
+
+    def __init__(self, node, leaf=None, shared=None):
+        self.node = node
+        self.leaf = node if leaf is None else leaf
+        self.shared = shared
+
+    def var(self, suffix, shape=None):
+        name = self.leaf + suffix
+        if self.shared is None:
+            return sym.Variable(name, shape=shape)
+        if name not in self.shared:
+            self.shared[name] = sym.Variable(name, shape=shape)
+        return self.shared[name]
+
+    def given(self, suffix, *keys):
+        """``{key: leaf}`` for the inputs an op would otherwise make itself
+        (``<node>_<key>``): none alone, the shared leaves in a loop."""
+        if self.shared is None:
+            return {}
+        return {k: self.var('%s_%s' % (suffix, k)) for k in keys}
+
+
+def _norm(x, kind, dm, names, suffix, eps=None):
+    """LayerNorm (gamma, beta) or RMSNorm (a plain scale) named
+    ``<node><suffix>``; ``eps`` None leaves each op its own default (1e-5
+    and 1e-6)."""
+    gamma = names.var(suffix + '_gamma', (dm,))
     kw = {} if eps is None else {'eps': eps}
+    name = names.node + suffix
     if kind == 'rms':
         return sym.RMSNorm(data=x, gamma=gamma, name=name, **kw)
     if kind != 'layer':
         raise ValueError("norm %r: 'layer' or 'rms'" % (kind,))
-    beta = sym.Variable(name + '_beta', shape=(dm,))
+    beta = names.var(suffix + '_beta', (dm,))
     return sym.LayerNorm(data=x, gamma=gamma, beta=beta, name=name, **kw)
 
 
@@ -39,10 +69,13 @@ LAYER_KINDS = {
     'ffn_dim': 0,         # this layer's feed-forward width; 0: the model's
     'router_input': 'mixer',  # what an 'experts' router reads: the
                               # 'mixer' input's norm | the 'ffn' input's
+    'post_norm': False,   # True: the mixer's and the feed-forward's outputs
+                          # are normed too before each residual add (the
+                          # sandwich): x + norm(mixer(norm x))
 }
 
 
-def _attention(h, num_heads, dm, name, num_kv_heads, use_flash, head_dim,
+def _attention(h, num_heads, dm, names, num_kv_heads, use_flash, head_dim,
                kind, eps):
     """q, k, v, attention and o over the normed input ``h``."""
     # GQA (num_kv_heads < num_heads): k/v projections shrink to
@@ -50,12 +83,15 @@ def _attention(h, num_heads, dm, name, num_kv_heads, use_flash, head_dim,
     head_dim = head_dim or dm // num_heads
     dq = head_dim * num_heads
     dkv = dq if not num_kv_heads else head_dim * num_kv_heads
+    name = names.node
     q = sym.FullyConnected(data=h, num_hidden=dq, flatten=False, no_bias=True,
-                           name=name + '_q')
+                           name=name + '_q', **names.given('_q', 'weight'))
     k = sym.FullyConnected(data=h, num_hidden=dkv, flatten=False,
-                           no_bias=True, name=name + '_k')
+                           no_bias=True, name=name + '_k',
+                           **names.given('_k', 'weight'))
     v = sym.FullyConnected(data=h, num_hidden=dkv, flatten=False,
-                           no_bias=True, name=name + '_v')
+                           no_bias=True, name=name + '_v',
+                           **names.given('_v', 'weight'))
     # use_flash=None defers to the op default (True, with the kernel's
     # own on-TPU/shape selection gate) — passing None through would
     # read as falsy and silently pin the einsum path
@@ -70,47 +106,54 @@ def _attention(h, num_heads, dm, name, num_kv_heads, use_flash, head_dim,
         att_kw['qk_norm'] = True
         if eps is not None:
             att_kw['qk_norm_eps'] = eps
+        att_kw.update(names.given('_attn', 'q_norm_gamma', 'k_norm_gamma'))
     att = sym.MultiHeadAttention(query=q, key=k, value=v, num_heads=num_heads,
                                  num_kv_heads=num_kv_heads, causal=True,
                                  use_rope=bool(kind['rope']),
                                  name=name + '_attn', **att_kw)
     return sym.FullyConnected(data=att, num_hidden=dm, flatten=False,
-                              no_bias=True, name=name + '_o')
+                              no_bias=True, name=name + '_o',
+                              **names.given('_o', 'weight'))
 
 
-def _block(x, num_heads, dm, dff, name, num_kv_heads=0, use_flash=None,
+def _block(x, num_heads, dm, dff, names, num_kv_heads=0, use_flash=None,
            head_dim=0, kind=LAYER_KINDS, experts=None, eps=None):
     """One pre-norm decoder block of the kinds ``kind`` names:
-    ``x + mixer(norm1 x)``, then ``+ ffn(norm2 ...)``. ``experts`` (for ffn
-    'experts'): the ExpertFFN attributes, and the layer's width is then one
-    expert's."""
+    ``x + mixer(norm1 x)``, then ``+ ffn(norm2 ...)`` (with ``post_norm``,
+    each branch's output normed before its add). ``names``: a ``_Names``.
+    ``experts`` (for ffn 'experts'): the ExpertFFN attributes, and the
+    layer's width is then one expert's."""
     unknown = set(kind) - set(LAYER_KINDS)
     if unknown:
         raise ValueError("layer kinds %s unknown (known: %s)"
                          % (sorted(unknown), sorted(LAYER_KINDS)))
     kind = dict(LAYER_KINDS, **kind)
     dff = kind['ffn_dim'] or dff
-    h = _norm(x, kind['norm'], dm, name + '_ln1', eps)
+    name = names.node
+
+    def add(x, out, suffix):
+        if kind['post_norm']:
+            out = _norm(out, kind['norm'], dm, names, suffix, eps)
+        return x + out
+
+    h = _norm(x, kind['norm'], dm, names, '_ln1', eps)
     mixer_in = h
     if kind['mixer'] == 'short_conv':
         taps = kind['conv_kernel']
         mixed = sym.ShortConv(
             data=h, kernel=taps,
-            in_weight=sym.Variable(name + '_conv_in_weight',
-                                   shape=(3 * dm, dm)),
-            conv_weight=sym.Variable(name + '_conv_weight',
-                                     shape=(dm, taps)),
-            out_weight=sym.Variable(name + '_conv_out_weight',
-                                    shape=(dm, dm)),
+            in_weight=names.var('_conv_in_weight', (3 * dm, dm)),
+            conv_weight=names.var('_conv_weight', (dm, taps)),
+            out_weight=names.var('_conv_out_weight', (dm, dm)),
             name=name + '_conv')
     elif kind['mixer'] == 'attention':
-        mixed = _attention(h, num_heads, dm, name, num_kv_heads, use_flash,
+        mixed = _attention(h, num_heads, dm, names, num_kv_heads, use_flash,
                            head_dim, kind, eps)
     else:
         raise ValueError("mixer %r: 'attention' or 'short_conv'"
                          % (kind['mixer'],))
-    x = x + mixed
-    h = _norm(x, kind['norm'], dm, name + '_ln2', eps)
+    x = add(x, mixed, '_post1')
+    h = _norm(x, kind['norm'], dm, names, '_ln2', eps)
     if kind['ffn'] == 'experts':
         if kind['router_input'] not in ('mixer', 'ffn'):
             raise ValueError("router_input %r: 'mixer' or 'ffn'"
@@ -119,43 +162,46 @@ def _block(x, num_heads, dm, dff, name, num_kv_heads=0, use_flash=None,
         h = sym.ExpertFFN(
             data=h,
             router_data=mixer_in if kind['router_input'] == 'mixer' else h,
-            router_weight=sym.Variable(
-                name + '_router_weight',
-                shape=(experts['num_experts'], dm)),
-            gate_weight=sym.Variable(name + '_gate_weight',
-                                     shape=(held, dff, dm)),
-            up_weight=sym.Variable(name + '_up_weight',
-                                   shape=(held, dff, dm)),
-            down_weight=sym.Variable(name + '_down_weight',
-                                     shape=(held, dm, dff)),
+            router_weight=names.var('_router_weight',
+                                    (experts['num_experts'], dm)),
+            gate_weight=names.var('_gate_weight', (held, dff, dm)),
+            up_weight=names.var('_up_weight', (held, dff, dm)),
+            down_weight=names.var('_down_weight', (held, dm, dff)),
             name=name + '_experts', **experts)[0]
-        return x + h
+        return add(x, h, '_post2')
     if kind['ffn'] == 'swiglu':
         gate = sym.FullyConnected(data=h, num_hidden=dff, flatten=False,
-                                  no_bias=True, name=name + '_ffn1')
+                                  no_bias=True, name=name + '_ffn1',
+                                  **names.given('_ffn1', 'weight'))
         up = sym.FullyConnected(data=h, num_hidden=dff, flatten=False,
-                                no_bias=True, name=name + '_ffn3')
+                                no_bias=True, name=name + '_ffn3',
+                                **names.given('_ffn3', 'weight'))
         h = sym.broadcast_mul(
             sym.Activation(data=gate, act_type='silu', name=name + '_silu'),
             up, name=name + '_glu')
         h = sym.FullyConnected(data=h, num_hidden=dm, flatten=False,
-                               no_bias=True, name=name + '_ffn2')
-        return x + h
+                               no_bias=True, name=name + '_ffn2',
+                               **names.given('_ffn2', 'weight'))
+        return add(x, h, '_post2')
     if kind['ffn'] != 'gelu':
         raise ValueError("ffn %r: 'gelu', 'swiglu' or 'experts'"
                          % (kind['ffn'],))
     h = sym.FullyConnected(data=h, num_hidden=dff, flatten=False,
-                           name=name + '_ffn1')
+                           name=name + '_ffn1',
+                           **names.given('_ffn1', 'weight', 'bias'))
     h = sym.Activation(data=h, act_type='gelu', name=name + '_gelu')
     h = sym.FullyConnected(data=h, num_hidden=dm, flatten=False,
-                           name=name + '_ffn2')
-    return x + h
+                           name=name + '_ffn2',
+                           **names.given('_ffn2', 'weight', 'bias'))
+    return add(x, h, '_post2')
 
 
 def _backbone(num_classes, num_layers, num_heads, model_dim, ffn_dim,
               num_kv_heads, use_flash, head_dim=0, layers=None, experts=None,
               final_norm='layer', head_bias=True, norm_eps=None,
-              tie_head=False):
+              tie_head=False, loops=1):
+    """The exits: one ``(logits, gate logit)`` a pass, the last pass's gate
+    None (alone: the one pass's logits and no gate)."""
     if layers is None:
         layers = [LAYER_KINDS] * num_layers
     if len(layers) != num_layers:
@@ -164,30 +210,53 @@ def _backbone(num_classes, num_layers, num_heads, model_dim, ffn_dim,
     if tie_head and head_bias:
         raise ValueError("tie_head: the table has no bias to share "
                          "(head_bias=False)")
+    if loops > 1 and experts and experts.get('route') == 'sigmoid_bias':
+        raise ValueError("loops: an expert layer's expert_bias state is "
+                         "not shared by the passes")
     data = sym.Variable('data')          # (batch, seq_len) int ids
     tied = {'weight': sym.Variable('embed_weight',
                                    shape=(num_classes, model_dim))} \
         if tie_head else {}
     x = sym.Embedding(data=data, input_dim=num_classes,
                       output_dim=model_dim, name='embed', **tied)
-    for i, kind in enumerate(layers):
-        x = _block(x, num_heads, model_dim, ffn_dim, 'layer%d' % i,
-                   num_kv_heads=num_kv_heads, use_flash=use_flash,
-                   head_dim=head_dim, kind=kind, experts=experts,
-                   eps=norm_eps)
-    x = _norm(x, final_norm, model_dim, 'lnf', norm_eps)
-    pred = sym.Reshape(data=x, shape=(-1, model_dim))
-    # a tied head multiplies by the table itself: one leaf, whose gradient
-    # is the sum of both uses
-    return sym.FullyConnected(data=pred, num_hidden=num_classes,
-                              no_bias=not head_bias, name='pred', **tied)
+    shared = None if loops == 1 else {}
+    exits = []
+    for t in range(loops):
+        # a pass's nodes are named for it, its leaves for the layer alone
+        at = '' if loops == 1 else 'ut%d_' % t
+        for i, kind in enumerate(layers):
+            x = _block(x, num_heads, model_dim, ffn_dim,
+                       _Names(at + 'layer%d' % i, 'layer%d' % i, shared),
+                       num_kv_heads=num_kv_heads, use_flash=use_flash,
+                       head_dim=head_dim, kind=kind, experts=experts,
+                       eps=norm_eps)
+        # the normed state is both this pass's exit and the next pass's
+        # input
+        top = _Names(at, '', shared)
+        x = _norm(x, final_norm, model_dim, top, 'lnf', norm_eps)
+        flat = {} if loops == 1 else {'name': at + 'flat'}
+        pred = sym.Reshape(data=x, shape=(-1, model_dim), **flat)
+        # a tied head multiplies by the table itself: one leaf, whose
+        # gradient is the sum of both uses (and so is a looped leaf's)
+        head = tied or top.given('pred', 'weight',
+                                 *(('bias',) if head_bias else ()))
+        logits = sym.FullyConnected(data=pred, num_hidden=num_classes,
+                                    no_bias=not head_bias,
+                                    name=at + 'pred', **head)
+        gate = None
+        if t < loops - 1:  # the last exit takes what the others leave
+            gate = sym.FullyConnected(
+                data=pred, num_hidden=1, name=at + 'exit_gate',
+                **top.given('exit_gate', 'weight', 'bias'))
+        exits.append((logits, gate))
+    return exits
 
 
 def get_symbol(num_classes=32000, seq_len=512, num_layers=4, num_heads=8,
                model_dim=512, ffn_dim=2048, num_kv_heads=0, use_flash=None,
                scalar_loss=False, head_dim=0, layers=None, experts=None,
                final_norm='layer', head_bias=True, norm_eps=None,
-               tie_head=False, **kwargs):
+               tie_head=False, loops=1, exit_loss=None, **kwargs):
     """Decoder LM symbol. scalar_loss=True emits a MakeLoss mean-NLL head
     (output ``loss``) instead of SoftmaxOutput — the (batch*seq, vocab)
     probability output is the right inference surface but costs a fresh
@@ -199,25 +268,49 @@ def get_symbol(num_classes=32000, seq_len=512, num_layers=4, num_heads=8,
     label's shape and folds to a constant.
 
     The block's kinds, all defaulting to the dense block this builder
-    always built: ``layers``, one dict a layer over ``LAYER_KINDS`` (norm;
-    the mixer: attention with its window, rope, rope_base and qk_norm, or
-    a gated short convolution; the feed-forward: biased GELU, dense gated
-    SwiGLU or experts, and its width where a layer's differs), so that
-    window + RoPE layers, global NoPE layers and convolution layers sit in
-    one model; ``head_dim`` where it is not model_dim / num_heads;
-    ``experts``, the ``ExpertFFN`` attributes (num_experts, experts_held,
-    first_expert, top_k, route, ...) of the layers whose ffn is 'experts',
-    with ``ffn_dim`` one expert's width; ``final_norm``; ``norm_eps`` for
-    every norm of the model (None: each op's default); ``head_bias`` False
-    for a bias-free head, ``tie_head`` for one that multiplies by the
-    embedding table. This is the only place the block is built for
-    training: the decode builders (serving/generate/model.py) and the
-    sharded step (parallel/transformer.py) build the dense LayerNorm block
-    alone and say so when handed another."""
-    pred = _backbone(num_classes, num_layers, num_heads, model_dim, ffn_dim,
-                     num_kv_heads, use_flash, head_dim, layers, experts,
-                     final_norm, head_bias, norm_eps, tie_head)
+    always built: ``layers``, one dict a layer over ``LAYER_KINDS`` (norm,
+    and with ``post_norm`` the sandwich; the mixer: attention with its
+    window, rope, rope_base and qk_norm, or a gated short convolution; the
+    feed-forward: biased GELU, dense gated SwiGLU or experts, and its width
+    where a layer's differs), so that window + RoPE layers, global NoPE
+    layers and convolution layers sit in one model; ``head_dim`` where it
+    is not model_dim / num_heads; ``experts``, the ``ExpertFFN`` attributes
+    (num_experts, experts_held, first_expert, top_k, route, ...) of the
+    layers whose ffn is 'experts', with ``ffn_dim`` one expert's width;
+    ``final_norm``; ``norm_eps`` for every norm of the model (None: each
+    op's default); ``head_bias`` False for a bias-free head, ``tie_head``
+    for one that multiplies by the embedding table.
+
+    ``loops`` > 1 runs the layer stack that many times with one set of
+    leaves (each read by every pass; the nodes named ``ut<t>_...`` for the
+    pass). Every pass ends in the final norm, whose output is the next
+    pass's input and the pass's exit: the head and, but for the last, an
+    exit gate (``exit_gate``, hidden -> 1), all shared by the exits. The
+    loss is then ``exit_loss`` (``{'beta': ...}``, required): the
+    ``LoopExitLoss`` objective over the exits, summed over the rows and
+    divided by their number, behind ``MakeLoss``.
+
+    This is the only place the block is built for training: the decode
+    builders (serving/generate/model.py) and the sharded step
+    (parallel/transformer.py) build the dense LayerNorm block alone, one
+    pass, and say so when handed another."""
+    if (loops > 1) != (exit_loss is not None):
+        raise ValueError("loops %d with exit_loss %r: a loop trains on the "
+                         "exits' objective, and only a loop has exits"
+                         % (loops, exit_loss))
+    exits = _backbone(num_classes, num_layers, num_heads, model_dim,
+                      ffn_dim, num_kv_heads, use_flash, head_dim, layers,
+                      experts, final_norm, head_bias, norm_eps, tie_head,
+                      loops)
     label = sym.Reshape(data=sym.Variable('softmax_label'), shape=(-1,))
+    if exit_loss is not None:
+        rows = sym.sum(sym.ones_like(sym.Cast(label, dtype='float32')))
+        total = sym.LoopExitLoss(
+            *[z for z, _ in exits], *[g for _, g in exits[:-1]], label,
+            num_exits=loops, beta=float(exit_loss['beta']),
+            name='exit_loss')
+        return sym.MakeLoss(sym._div(total, rows), name='loss')
+    (pred, _), = exits
     if scalar_loss:
         rows = sym.sum(sym.ones_like(sym.Cast(label, dtype='float32')))
         nll = sym._div(sym.softmax_cross_entropy(pred, label), rows)

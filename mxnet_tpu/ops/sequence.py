@@ -1,16 +1,19 @@
 """Sequence operators + binary loss.
 
 TPU-native equivalents of src/operator/sequence_{mask,last,reverse}.cc and
-src/operator/tensor/loss_binary_op.cc (softmax_cross_entropy). Layout
+src/operator/tensor/loss_binary_op.cc (softmax_cross_entropy), and the
+objective over the exits of a stack run several times (LoopExitLoss). Layout
 follows the reference: time-major (max_len, batch, ...) unless axis says
 otherwise; sequence_length is a (batch,) vector of valid lengths.
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 
-from .registry import defop
+from .registry import REQUIRED, defop, note_built
 
 
 def _len_mask(seq_len, max_len, batch, dtype):
@@ -122,3 +125,99 @@ def _softmax_cross_entropy(attrs, data, label):
     """Scalar summed cross-entropy (reference loss_binary_op.cc), float32
     whatever the logits' dtype."""
     return _summed_nll(data, label.astype(jnp.int32).reshape(-1))
+
+
+def _exit_distribution(gates):
+    """``(log p, p)``, each (exits, rows) float32, from the gate logits
+    (exits - 1, rows) float32: ``p_t = lambda_t prod_{j<t} (1 - lambda_j)``
+    with ``lambda = sigmoid(gate)``, and the last exit what the others
+    leave. In log space, where a product of many small factors stays
+    finite."""
+    log_exit = jax.nn.log_sigmoid(gates)
+    log_stay = jax.nn.log_sigmoid(-gates)
+    before = jnp.cumsum(log_stay, axis=0) - log_stay  # sum over j < t
+    logp = jnp.concatenate([log_exit + before,
+                            jnp.sum(log_stay, axis=0, keepdims=True)], 0)
+    return logp, jnp.exp(logp)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _exit_loss(logits, gates, label, beta):
+    """sum over rows of ``sum_t p_t CE_t + beta sum_t p_t log p_t``: the
+    exits' expected cross-entropy less ``beta`` times the exit
+    distribution's entropy. ``logits``: one (rows, vocab) array an exit;
+    ``gates``: one (rows,) gate logit an exit but the last. Each exit's
+    cross-entropy is ``_summed_nll``'s closed form a row (its own float32
+    logsumexp, the label's logit gathered); the weights and the entropy are
+    float32; the backward is written out and builds no one-hot."""
+    return _exit_loss_fwd(logits, gates, label, beta)[0]
+
+
+def _exit_loss_fwd(logits, gates, label, beta):
+    lses, ce = [], []
+    for z in logits:  # one exit's float32 logsumexp at a time
+        lse = jax.nn.logsumexp(z.astype(jnp.float32), axis=-1)
+        picked = jnp.take_along_axis(z, label[:, None], axis=1)[:, 0]
+        lses.append(lse)
+        ce.append(lse - picked.astype(jnp.float32))
+    ce = jnp.stack(ce)
+    a = jnp.stack([g.astype(jnp.float32) for g in gates])
+    logp, p = _exit_distribution(a)
+    loss = jnp.sum(p * (ce + beta * logp))
+    return loss, (logits, gates, label, lses, ce, a, logp, p)
+
+
+def _exit_loss_bwd(beta, res, g):
+    logits, gates, label, lses, ce, a, logp, p = res
+    # d loss / d p_t is CE_t + beta (log p_t + 1); the constant beta drops
+    # out, the p's summing to 1 whatever the gates
+    cp = p * (ce + beta * logp)
+    after = jnp.cumsum(cp[::-1], axis=0)[::-1] - cp  # sum over t > j
+    lam = jax.nn.sigmoid(a)
+    # d p_t / d gate_j = p_t (1 - lambda_j) at t = j, -p_t lambda_j at t > j
+    d_gates = g * (cp[:-1] * (1.0 - lam) - lam * after[:-1])
+    d_logits = []
+    for t, (z, lse) in enumerate(zip(logits, lses)):
+        prob = jnp.exp(z.astype(jnp.float32) - lse[:, None])
+        col = jax.lax.broadcasted_iota(jnp.int32, z.shape, 1)
+        d = (g * p[t])[:, None] * (prob - (col == label[:, None]))
+        # stored once, as _summed_nll's: the head multiplies it twice
+        d_logits.append(jax.lax.optimization_barrier(d.astype(z.dtype)))
+    return (tuple(d_logits),
+            tuple(d.astype(x.dtype) for d, x in zip(d_gates, gates)),
+            None)
+
+
+_exit_loss.defvjp(_exit_loss_fwd, _exit_loss_bwd)
+
+
+def _exit_args(attrs):
+    n = int(attrs["num_exits"])
+    return (tuple("logits%d" % t for t in range(n))
+            + tuple("gate%d" % t for t in range(n - 1)) + ("label",))
+
+
+@defop(
+    "LoopExitLoss",
+    arg_names=_exit_args,
+    param_spec={"num_exits": REQUIRED, "beta": 0.1},
+    no_grad_inputs=("label",),
+)
+def _loop_exit_loss(attrs, *inputs):
+    """Scalar summed objective over the exits of a layer stack run
+    ``num_exits`` times (each pass's exit reads the state it leaves):
+    ``logits0..`` (rows, vocab) an exit, ``gate0..`` the gate logit of
+    every exit but the last, (rows,) or (rows, 1), whose sigmoid is the
+    chance of leaving there, and ``label`` (rows,). Per row, the exits'
+    cross-entropies weighed by the exit distribution, plus ``beta`` times
+    ``sum_t p_t log p_t`` (the distribution's entropy, negated), float32
+    whatever the logits' dtype."""
+    n = int(attrs["num_exits"])
+    if n < 2:
+        raise ValueError("LoopExitLoss: num_exits %d; a loop has 2 or more"
+                         % n)
+    logits, gates, label = inputs[:n], inputs[n:2 * n - 1], inputs[-1]
+    label = label.astype(jnp.int32).reshape(-1)
+    note_built({"op": "LoopExitLoss", "exits": n, "rows": label.shape[0]})
+    return _exit_loss(tuple(logits), tuple(g.reshape(-1) for g in gates),
+                      label, float(attrs["beta"]))

@@ -623,4 +623,5 @@ def test_dense_steps_span_names_the_head_size_and_no_more():
         ("MultiHeadAttention", 4, "layer0_attn"), ("Embedding", None, "embed")]
     assert len(spans) == 2 and not {
         "conv_layers", "moe_layers", "moe_route", "attn_head_dim",
-        "uncast_table_bytes"} & set(spans[-1])
+        "uncast_table_bytes", "loop_steps", "loop_layers",
+        "loop_exits"} & set(spans[-1])

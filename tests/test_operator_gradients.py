@@ -298,6 +298,14 @@ _case("softmax_cross_entropy:", lambda: (
     sym.softmax_cross_entropy(V("data"), V("label")),
     {"data": _u((3, 4)), "label": np.array([0, 2, 1], np.float32)},
     {"grad_nodes": ["data"], "numeric_eps": 1e-2, "rtol": 0.1, "atol": 2e-2}))
+_case("LoopExitLoss:", lambda: (
+    sym.LoopExitLoss(V("z0"), V("z1"), V("z2"), V("g0"), V("g1"), V("label"),
+                     num_exits=3, beta=0.1),
+    {"z0": _u((3, 4)), "z1": _u((3, 4)), "z2": _u((3, 4)),
+     "g0": _u((3, 1)), "g1": _u((3, 1)),
+     "label": np.array([0, 2, 1], np.float32)},
+    {"grad_nodes": ["z0", "z1", "z2", "g0", "g1"], "numeric_eps": 1e-2,
+     "rtol": 0.1, "atol": 2e-2}))
 _case("Dropout:p0", lambda: (sym.Dropout(V("data"), p=0.0),
                              {"data": _u((2, 3))}, {}))
 

@@ -107,9 +107,9 @@ _LM = dict(num_layers=2, num_heads=2, model_dim=16, ffn_dim=32, num_kv_heads=1,
 
 def _old_head(vocab):
     """``get_symbol(scalar_loss=True)`` as it was before ISSUE 30."""
-    pred = transformer._backbone(vocab, _LM["num_layers"], _LM["num_heads"],
-                                 _LM["model_dim"], _LM["ffn_dim"],
-                                 _LM["num_kv_heads"], _LM["use_flash"])
+    (pred, _), = transformer._backbone(
+        vocab, _LM["num_layers"], _LM["num_heads"], _LM["model_dim"],
+        _LM["ffn_dim"], _LM["num_kv_heads"], _LM["use_flash"])
     label = sym.Reshape(data=sym.Variable("softmax_label"), shape=(-1,))
     logp = sym.log_softmax(pred, axis=-1)
     onehot = sym.one_hot(label, depth=vocab)
