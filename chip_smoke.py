@@ -67,7 +67,8 @@ class Sizes:
     flash_batch: int = 32
     stream_seq: int = 16384
     # flash at head size 64 (64-lane blocks), 4 query heads a KV head:
-    # a sequence the fused backward takes, and one it leaves to dq and dkv
+    # a sequence the fused backward takes whole, and one it takes in
+    # query superblocks
     head64_seqs: tuple = (2048, 8192)
     lstm_n: int = 128
     lstm_h: int = 512

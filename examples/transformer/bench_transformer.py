@@ -17,9 +17,11 @@ where it is selected):
 
 3. kernels (``--kernels``, alone): the flash kernels' milliseconds
    (forward; dq and dkv, the two-kernel backward; the fused backward that
-   replaces them where its accumulators fit VMEM), each alone, at the
-   shape of the benchmark cell ``lm_train_4k`` — the measurement
-   PERF.md's findings for PRs 26 and 32 start from.
+   replaces them wherever its accumulators fit VMEM at some query
+   superblock), each alone, at the shape of the benchmark cell
+   ``lm_train_4k`` — the measurement PERF.md's findings for PRs 26 and 32
+   start from — and at the two 8192-token cells' shapes, where the fused
+   backward runs in query superblocks.
 
     python examples/transformer/bench_transformer.py
     python examples/transformer/bench_transformer.py --kernels
@@ -146,13 +148,16 @@ def micro(args):
 
 
 def kernel_times(fa, batch, heads, kv_heads, seq, head_dim, causal=True,
-                 reps=20, seed=0, only=("fwd", "dq", "dkv", "fused")):
+                 reps=20, seed=0, only=("fwd", "dq", "dkv", "fused"),
+                 window=0, q_super=None):
     """Milliseconds of each flash kernel alone (forward with lse; the
-    two-kernel backward's dq and dkv; the fused backward) at one shape,
-    bfloat16: each closure keeps ONE of the kernels (XLA drops a
-    pallas_call whose results nobody reads; checked in the compiled text),
-    runs ``reps`` times back to back and is read once; the least of three
-    such blocks. The backward arms call the two paths directly, whatever
+    two-kernel backward's dq and dkv; the fused backward, at a query
+    superblock of ``q_super`` rows, the whole sequence by default) at one
+    shape, bfloat16, under a band of ``window`` keys if one is given: each
+    closure keeps ONE of the kernels (XLA drops a pallas_call whose
+    results nobody reads; checked in the compiled text), runs ``reps``
+    times back to back and is read once; the least of three such blocks.
+    The backward arms call the two paths directly, whatever
     `_fa_backward` would choose at the shape. ``fa`` is the kernel module.
     Returns ({"fwd": ms, ...}, {"fwd": (o, lse), "dq": dq, "dkv": (dk,
     dv), "fused": (dq, dk, dv)}) for the kernels in ``only``."""
@@ -172,20 +177,20 @@ def kernel_times(fa, batch, heads, kv_heads, seq, head_dim, causal=True,
     k, v = rand(rows, seq, head_dim), rand(rows, seq, head_dim)
     scale = head_dim ** -0.5
     fwd = jax.jit(lambda q, k, v: fa._fa_forward(
-        q, k, v, causal, scale, interp, with_lse=True))
+        q, k, v, causal, scale, interp, with_lse=True, window=window))
     o, lse = fwd(q, k, v)
     # flash's row sums D, the XLA pass `_fa_backward` makes before either
     # path: outside the timed kernels
     bwd_args = (q, k, v, do, lse, fa._row_sums(o, do))
 
     def split(*a):
-        return fa._fa_backward_split(a, causal, scale, interp)
+        return fa._fa_backward_split(a, causal, scale, interp, window)
 
     arms = {"fwd": (fwd, (q, k, v)),
             "dq": (jax.jit(lambda *a: split(*a)[0]), bwd_args),
             "dkv": (jax.jit(lambda *a: split(*a)[1:]), bwd_args),
             "fused": (jax.jit(lambda *a: fa._fa_backward_fused(
-                a, causal, scale, interp)), bwd_args)}
+                a, causal, scale, interp, window, q_super)), bwd_args)}
     ms, outs = {}, {}
     for name in only:
         f, xs = arms[name]
@@ -212,9 +217,10 @@ def kernels(args):
     bfloat16): one layer's calls, the fused backward beside the dq and dkv
     kernels it replaces there, and how far its three results lie from
     theirs (norm of the difference over the norm). PERF.md (Findings, PRs
-    26 and 32) has the readings this repeats. Then lfm2_train_8k's calls
-    at head size 64, beside a head of 128 at the same shape (Findings, PR
-    35)."""
+    26 and 32) has the readings this repeats. Then the same at the two
+    8192-token cells' shapes (PERF.md, Findings), where the fused backward
+    runs in the query superblocks `_fa_backward` chooses (and at half of
+    that, to see what a superblock's length costs)."""
     import numpy as np
     import jax
     from mxnet_tpu.ops.pallas import flash_attention as fa
@@ -233,23 +239,42 @@ def kernels(args):
         a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
         return float(np.linalg.norm(a - b) / np.linalg.norm(b))
 
-    split = (outs["dq"],) + tuple(outs["dkv"])
-    print("fused against dq/dkv, |difference| / |dq/dkv|: "
-          + "  ".join("%s %.3g" % (n, gap(a, b)) for n, a, b in
-                      zip(("dq", "dk", "dv"), outs["fused"], split)))
-    # a head of 64 (lfm2_train_8k: 32 heads over 8 KV heads, 8192 tokens,
-    # the split backward) in its 64-lane blocks, beside what the same heads
-    # padded with zeros to 128 would cost: the same kernels at 128
-    shape = (1, 8, 2, 256) if on_cpu else (1, 32, 8, 8192)
-    for head_dim in (64, 128):
-        m, _ = kernel_times(fa, *shape, head_dim, causal=args.causal,
-                            reps=1 if on_cpu else 20,
-                            only=("fwd", "dq", "dkv"))
-        print("kernels B=%d H=%d HKV=%d S=%d D=%d causal=%s: fwd %.3f ms  "
-              "dq %.3f ms  dkv %.3f ms" % (
-                  shape + (head_dim, args.causal, m["fwd"], m["dq"],
-                           m["dkv"])))
-        ms["d%d" % head_dim] = m
+    def gaps(outs):
+        split = (outs["dq"],) + tuple(outs["dkv"])
+        return "fused against dq/dkv, |difference| / |dq/dkv|: " + "  ".join(
+            "%s %.3g" % (n, gap(a, b)) for n, a, b in
+            zip(("dq", "dk", "dv"), outs["fused"], split))
+
+    print(gaps(outs))
+    # smallthinker_train_8k: 28 heads over 4 KV heads of 128, a global
+    # layer and a 4096 band; lfm2_train_8k: two sequences, 32 heads over 8
+    # KV heads of 64
+    cells = ([("smallthinker_global", (1, 8, 2, 256, 128), 0),
+              ("smallthinker_window", (1, 8, 2, 256, 128), 128),
+              ("lfm2", (1, 8, 2, 256, 64), 0)] if on_cpu else
+             [("smallthinker_global", (1, 28, 4, 8192, 128), 0),
+              ("smallthinker_window", (1, 28, 4, 8192, 128), 4096),
+              ("lfm2", (2, 32, 8, 8192, 64), 0)])
+    for name, shape, window in cells:
+        seq, head_dim = shape[3], shape[4]
+        q_super = fa._fused_q_super(seq, seq, head_dim, 2)
+        m, outs = kernel_times(fa, *shape, causal=args.causal,
+                               reps=1 if on_cpu else 20, window=window,
+                               q_super=q_super)
+        half = ""
+        if (q_super // 2) % fa._pick_block(seq, fa.BLOCK_Q) == 0:
+            h, _ = kernel_times(fa, *shape, causal=args.causal,
+                                reps=1 if on_cpu else 20, window=window,
+                                q_super=q_super // 2, only=("fused",))
+            half = "  fused at %d rows %.3f ms" % (q_super // 2, h["fused"])
+        print("cell %s B=%d H=%d HKV=%d S=%d D=%d window=%d causal=%s: "
+              "fwd %.3f ms  dq %.3f ms  dkv %.3f ms  fused at %d rows "
+              "%.3f ms  fused under dq+dkv by %.3f ms%s"
+              % ((name,) + shape + (window, args.causal, m["fwd"], m["dq"],
+                                    m["dkv"], q_super, m["fused"],
+                                    m["dq"] + m["dkv"] - m["fused"], half)))
+        print("  " + gaps(outs))
+        ms[name] = m
     return ms
 
 

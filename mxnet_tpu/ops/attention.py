@@ -159,7 +159,7 @@ def _multi_head_attention(attrs, query, key, value, q_norm_gamma=None,
     # the kernels' gate and their backward add to this record what they
     # build (flash_attention.py); without them it stands as it is
     note_built({"op": "MultiHeadAttention", "head_dim": d, "window": None,
-                "kernel": False, "backward": None})
+                "kernel": False, "backward": None, "q_super": None})
     if attrs["qk_norm"]:
         q = _head_norm(q, q_norm_gamma, attrs["qk_norm_eps"])
         k = _head_norm(k, k_norm_gamma, attrs["qk_norm_eps"])

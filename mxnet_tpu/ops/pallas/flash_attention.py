@@ -25,15 +25,26 @@ and superblocks the same way. The superblock is sized per shape:
 The fourth is the **fused backward** (`_fa_bwd_fused_kernel`): dq, dk and
 dv from one walk over the live tiles, so s, p, dp and ds are rebuilt once
 and a tile costs five products where dq and dkv together cost seven, and
-q, dO, lse and D are fetched once a head and not once a key block. It
-keeps all three accumulators whole in VMEM (f32: (tq + 2 tk) x d x 4
-bytes), so `_fa_backward` takes it where a byte count from the shapes
-fits a kernel's scoped VMEM (`_SCOPED_VMEM`: 4096 x 4096 in bfloat16 at
-head_dim 128, not 8192) and the dq and dkv kernels otherwise. One
-algorithm, two regimes read from the input, like resident against
-streaming. Measured on a v5e (PERF.md, Findings, PR 32): 3.51 ms against
-2.13 + 2.78 at 4096 causal, 12 heads a KV head, and no slower at any
-shape it fits.
+q, dO, lse and D are fetched once a query superblock and not once a key
+block. dk's and dv's f32 accumulators are whole in VMEM (2 tk x d x 4
+bytes); dq's holds one query superblock, the query side arriving one
+superblock per grid step. `_fa_backward` reads from a byte count of the
+shapes (`_fused_bwd_vmem_bytes` against `_SCOPED_VMEM`, the VMEM a kernel
+gets without asking) the longest superblock that fits (`_fused_q_super`):
+
+- **whole** where the query sequence fits as one superblock (4096 x 4096
+  in bfloat16 at head_dim 128): the grid has no superblock axis;
+- **superblocked** where only shorter ones fit (8192 in bfloat16: 2048
+  rows; 4096 in float32: 2048): a key block wholly past a superblock's
+  diagonal or outside its band is a dead grid step, its K/V index
+  clamped to a live block (no DMA) and no tile walked;
+- the **dq and dkv kernels** where not even one query tile fits, because
+  dk's and dv's accumulators alone fill the 16 MiB (16384 in bfloat16).
+
+Measured on a v5e (PERF.md, Findings): 3.51 ms against 2.13 + 2.78 at
+4096 causal, 12 heads a KV head, and no slower at any shape it fits; in
+superblocks of 2048 at 8192, 7.95 ms against 4.60 + 6.13 (28 heads over
+4 KV heads), and 1024-row superblocks 6-9% slower than 2048-row ones.
 
 What the tile loop costs besides its matrix products decides the speed
 (PERF.md, Findings, PRs 26 and 32; measured on a v5e at 4096 causal, 12
@@ -505,37 +516,49 @@ def _fa_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dvec_ref,
         dv_ref[0] = dv_acc_ref[...].astype(dv_ref.dtype)
 
 
+def _both(a, b):
+    """a & b, where b may be a Python bool the trace already knows (one
+    query superblock): nothing more to trace then."""
+    return a if b is True else a & b
+
+
 def _fa_bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dvec_ref,
                          dq_ref, dk_ref, dv_ref, dq_acc_ref, dk_acc_ref,
                          dv_acc_ref, *, causal, scale, block_q, offset, g,
-                         num_k, window=0):
-    """dQ, dK and dV for one (batch*kv-head, group head, k-block): every
-    live tile builds s, p, dp and ds ONCE and spends five products on
-    them, where the dq and dkv kernels together spend seven. q, dO, lse
-    and D of the head are whole in VMEM (fetched once a head, not once a
-    key block), K and V arrive one block a grid step, and the in-kernel
-    loop walks the head's block_q tiles from the diagonal on.
+                         num_k, num_qs=1, window=0):
+    """dQ, dK and dV for one (batch*kv-head, group head, q-superblock,
+    k-block): every live tile builds s, p, dp and ds ONCE and spends five
+    products on them, where the dq and dkv kernels together spend seven.
+    q, dO, lse and D of one query superblock of the head are whole in VMEM
+    (fetched once a superblock, not once a key block), K and V arrive one
+    block a grid step, and the in-kernel loop walks the superblock's
+    block_q tiles from the diagonal on. With one superblock (``num_qs``
+    1, the whole query sequence) the grid has no superblock axis.
 
     The tile body is the dkv kernel's (transposed scores, lse and D as
     the rows they are stored in) plus ``dq[rows] += ds k``, whose left
-    side is the one tile of the five products that is transposed. All
-    three accumulators are whole-sequence f32 VMEM scratch: dq's is
-    zeroed at the head's first key block and written, scaled, at its
-    last; a key block's rows of dk's and dv's are zeroed at the group's
-    first head and written, in the output dtype, at its last — the GQA
-    sum stays an f32 sum inside the kernel."""
+    side is the one tile of the five products that is transposed. dq's
+    f32 accumulator holds the superblock: zeroed at its first key block
+    and written, scaled, at its last. dk's and dv's are whole-sequence
+    f32 VMEM scratch: a key block's rows are zeroed at the group's first
+    head's first superblock and written, in the output dtype, at its last
+    head's last — the GQA sum and the sum over superblocks stay one f32
+    sum inside the kernel, in the dkv kernel's order (head, superblock,
+    tile)."""
     bk = k_ref.shape[1]
-    tq = q_ref.shape[2]
+    sq = q_ref.shape[2]                                # q superblock size
     gi = pl.program_id(1)
-    ki = pl.program_id(2)
+    qsi = pl.program_id(2) if num_qs > 1 else 0
+    ki = pl.program_id(3 if num_qs > 1 else 2)
     k0 = ki * bk
     krows = pl.ds(pl.multiple_of(k0, bk), bk)
+    q_base = qsi * sq + offset
 
     @_when(num_k == 1, ki == 0)
     def _init_dq():
         dq_acc_ref[...] = jnp.zeros_like(dq_acc_ref)
 
-    @_when(g == 1, gi == 0)
+    @_when(g == 1 and num_qs == 1, _both(gi == 0, qsi == 0))
     def _init_dkv():
         dk_acc_ref[krows, :] = jnp.zeros((bk, dk_acc_ref.shape[1]),
                                          jnp.float32)
@@ -549,7 +572,7 @@ def _fa_bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dvec_ref,
         q, do = q_ref[0, 0, rows, :], do_ref[0, 0, rows, :]
         st = _dot(k, q, _NT) * scale                   # (BK, BQ)
         if causal:
-            st = jnp.where(_keep(st.shape, offset + qb * block_q, k0, 1,
+            st = jnp.where(_keep(st.shape, q_base + qb * block_q, k0, 1,
                                  window), st, _NEG_INF)
         pt = jnp.exp(st - lse_ref[0, 0, :, rows])
         dv_acc_ref[krows, :] += _dot(pt, do, _NN)
@@ -559,15 +582,16 @@ def _fa_bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dvec_ref,
 
     # causal: tiles whose last (offset) query position precedes this k
     # block's start contribute nothing (every entry masked), nor do those
-    # whose band has passed the block
-    _walk(tile, *_query_tiles(causal, k0, bk, offset, block_q,
-                              tq // block_q, window))
+    # whose band has passed the block; a key block dead for the whole
+    # superblock walks none (its K/V index is clamped to a live block)
+    _walk(tile, *_query_tiles(causal, k0, bk, q_base, block_q,
+                              sq // block_q, window))
 
     @_when(num_k == 1, ki == num_k - 1)
     def _write_dq():
         dq_ref[0, 0] = (dq_acc_ref[...] * scale).astype(dq_ref.dtype)
 
-    @_when(g == 1, gi == g - 1)
+    @_when(g == 1 and num_qs == 1, _both(gi == g - 1, qsi == num_qs - 1))
     def _write_dkv():
         dk_ref[0] = (dk_acc_ref[krows, :] * scale).astype(dk_ref.dtype)
         dv_ref[0] = dv_acc_ref[krows, :].astype(dv_ref.dtype)
@@ -582,51 +606,95 @@ def _fa_bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dvec_ref,
 _SCOPED_VMEM = 16 * 2 ** 20
 
 
-def _fused_bwd_vmem_bytes(tq, tk, d, itemsize):
-    """What the fused backward holds in VMEM, from the shapes alone:
-    every input and output block twice (the pipeline's two buffers), the
-    three f32 accumulators, and two f32 score-sized tiles for what the
-    loop spills (the compiler asks for 1.0-1.5 at 512 x 512: the cell's
-    shape, 15.5 MiB by this count, compiles from 14.5-15 MiB up)."""
+def _fused_bwd_vmem_bytes(tq, tk, d, itemsize, q_super=None):
+    """What the fused backward holds in VMEM at a query superblock of
+    ``q_super`` rows (the whole ``tq`` by default), from the shapes alone:
+    every input and output block twice (the pipeline's two buffers: q,
+    dO, dq, lse and D at the superblock's rows, K, V, dK and dV at a key
+    block's), the f32 accumulators (dq's at the superblock's rows, dk's
+    and dv's at the whole key sequence's), and two f32 score-sized tiles
+    for what the loop spills (the compiler asks for 1.0-1.5 at 512 x 512:
+    lm_train_4k's shape, 15.5 MiB by this count, compiles from 14.5-15 MiB
+    up)."""
+    sq = q_super or tq
     block_q, block_k = _pick_block(tq, BLOCK_Q), _pick_block(tk, BLOCK_K)
     d = -(-d // _LANES) * _LANES        # a row of 64 takes a whole vreg row
-    row = 8 * tq * 4                    # a (1, tq) f32 row pads to 8 sublanes
-    blocks = ((2 * tq * d + 2 * block_k * d) * itemsize + 2 * row  # in
-              + (tq * d + 2 * block_k * d) * itemsize)             # out
-    acc = (tq + 2 * tk) * d * 4
+    row = 8 * sq * 4                    # a (1, sq) f32 row pads to 8 sublanes
+    blocks = ((2 * sq * d + 2 * block_k * d) * itemsize + 2 * row  # in
+              + (sq * d + 2 * block_k * d) * itemsize)             # out
+    acc = (sq + 2 * tk) * d * 4
     tiles = 2 * block_q * block_k * 4
     return 2 * blocks + acc + tiles
 
 
-def _fa_backward_fused(args, causal, scale, interpret, window=0):
+def _fused_q_super(tq, tk, d, itemsize):
+    """Rows of the fused backward's query superblock: the largest divisor
+    of ``tq`` that is a whole number of query tiles and whose byte count
+    (`_fused_bwd_vmem_bytes`) fits `_SCOPED_VMEM` — ``tq`` itself where the
+    whole sequence fits — or None where not even one tile does, because
+    dk's and dv's whole-sequence accumulators alone do not."""
+    n = tq // _pick_block(tq, BLOCK_Q)
+    for parts in range(1, n + 1):
+        if n % parts == 0 and _fused_bwd_vmem_bytes(
+                tq, tk, d, itemsize, tq // parts) <= _SCOPED_VMEM:
+            return tq // parts
+    return None
+
+
+def _fa_backward_fused(args, causal, scale, interpret, window=0,
+                       q_super=None):
     """dq, dk, dv from ONE kernel over a (batch*kv-head, group head,
-    k-block) grid: `_fa_bwd_fused_kernel`."""
+    q-superblock, k-block) grid: `_fa_bwd_fused_kernel`. ``q_super``: the
+    query superblock's rows, the whole ``tq`` by default, when the grid
+    has no superblock axis."""
     q, k, v = args[:3]
     bkv, g, tq, d = q.shape
     tk = k.shape[1]
+    offset = tk - tq
     block_q = _pick_block(tq, BLOCK_Q)
     block_k = _pick_block(tk, BLOCK_K)
     num_k = tk // block_k
-    q_spec = pl.BlockSpec((1, 1, tq, d), lambda b, gi, ki: (b, gi, 0, 0))
-    qrow_spec = pl.BlockSpec((1, 1, 1, tq), lambda b, gi, ki: (b, gi, 0, 0))
-    kv_spec = pl.BlockSpec((1, block_k, d), lambda b, gi, ki: (b, ki, 0))
-    # dk/dv blocks are written during the group's LAST head only: until
-    # then the index stays put, and Pallas writes a block back when its
-    # index changes, so no block leaves before it is written
-    dkv_spec = pl.BlockSpec(
-        (1, block_k, d),
-        lambda b, gi, ki: (b, jnp.where(gi == g - 1, ki, 0), 0))
+    q_super = q_super or tq
+    num_qs = tq // q_super
+    if num_qs == 1:
+        grid = (bkv, g, num_k)
+
+        def at(f):                      # the index maps at superblock 0
+            return lambda b, gi, ki: f(b, gi, 0, ki)
+    else:
+        grid = (bkv, g, num_qs, num_k)
+
+        def at(f):
+            return f
+
+    q_spec = pl.BlockSpec((1, 1, q_super, d),
+                          at(lambda b, gi, qsi, ki: (b, gi, qsi, 0)))
+    qrow_spec = pl.BlockSpec((1, 1, 1, q_super),
+                             at(lambda b, gi, qsi, ki: (b, gi, 0, qsi)))
+    # a key block wholly past the superblock's diagonal, or outside its
+    # band, re-addresses a live one (no DMA) while the kernel walks no
+    # tile of it; one superblock keeps the plain map (every causal key
+    # block is live for it, but a band's ahead of a long offset)
+    kv_spec = pl.BlockSpec((1, block_k, d), at(_kv_stream_idx(
+        q_super, block_k, offset, causal and num_qs > 1, window)))
+    # dk/dv blocks are written during the group's LAST head's last
+    # superblock only: until then the index stays put, and Pallas writes a
+    # block back when its index changes, so no block leaves before it is
+    # written
+    dkv_spec = pl.BlockSpec((1, block_k, d), at(
+        lambda b, gi, qsi, ki: (b, jnp.where(
+            _both(gi == g - 1, qsi == num_qs - 1), ki, 0), 0)))
     return pl.pallas_call(
         functools.partial(_fa_bwd_fused_kernel, causal=causal, scale=scale,
-                          block_q=block_q, offset=tk - tq, g=g,
-                          num_k=num_k, window=window),
-        grid=(bkv, g, num_k),
+                          block_q=block_q, offset=offset, g=g,
+                          num_k=num_k, num_qs=num_qs, window=window),
+        grid=grid,
         in_specs=[q_spec, kv_spec, kv_spec, q_spec, qrow_spec, qrow_spec],
         out_specs=[q_spec, dkv_spec, dkv_spec],
         out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
                    jax.ShapeDtypeStruct(k.shape, k.dtype),
                    jax.ShapeDtypeStruct(v.shape, v.dtype)],
-        scratch_shapes=[pltpu.VMEM((tq, d), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((q_super, d), jnp.float32),
                         pltpu.VMEM((tk, d), jnp.float32),
                         pltpu.VMEM((tk, d), jnp.float32)],
         cost_estimate=pl.CostEstimate(
@@ -636,7 +704,7 @@ def _fa_backward_fused(args, causal, scale, interpret, window=0):
             bytes_accessed=_bwd_in_bytes(args),
             transcendentals=bkv * g * tq * tk),
         interpret=interpret,
-        **_compiler_params(interpret, grid_dims=3),
+        **_compiler_params(interpret, grid_dims=len(grid)),
     )(*args)
 
 
@@ -749,19 +817,24 @@ def _fa_backward(q, k, v, o, lse, do, causal, scale, interpret,
     Tq). Returns (dq like q, dk/dv like k/v) — dk/dv already summed over
     the query-head group inside the kernel.
 
-    One algorithm, two regimes read from the shapes: the fused kernel
-    where its whole-sequence accumulators fit a kernel's scoped VMEM
-    (`_fused_bwd_vmem_bytes` against `_SCOPED_VMEM`), the dq and dkv
-    kernels otherwise (long sequences, wide dtypes). Which was built goes
+    One algorithm, read from the shapes: the fused kernel at the longest
+    query superblock whose accumulators fit a kernel's scoped VMEM
+    (`_fused_q_super`: the whole sequence up to 4096 x 4096 in bfloat16
+    at head_dim 128, 2048 rows at 8192), the dq and dkv kernels where
+    not even one query tile fits (dk's and dv's whole-sequence
+    accumulators alone fill it: 16384 in bfloat16). Which was built goes
     into the record of the graph node ``node`` whose forward this is the
-    backward of (``note_built``: ``backward`` ``"fused"`` or ``"split"``)."""
+    backward of (``note_built``: ``backward`` ``"fused"`` or ``"split"``,
+    ``q_super`` the superblock's rows, None for the split pair)."""
     args = (q, k, v, do, lse, _row_sums(o, do, g_lse))
-    fused = _fused_bwd_vmem_bytes(q.shape[2], k.shape[1], q.shape[3],
-                                  q.dtype.itemsize) <= _SCOPED_VMEM
+    q_super = _fused_q_super(q.shape[2], k.shape[1], q.shape[3],
+                             q.dtype.itemsize)
     note_built({"op": "MultiHeadAttention",
-                "backward": "fused" if fused else "split"}, node=node)
-    if fused:
-        return _fa_backward_fused(args, causal, scale, interpret, window)
+                "backward": "fused" if q_super else "split",
+                "q_super": q_super}, node=node)
+    if q_super:
+        return _fa_backward_fused(args, causal, scale, interpret, window,
+                                  q_super)
     return _fa_backward_split(args, causal, scale, interpret, window)
 
 

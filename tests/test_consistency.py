@@ -562,11 +562,15 @@ _FLASH_CASES = {
 }
 
 
-def _flash_case_setup(monkeypatch, fa, blocks, streaming, path="split"):
+def _flash_case_setup(monkeypatch, fa, blocks, streaming, path="split",
+                      shape=None):
     """Tiles, regime and backward path of one case. The path follows from
-    the shapes alone (`_fused_bwd_vmem_bytes` against `_SCOPED_VMEM`),
-    and every CI-sized shape fits: "split" tells the byte count that
-    nothing does, which is what a streaming shape reads at its real size."""
+    the shapes alone (`_fused_q_super`: `_fused_bwd_vmem_bytes` against
+    `_SCOPED_VMEM`), and every CI-sized shape fits whole: "split" tells
+    the byte count that nothing does, which is what a streaming shape
+    reads at its real size; "superblocked" (``shape``: tq, tk, dtype)
+    that a superblock of one query tile does and no longer one, as 8192
+    tokens read at theirs a superblock of 2048."""
     if blocks:
         monkeypatch.setattr(fa, "BLOCK_Q", blocks[0])
         monkeypatch.setattr(fa, "BLOCK_K", blocks[1])
@@ -575,6 +579,11 @@ def _flash_case_setup(monkeypatch, fa, blocks, streaming, path="split"):
         monkeypatch.setattr(fa, "SUPER_TARGET", streaming[1])
     if path == "split":
         monkeypatch.setattr(fa, "_SCOPED_VMEM", 0)
+    if path == "superblocked":
+        tq, tk, dtype = shape
+        monkeypatch.setattr(fa, "_SCOPED_VMEM", fa._fused_bwd_vmem_bytes(
+            tq, tk, fa._LANES, jnp.dtype(dtype).itemsize,
+            fa._pick_block(tq, fa.BLOCK_Q)))
 
 
 @pytest.fixture
@@ -587,20 +596,31 @@ def built():
         yield into.layers
 
 
-def _assert_built_one(built, path):
-    """One backward was traced, on ``path``."""
-    assert [r["backward"] for r in built if r.get("backward")] == [path]
+def _assert_built_one(built, path, tq=None):
+    """One backward was traced, on ``path``: "superblocked" is the fused
+    kernel at a query superblock under ``tq``, "fused" at ``tq`` whole."""
+    got = [(r["backward"], r["q_super"]) for r in built if r.get("backward")]
+    assert len(got) == 1 and got[0][0] == {"superblocked": "fused"}.get(
+        path, path), got
+    if path == "superblocked":
+        assert got[0][1] < tq, got
+    elif path == "fused" and tq is not None:
+        assert got[0][1] == tq, got
 
 
-def _case_paths(cases, is_streaming):
+def _case_paths(cases, is_streaming, query_tiles):
     """(case, path) pairs: every case through the dq and dkv kernels, the
-    resident ones through the fused kernel too."""
-    return [(c, p) for c in sorted(cases) for p in ("split", "fused")
-            if p == "split" or not is_streaming(cases[c])]
+    resident ones through the fused kernel too, and those of more than
+    one query tile through it at a superblock of one tile."""
+    return [(c, p) for c in sorted(cases)
+            for p in ("split", "fused", "superblocked")
+            if p == "split" or not is_streaming(cases[c])
+            and (p == "fused" or query_tiles(cases[c]) > 1)]
 
 
-@pytest.mark.parametrize(
-    "case,path", _case_paths(_FLASH_CASES, lambda c: c[7] is not None))
+@pytest.mark.parametrize("case,path", _case_paths(
+    _FLASH_CASES, lambda c: c[7] is not None,
+    lambda c: c[2] // (c[6][0] if c[6] else 512)))
 def test_pallas_flash_cases_match_xla(case, path, monkeypatch, built):
     """Forward and all three gradients of the flash kernels against the
     XLA reference, one case per way the tile walk can go: which tiles a
@@ -612,7 +632,8 @@ def test_pallas_flash_cases_match_xla(case, path, monkeypatch, built):
     from mxnet_tpu.ops.attention import _grouped_attention
 
     h, hkv, tq, tk, causal, dtype, blocks, streaming = _FLASH_CASES[case]
-    _flash_case_setup(monkeypatch, fa, blocks, streaming, path)
+    _flash_case_setup(monkeypatch, fa, blocks, streaming, path,
+                      (tq, tk, dtype))
     rng = np.random.RandomState(sorted(_FLASH_CASES).index(case))
     B, D = 1, 8
     q = jnp.asarray(rng.randn(B, h, tq, D).astype(np.float32), dtype)
@@ -646,7 +667,7 @@ def test_pallas_flash_cases_match_xla(case, path, monkeypatch, built):
         np.testing.assert_allclose(np.asarray(a, np.float32),
                                    np.asarray(b, np.float32),
                                    err_msg="d" + name, **tol[1])
-    _assert_built_one(built, path)
+    _assert_built_one(built, path, tq)
 
 
 _FLASH_LSE_CASES = {
@@ -657,9 +678,9 @@ _FLASH_LSE_CASES = {
 }
 
 
-@pytest.mark.parametrize(
-    "case,path", _case_paths(_FLASH_LSE_CASES, lambda c: c[4] is not None))
-def test_pallas_flash_with_lse_cotangent(case, path, monkeypatch):
+@pytest.mark.parametrize("case,path", _case_paths(
+    _FLASH_LSE_CASES, lambda c: c[4] is not None, lambda c: c[1] // 256))
+def test_pallas_flash_with_lse_cotangent(case, path, monkeypatch, built):
     """`_flash_with_lse` (ring attention's per-shard call): lse is a real
     output, and a non-zero cotangent on it folds into D in the backward,
     before either backward path. A loss over BOTH outputs against the same
@@ -667,7 +688,8 @@ def test_pallas_flash_with_lse_cotangent(case, path, monkeypatch):
     from mxnet_tpu.ops.pallas import flash_attention as fa
 
     g, tq, tk, causal, streaming = _FLASH_LSE_CASES[case]
-    _flash_case_setup(monkeypatch, fa, (256, 256), streaming, path)
+    _flash_case_setup(monkeypatch, fa, (256, 256), streaming, path,
+                      (tq, tk, "float32"))
     rng = np.random.RandomState(3)
     rows, D = 2, 8
     scale = 0.4
@@ -705,9 +727,11 @@ def test_pallas_flash_with_lse_cotangent(case, path, monkeypatch):
     for name, a, b in zip("qkv", gk, gr):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=2e-3,
                                    atol=5e-4, err_msg="d" + name)
+    _assert_built_one(built, path, tq)
 
 
-# (rows, group, tq, tk, causal, dtype, blocks)
+# (rows, group, tq, tk, causal, dtype, blocks[, query superblock rows,
+# window]): without a superblock, the whole query sequence is one
 _FUSED_CASES = {
     # 3 x 3 tiles: key block 0 walks a diagonal tile and two interior
     # ones, key block 2 the diagonal one alone
@@ -723,6 +747,25 @@ _FUSED_CASES = {
     "mqa_noncausal": (1, 4, 256, 768, False, "float32", (256, 256)),
     "noncausal_default_tiles": (2, 1, 512, 1024, False, "float32", None),
     "bf16_causal_gqa": (2, 2, 512, 512, True, "bfloat16", (256, 256)),
+    # query superblocks of one or two tiles: the diagonal crosses each
+    # superblock, and key blocks past it are dead steps of the grid
+    "superblock_causal_diagonal_crosses_boundaries":
+        (2, 2, 768, 768, True, "float32", (256, 256), 256),
+    # a band of 200 keys: key block 0 is live for superblock 0 and dead for
+    # superblock 1 (its first query's band starts at 313), key block 1's
+    # reach ends inside superblock 1 (at query 710), and key blocks past
+    # superblock 0's diagonal are dead for it
+    "superblock_band_ends_inside_a_superblock":
+        (1, 2, 1024, 1024, True, "float32", (256, 256), 512, 200),
+    "superblock_group3_causal":
+        (2, 3, 1024, 1024, True, "float32", (256, 256), 256),
+    # offset 512 = tk - tq: superblocks at query positions 512 and 768
+    "superblock_offset_tq_under_tk":
+        (2, 2, 512, 1024, True, "float32", (256, 256), 256),
+    "superblock_noncausal": (1, 2, 512, 768, False, "float32", (256, 256),
+                             256),
+    "superblock_bf16_causal_gqa":
+        (2, 2, 1024, 1024, True, "bfloat16", (256, 256), 512),
 }
 
 
@@ -730,12 +773,14 @@ _FUSED_CASES = {
 def test_pallas_flash_fused_backward_equals_two_kernel_path(case,
                                                             monkeypatch):
     """The fused backward against the dq and dkv kernels on the SAME
-    inputs (q, k, v, dO, lse, D), both called directly: dk and dv walk
-    the same tiles in the same order and are equal to the last bit; dq
-    adds the same per-tile products, from transposed scores."""
+    inputs (q, k, v, dO, lse, D), both called directly, whole or in query
+    superblocks: dk and dv walk the same tiles in the same order (group
+    head, superblock, tile) and are equal to the last bit; dq adds the
+    same per-tile products, from transposed scores."""
     from mxnet_tpu.ops.pallas import flash_attention as fa
 
-    rows, g, tq, tk, causal, dtype, blocks = _FUSED_CASES[case]
+    rows, g, tq, tk, causal, dtype, blocks = _FUSED_CASES[case][:7]
+    q_super, window = (_FUSED_CASES[case][7:] + (None, 0))[:2]
     _flash_case_setup(monkeypatch, fa, blocks, None, "fused")
     rng = np.random.RandomState(sorted(_FUSED_CASES).index(case))
     D, scale = 8, 0.35
@@ -745,10 +790,11 @@ def test_pallas_flash_fused_backward_equals_two_kernel_path(case,
 
     q, do = rand(rows, g, tq, D), rand(rows, g, tq, D)
     k, v = rand(rows, tk, D), rand(rows, tk, D)
-    o, lse = fa._fa_forward(q, k, v, causal, scale, True, with_lse=True)
+    o, lse = fa._fa_forward(q, k, v, causal, scale, True, with_lse=True,
+                            window=window)
     args = (q, k, v, do, lse, fa._row_sums(o, do))
-    fused = fa._fa_backward_fused(args, causal, scale, True)
-    split = fa._fa_backward_split(args, causal, scale, True)
+    fused = fa._fa_backward_fused(args, causal, scale, True, window, q_super)
+    split = fa._fa_backward_split(args, causal, scale, True, window)
     for name, a, b in zip(("dq", "dk", "dv"), fused, split):
         assert a.shape == b.shape and a.dtype == b.dtype, name
     np.testing.assert_array_equal(np.asarray(fused[1], np.float32),
@@ -761,43 +807,59 @@ def test_pallas_flash_fused_backward_equals_two_kernel_path(case,
                                np.asarray(split[0], np.float32), **tol)
 
 
-@pytest.mark.parametrize("shape,want", [
-    # (rows, group, tq, tk, dtype): the benchmark cell lm_train_4k, 15.5
-    # MiB of the 16 a kernel gets ...
-    ((4, 12, 4096, 4096, "bfloat16"), "fused"),
-    # ... and the same lengths in float32, 22.5
-    ((4, 12, 4096, 4096, "float32"), "split"),
-    ((1, 2, 8192, 8192, "bfloat16"), "split"),
-    ((1, 2, 16384, 16384, "bfloat16"), "split"),
+@pytest.mark.parametrize("shape,want,q_super", [
+    # (rows, group, tq, tk, dtype, head size, window): the benchmark cell
+    # lm_train_4k, 15.5 MiB of the 16 a kernel gets, the whole sequence ...
+    ((4, 12, 4096, 4096, "bfloat16", 128, 0), "fused", 4096),
+    # ... and the same lengths in float32: 22.5 whole, 15.25 at 2048 rows
+    ((4, 12, 4096, 4096, "float32", 128, 0), "fused", 2048),
+    ((1, 2, 8192, 8192, "bfloat16", 128, 0), "fused", 2048),
+    ((1, 2, 8192, 8192, "float32", 128, 0), "fused", 1024),
+    # dk's and dv's whole-sequence accumulators alone take the 16 MiB
+    ((1, 2, 16384, 16384, "bfloat16", 128, 0), "split", None),
     # few queries against a long cache: K and V arrive block by block, so
     # only dk's and dv's accumulators grow with it (15.1 MiB)
-    ((2, 12, 1024, 10240, "bfloat16"), "fused"),
-    ((2, 12, 512, 4096, "bfloat16"), "fused"),
-    ((2, 1, 768, 1280, "float32"), "fused"),
+    ((2, 12, 1024, 10240, "bfloat16", 128, 0), "fused", 1024),
+    ((2, 12, 512, 4096, "bfloat16", 128, 0), "fused", 512),
+    ((2, 1, 768, 1280, "float32", 128, 0), "fused", 768),
+    # the cells smallthinker_train_8k (a global layer and a 4096 band) and
+    # lfm2_train_8k (two sequences at head size 64, counted at 128)
+    ((4, 7, 8192, 8192, "bfloat16", 128, 0), "fused", 2048),
+    ((4, 7, 8192, 8192, "bfloat16", 128, 4096), "fused", 2048),
+    ((16, 4, 8192, 8192, "bfloat16", 64, 0), "fused", 2048),
 ])
 def test_pallas_flash_backward_path_follows_from_the_shapes(shape, want,
-                                                            built):
-    """`_fa_backward` takes the fused kernel exactly where the byte count
-    of its blocks, accumulators and tiles fits a kernel's scoped VMEM, and
-    says which path it built as the program is traced (nothing runs
-    here: the real shapes are only traced)."""
+                                                            q_super, built):
+    """`_fa_backward` takes the fused kernel at the longest query
+    superblock whose blocks, accumulators and tiles the byte count fits
+    into a kernel's scoped VMEM, and the dq and dkv kernels where none
+    does, and says which path it built, and at which superblock, as the
+    program is traced (nothing runs here: the real shapes are only
+    traced)."""
     from mxnet_tpu.ops.pallas import flash_attention as fa
 
-    rows, g, tq, tk, dtype = shape
-    d = 128
-    fits = fa._fused_bwd_vmem_bytes(
-        tq, tk, d, jnp.dtype(dtype).itemsize) <= fa._SCOPED_VMEM
-    assert fits == (want == "fused")
+    rows, g, tq, tk, dtype, d, window = shape
+    itemsize = jnp.dtype(dtype).itemsize
+    assert fa._fused_q_super(tq, tk, d, itemsize) == q_super
+    if q_super:
+        assert fa._fused_bwd_vmem_bytes(
+            tq, tk, d, itemsize, q_super) <= fa._SCOPED_VMEM
+    if q_super != tq:
+        # the next longer superblock, or one query tile, does not fit
+        longer = 2 * q_super if q_super else fa._pick_block(tq, fa.BLOCK_Q)
+        assert fa._fused_bwd_vmem_bytes(
+            tq, tk, d, itemsize, longer) > fa._SCOPED_VMEM
     q = jax.ShapeDtypeStruct((rows, g, tq, d), jnp.dtype(dtype))
     kv = jax.ShapeDtypeStruct((rows, tk, d), jnp.dtype(dtype))
     lse = jax.ShapeDtypeStruct((rows, g, 1, tq), jnp.float32)
     dq, dk, dv = jax.eval_shape(
         lambda q, k, v, o, lse, do: fa._fa_backward(
-            q, k, v, o, lse, do, True, d ** -0.5, True),
+            q, k, v, o, lse, do, True, d ** -0.5, True, window=window),
         q, kv, kv, q, lse, q)
     assert (dq.shape, dq.dtype) == (q.shape, q.dtype)
     assert (dk.shape, dv.shape, dk.dtype) == (kv.shape, kv.shape, kv.dtype)
-    _assert_built_one(built, want)
+    _assert_built_one(built, want if q_super in (None, tq)
+                      else "superblocked", tq)
 
 
 @pytest.mark.parametrize("t,pref,want", [

@@ -234,14 +234,16 @@ def _plain_attention(q, k, v):
 
 
 @pytest.mark.parametrize("t,regime", [(512, "fused"), (768, "fused"),
+                                      (1024, "superblocked"),
                                       (1024, "split"), (1536, "split")])
 def test_flash_kernels_at_head_size_64_interpreted(t, regime, monkeypatch):
-    """Forward, the fused backward, and the dq and dkv kernels at head size
-    64 (64-lane blocks: no padding) in interpret mode against the einsum
-    path, 4 query heads a KV head as the benchmark cell has them: output
-    and the gradients of q, k and v within the tolerance the tests hold a
-    head of 128 to. The kernels' record says what head size and which
-    backward were traced."""
+    """Forward, the fused backward (whole, and in query superblocks of one
+    tile), and the dq and dkv kernels at head size 64 (64-lane blocks: no
+    padding) in interpret mode against the einsum path, 4 query heads a KV
+    head as the benchmark cell has them: output and the gradients of q, k
+    and v within the tolerance the tests hold a head of 128 to. The
+    kernels' record says what head size, which backward and which query
+    superblock were traced."""
     from mxnet_tpu.ops.pallas import flash_attention as fa
     from mxnet_tpu.ops.registry import built_layers
 
@@ -251,14 +253,19 @@ def test_flash_kernels_at_head_size_64_interpreted(t, regime, monkeypatch):
         monkeypatch.setattr(fa, "_RESIDENT_MAX", 256)
         monkeypatch.setattr(fa, "SUPER_TARGET", 512)
         monkeypatch.setattr(fa, "_SCOPED_VMEM", 0)
+    if regime == "superblocked":
+        monkeypatch.setattr(fa, "_SCOPED_VMEM",
+                            fa._fused_bwd_vmem_bytes(t, t, 64, 4, 256))
     rng = np.random.RandomState(t)
     q, k, v = (_rand(rng, 1, n, t, 64) for n in (8, 2, 2))
     with built_layers() as built:
         _close(lambda q, k, v: fa.flash_attention(q, k, v, causal=True,
                                                   interpret=True),
                _plain_attention, (q, k, v), tol=3e-5)
-    assert {r["backward"] for r in built.layers if "backward" in r} == {
-        regime}
+    assert {(r["backward"], r["q_super"]) for r in built.layers
+            if "backward" in r} == {{"fused": ("fused", t),
+                                     "superblocked": ("fused", 256),
+                                     "split": ("split", None)}[regime]}
     assert {r["head_dim"] for r in built.layers if r.get("kernel")} == {64}
 
 
@@ -266,8 +273,8 @@ def test_flash_gate_takes_a_head_of_64_and_counts_its_vmem_at_128():
     """The compiled path's contract: whole lanes, or half a vreg's. A row
     of 64 takes a whole vreg row in VMEM, so the fused backward's byte
     count at a head of 64 is a head of 128's: 8192 tokens do not fit it
-    (the chip's compiler asked 24.25 MiB where the count by 64 said
-    15.5)."""
+    whole (the chip's compiler asked 24.25 MiB where the count by 64 said
+    15.5), and fit it at a query superblock of 2048 rows (15.25 MiB)."""
     from mxnet_tpu.ops.pallas import flash_attention as fa
 
     assert fa.kernel_qualifies(8192, 8192, 64, causal=True)
@@ -278,6 +285,10 @@ def test_flash_gate_takes_a_head_of_64_and_counts_its_vmem_at_128():
     assert fa._fused_bwd_vmem_bytes(8192, 8192, 64, 2) \
         == fa._fused_bwd_vmem_bytes(8192, 8192, 128, 2) > fa._SCOPED_VMEM
     assert fa._fused_bwd_vmem_bytes(4096, 4096, 64, 2) <= fa._SCOPED_VMEM
+    assert fa._fused_q_super(8192, 8192, 64, 2) == 2048
+    assert fa._fused_bwd_vmem_bytes(8192, 8192, 64, 2, 2048) \
+        == fa._fused_bwd_vmem_bytes(8192, 8192, 128, 2, 2048) \
+        <= fa._SCOPED_VMEM
 
 
 # --- the expert layer's sigmoid-and-bias route -----------------------------------

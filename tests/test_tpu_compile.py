@@ -34,20 +34,22 @@ def one_chip():
 
 
 # (rows = batch * kv heads, group, tq, tk, causal, dtype, backward kernels:
-# 1 where the fused backward's accumulators fit a kernel's scoped VMEM, 2
-# (dq and dkv) where they do not)
+# 1 where the fused backward's accumulators fit a kernel's scoped VMEM at
+# some query superblock, 2 (dq and dkv) where they do not)
 _FLASH_SHAPES = {
     # the benchmark cell lm_train_4k: 2 x 2 KV heads, 12 query heads each
     "lm_train_4k": (4, 12, 4096, 4096, True, "bfloat16", 1),
     # the cell smallthinker_train_8k: 1 x 4 KV heads, 7 query heads each; a
-    # global layer, and a window layer (a trailing 8th field: the window)
-    "smallthinker_8k_global": (4, 7, 8192, 8192, True, "bfloat16", 2),
-    "smallthinker_8k_window": (4, 7, 8192, 8192, True, "bfloat16", 2, 4096),
+    # global layer, and a window layer (a trailing 8th field: the window);
+    # the fused backward at query superblocks of 2048 rows
+    "smallthinker_8k_global": (4, 7, 8192, 8192, True, "bfloat16", 1),
+    "smallthinker_8k_window": (4, 7, 8192, 8192, True, "bfloat16", 1, 4096),
     # a band through the fused backward, and through streamed superblocks
     "window_1024_of_4096": (4, 12, 4096, 4096, True, "bfloat16", 1, 1024),
     "window_4096_of_16384": (1, 2, 16384, 16384, True, "bfloat16", 2, 4096),
-    # the longest resident sequence, in the widest dtype: the VMEM wall
-    "resident_8192_f32": (1, 2, 8192, 8192, False, "float32", 2),
+    # the longest resident sequence, in the widest dtype: superblocks of
+    # 1024 rows
+    "resident_8192_f32": (1, 2, 8192, 8192, False, "float32", 1),
     "streaming_16384": (1, 2, 16384, 16384, True, "bfloat16", 2),
     # serving prefill against a cache: tq < tk, forward without lse too
     "prefill_512_of_4096": (2, 12, 512, 4096, True, "bfloat16", 1),
@@ -57,11 +59,11 @@ _FLASH_SHAPES = {
 
 
 # the same at head size 64, in 64-lane blocks (lfm2_train_8k: 1 x 8 KV
-# heads, 4 query heads each, the split backward at 8192; the fused one
-# where it fits, whose accumulators take a head of 128's VMEM)
+# heads, 4 query heads each; the fused backward, whose accumulators take a
+# head of 128's VMEM, at superblocks of 2048 rows at 8192)
 _FLASH_HEAD64 = {
-    "lfm2_8k": (8, 4, 8192, 8192, True, "bfloat16", 2),
-    "lfm2_8k_two_sequences": (16, 4, 8192, 8192, True, "bfloat16", 2),
+    "lfm2_8k": (8, 4, 8192, 8192, True, "bfloat16", 1),
+    "lfm2_8k_two_sequences": (16, 4, 8192, 8192, True, "bfloat16", 1),
     "fused_4096": (8, 4, 4096, 4096, True, "bfloat16", 1),
     "window_1024_of_4096": (8, 4, 4096, 4096, True, "bfloat16", 1, 1024),
     "streaming_16384": (1, 2, 16384, 16384, True, "bfloat16", 2),
@@ -325,7 +327,8 @@ def test_expert_layer_step_compiles_to_grouped_kernels(expert_layer_step):
     worst-case buffer and the matrices in the layout the op holds them,
     none is left to the compiler's ``ragged-dot``, there is no dense
     product over all sixteen held experts, and the one window-free layer's
-    flash kernels are there."""
+    flash kernels are there: the forward and the fused backward, in query
+    superblocks at 8192 tokens."""
     text, ops, lines = expert_layer_step
     # 8192 tokens x 6 choices = 49152 rows, the worst case (12288 expected)
     grouped = [o for o in ops if o["group"] == "expert_products"]
@@ -348,7 +351,7 @@ def test_expert_layer_step_compiles_to_grouped_kernels(expert_layer_step):
     assert "ragged-dot" not in text
     # no product, mask or one-hot with an axis over the held experts
     assert not re.findall(r"(?:bf16|f32)\[16,(?:24576|49152|8192),", text)
-    assert sum(o["kernel"] and o["group"] == "flash" for o in ops) == 3
+    assert sum(o["kernel"] and o["group"] == "flash" for o in ops) == 2
     assert {"expert_products", "expert_moves", "flash"} <= {
         o["group"] for o in ops}
 
