@@ -100,10 +100,15 @@ def _layer_attrs(layers, symbol):
     it was traced (``ops/registry.py`` ``built_layers``): how many, and of
     one layer the experts held, the choices a token, the rows of its
     sorted-assignment buffer as allocated and the assignments expected
-    under uniform routing; and for a stack run several times
-    (``_loop_attrs``). The rest of what the layers say is the program
-    record's (``telemetry.programs()``: ``layers``)."""
+    under uniform routing; for a stack run several times (``_loop_attrs``);
+    and where a multi-token-prediction module was traced, ``mtp_depth``
+    (its ``MultiTokenLoss`` nodes: the modules) and ``mtp_weight`` (the
+    first one's weight in the loss). The rest of what the layers say is the
+    program record's (``telemetry.programs()``: ``layers``)."""
     attrs = _loop_attrs(layers, symbol)
+    mtp = [layer for layer in layers if layer["op"] == "MultiTokenLoss"]
+    if mtp:
+        attrs.update(mtp_depth=len(mtp), mtp_weight=mtp[0]["weight"])
     moe = [layer for layer in layers if layer["op"] == "ExpertFFN"]
     if not moe:
         return attrs

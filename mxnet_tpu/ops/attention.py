@@ -65,6 +65,15 @@ def rope(x, positions=None, base=10000.0):
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
 
 
+def _rope_tail(x, dims, base):
+    """RoPE over the trailing ``dims`` of each head of (..., T, D), the rest
+    as it is; ``dims`` 0: the whole head."""
+    if not dims:
+        return rope(x, base=base)
+    return jnp.concatenate([x[..., :-dims], rope(x[..., -dims:], base=base)],
+                           axis=-1)
+
+
 def _causal_mask(tq, tk, window=0):
     """(tq, tk) bool: query i (at position i + tk - tq, so that kv may be
     longer than q) sees the keys up to its own; with a ``window``, the
@@ -107,7 +116,7 @@ def _mha_arg_names(attrs):
     arg_names=_mha_arg_names,
     param_spec={"num_heads": 1, "num_kv_heads": 0, "causal": False,
                 "use_rope": False, "use_flash": True, "window": 0,
-                "rope_base": 10000.0, "qk_norm": False,
+                "rope_base": 10000.0, "rope_dims": 0, "qk_norm": False,
                 "qk_norm_eps": 1e-6},
 )
 def _multi_head_attention(attrs, query, key, value, q_norm_gamma=None,
@@ -130,7 +139,10 @@ def _multi_head_attention(attrs, query, key, value, q_norm_gamma=None,
     ``window`` > 0 (with ``causal``) lets query i see keys j with
     i - window < j <= i only; 0 is no window. ``rope_base`` is the base
     of the rotary frequencies where ``use_rope`` is set; a layer without
-    ``use_rope`` has no position encoding at all.
+    ``use_rope`` has no position encoding at all. ``rope_dims`` > 0
+    rotates the trailing ``rope_dims`` of every q and k head and leaves
+    the rest of the head unrotated (latent attention's decoupled key);
+    0 rotates the whole head.
 
     ``qk_norm`` adds two inputs, ``q_norm_gamma`` and ``k_norm_gamma``
     (head size,): every query head and every key head is RMS-normalised
@@ -156,16 +168,22 @@ def _multi_head_attention(attrs, query, key, value, q_norm_gamma=None,
 
     q = split(query, tq, h)
     k, v = split(key, tk, hkv), split(value, tk, hkv)
+    rope_dims = int(attrs["rope_dims"])
+    if not 0 <= rope_dims <= d or rope_dims % 2:
+        raise ValueError("rope_dims %d: an even part of a head of %d"
+                         % (rope_dims, d))
     # the kernels' gate and their backward add to this record what they
     # build (flash_attention.py); without them it stands as it is
-    note_built({"op": "MultiHeadAttention", "head_dim": d, "window": None,
-                "kernel": False, "backward": None, "q_super": None})
+    note_built({"op": "MultiHeadAttention", "head_dim": d,
+                "rope_dims": (rope_dims or d) if attrs["use_rope"] else 0,
+                "window": None, "kernel": False, "backward": None,
+                "q_super": None})
     if attrs["qk_norm"]:
         q = _head_norm(q, q_norm_gamma, attrs["qk_norm_eps"])
         k = _head_norm(k, k_norm_gamma, attrs["qk_norm_eps"])
     if attrs["use_rope"]:
         base = float(attrs["rope_base"])
-        q, k = rope(q, base=base), rope(k, base=base)
+        q, k = _rope_tail(q, rope_dims, base), _rope_tail(k, rope_dims, base)
     if attrs["use_flash"]:
         # flash_attention owns the selection gate (on-TPU + block
         # contract + MIN_SEQ) and takes narrow (B, Hkv, Tk, D) k/v
@@ -199,6 +217,36 @@ def _mha_infer(attrs, shapes):
 
 
 get_op("MultiHeadAttention").infer_params = _mha_infer
+
+
+@defop(
+    "LatentKV",
+    arg_names=("latent", "kv"),
+    num_outputs=2,
+    output_names=("key", "value"),
+    param_spec={"num_heads": 1, "head_dim": 0, "rope_dims": 0},
+)
+def _latent_kv(attrs, latent, kv):
+    """Latent attention's key and value, assembled per head (DeepSeek-V2's
+    MLA). ``latent`` (B, T, c + r): the kv down-projection, whose trailing
+    ``rope_dims`` r are the key's rotary part, one for every head; ``kv``
+    (B, T, H * (n + v)): the normed latent's up-projection, a head's
+    unrotated key part of ``n = head_dim - r`` and its value of v. Output
+    0, the key (B, T, H * head_dim): a head's n and then the shared r, which
+    ``MultiHeadAttention`` rotates with ``rope_dims`` r; output 1, the value
+    (B, T, H * v)."""
+    h, r = int(attrs["num_heads"]), int(attrs["rope_dims"])
+    n = int(attrs["head_dim"]) - r
+    b, t = kv.shape[:2]
+    per_head = kv.reshape(b, t, h, -1)
+    if n <= 0 or per_head.shape[-1] <= n:
+        raise ValueError("LatentKV: a head's %d up-projected numbers hold no "
+                         "key part of %d and a value"
+                         % (per_head.shape[-1], n))
+    k_rope = jnp.broadcast_to(latent[..., None, latent.shape[-1] - r:],
+                              (b, t, h, r))
+    key = jnp.concatenate([per_head[..., :n], k_rope.astype(kv.dtype)], -1)
+    return key.reshape(b, t, -1), per_head[..., n:].reshape(b, t, -1)
 
 
 def _grouped_attention(q, k, v, hkv, causal, scale=None, mask=None,

@@ -1,8 +1,9 @@
 """Sequence operators + binary loss.
 
 TPU-native equivalents of src/operator/sequence_{mask,last,reverse}.cc and
-src/operator/tensor/loss_binary_op.cc (softmax_cross_entropy), and the
-objective over the exits of a stack run several times (LoopExitLoss). Layout
+src/operator/tensor/loss_binary_op.cc (softmax_cross_entropy), the
+objective over the exits of a stack run several times (LoopExitLoss), and a
+multi-token-prediction module's shifted one (MultiTokenLoss). Layout
 follows the reference: time-major (max_len, batch, ...) unless axis says
 otherwise; sequence_length is a (batch,) vector of valid lengths.
 """
@@ -125,6 +126,28 @@ def _softmax_cross_entropy(attrs, data, label):
     """Scalar summed cross-entropy (reference loss_binary_op.cc), float32
     whatever the logits' dtype."""
     return _summed_nll(data, label.astype(jnp.int32).reshape(-1))
+
+
+@defop(
+    "MultiTokenLoss",
+    arg_names=("data", "label"),
+    param_spec={"weight": 1.0},
+    no_grad_inputs=("label",),
+)
+def _multi_token_loss(attrs, data, label):
+    """A multi-token-prediction module's objective: ``weight`` times the
+    mean over positions of the closed-form cross-entropy of the logits at
+    position i against the label one position later, over the T - 1
+    positions that have one. ``data`` (B * T, vocab), the rows in
+    ``label``'s order; ``label`` (B, T) the next-token labels, so that
+    position i's target is the token two after it. Float32 whatever the
+    logits' dtype."""
+    weight = float(attrs["weight"])
+    b, t = label.shape
+    note_built({"op": "MultiTokenLoss", "weight": weight})
+    logits = data.reshape(b, t, -1)[:, :t - 1].reshape(b * (t - 1), -1)
+    target = label.astype(jnp.int32)[:, 1:].reshape(-1)
+    return _summed_nll(logits, target) * (weight / (b * (t - 1)))
 
 
 def _exit_distribution(gates):

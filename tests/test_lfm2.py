@@ -635,4 +635,4 @@ def test_dense_steps_span_names_the_head_size_and_no_more():
     assert len(spans) == 2 and not {
         "conv_layers", "moe_layers", "moe_route", "attn_head_dim",
         "uncast_table_bytes", "loop_steps", "loop_layers",
-        "loop_exits"} & set(spans[-1])
+        "loop_exits", "mtp_depth", "mtp_weight"} & set(spans[-1])

@@ -306,6 +306,11 @@ _case("LoopExitLoss:", lambda: (
      "label": np.array([0, 2, 1], np.float32)},
     {"grad_nodes": ["z0", "z1", "z2", "g0", "g1"], "numeric_eps": 1e-2,
      "rtol": 0.1, "atol": 2e-2}))
+_case("MultiTokenLoss:", lambda: (
+    sym.MultiTokenLoss(V("data"), V("label"), weight=0.3),
+    {"data": _u((6, 4)), "label": np.array([[0, 2, 1], [3, 1, 0]],
+                                           np.float32)},
+    {"grad_nodes": ["data"], "numeric_eps": 1e-2, "rtol": 0.1, "atol": 2e-2}))
 _case("Dropout:p0", lambda: (sym.Dropout(V("data"), p=0.0),
                              {"data": _u((2, 3))}, {}))
 
@@ -355,6 +360,12 @@ _case("ExpertFFN:", lambda: (
      "gate_weight": _u((2, 3, 4)), "up_weight": _u((2, 3, 4)),
      "down_weight": _u((2, 4, 3))},
     {"numeric_eps": 1e-2, "rtol": 0.12, "atol": 3e-2}))
+for _out, _name in ((0, "key"), (1, "value")):
+    _case("LatentKV:%s" % _name, lambda o=_out: (
+        sym.LatentKV(V("latent"), V("kv"), num_heads=2, head_dim=4,
+                     rope_dims=2)[o],
+        {"latent": _u((1, 3, 5)), "kv": _u((1, 3, 2 * (2 + 3)))},
+        {"numeric_eps": 1e-2, "rtol": 0.12, "atol": 3e-2}))
 _case("ShortConv:", lambda: (
     sym.ShortConv(V("data"), V("in_weight"), V("conv_weight"),
                   V("out_weight"), kernel=3),

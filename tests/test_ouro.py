@@ -207,7 +207,10 @@ def test_post_norm_alone_is_the_sandwich():
 
 # graph hashes of the three cells' toy configurations (the families'
 # symbols under a fresh NameManager), taken at the parent of ISSUE 39: a loop
-# of one pass and no post_norm build exactly the graph the builder built
+# of one pass and no post_norm build exactly the graph the builder built.
+# Since ``MultiHeadAttention`` has ``rope_dims`` every attention node also
+# serializes that attribute's default, which the hash leaves out: nothing
+# else may differ
 TODAYS = {"toy_lm": ("transformer_lm", "db00ad483be9ac8d", 31),
           "toy_smallthinker": ("smallthinker_lm", "581884492ef5d781", 45),
           "toy_lfm2": ("lfm2_moe_lm", "3834b4a502980c58", 51)}
@@ -219,7 +222,12 @@ def test_one_pass_builds_todays_graph(toy_name):
     cfg = _toy(toy_name)
     with mx.name.NameManager():
         sym = _family(family).symbol(cfg, True)
-    assert hashlib.sha256(sym.tojson().encode()).hexdigest()[:16] == digest
+    graph = json.loads(sym.tojson())
+    attention = [n["attrs"] for n in graph["nodes"]
+                 if n["op"] == "MultiHeadAttention"]
+    assert attention and all(a.pop("rope_dims") == "0" for a in attention)
+    text = json.dumps(graph, indent=2)
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] == digest
     assert len(sym.list_arguments()) == n_args
     assert not [n for n in sym._nodes() if n.name.startswith("ut")
                 or not n.is_var and n.op.name == "LoopExitLoss"]

@@ -71,6 +71,18 @@ _FLASH_HEAD64 = {
 }
 
 
+# the same at head size 256 (glm_flash_train_4k: 20 heads, one a KV head;
+# the fused backward at query superblocks of 512 rows at 4096)
+_FLASH_HEAD256 = {
+    "glm_4k": (20, 1, 4096, 4096, True, "bfloat16", 1),
+}
+
+
+@pytest.mark.parametrize("name", sorted(_FLASH_HEAD256))
+def test_flash_kernels_compile_for_v5e_at_head_size_256(name, one_chip):
+    _compile_flash(one_chip, 256, *_FLASH_HEAD256[name])
+
+
 @pytest.mark.parametrize("name", sorted(_FLASH_SHAPES))
 def test_flash_kernels_compile_for_v5e(name, one_chip):
     _compile_flash(one_chip, 128, *_FLASH_SHAPES[name])
