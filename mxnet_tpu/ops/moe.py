@@ -67,6 +67,17 @@ autodiff's: a ``custom_vjp`` that recomputed the forward from the layer's
 inputs compiled to the same program, XLA merging the second forward with
 the first.
 
+What is ONE number a row never moves by a gather or a scatter, which cost
+0.17-0.66 ms each where a sort of the same R keys takes 0.04 (PERF.md,
+section 7, item 13). The routing weights go to sorted-row order as the
+payload of a sort keyed on each assignment's row (``inv``) and come back,
+in the backward, as the payload of one keyed on ``order``
+(``_row_weights``). The group sizes are a count over (N k, held), not a
+search of the sorted keys gathered. The router's picks are a float32
+select over (N, k, E) and a sum over E (``_picked``), whose backward is
+elementwise, where ``take_along_axis`` and ``top_k``'s values gathered
+them and scattered their gradients into (N, E); ``top_k`` still chooses.
+
 Where the ``valid`` mask lives: in ``_row_weights``, always (a row past the
 held assignments gets weight 0, which multiplies the down product's input
 and every gradient that returns through it), and in the combine's ``ok``
@@ -95,8 +106,10 @@ def buffer_rows(tokens, top_k, held, experts):
 
 # --- moves between token order and sorted-row order ---------------------------
 # A plan (dict of int/bool arrays) describes one permutation both ways:
-#   tok (R,), order (R,), valid (R,): row r holds assignment order[r], of
-#       token tok[r]; rows past the held assignments are not valid;
+#   order (N k,), inv (N k,): row r holds assignment order[r], assignment a
+#       sits in row inv[a] (none where inv[a] >= R: experts_held < top_k);
+#   tok (R,), valid (R,): row r holds an assignment of token tok[r]; rows
+#       past the held assignments are not valid;
 #   spread_mask: valid, or None where what _spread writes into a row that
 #       is not valid cannot reach any result (_plan);
 #   slot (N, k), ok (N, k): assignment (t, j) sits in row slot[t, j], if ok.
@@ -142,31 +155,50 @@ def _collect(rows, plan):
     return jnp.sum(got, axis=0, dtype=jnp.float32).astype(rows.dtype)
 
 
+def _sorted_by(keys, values):
+    """``values`` put in the order of ``keys``, a permutation of as many:
+    one sort that carries them as its payload."""
+    return jax.lax.sort((keys, values), num_keys=1)[1]
+
+
 @jax.custom_vjp
 def _row_weights(w, plan):
-    """Routing weights (N, k) -> one a sorted row (R,)."""
-    return _take(w.reshape(-1), plan["order"], plan["valid"])
+    """Routing weights (N, k) -> one a sorted row (R,): sorted by the row
+    each assignment sits in (``inv``)."""
+    rows = plan["valid"].shape[0]
+    return jnp.where(plan["valid"],
+                     _sorted_by(plan["inv"], w.reshape(-1))[:rows], 0)
+
+
+def _row_weights_bwd(plan, g):
+    """(R,) -> (N, k), sorted back by ``order``. A row that is not valid
+    gives 0, and so does an assignment with no row (zeros padded past R
+    where ``rows < N * k``): the ``ok`` mask, applied before the sort."""
+    g = jnp.where(plan["valid"], g, 0)
+    if g.shape[0] < plan["order"].shape[0]:
+        g = jnp.pad(g, (0, plan["order"].shape[0] - g.shape[0]))
+    return _sorted_by(plan["order"], g).reshape(plan["ok"].shape), None
 
 
 _spread.defvjp(lambda x, plan: (_spread(x, plan), plan),
                lambda plan, g: (_collect(g, plan), None))
 _collect.defvjp(lambda rows, plan: (_collect(rows, plan), plan),
                 lambda plan, g: (_spread(g, plan), None))
-_row_weights.defvjp(
-    lambda w, plan: (_row_weights(w, plan), plan),
-    lambda plan, g: (_take(g, plan["slot"], plan["ok"]), None))
+_row_weights.defvjp(lambda w, plan: (_row_weights(w, plan), plan),
+                    _row_weights_bwd)
 
 
 def _sort_assignments(idx, first, held):
     """idx (N, k): the experts each token chose. Returns the assignments'
     order by held expert (those of other chips' experts last), its inverse,
-    and how many each held expert took."""
+    and how many each held expert took: a count over (N k, held), not a
+    search of the sorted keys."""
     local = idx.reshape(-1) - first
     key = jnp.where((local >= 0) & (local < held), local, held)
     order = jnp.argsort(key, stable=True).astype(jnp.int32)
     inv = jnp.argsort(order).astype(jnp.int32)
-    ends = jnp.searchsorted(key[order], jnp.arange(held + 1, dtype=key.dtype))
-    sizes = jnp.diff(ends).astype(jnp.int32)
+    sizes = jnp.sum(key[:, None] == jnp.arange(held, dtype=key.dtype),
+                    axis=0, dtype=jnp.int32)
     return order, inv, sizes
 
 
@@ -177,18 +209,19 @@ def _plan(order, inv, total, rows, top_k):
     ``_row_weights`` gives it weight 0, and that zero multiplies the down
     product's input and every gradient that returns through it, so the
     down product's result, dgate, dup, both dX products and all three dW
-    are zero on such rows whatever ``_spread`` wrote there, and
-    ``_collect`` and ``_row_weights``' backward read such rows only through
-    slots that are not ``ok``. (It holds a real token's row through the
-    last held expert's matrices: no likelier to overflow, and so to turn
-    its zero weight into a NaN, than a row that holds an assignment.)
-    A smaller buffer (``experts_held < top_k``) keeps the mask."""
+    are zero on such rows whatever ``_spread`` wrote there, ``_collect``
+    reads such rows only through slots that are not ``ok``, and
+    ``_row_weights``' backward zeroes them before it sorts back. (It holds
+    a real token's row through the last held expert's matrices: no
+    likelier to overflow, and so to turn its zero weight into a NaN, than
+    a row that holds an assignment.) A smaller buffer (``experts_held <
+    top_k``) keeps the mask."""
     valid = jnp.arange(rows, dtype=jnp.int32) < total
-    inv = inv.reshape(-1, top_k)
-    return {"tok": order[:rows] // top_k, "order": order[:rows],
+    return {"tok": order[:rows] // top_k, "order": order, "inv": inv,
             "valid": valid,
             "spread_mask": None if rows == inv.size else valid,
-            "slot": jnp.minimum(inv, rows - 1), "ok": inv < total}
+            "slot": jnp.minimum(inv, rows - 1).reshape(-1, top_k),
+            "ok": (inv < total).reshape(-1, top_k)}
 
 
 def product_path(rows, d, f, dtype):
@@ -246,19 +279,33 @@ def route(router_data, router_weight, top_k, norm_topk, bias=None,
     hp = jax.lax.Precision.HIGHEST
     logits = jnp.einsum("nd,ed->ne", router_data.astype(jnp.float32),
                         router_weight.astype(jnp.float32), precision=hp)
+    stop = jax.lax.stop_gradient
     if bias is not None:
         scores = jax.nn.sigmoid(logits)
-        _, idx = jax.lax.top_k(
-            scores + jax.lax.stop_gradient(bias.astype(jnp.float32)), top_k)
-        w = jnp.take_along_axis(scores, idx, 1)
+        _, idx = jax.lax.top_k(stop(scores + bias.astype(jnp.float32)), top_k)
+        w = _picked(scores, idx)
         if norm_topk:
             w = w / (jnp.sum(w, axis=-1, keepdims=True) + norm_eps)
         return w * scale, idx
+    _, idx = jax.lax.top_k(stop(logits), top_k)
     if norm_topk:
-        top, idx = jax.lax.top_k(logits, top_k)
-        return jax.nn.softmax(top, axis=-1), idx
-    _, idx = jax.lax.top_k(logits, top_k)
-    return jnp.take_along_axis(jax.nn.softmax(logits, axis=-1), idx, 1), idx
+        return jax.nn.softmax(_picked(logits, idx), axis=-1), idx
+    return _picked(jax.nn.softmax(logits, axis=-1), idx), idx
+
+
+def _picked(values, idx):
+    """``values`` (N, E) float32 at the experts ``idx`` (N, k) chose: a
+    select over (N, k, E) and a sum over E in float32, exact (one term is
+    not zero), whose backward is elementwise too. ``take_along_axis`` (and
+    ``top_k``'s own values) gather one number a row and scatter it back
+    into (N, E) in the backward; a one-hot product would round to
+    bfloat16 on the MXU at the default precision. The barrier keeps the
+    picks a result of their own, as a gather's were: XLA otherwise merges
+    a later sum over k (``norm_topk``) into this sum over E, one reduction
+    over (k, E) that adds the chosen scores in another order."""
+    hit = idx[:, :, None] == jnp.arange(values.shape[-1], dtype=idx.dtype)
+    return jax.lax.optimization_barrier(
+        jnp.sum(jnp.where(hit, values[:, None, :], 0), axis=-1))
 
 
 def _aux_names(attrs):

@@ -1,11 +1,13 @@
 #!/usr/bin/env python3
 """A builder's probe, no verdict: the expert layer's moves between token
 order and sorted-row order, each alone under ``jax.jit``, at the shapes of
-the two sparse benchmark cells (``smallthinker_train_8k``: N = 8192 tokens,
+the sparse benchmark cells (``smallthinker_train_8k``: N = 8192 tokens,
 k = 6, d = 2560, R = 49152 rows; ``lfm2_train_8k``: N = 16384, k = 4,
-d = 2048, R = 65536), bfloat16.
+d = 2048, R = 65536; ``glm_flash_train_4k``: N = 4096, k = 4, d = 2048,
+R = 16384), bfloat16.
 
-    chiprun -- python tools/bench_expert_moves.py
+    python tools/bench_expert_moves.py [--shapes ...] \
+        [--cases bookkeeping]  # on one v5e chip
 
 What it separates: the layer's fast gather (``_spread``: a table of N rows,
 a one-dimensional index, a two-dimensional result) and its slow one (the
@@ -25,6 +27,13 @@ table cut into eight column pieces that each fit: slower than the one
 gather from HBM (PERF.md, Findings, PR 36). A gather alone reads 0.8 ms
 longer than the same gather inside the step. One line of JSON a reading:
 ms a call, GB/s over the bytes it must move, the HBM floor of those bytes.
+
+The two ``bookkeeping`` readings time what moves ONE number a row, forward
+and backward, alone: the router (the cell's route), the sort by expert,
+the group sizes and the row weights, in the layer's form (sorts and
+selects) and in the gather-based form it replaced (the reference kept in
+``tests/test_expert_bookkeeping.py``), with how many gathers and scatters
+each compiled program holds and how far the two forms' results lie apart.
 """
 import argparse
 import json
@@ -32,8 +41,9 @@ import os
 import re
 import sys
 
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
-sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+sys.path.insert(0, ROOT)
 
 from bench_grouped_matmul import timed  # noqa: E402
 
@@ -42,7 +52,14 @@ HBM_BYTES_PER_S = 819e9  # one v5e (benchmark/lib/peaks.json)
 # (tokens N, top_k, d, experts held, experts)
 SHAPES = {"smallthinker_train_8k": (8192, 6, 2560, 16, 64),
           "lfm2_train_8k": (16384, 4, 2048, 8, 64),
+          "glm_flash_train_4k": (4096, 4, 2048, 8, 64),
           "tiny": (64, 3, 128, 4, 8)}  # a rehearsal on the CPU: no time
+# each cell's router: moe.route's keywords, and whether it takes a bias
+ROUTES = {"smallthinker_train_8k": ({"norm_topk": True}, False),
+          "lfm2_train_8k": ({"norm_topk": True, "norm_eps": 1e-6}, True),
+          "glm_flash_train_4k": ({"norm_topk": True, "norm_eps": 1e-20,
+                                  "scale": 1.8}, True),
+          "tiny": ({"norm_topk": True, "norm_eps": 1e-6}, True)}
 
 
 def plan_of(n, k, held, experts, rng):
@@ -58,6 +75,56 @@ def plan_of(n, k, held, experts, rng):
                       .astype(np.int32))
     order, inv, sizes = moe._sort_assignments(idx, 0, held)
     return moe._plan(order, inv, jnp.sum(sizes), n * k, k)
+
+
+def bookkeeping(forms, k, held, rows, route_kw):
+    """Router, sort, group sizes and row weights, forward and backward, in
+    ``forms`` (``moe`` or the gather-based reference): a function of the
+    router's input and matrix, the selection bias and a cotangent of the
+    row weights."""
+    import jax
+    import jax.numpy as jnp
+    from mxnet_tpu.ops import moe
+
+    def f(router_data, router_weight, bias, ct):
+        def loss(router_data, router_weight):
+            w, idx = forms.route(router_data, router_weight, k, bias=bias,
+                                 **route_kw)
+            order, inv, sizes = forms._sort_assignments(idx, 0, held)
+            plan = moe._plan(order, inv, jnp.sum(sizes), rows, k)
+            return jnp.sum(forms._row_weights(w, plan) * ct), sizes
+        return jax.value_and_grad(loss, (0, 1), has_aux=True)(
+            router_data, router_weight)
+    return f
+
+
+def bookkeeping_cases(n, k, d, held, experts, rng, route):
+    """The bookkeeping in the layer's form and in the reference's."""
+    import types
+
+    import jax.numpy as jnp
+    import numpy as np
+    from mxnet_tpu.ops import moe
+
+    sys.path.insert(0, os.path.join(ROOT, "tests"))
+    import test_expert_bookkeeping as reference
+
+    gathered = types.SimpleNamespace(
+        route=reference.route_gathered,
+        _sort_assignments=reference.sort_gathered,
+        _row_weights=reference.row_weights_gathered)
+    route_kw, biased = route
+    rows = n * min(k, held)
+    args = (jnp.asarray(rng.standard_normal((n, d), np.float32),
+                        jnp.bfloat16),
+            jnp.asarray(0.02 * rng.standard_normal((experts, d), np.float32)),
+            jnp.asarray(0.02 * rng.standard_normal(experts, np.float32))
+            if biased else None,
+            jnp.asarray(rng.standard_normal(rows, np.float32)))
+    return [("bookkeeping, gathers and scatters (the reference)",
+             bookkeeping(gathered, k, held, rows, route_kw), args, None),
+            ("bookkeeping, sorts and selects (the layer's)",
+             bookkeeping(moe, k, held, rows, route_kw), args, None)]
 
 
 def cases(n, k, d, held, experts, rng):
@@ -162,8 +229,11 @@ def tables_in_vmem(text):
 
 def main(argv=None):
     ap = argparse.ArgumentParser()
-    ap.add_argument("--shapes", default="smallthinker_train_8k,lfm2_train_8k",
+    ap.add_argument("--shapes", default="smallthinker_train_8k,lfm2_train_8k,"
+                    "glm_flash_train_4k",
                     help="comma-separated, of: " + ", ".join(SHAPES))
+    ap.add_argument("--cases", default="",
+                    help="only the readings whose name holds this")
     ap.add_argument("--reps", type=int, default=20)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
@@ -173,23 +243,39 @@ def main(argv=None):
     print(json.dumps({"device": jax.devices()[0].device_kind}))
     for shape in args.shapes.split(","):
         n, k, d, held, experts = SHAPES[shape]
-        results = {}
-        for what, f, f_args, nbytes in cases(
-                n, k, d, held, experts, np.random.default_rng(args.seed)):
+        results = {"combine": [], "bookkeeping": []}
+        rng = np.random.default_rng(args.seed)
+        for what, f, f_args, nbytes in (
+                cases(n, k, d, held, experts, rng)
+                + bookkeeping_cases(n, k, d, held, experts, rng,
+                                    ROUTES[shape])):
+            if args.cases not in what:
+                continue
             f = jax.jit(f)
             ms, out = timed(f, f_args, args.reps)
-            if what.startswith("combine"):
-                results[what] = np.asarray(out, np.float32)
-            print(json.dumps({
-                "shape": shape, "N": n, "k": k, "d": d, "what": what,
-                "ms": round(ms, 4),
-                "GB_per_s": round(nbytes / ms / 1e6, 1),
-                "hbm_floor_ms": round(1e3 * nbytes / HBM_BYTES_PER_S, 4),
-                "gathers_from_vmem": tables_in_vmem(
-                    f.lower(*f_args).compile().as_text())}), flush=True)
-        old, new = list(results.values())[:2]
-        print(json.dumps({"shape": shape, "combines_differ_by": float(
-            np.abs(old - new).max())}), flush=True)
+            for kind, got in results.items():
+                if what.startswith(kind):
+                    got.append([np.asarray(a, np.float32) for a in
+                                jax.tree_util.tree_leaves(out)])
+            text = f.lower(*f_args).compile().as_text()
+            line = {"shape": shape, "N": n, "k": k, "d": d, "what": what,
+                    "ms": round(ms, 4), "gathers_from_vmem":
+                    tables_in_vmem(text)}
+            if nbytes is None:
+                line["gathers_and_scatters"] = len(re.findall(
+                    r" (?:gather|scatter)\(", text))
+            else:
+                line.update({"GB_per_s": round(nbytes / ms / 1e6, 1),
+                             "hbm_floor_ms": round(
+                                 1e3 * nbytes / HBM_BYTES_PER_S, 4)})
+            print(json.dumps(line), flush=True)
+        for kind, got in results.items():
+            if len(got) >= 2:
+                differ = [float(np.abs(a - b).max())
+                          for a, b in zip(got[0], got[1])]
+                print(json.dumps({"shape": shape,
+                                  kind + "_forms_differ_by": differ}),
+                      flush=True)
 
 
 if __name__ == "__main__":

@@ -15,7 +15,8 @@ PERF.md's section 5 is this table; it needs no dump and no cold cache.
 ``--root``: another checkout's benchmark and program (a ``git archive``;
 one from before the record prints the result line and no table). Also
 printed: what the record's first read cost, and the bytes the compiled
-program wants beside what the device gives.
+program wants beside what the device gives. ``--out`` keeps every
+operation's ms a step, group, node, result type and opcode.
 """
 import argparse
 import collections
@@ -73,8 +74,11 @@ def main(argv=None):
     print("layers: %s" % json.dumps(rec["layers"]))
     total, where = programs.group_seconds(kept["op_seconds"], rec)
     node_of = {op["name"]: op["node"] for op in rec["ops"]}
+    opcode_of = {op["name"]: op["opcode"] for op in rec["ops"]}
     ms = {own_names.own_name(line): 1e3 * s / steps
           for line, s in kept["op_seconds"].items()}
+    types = {own_names.own_name(line): result_type(line)
+             for line in kept["op_seconds"]}
     grouped = collections.defaultdict(list)
     for name, group in where.items():
         grouped[group].append((ms[name], name))
@@ -101,8 +105,21 @@ def main(argv=None):
                        "lines": [line[:1500] for line, _ in sorted(
                            kept["op_seconds"].items(),
                            key=lambda kv: -kv[1])[:40]],
-                       "ops": {n: [v, where[n], node_of.get(n)]
+                       # ms a step, group, graph node, result type and
+                       # opcode (a fusion's: its heaviest instruction's)
+                       "ops": {n: [v, where[n], node_of.get(n), types[n],
+                                   opcode_of.get(n)]
                                for n, v in ms.items()}}, f)
+
+
+def result_type(line):
+    """The result type an operation's line gives (``f32[49152]{0}``, a
+    tuple's in its parentheses), what stands between `` = `` and the
+    opcode."""
+    rest = line.split(" = ", 1)[-1]
+    if rest.startswith("("):
+        return rest[:rest.find(")") + 1]
+    return rest.split(" ", 1)[0]
 
 
 if __name__ == "__main__":
