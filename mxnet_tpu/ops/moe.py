@@ -33,6 +33,17 @@ with no option to choose it:
   same products, 45-52%, beside transposed copies of the matrices); its
   transposes are autodiff's.
 
+The gate and the up product read the same rows, so the rows' gradient is
+the SUM of their two dX. ``_gate_up`` makes the two one differentiable
+pair, and on the kernel path the up product's dX is a ``gmm`` that takes
+the gate product's dX as its addend, adds it in float32 to its own rows and
+stores the sum once: no add over ``(rows, d)`` of its own (1.15-1.22 ms a
+layer at the expert cells' shapes; PERF.md, section 7, item 13). Where the
+addend's tile does not fit VMEM beside the dX kernel's blocks
+(``dx_sum_path``, from the shapes alone; the record says ``dx_sum``), and
+on the ``ragged_dot`` path, the two dX are added after both, as autodiff
+did.
+
 Shapes are static, so the sorted rows live in a buffer that holds
 the worst case (every token's every choice held here), and the products
 walk ALL of it whatever the routing: the last held expert's group is
@@ -62,8 +73,8 @@ a gather's own time is where its TABLE lies (PERF.md, Findings, PR 36): one
 of N rows (42-67 MB) XLA keeps in VMEM, and the gather writes at the HBM's
 bandwidth (0.39 ms for 49152 x 2560 or 65536 x 2048 rows); one of R rows
 (251-268 MB) stays in HBM and is read at 33-41 ns a row (2.0-2.2 ms),
-whatever the index's shape or order. Around the products the backward is
-autodiff's: a ``custom_vjp`` that recomputed the forward from the layer's
+whatever the index's shape or order. Around the products (and the pair
+above) the backward is autodiff's: a ``custom_vjp`` that recomputed the forward from the layer's
 inputs compiled to the same program, XLA merging the second forward with
 the first.
 
@@ -88,6 +99,8 @@ writes there immaterial, and its ``select`` pass over ``(rows, d)`` is not
 made (``_plan``).
 """
 from __future__ import annotations
+
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -237,27 +250,91 @@ def product_path(rows, d, f, dtype):
     return "ragged_dot"
 
 
+def dx_sum_path(rows, d, f, dtype):
+    """Where a layer of these shapes sums the dX of its gate and up
+    products, which read the same rows: inside the up product's kernel,
+    the gate's dX its addend (``"kernel"``), on the Pallas path where the
+    dX kernel's column blocks fit VMEM beside the addend's tile; by an add
+    after both (``"add"``) elsewhere. From the shapes alone, as
+    ``product_path``."""
+    from .pallas import grouped_matmul
+    if product_path(rows, d, f, dtype) == "pallas" and grouped_matmul.fits(
+            rows, d, f, jnp.dtype(dtype).itemsize, addend=True):
+        return "kernel"
+    return "add"
+
+
 def _ragged(x, w, sizes):
     """x (R, in) by w (E, out, in), rows grouped by ``sizes``: (R, out)."""
     return jax.lax.ragged_dot(x, jnp.swapaxes(w, 1, 2), sizes,
                               preferred_element_type=x.dtype)
 
 
-def _held_part(rows, top_k, act, grouped, x, w, wg, wu, wd, order, inv,
+def _grouped(path):
+    """x (R, in) by w (E, out, in) grouped by ``sizes`` on ``path``, a
+    function of (x, w, sizes) with its backward."""
+    if path == "pallas":
+        from .pallas.grouped_matmul import grouped_matmul
+        return grouped_matmul
+    return _ragged
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
+def _gate_up(xs, wg, wu, sizes, path, dx_sum):
+    """The gate and the up product of the same rows ``xs``: (g, u), one
+    differentiable pair, so that their two dX are summed where ``dx_sum``
+    says (``dx_sum_path``)."""
+    product = _grouped(path)
+    return product(xs, wg, sizes), product(xs, wu, sizes)
+
+
+def _gate_up_fwd(xs, wg, wu, sizes, path, dx_sum):
+    return _gate_up(xs, wg, wu, sizes, path, dx_sum), (xs, wg, wu, sizes)
+
+
+def _gate_up_bwd(path, dx_sum, res, cts):
+    """dX of both products summed, dW of each. On the Pallas path ``gmm``
+    reads each matrix the other way and ``tgmm`` gives its dW; where
+    ``dx_sum`` is "kernel" the up product's dX takes the gate's as its
+    addend and stores the sum once, else the two are added after both. The
+    ``ragged_dot`` path is autodiff's transposes of the pair and the add
+    of its two dX."""
+    xs, wg, wu, sizes = res
+    dg, du = cts
+    if path != "pallas":
+        _, vjp = jax.vjp(lambda xs, wg, wu: (_ragged(xs, wg, sizes),
+                                             _ragged(xs, wu, sizes)),
+                         xs, wg, wu)
+        return vjp(cts) + (None,)
+    from .pallas.grouped_matmul import gmm, tgmm
+    dx = gmm(dg, wg, sizes, transposed=False)
+    if dx_sum == "kernel":
+        dx = gmm(du, wu, sizes, transposed=False, addend=dx)
+    else:
+        dx = dx + gmm(du, wu, sizes, transposed=False)
+    return (dx, tgmm(dg, xs, sizes, out_dtype=wg.dtype),
+            tgmm(du, xs, sizes, out_dtype=wu.dtype), None)
+
+
+_gate_up.defvjp(_gate_up_fwd, _gate_up_bwd)
+
+
+def _held_part(rows, top_k, act, path, dx_sum, x, w, wg, wu, wd, order, inv,
                sizes):
     """The held experts' part of the layer through a buffer of ``rows``
-    sorted rows, all of which the products (``grouped``) walk."""
+    sorted rows, all of which the products (on ``path``) walk."""
     total = jnp.sum(sizes)
     plan = _plan(order, inv, total, rows, top_k)
     walked = sizes.at[-1].add(rows - total)  # the empty rows: zeros
     xs = _spread(x, plan)
-    a = act(grouped(xs, wg, walked)) * grouped(xs, wu, walked)
+    g, u = _gate_up(xs, wg, wu, walked, path, dx_sum)
+    a = act(g) * u
     # a row's routing weight scales the down product's INPUT (f wide),
     # not its result (d wide): the same product, a third of the elements
     # to scale, and the backward keeps no (rows, d) result for the
     # weights' gradient
     a = (a * _row_weights(w, plan)[:, None]).astype(a.dtype)
-    return _collect(grouped(a, wd, walked), plan)
+    return _collect(_grouped(path)(a, wd, walked), plan)
 
 
 ROUTES = ("softmax", "sigmoid_bias")
@@ -373,15 +450,14 @@ def _expert_ffn(attrs, inputs, aux, ctx):
                    float(attrs["scale"]))
     order, inv, sizes = _sort_assignments(idx, first, held)
     rows, expected = buffer_rows(x.shape[0], top_k, held, experts)
-    path = product_path(rows, d, gate_weight.shape[1], x.dtype)
+    f = gate_weight.shape[1]
+    path = product_path(rows, d, f, x.dtype)
+    dx_sum = dx_sum_path(rows, d, f, x.dtype)
     note_built({"op": "ExpertFFN", "experts_held": held, "top_k": top_k,
                 "buffer_rows": rows, "expected_rows": expected,
-                "route": attrs["route"], "path": path})
-    grouped = _ragged
-    if path == "pallas":
-        from .pallas.grouped_matmul import grouped_matmul as grouped
-    y = _held_part(rows, top_k, act, grouped, x, w, gate_weight, up_weight,
-                   down_weight, order, inv, sizes)
+                "route": attrs["route"], "path": path, "dx_sum": dx_sum})
+    y = _held_part(rows, top_k, act, path, dx_sum, x, w, gate_weight,
+                   up_weight, down_weight, order, inv, sizes)
     counts = jax.lax.stop_gradient(sizes.astype(jnp.float32))
     return (y.reshape(data.shape), counts), ()
 

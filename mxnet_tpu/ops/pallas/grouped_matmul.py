@@ -47,6 +47,18 @@ second walk over the rows), ``tgmm`` halves its output block
 ``fits`` counts the bytes from the shapes and the caller takes
 ``jax.lax.ragged_dot`` where neither fits: no other blocking has been
 timed against it.
+
+``gmm`` takes an ``addend`` (R, n): its result is then ``addend + x w``,
+the addend's tile added in float32 to a visit's own rows and the sum
+rounded once. The expert layer's up product writes its dX so, the gate
+product's dX its addend (``ops/moe.py`` ``_gate_up``), and the sum of the
+two is not a pass over (R, d) of its own. The addend is one more row tile
+in VMEM, twice (the pipeline's two buffers), which the count holds
+(``gmm_vmem_bytes``), so it may take the columns in two blocks where the
+product alone stands whole (``smallthinker_train_8k``'s up dX: 14.4 MB
+alone, 17.0 with it, 10.5 in halves). Where not even ``MAX_COLUMN_BLOCKS``
+fit it (``fits`` with ``addend``), the caller sums the two dX after them,
+as autodiff did.
 """
 from __future__ import annotations
 
@@ -126,15 +138,18 @@ def _mine(tile_ref, lo_ref, hi_ref, v, tm):
     return (r >= lo_ref[v]) & (r < hi_ref[v])
 
 
-def _gmm_kernel(group_ref, tile_ref, lo_ref, hi_ref, x_ref, w_ref, o_ref, *,
+def _gmm_kernel(group_ref, tile_ref, lo_ref, hi_ref, x_ref, w_ref, *refs,
                 chunk, transposed, visit_axis=0):
     del group_ref  # the index maps' only
+    *addend_ref, o_ref = refs
     tm, n = o_ref.shape
     mine = _mine(tile_ref, lo_ref, hi_ref, pl.program_id(visit_axis), tm)
     for j in range(0, n, chunk):
         w = w_ref[j:j + chunk, :] if transposed else w_ref[:, j:j + chunk]
         y = jax.lax.dot_general(x_ref[...], w, _NT if transposed else _NN,
                                 preferred_element_type=jnp.float32)
+        if addend_ref:
+            y = y + addend_ref[0][:, j:j + chunk].astype(jnp.float32)
         # the rows of other groups keep what their own visit stored, or
         # will store: every row is some visit's
         o_ref[:, j:j + chunk] = jnp.where(
@@ -142,25 +157,27 @@ def _gmm_kernel(group_ref, tile_ref, lo_ref, hi_ref, x_ref, w_ref, o_ref, *,
         ).astype(o_ref.dtype)
 
 
-def gmm_vmem_bytes(tm, k, n, itemsize):
+def gmm_vmem_bytes(tm, k, n, itemsize, addend=False):
     """What ``gmm`` holds in VMEM, from the shapes alone: the matrix, a
     row tile and a result tile twice each (the pipeline's two buffers),
-    and the kernel's temporaries. ``n``: the result's columns computed a
-    grid step, the whole width or a column block of it."""
-    return 2 * (k * n + tm * k + tm * n) * itemsize + _TEMPORARIES
+    the addend's tile twice where it takes one, and the kernel's
+    temporaries. ``n``: the result's columns computed a grid step, the
+    whole width or a column block of it."""
+    return (2 * (k * n + tm * k + tm * n * (2 if addend else 1)) * itemsize
+            + _TEMPORARIES)
 
 
-def gmm_column_blocks(tm, k, n, itemsize):
+def gmm_column_blocks(tm, k, n, itemsize, addend=False):
     """In how many column blocks ``gmm`` computes a result of ``n``
     columns so that a block of the matrix fits VMEM twice beside the
-    tiles: 1 wherever the whole matrix does (the kernel PR 34 measured),
-    else ``MAX_COLUMN_BLOCKS`` = 2 halves of whole lanes (PR 35 measured)
-    where those do; 0 where they do not. A block more is one more pass
-    over the rows, for a matrix that still crosses HBM once a group and
-    block."""
+    tiles (with an ``addend``'s among them): 1 wherever the whole matrix
+    does (the whole-matrix kernel measured), else ``MAX_COLUMN_BLOCKS`` =
+    2 halves of whole lanes (measured too) where those do; 0 where they
+    do not. A block more is one more pass over the rows, for a matrix that
+    still crosses HBM once a group and block."""
     for parts in range(1, MAX_COLUMN_BLOCKS + 1):
         if n % (parts * _LANES) == 0 and gmm_vmem_bytes(
-                tm, k, n // parts, itemsize) <= _SCOPED_VMEM:
+                tm, k, n // parts, itemsize, addend) <= _SCOPED_VMEM:
             return parts
     return 0
 
@@ -174,22 +191,28 @@ def _params(interpret, semantics):
 
 @functools.partial(jax.jit, static_argnames=("transposed", "tm", "chunk",
                                              "interpret"))
-def gmm(x, w, sizes, transposed=True, tm=ROWS, chunk=GMM_CHUNK,
+def gmm(x, w, sizes, transposed=True, addend=None, tm=ROWS, chunk=GMM_CHUNK,
         interpret=False):
     """x (R, k), rows grouped by ``sizes`` (E,), by w (E, n, k)
     (``transposed``, the default: contracted over its last dim) or (E, k,
-    n): (R, n) in x's dtype, accumulated in float32."""
+    n): (R, n) in x's dtype, accumulated in float32, plus ``addend`` (R,
+    n) where given (added in float32, the sum rounded once)."""
     rows, k = x.shape
     n = w.shape[1] if transposed else w.shape[2]
     if w.shape[2 if transposed else 1] != k or rows % tm:
         raise ValueError("gmm: x %s by w %s (transposed=%s), row tile %d"
                          % (x.shape, w.shape, transposed, tm))
+    if addend is not None and (addend.shape != (rows, n)
+                               or addend.dtype != x.dtype):
+        raise ValueError("gmm: addend %s %s for a result (%d, %d) %s"
+                         % (addend.shape, addend.dtype, rows, n, x.dtype))
+    added = () if addend is None else (addend,)
     visits = group_visits(sizes, rows, tm)
     e = w.shape[0]
     # a matrix too large to stand in VMEM twice goes in `parts` column
     # blocks, an outer grid dimension, each a walk over all the visits; one
     # block is the grid of visits alone, as PR 34 built it
-    parts = gmm_column_blocks(tm, k, n, x.dtype.itemsize) or 1
+    parts = gmm_column_blocks(tm, k, n, x.dtype.itemsize, bool(added)) or 1
     blocked = parts > 1
     nc = n // parts
     chunk = min(chunk, nc)
@@ -222,19 +245,19 @@ def gmm(x, w, sizes, transposed=True, tm=ROWS, chunk=GMM_CHUNK,
                 pl.BlockSpec((tm, k), rows_of),
                 pl.BlockSpec((None, nc, k) if transposed else (None, k, nc),
                              matrix_of),
-            ],
+            ] + [pl.BlockSpec((tm, nc), result_of)] * len(added),
             out_specs=pl.BlockSpec((tm, nc), result_of),
         ),
         out_shape=jax.ShapeDtypeStruct((rows, n), x.dtype),
         cost_estimate=pl.CostEstimate(
             flops=2 * rows * k * n, transcendentals=0,
-            bytes_accessed=(x.size * parts + w.size + rows * n)
-            * x.dtype.itemsize),
+            bytes_accessed=(x.size * parts + w.size
+                            + rows * n * (1 + len(added))) * x.dtype.itemsize),
         name="expert_gmm",
         metadata={"ragged_dot_tiling": "%d,%d,%d" % (tm, k, chunk)},
         interpret=interpret,
         **_params(interpret, ("arbitrary",) * (1 + blocked)),
-    )(*visits, x, w)
+    )(*visits, x, w, *added)
 
 
 def _tgmm_kernel(group_ref, tile_ref, lo_ref, hi_ref, a_ref, b_ref, o_ref,
@@ -347,14 +370,15 @@ def tgmm(a, b, sizes, tm=ROWS, wide=None, out_dtype=None,
     )(*visits, a, b)
 
 
-def fits(rows, k, n, itemsize):
+def fits(rows, k, n, itemsize, addend=False):
     """Whether the kernels of a product of (rows, k) rows by (E, n, k)
     matrices (forward, dX, dW) take their tiles inside a kernel's VMEM:
     the shape half of the gate (``ops/moe.py`` asks ``on_tpu`` for the
-    other). Widths are whole lanes, rows whole tiles."""
+    other). Widths are whole lanes, rows whole tiles. ``addend``: dX also
+    takes an (rows, k) addend (``gmm``'s)."""
     return (k % _LANES == 0 and n % _LANES == 0 and rows % ROWS == 0
             and gmm_column_blocks(ROWS, k, n, itemsize) > 0    # forward
-            and gmm_column_blocks(ROWS, n, k, itemsize) > 0    # dX
+            and gmm_column_blocks(ROWS, n, k, itemsize, addend) > 0  # dX
             and tgmm_wide(ROWS, n, k, itemsize, itemsize) > 0)
 
 

@@ -1,9 +1,11 @@
 """The expert layer's grouped products as the repo's own Pallas kernels
-(``ops/pallas/grouped_matmul.py``), interpreted here: ``gmm`` both ways and
-``tgmm`` against a plain loop over the groups, the visits a set of group
-sizes is cut into, ``ExpertFFN`` down the kernel path against the
-``ragged_dot`` path, and the VMEM count at the benchmark cell's shapes. The
-kernels compile for a described v5e in ``tests/test_tpu_compile.py``."""
+(``ops/pallas/grouped_matmul.py``), interpreted here: ``gmm`` both ways,
+with and without an addend, and ``tgmm`` against a plain loop over the
+groups, the visits a set of group sizes is cut into, the gate and up
+products as one pair whose two dX are summed in the kernel, ``ExpertFFN``
+down the kernel path against the ``ragged_dot`` path, and the VMEM count at
+the benchmark cells' shapes. The kernels compile for a described v5e in
+``tests/test_tpu_compile.py``."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -112,6 +114,94 @@ def test_gmm_in_column_blocks_against_a_loop(case, transposed, monkeypatch):
                                atol=1e-4)
 
 
+@pytest.mark.parametrize("blocks", [1, 2])
+@pytest.mark.parametrize("transposed", [True, False])
+@pytest.mark.parametrize("case", sorted(SIZES))
+def test_gmm_with_an_addend_against_a_loop(case, transposed, blocks,
+                                           monkeypatch):
+    """``addend + x w``: every row gets its addend once, from the visit of
+    its own group, whole or in two column blocks (a VMEM shrunk until the
+    addend's tile no longer fits beside the whole matrix, as at
+    ``smallthinker_train_8k``'s up dX)."""
+    sizes, k, n = SIZES[case], 128, 512
+    if blocks == 2:
+        monkeypatch.setattr(gm, "_TEMPORARIES", 0)
+        monkeypatch.setattr(gm, "_SCOPED_VMEM", gm.gmm_vmem_bytes(
+            TM, k, n, 4))
+    assert gm.gmm_column_blocks(TM, k, n, 4) == 1
+    assert gm.gmm_column_blocks(TM, k, n, 4, addend=True) == blocks
+    x = _rows((ROWS, k), "float32", 13)
+    w = _rows((4, n, k) if transposed else (4, k, n), "float32", 14)
+    addend = _rows((ROWS, n), "float32", 15)
+    with jax.default_matmul_precision("highest"):
+        got = gm.gmm(jnp.asarray(x), jnp.asarray(w),
+                     jnp.asarray(sizes, jnp.int32), transposed=transposed,
+                     addend=jnp.asarray(addend), tm=TM, chunk=128,
+                     interpret=True)
+    np.testing.assert_allclose(
+        got, addend + _loop_gmm(x, w, sizes, transposed), atol=1e-4)
+
+
+def test_gmm_rounds_the_sum_with_its_addend_once():
+    """In bfloat16 the addend is added to the float32 accumulator and the
+    sum rounded once: the exact sum rounded once on all but a few elements
+    (where the accumulation order moves a near-tie), which rounding the
+    product first and then the sum gives on about three quarters. An
+    addend of another dtype than the rows is refused (the VMEM count
+    reads the rows' itemsize)."""
+    sizes = SIZES["an_empty_group_and_a_cut_tile"]
+    x = _rows((ROWS, 128), "bfloat16", 16)
+    w = _rows((4, 128, 256), "bfloat16", 17)
+    addend = _rows((ROWS, 256), "bfloat16", 18)
+    with jax.default_matmul_precision("highest"):
+        got = gm.gmm(jnp.asarray(x), jnp.asarray(w),
+                     jnp.asarray(sizes, jnp.int32), transposed=False,
+                     addend=jnp.asarray(addend), tm=TM, chunk=128,
+                     interpret=True)
+    product = _loop_gmm(x, w, sizes, False)
+
+    def rounded(a):
+        return np.asarray(jnp.asarray(a, jnp.bfloat16), np.float32)
+
+    once = rounded(addend.astype(np.float32) + product)
+    twice = rounded(rounded(product) + addend.astype(np.float32))
+    got = np.asarray(got, np.float32)
+    assert np.mean(got == once) > 0.999 and np.mean(twice == once) < 0.9
+    np.testing.assert_allclose(got, once, rtol=2 ** -7)
+    with pytest.raises(ValueError, match="addend"):
+        gm.gmm(jnp.asarray(x), jnp.asarray(w), jnp.asarray(sizes, jnp.int32),
+               transposed=False, addend=jnp.asarray(addend, jnp.float32),
+               tm=TM, interpret=True)
+
+
+@pytest.mark.parametrize("dx_sum", ["kernel", "add"])
+def test_gate_up_pair_against_the_ragged_dot_pair(dx_sum):
+    """``moe._gate_up`` on the kernels (interpreted), the two dX summed in
+    the up product's kernel or after it, against the same pair through
+    ``jax.lax.ragged_dot``: both products and the three gradients (dX of
+    both summed, dW of each)."""
+    sizes = jnp.asarray([100, 0, 220, 192], jnp.int32)  # 512 rows
+    xs = jnp.asarray(_rows((512, 128), "float32", 19))
+    wg = jnp.asarray(_rows((4, 256, 128), "float32", 20))
+    wu = jnp.asarray(_rows((4, 256, 128), "float32", 21))
+    cts = (jnp.asarray(_rows((512, 256), "float32", 22)),
+           jnp.asarray(_rows((512, 256), "float32", 23)))
+
+    def pair(path, how):
+        def f(xs, wg, wu):
+            return moe._gate_up(xs, wg, wu, sizes, path, how)
+        with jax.default_matmul_precision("highest"):
+            out, vjp = jax.vjp(f, xs, wg, wu)
+            return out + vjp(cts)
+
+    want = pair("ragged_dot", "add")
+    with pltpu.force_tpu_interpret_mode():
+        got = pair("pallas", dx_sum)
+    for a, b in zip(got, want):
+        assert a.shape == b.shape
+        np.testing.assert_allclose(a, b, atol=1e-3 * np.abs(b).max())
+
+
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
 @pytest.mark.parametrize("ka,nb", [(128, 256), (256, 128)])
 @pytest.mark.parametrize("case", sorted(SIZES))
@@ -180,7 +270,8 @@ def _expert_layer(e, held, first, top_k):
 
 
 def _built(layers):
-    return [r["path"] for r in layers if r["op"] == "ExpertFFN"]
+    return [(r["path"], r["dx_sum"]) for r in layers
+            if r["op"] == "ExpertFFN"]
 
 
 @pytest.mark.parametrize("dtype,tol", [("float32", 1e-4), ("bfloat16", 0.03)])
@@ -188,7 +279,8 @@ def test_expert_layer_down_the_kernel_path(dtype, tol, monkeypatch):
     """``ExpertFFN`` where its gate says "pallas" (the platform answered
     for the chip, the kernels interpreted) against the same layer through
     ``jax.lax.ragged_dot``: the output and all six gradients, and the
-    layer's own record says which path each trace took."""
+    layer's own record says which path each trace took and where it
+    summed the two dX products (in the kernel on the kernel path)."""
     e, held, first, top_k, tokens, d, f = 8, 4, 2, 2, 256, 128, 256
     rng = np.random.RandomState(11)
 
@@ -204,25 +296,62 @@ def test_expert_layer_down_the_kernel_path(dtype, tol, monkeypatch):
     assert rows % gm.ROWS == 0 and gm.fits(rows, d, f, jnp.dtype(dtype).itemsize)
     layer = _expert_layer(e, held, first, top_k)
 
-    def both(path):
+    def both(path, dx_sum):
         with jax.default_matmul_precision("highest"), \
                 built_layers() as built:
             (y, counts), vjp = jax.vjp(layer, *args)
             grads = vjp((ct, jnp.zeros_like(counts)))
-        assert _built(built.layers) == [path]
+        assert _built(built.layers) == [(path, dx_sum)]
         return (y,) + tuple(grads)
 
     assert moe.product_path(rows, d, f, dtype) == "ragged_dot"  # the CPU
-    want = both("ragged_dot")
+    assert moe.dx_sum_path(rows, d, f, dtype) == "add"
+    want = both("ragged_dot", "add")
     monkeypatch.setattr(pallas, "on_tpu", lambda: True)
     assert moe.product_path(rows, d, f, dtype) == "pallas"
+    assert moe.dx_sum_path(rows, d, f, dtype) == "kernel"
     with pltpu.force_tpu_interpret_mode():
-        got = both("pallas")
+        got = both("pallas", "kernel")
     for a, b in zip(got, want):
         assert a.dtype == b.dtype and a.shape == b.shape
         b = np.asarray(b, np.float32)
         np.testing.assert_allclose(np.asarray(a, np.float32), b,
                                    atol=tol * max(1.0, np.abs(b).max()))
+
+
+def test_expert_layer_adds_the_two_dx_where_the_addend_does_not_fit(
+        monkeypatch):
+    """The kernel path where the shapes leave no room for the addend
+    (``dx_sum_path`` "add", here forced at a small shape): both dX
+    products, then their add, the same layer to float32's rounding, and
+    the record says so."""
+    e, held, top_k, tokens, d, f = 8, 4, 2, 256, 128, 256
+    rng = np.random.RandomState(12)
+    args = tuple(jnp.asarray(rng.randn(*shape).astype(np.float32) * scale)
+                 for shape, scale in (((1, tokens, d), 1.0),
+                                      ((1, tokens, d), 1.0), ((e, d), 1.0),
+                                      ((held, f, d), 0.1),
+                                      ((held, f, d), 0.1),
+                                      ((held, d, f), 0.1)))
+    ct = jnp.asarray(rng.randn(1, tokens, d).astype(np.float32))
+    layer = _expert_layer(e, held, 0, top_k)
+
+    def grads():
+        with jax.default_matmul_precision("highest"), \
+                built_layers() as built:
+            (_, counts), vjp = jax.vjp(layer, *args)
+            out = vjp((ct, jnp.zeros_like(counts)))
+        return _built(built.layers), out
+
+    monkeypatch.setattr(pallas, "on_tpu", lambda: True)
+    with pltpu.force_tpu_interpret_mode():
+        built, want = grads()
+        assert built == [("pallas", "kernel")]
+        monkeypatch.setattr(moe, "dx_sum_path", lambda *shapes: "add")
+        built, got = grads()
+        assert built == [("pallas", "add")]
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(a, b, atol=1e-4 * np.abs(b).max())
 
 
 @pytest.mark.parametrize("rows,d,f,why", [
@@ -295,3 +424,47 @@ def test_vmem_count_at_the_second_cells_shapes():
     assert not gm.fits(rows, d, 2 * f, 2)
     assert gm.gmm_column_blocks(256, 8192, 4096, 2) == 0
     assert not gm.fits(512, 8192, 4096, 2)
+
+
+# (rows walked, d, f) of one expert layer of each expert cell: its up
+# product's dX (f wide in, d wide out) with the gate's dX as its addend
+_CELLS = {"smallthinker_train_8k": (49152, 2560, 768),
+          "lfm2_train_8k": (65536, 2048, 1536),
+          "glm_flash_train_4k": (16384, 2048, 1536)}
+
+
+@pytest.mark.parametrize("cell", sorted(_CELLS))
+def test_vmem_count_with_the_addend_at_the_cells_shapes(cell, monkeypatch):
+    """The addend's tile twice beside the dX kernel's: SmallThinker's up
+    dX, whose matrix stands whole alone (14.42 MB), goes to two column
+    blocks (17.04 MB whole, 10.49 in halves); LFM2's and GLM's stay at two
+    blocks with 1 MB more (13.11 MB). All three sum their dX in the kernel
+    on the chip."""
+    rows, d, f = _CELLS[cell]
+    limit = 16 * 2 ** 20
+    addend = 2 * 256 * d * 2  # a (256, d) bfloat16 tile, twice
+    assert gm.gmm_vmem_bytes(256, f, d, 2, addend=True) == (
+        gm.gmm_vmem_bytes(256, f, d, 2) + addend)
+    assert gm.gmm_column_blocks(256, f, d, 2, addend=True) == 2
+    assert gm.gmm_vmem_bytes(256, f, d // 2, 2, addend=True) == {
+        2560: 10_485_760, 2048: 13_107_200}[d] <= limit
+    assert gm.gmm_column_blocks(256, f, d, 2) == (1 if d == 2560 else 2)
+    assert gm.fits(rows, d, f, 2, addend=True)
+    monkeypatch.setattr(pallas, "on_tpu", lambda: True)
+    assert moe.dx_sum_path(rows, d, f, "bfloat16") == "kernel"
+
+
+@pytest.mark.parametrize("rows,d,f,path,why", [
+    (32768, 2048, 2304, "pallas", "the dX's halves fit, not beside the "
+     "addend's tile (15.99 MB alone, 17.04 with it)"),
+    (512, 100, 256, "ragged_dot", "the compiler's products"),
+])
+def test_dx_sum_where_the_addend_does_not_fit(rows, d, f, path, why,
+                                              monkeypatch):
+    monkeypatch.setattr(pallas, "on_tpu", lambda: True)
+    assert moe.product_path(rows, d, f, "bfloat16") == path, why
+    assert moe.dx_sum_path(rows, d, f, "bfloat16") == "add", why
+    if path == "pallas":
+        assert gm.fits(rows, d, f, 2) and not gm.fits(rows, d, f, 2,
+                                                      addend=True)
+        assert gm.gmm_column_blocks(256, f, d, 2, addend=True) == 0

@@ -252,12 +252,23 @@ _GROUPED.update({
 })
 
 
+# and the up product's dX with the gate product's dX as its addend
+# (``ops/moe.py`` ``_gate_up``), a (rows, d) tile more in VMEM twice:
+# SmallThinker's in two column blocks (its whole matrix beside the tiles
+# and the addend's is over the 16 MiB), LFM2's in two as without it
+_GROUPED.update({
+    "dx_up_addend": ("gmm", (49152, 768), (16, 768, 2560), False),
+    "lfm2_dx_up_addend": ("gmm", (32768, 1536), (8, 1536, 2048), False),
+})
+
+
 @pytest.mark.parametrize("name", sorted(_GROUPED))
 def test_grouped_matmul_kernels_compile_for_v5e(name, one_chip, monkeypatch):
     """Each at the cell's shape, in the 16 MiB of VMEM a kernel gets
     unasked (a block over it fails HERE), one Mosaic call named for what it
     is, its tiling under the compiler's own key; a 512-row tile beside the
-    whole matrix twice, which `fits` counts out, is refused."""
+    whole matrix twice, which `fits` counts out, is refused. A dX with an
+    addend reads it as one more operand of the same call."""
     from mxnet_tpu.ops.pallas import grouped_matmul as gm
 
     kernel, a, b, transposed = _GROUPED[name]
@@ -267,7 +278,13 @@ def test_grouped_matmul_kernels_compile_for_v5e(name, one_chip, monkeypatch):
 
     groups = b[0] if kernel == "gmm" else 16 if a[0] == 49152 else 8
     args = (sds(a), sds(b), sds((groups,), "int32"))
-    if kernel == "gmm":
+    if name.endswith("_addend"):
+        assert gm.gmm_column_blocks(256, a[1], b[2], 2, addend=True) == 2
+        args += (sds((a[0], b[2])),)
+
+        def f(x, w, s, addend):
+            return gm.gmm(x, w, s, transposed=False, addend=addend)
+    elif kernel == "gmm":
         def f(x, w, s, **kw):
             return gm.gmm(x, w, s, transposed=transposed, **kw)
     else:
@@ -376,9 +393,11 @@ def test_expert_layer_moves_are_flat_gathers(expert_layer_step):
     result, no array has the six choices on its second-minor dimension,
     the k slabs ``[6,8192,2560]`` are a view of the gather's result that
     ONE fusion a combine sums (no slab is sliced out or copied on the
-    way), and no ``select`` pass over ``[49152,2560]`` stands between a
+    way), no ``select`` pass over ``[49152,2560]`` stands between a
     gather and the product that reads it (``_spread`` without its mask;
-    the gather told its indices lie inside the table)."""
+    the gather told its indices lie inside the table), and no ``add``
+    sums the two dX products: the up product's dX kernel reads the gate
+    product's as its addend, beside its rows (``_gate_up``)."""
     text, ops, _ = expert_layer_step
     gathers = re.findall(r"= (\w+)\[([\d,]*)\]\S* gather\(", text)
     assert gathers.count(("bf16", "49152,2560")) == 4, gathers
@@ -386,9 +405,15 @@ def test_expert_layer_moves_are_flat_gathers(expert_layer_step):
     assert "8192,6,2560]" not in text
     routing = [o for o in ops if o["group"] == "expert_moves"]
     rows = [o for o in routing if o["result"] == "bf16[49152,2560]"]
-    # the four gathers and the add of the two dX products, nothing else
-    assert sorted(o["opcode"] for o in rows) == ["add"] + ["fusion"] * 4, [
+    # the four gathers, nothing else: the two dX products are summed in
+    # the kernel that writes the second
+    assert sorted(o["opcode"] for o in rows) == ["fusion"] * 4, [
         (o["name"], o["opcode"]) for o in rows]
+    summed = [o for o in ops if o["name"].startswith("expert_gmm")
+              and "bf16[49152,768]" in o["operands"]
+              and "bf16[49152,2560]" in o["operands"]]
+    assert len(summed) == 1 and summed[0]["result"] == "bf16[49152,2560]", [
+        (o["name"], o["operands"]) for o in summed]
     slabs = [o for o in ops if "bf16[6,8192,2560]" in o["operands"]
              or o["result"].startswith("bf16[6,8192,2560]")]
     assert [(o["opcode"], o["result"]) for o in slabs] == [

@@ -34,6 +34,13 @@ the group sizes and the row weights, in the layer's form (sorts and
 selects) and in the gather-based form it replaced (the reference kept in
 ``tests/test_expert_bookkeeping.py``), with how many gathers and scatters
 each compiled program holds and how far the two forms' results lie apart.
+
+The two ``dx_pair`` readings time the sum of the gate and the up product's
+dX alone (``ops/moe.py`` ``_gate_up``'s backward, the Pallas kernels:
+interpreted off the TPU): two ``gmm`` calls and an add over (R, d), and the
+second ``gmm`` with the first's result as its ``addend``, with the HBM and
+the MXU floors of each and how far the two sums lie apart relative to the
+largest (bfloat16 rounds the first sum twice, the second once).
 """
 import argparse
 import json
@@ -48,12 +55,16 @@ sys.path.insert(0, ROOT)
 from bench_grouped_matmul import timed  # noqa: E402
 
 HBM_BYTES_PER_S = 819e9  # one v5e (benchmark/lib/peaks.json)
+MXU_FLOP_PER_S = 197e12
 
 # (tokens N, top_k, d, experts held, experts)
 SHAPES = {"smallthinker_train_8k": (8192, 6, 2560, 16, 64),
           "lfm2_train_8k": (16384, 4, 2048, 8, 64),
           "glm_flash_train_4k": (4096, 4, 2048, 8, 64),
-          "tiny": (64, 3, 128, 4, 8)}  # a rehearsal on the CPU: no time
+          "tiny": (64, 4, 128, 4, 8)}  # a rehearsal on the CPU: no time
+# each cell's expert width f
+EXPERT_WIDTH = {"smallthinker_train_8k": 768, "lfm2_train_8k": 1536,
+                "glm_flash_train_4k": 1536, "tiny": 256}
 # each cell's router: moe.route's keywords, and whether it takes a bias
 ROUTES = {"smallthinker_train_8k": ({"norm_topk": True}, False),
           "lfm2_train_8k": ({"norm_topk": True, "norm_eps": 1e-6}, True),
@@ -125,6 +136,51 @@ def bookkeeping_cases(n, k, d, held, experts, rng, route):
              bookkeeping(gathered, k, held, rows, route_kw), args, None),
             ("bookkeeping, sorts and selects (the layer's)",
              bookkeeping(moe, k, held, rows, route_kw), args, None)]
+
+
+def dx_pair_cases(n, k, d, held, experts, f, rng):
+    """The two dX of the gate and up products summed: after both kernels,
+    or in the second as its addend. The group sizes are the layer's own
+    for a uniform routing, the last stretched over the empty rows."""
+    import jax.numpy as jnp
+    import numpy as np
+    from mxnet_tpu.ops import moe
+    from mxnet_tpu.ops.pallas import grouped_matmul as gm
+    from mxnet_tpu.ops.pallas import on_tpu
+
+    rows = n * min(k, held)
+    idx = np.argsort(rng.random((n, experts)), axis=1)[:, :k]
+    _, _, sizes = moe._sort_assignments(jnp.asarray(idx, jnp.int32), 0, held)
+    walked = sizes.at[-1].add(rows - jnp.sum(sizes))
+    interpret = not on_tpu()
+
+    def rand(*shape, scale=1.0):
+        return jnp.asarray(scale * rng.standard_normal(shape, np.float32),
+                           jnp.bfloat16)
+
+    def gmm(dy, w, addend=None):
+        return gm.gmm(dy, w, walked, transposed=False, addend=addend,
+                      interpret=interpret)
+
+    def added(dg, du, wg, wu):
+        return gmm(dg, wg) + gmm(du, wu)
+
+    def in_kernel(dg, du, wg, wu):
+        return gmm(du, wu, addend=gmm(dg, wg))
+
+    args = (rand(rows, f), rand(rows, f), rand(held, f, d, scale=0.02),
+            rand(held, f, d, scale=0.02))
+    blocks = (gm.gmm_column_blocks(gm.ROWS, f, d, 2),
+              gm.gmm_column_blocks(gm.ROWS, f, d, 2, addend=True))
+    row_f, row_d, matrices = 2 * f, 2 * d, 2 * 2 * held * f * d
+    flops = 2 * 2 * rows * f * d
+    return [("dx_pair, two products and an add (%d+%d column blocks)"
+             % (blocks[0], blocks[0]), added, args,
+             rows * (2 * blocks[0] * row_f + 5 * row_d) + matrices, flops),
+            ("dx_pair, the second with the first as its addend "
+             "(%d+%d column blocks)" % (blocks[0], blocks[1]), in_kernel,
+             args, rows * ((blocks[0] + blocks[1]) * row_f + 3 * row_d)
+             + matrices, flops)]
 
 
 def cases(n, k, d, held, experts, rng):
@@ -243,12 +299,14 @@ def main(argv=None):
     print(json.dumps({"device": jax.devices()[0].device_kind}))
     for shape in args.shapes.split(","):
         n, k, d, held, experts = SHAPES[shape]
-        results = {"combine": [], "bookkeeping": []}
+        results = {"combine": [], "bookkeeping": [], "dx_pair": []}
         rng = np.random.default_rng(args.seed)
-        for what, f, f_args, nbytes in (
+        for what, f, f_args, nbytes, *flops in (
                 cases(n, k, d, held, experts, rng)
                 + bookkeeping_cases(n, k, d, held, experts, rng,
-                                    ROUTES[shape])):
+                                    ROUTES[shape])
+                + dx_pair_cases(n, k, d, held, experts, EXPERT_WIDTH[shape],
+                                rng)):
             if args.cases not in what:
                 continue
             f = jax.jit(f)
@@ -268,14 +326,23 @@ def main(argv=None):
                 line.update({"GB_per_s": round(nbytes / ms / 1e6, 1),
                              "hbm_floor_ms": round(
                                  1e3 * nbytes / HBM_BYTES_PER_S, 4)})
+            if flops:
+                line["mxu_floor_ms"] = round(1e3 * flops[0] / MXU_FLOP_PER_S,
+                                             4)
             print(json.dumps(line), flush=True)
         for kind, got in results.items():
             if len(got) >= 2:
                 differ = [float(np.abs(a - b).max())
                           for a, b in zip(got[0], got[1])]
-                print(json.dumps({"shape": shape,
-                                  kind + "_forms_differ_by": differ}),
-                      flush=True)
+                line = {"shape": shape, kind + "_forms_differ_by": differ}
+                if kind == "dx_pair":
+                    # one rounding of the sum against two: within a
+                    # bfloat16 step of the largest element
+                    line["relative"] = differ[0] / float(
+                        np.abs(got[0][0]).max())
+                    line["within_bfloat16_rounding"] = (
+                        line["relative"] <= 2.0 ** -7)
+                print(json.dumps(line), flush=True)
 
 
 if __name__ == "__main__":
