@@ -3,9 +3,9 @@
 ``MXNET_COMPILE_WITNESS=1`` arms a process-wide recorder that every
 sanctioned compile surface (``predict.Predictor._compile``,
 ``quant.QuantizedPredictor._compile``, ``serving.generate.programs``,
-the executor train-step AOT path, and ``progcache.load``) reports into:
-each fresh XLA compile is recorded with (kind, key, shapes, stack), each
-persistent-progcache disk load with (kind, key). After
+the train step's AUTO-layout learning compile, and ``progcache.load``)
+reports into: each fresh XLA compile is recorded with (kind, key, shapes,
+stack), each persistent-progcache disk load with (kind, key). After
 :func:`steady_state` is called — the phase marker a server flips once
 warmup is done — ANY fresh compile is a violation:
 the recompile storm the bounded-program invariant forbids, caught with

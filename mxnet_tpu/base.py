@@ -59,8 +59,10 @@ def init_compile_cache() -> str:
     An entry point (chip_smoke.py, tools/probe_fit_step.py) calls this once,
     before the first compile. Where ``JAX_COMPILATION_CACHE_DIR`` is set,
     JAX reads it and nothing is set in code; otherwise the cache goes to
-    :data:`COMPILE_CACHE_DIR`. (The separate ``mxnet_tpu.progcache`` of
-    serialized executables is unrelated and stays off by default.)"""
+    :data:`COMPILE_CACHE_DIR`. This is the train step's warm restart. (The
+    separate ``mxnet_tpu.progcache`` of serialized executables serves
+    ``Predictor``, ``quant`` and serving only, and stays off by
+    default.)"""
     d = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if d:
         return d

@@ -30,16 +30,15 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from . import progcache as _progcache
 from . import random as _random
 from . import telemetry as _telemetry
 from .analysis import compile_witness as _witness
 from .base import MXNetError
 from .context import Context, default_context
 from .ndarray import NDArray
-from .ops.matrix import gathered_rows_as
 from .ops.registry import built_layers
 from .telemetry.programs import graph_nodes as _graph_nodes
+from .telemetry.programs import layer_summary as _layer_summary
 from .telemetry.programs import note as _note_program
 
 
@@ -59,91 +58,45 @@ def _cast_floats(tree, dtype, src=None, skip=()):
     return jax.tree_util.tree_map(cast, tree)
 
 
-def _gathered_only(symbol):
-    """The argument leaves of ``symbol`` that nothing reads but the
-    ``weight`` input of an ``Embedding``. Casting such a table is a pass
-    over the whole of it for the sake of the few rows a step gathers; the
-    fused step leaves it in its master dtype and ``Embedding`` casts the
-    rows (ops/matrix.py ``gathered_rows_as``)."""
-    only = {}
+def master_leaves(symbol):
+    """The leaves of ``symbol``, arguments and auxiliary states, that stay
+    in their master dtype under a compute dtype: those that every node
+    reading them declares so (``OpDef.master_inputs``, with the op's
+    reason beside it) and that are no head of the graph, which its caller
+    reads. An auxiliary state that a declaring op and another op both read
+    is cast; no model here has one."""
+    keep = {}
     for node in symbol._nodes():
         if node.is_var:
             continue
+        declared = node.op.master_inputs
+        names = (node.op.get_arg_names(node.attrs) +
+                 node.op.get_aux_names(node.attrs)) if declared else ()
         for pos, (child, _) in enumerate(node.inputs):
-            if child.is_var and not child.is_aux:
-                gathered = (node.op.name == "Embedding" and
-                            node.op.get_arg_names(node.attrs)[pos] == "weight")
-                only[child.name] = only.get(child.name, True) and gathered
-    heads = {node.name for node, _ in symbol._entries if node.is_var}
-    return frozenset(n for n, ok in only.items() if ok and n not in heads)
-
-
-def _float32_state(symbol):
-    """The auxiliary states that stay float32 under a compute dtype: those
-    their op declares (``OpDef.float32_aux``; ``ExpertFFN``'s
-    ``expert_bias`` chooses experts, and rounded to bfloat16 it chooses
-    others)."""
-    keep = set()
-    for node in symbol._nodes():
-        if node.is_var or not node.op.float32_aux:
-            continue
-        aux = node.op.get_aux_names(node.attrs)
-        states = node.inputs[len(node.inputs) - len(aux):]
-        keep.update(child.name for name, (child, _) in zip(aux, states)
-                    if name in node.op.float32_aux)
-    return frozenset(keep)
-
-
-def _layer_attrs(layers, symbol):
-    """The ``executor.train_step`` span's static attributes for the
-    ``ExpertFFN`` layers among what the step's layers told of themselves as
-    it was traced (``ops/registry.py`` ``built_layers``): how many, and of
-    one layer the experts held, the choices a token, the rows of its
-    sorted-assignment buffer as allocated and the assignments expected
-    under uniform routing; for a stack run several times (``_loop_attrs``);
-    and where a multi-token-prediction module was traced, ``mtp_depth``
-    (its ``MultiTokenLoss`` nodes: the modules) and ``mtp_weight`` (the
-    first one's weight in the loss). The rest of what the layers say is the
-    program record's (``telemetry.programs()``: ``layers``)."""
-    attrs = _loop_attrs(layers, symbol)
-    mtp = [layer for layer in layers if layer["op"] == "MultiTokenLoss"]
-    if mtp:
-        attrs.update(mtp_depth=len(mtp), mtp_weight=mtp[0]["weight"])
-    moe = [layer for layer in layers if layer["op"] == "ExpertFFN"]
-    if not moe:
-        return attrs
-    one = moe[0]
-    return dict(attrs, moe_layers=len(moe),
-                moe_experts_held=one["experts_held"], moe_top_k=one["top_k"],
-                moe_buffer_rows=one["buffer_rows"],
-                moe_expected_rows=one["expected_rows"])
-
-
-def _loop_attrs(layers, symbol):
-    """Where a ``LoopExitLoss`` was traced: ``loop_exits``, its exits;
-    ``loop_layers``, the layers held, told apart by the leaves their mixer
-    reads (its own and its projections'); ``loop_steps``, the mixers traced
-    over the layers held: the times the step runs each layer."""
-    exits = [layer for layer in layers if layer["op"] == "LoopExitLoss"]
-    if not exits:
-        return {}
-    nodes = {n.name: n for n in symbol._nodes() if not n.is_var}
-
-    def leaves(node, depth=2):
-        out = set()
-        for child, _ in node.inputs:
             if child.is_var:
-                out.add(child.name)
-            elif depth > 1:
-                out |= leaves(child, depth - 1)
-        return frozenset(out)
+                ok = pos < len(names) and names[pos] in declared
+                keep[child.name] = keep.get(child.name, True) and ok
+    heads = {node.name for node, _ in symbol._entries if node.is_var}
+    return frozenset(n for n, ok in keep.items() if ok and n not in heads)
 
-    runs = [leaves(nodes[layer["node"]]) for layer in layers
-            if layer["op"] in ("MultiHeadAttention", "ShortConv")
-            and layer["node"] in nodes]
-    held = len(set(runs))
-    return dict(loop_exits=exits[0]["exits"], loop_layers=held,
-                loop_steps=len(runs) / held if held else 0.0)
+
+def _in_compute_dtype(eval_fn, dtype, master):
+    """``eval_fn`` computing in ``dtype``: every float32 leaf but those
+    ``master`` names cast to it on the way in, the outputs and the
+    auxiliary updates cast back to float32 on the way out. Under a vjp the
+    cast's transpose hands float32 gradients back. ``eval_fn`` itself
+    where ``dtype`` is None."""
+    if dtype is None:
+        return eval_fn
+
+    def in_dtype(arg_values, aux_values, is_train, rng):
+        outs, aux_up = eval_fn(_cast_floats(arg_values, dtype, skip=master),
+                               _cast_floats(aux_values, dtype, skip=master),
+                               is_train, rng)
+        return (_cast_floats(outs, jnp.float32, src=dtype),
+                _cast_floats(aux_up, jnp.float32, src=dtype))
+
+    return in_dtype
 
 
 def _relaid(tree, formats):
@@ -222,12 +175,14 @@ class Executor:
 
         compute_dtype: optional low-precision compute dtype ("bfloat16").
         Mixed precision the TPU-native way: parameters, gradients, and
-        optimizer state stay float32 (master weights); inside the single
-        jitted graph all float32 leaves are cast to compute_dtype so matmuls
-        and convs hit the MXU's bf16 path, and outputs/gradients are cast
-        back to float32. This is the analogue of the reference's fp16
-        training path (Cast ops + float16 data, tests/python/train/
-        test_dtype.py) — bf16 needs no loss scaling, unlike fp16.
+        optimizer state stay float32 (master weights); inside each of the
+        three jitted programs every float32 leaf but those its ops take in
+        the master dtype (``master_leaves``) is cast to compute_dtype so
+        matmuls and convs hit the MXU's bf16 path, and outputs/gradients
+        are cast back to float32 (``_in_compute_dtype``). This is the
+        analogue of the reference's fp16 training path (Cast ops + float16
+        data, tests/python/train/test_dtype.py) — bf16 needs no loss
+        scaling, unlike fp16.
         Default from MXNET_COMPUTE_DTYPE env var."""
         self._symbol = symbol
         if compute_dtype is None:
@@ -235,9 +190,9 @@ class Executor:
         self._compute_dtype = (jnp.dtype(compute_dtype)
                                if compute_dtype not in (None, "", "float32")
                                else None)
-        # auxiliary states the compute dtype leaves alone
-        self._state32 = (_float32_state(symbol)
-                         if self._compute_dtype is not None else ())
+        # the leaves the compute dtype leaves alone
+        self._master = (master_leaves(symbol)
+                        if self._compute_dtype is not None else frozenset())
         self._ctx = ctx if isinstance(ctx, Context) else (ctx[0] if ctx else default_context())
         self._group2ctx = group2ctx
         arg_names = symbol.list_arguments()
@@ -274,7 +229,9 @@ class Executor:
 
         self._arg_names = arg_names
         self._aux_names = aux_names
-        self._eval_fn = _under_mesh(symbol.build_eval(), mesh)
+        self._eval_fn = _in_compute_dtype(
+            _under_mesh(symbol.build_eval(compute_dtype=self._compute_dtype),
+                        mesh), self._compute_dtype, self._master)
         self._fwd_cache: Dict[bool, Any] = {}
         self._bwd_fn = None
         self._fwd_bwd_fn = None
@@ -309,17 +266,9 @@ class Executor:
         fn = self._fwd_cache.get(is_train)
         if fn is None:
             eval_fn = self._eval_fn
-            cd, state32 = self._compute_dtype, self._state32
 
             def fwd(arg_values, aux_values, rng):
-                if cd is not None:
-                    arg_values = _cast_floats(arg_values, cd)
-                    aux_values = _cast_floats(aux_values, cd, skip=state32)
-                outs, aux_up = eval_fn(arg_values, aux_values, is_train, rng)
-                if cd is not None:
-                    outs = _cast_floats(outs, jnp.float32, src=cd)
-                    aux_up = _cast_floats(aux_up, jnp.float32, src=cd)
-                return outs, aux_up
+                return eval_fn(arg_values, aux_values, is_train, rng)
 
             fn = jax.jit(fwd)
             self._fwd_cache[is_train] = fn
@@ -333,8 +282,6 @@ class Executor:
             grad_names = [n for n in self._arg_names if self.grad_req.get(n) != "null"]
             reqs = tuple(self.grad_req[n] for n in grad_names)
 
-            cd, state32 = self._compute_dtype, self._state32
-
             def fwd_bwd(arg_values, aux_values, rng, head_grads, old_grads):
                 grad_vals = [arg_values[n] for n in grad_names]
 
@@ -342,17 +289,7 @@ class Executor:
                     av = dict(arg_values)
                     for n, v in zip(grad_names, gvals):
                         av[n] = v
-                    auxv = aux_values
-                    if cd is not None:
-                        # bf16 compute; vjp of the cast returns f32 grads
-                        # (transpose of convert_element_type casts back).
-                        av = _cast_floats(av, cd)
-                        auxv = _cast_floats(auxv, cd, skip=state32)
-                    outs, aux_up = eval_fn(av, auxv, True, rng)
-                    if cd is not None:
-                        outs = _cast_floats(outs, jnp.float32, src=cd)
-                        aux_up = _cast_floats(aux_up, jnp.float32, src=cd)
-                    return outs, aux_up
+                    return eval_fn(av, aux_values, True, rng)
 
                 (outs, aux_up), vjp = jax.vjp(lambda *g: f(*g), *grad_vals, has_aux=False)
                 if head_grads is None:
@@ -430,10 +367,7 @@ class Executor:
         eval_fn = _under_mesh(self._eval_fn, mesh)
         grad_names = list(self._grad_names_list())
         data_names = [n for n in self._arg_names if n not in set(grad_names)]
-        cd = self._compute_dtype
-        tables = _gathered_only(self._symbol) if cd is not None else ()
         chain = max(1, int(chain))
-        state32 = self._state32
         built = built_layers()  # what the layers tell of themselves, as traced
         from .parallel import collectives as _coll
         stage = _coll.sharded_stage(mesh, shard_axis)
@@ -470,16 +404,7 @@ class Executor:
                     full = _coll.zero2_grad_scatter(full, mesh, shard_axis)
                 av = dict(data_values)
                 av.update(full)
-                auxv = aux_values
-                if cd is not None:
-                    av = _cast_floats(av, cd, skip=tables)
-                    auxv = _cast_floats(auxv, cd, skip=state32)
-                with gathered_rows_as(cd):
-                    outs, aux_up = eval_fn(av, auxv, True, rng)
-                if cd is not None:
-                    outs = _cast_floats(outs, jnp.float32, src=cd)
-                    aux_up = _cast_floats(aux_up, jnp.float32, src=cd)
-                return outs, aux_up
+                return eval_fn(av, aux_values, True, rng)
 
             fd = _coll.zero3_remat(f) if stage >= 3 else f
             (outs, aux_up), vjp = jax.vjp(fd, arg)
@@ -560,6 +485,19 @@ class Executor:
             # (compiled, or read from the compilation cache) under
             return bool((getattr(sp, "args", None) or {}).get("compiled"))
 
+        def _asked_back(jitted, *args):
+            """The program ``jitted`` runs at ``args``, the step's outputs
+            with its other arguments, or None: jit keeps its lowering and
+            its executable, and asked again at the shapes, shardings and
+            formats the next call passes it lowers and compiles nothing."""
+            try:
+                return jitted.lower(*args).compile()
+            except Exception:
+                logging.getLogger("mxnet_tpu").warning(
+                    "train step: jit did not hand its program back; the "
+                    "program record has no ops", exc_info=True)
+                return None
+
         def _note(compiled=None, under=None):
             """The program just built says what it is
             (``telemetry.programs()``); the compiled step is not kept."""
@@ -618,11 +556,11 @@ class Executor:
                 aot["gather_bytes"] = sum(
                     int(a.size * jnp.dtype(a.dtype).itemsize)
                     for a in jax.tree_util.tree_leaves(params))
-                # what _cast_floats would have cast and, being a table
-                # that only gathers read, is left in its master dtype
+                # what _cast_floats would have cast and, being an
+                # argument its ops take in the master dtype, leaves alone
                 aot["uncast_table_bytes"] = sum(
                     4 * int(a.size) for n, a in {**dv, **params}.items()
-                    if n in tables and a.dtype == jnp.float32)
+                    if n in self._master and a.dtype == jnp.float32)
                 aot["gauges"] = True
             if use_auto:
                 if not aot.get("informats"):
@@ -712,73 +650,18 @@ class Executor:
                     # the program that RUNS is the one jit just compiled
                     # for the learned formats, and it names its
                     # instructions otherwise than `learned` does (PERF.md,
-                    # Findings, PR 37). jit keeps its lowering and its
-                    # executable: asked again at the same arguments'
-                    # shapes and formats it lowers and compiles nothing
-                    try:
-                        running = aot["jit"].lower(
-                            new_params, new_states, aux_values, rng, dv,
-                            *extra).compile()
-                    except Exception:
-                        logging.getLogger("mxnet_tpu").warning(
-                            "train step: jit did not hand its program back; "
-                            "the program record has no ops", exc_info=True)
-                        running = None
-                    _note(running, learn)
+                    # Findings: the program record)
+                    _note(_asked_back(aot["jit"], new_params, new_states,
+                                      aux_values, rng, dv, *extra), learn)
                 elif _built_one(sp):  # another program (new shapes), with
                     _note()           # no compiled object in hand to read
             else:
-                if _progcache.enabled() and "exec" not in aot:
-                    # Persistent program cache for the fused step: key by
-                    # the LOWERED text — update_fn is arbitrary Python, so
-                    # only lowering captures the actual program (a metadata
-                    # key could collide across optimizer rules). Donation
-                    # is part of the key and survives serialization. Any
-                    # failure pins the plain-jit path for this step fn.
-                    with _build("progcache") as sp:
-                        try:
-                            lowered = jitted.lower(params, states, aux_values,
-                                                   rng, dv, *extra)
-                            key = _progcache.lowered_key(
-                                lowered.as_text(), donate=(0, 1),
-                                extra="train_step")
-                            exe = _progcache.load(key, kind="train_step")
-                            if exe is None:
-                                exe = lowered.compile()
-                                _witness.record_compile("train_step",
-                                                        key=key[:16])
-                                _progcache.store(key, exe, note="train_step",
-                                                 kind="train_step")
-                            aot["exec"] = exe
-                            _note(exe, sp)
-                        except Exception:
-                            logging.getLogger("mxnet_tpu").warning(
-                                "progcache: train-step AOT path failed; "
-                                "using plain jit", exc_info=True)
-                            aot["exec"] = None
                 with _dispatch() as sp:
-                    if aot.get("exec") is not None:
-                        try:
-                            outs, new_params, new_states, aux_up = \
-                                aot["exec"](params, states, aux_values, rng,
-                                            dv, *extra)
-                        except Exception:
-                            # a stale/incompatible loaded executable must
-                            # never fail the step: recompile via the jit
-                            # path (inputs are intact — argument processing
-                            # precedes any donation) and stop using the
-                            # cached program
-                            logging.getLogger("mxnet_tpu").warning(
-                                "progcache: cached train step unusable; "
-                                "recompiling", exc_info=True)
-                            aot["exec"] = None
-                            outs, new_params, new_states, aux_up = jitted(
-                                params, states, aux_values, rng, dv, *extra)
-                    else:
-                        outs, new_params, new_states, aux_up = jitted(
-                            params, states, aux_values, rng, dv, *extra)
-                if _built_one(sp):  # jit built it: no compiled object
-                    _note()         # in hand to read it from
+                    outs, new_params, new_states, aux_up = jitted(
+                        params, states, aux_values, rng, dv, *extra)
+                if _built_one(sp):  # the first call's program, or new shapes'
+                    _note(_asked_back(jitted, new_params, new_states,
+                                      aux_values, rng, dv, *extra))
             for n, v in aux_up.items():
                 self.aux_dict[n]._data = v
             self.outputs = [NDArray(o) for o in outs]
@@ -788,10 +671,10 @@ class Executor:
             # jit dispatch is async: the span is the HOST side of the step.
             # What the call does by its own hand before the dispatch
             # (placing the snapshot, the AUTO-layout learning compile, the
-            # relayout, the program cache) is a `build` child each; the
-            # call into the program is the `dispatch` child, and the
-            # compile listener says on both whether, and for how long, jit
-            # traced, lowered, compiled or read its cache inside them
+            # relayout) is a `build` child each; the call into the program
+            # is the `dispatch` child, and the compile listener says on both
+            # whether, and for how long, jit traced, lowered, compiled or
+            # read its cache inside them
             self._train_steps += 1
             # the bytes are reckoned on the first call, and the expert
             # layers are listed as that call traces the step
@@ -800,12 +683,13 @@ class Executor:
                                  step=self._train_steps, chain=chain,
                                  stage=stage,
                                  gather_bytes=aot.get("gather_bytes", 0),
-                                 **(_layer_attrs(built.layers, self._symbol)
+                                 **(_layer_summary(built.layers, self._symbol)
                                     if known else {})) as sp:
                 out = _run_impl(params, states, data_values, *extra)
                 if not known:
                     sp.add("gather_bytes", aot.get("gather_bytes", 0))
-                    sp.annotate(**_layer_attrs(built.layers, self._symbol))
+                    sp.annotate(**_layer_summary(built.layers,
+                                                 self._symbol))
                 return out
 
         run.lower = lower

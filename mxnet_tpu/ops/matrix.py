@@ -7,9 +7,7 @@ the MXU primitive.
 """
 from __future__ import annotations
 
-import contextlib
 import functools
-import threading
 
 import jax
 import jax.numpy as jnp
@@ -273,24 +271,6 @@ alias("Pad", "pad")
 
 
 # --- indexing (reference indexing_op.cc) ------------------------------------
-_tracing = threading.local()
-
-
-@contextlib.contextmanager
-def gathered_rows_as(dtype):
-    """Entered by the executor around the trace of a graph it computes in
-    ``dtype`` (None: as stored). A float32 table that reaches ``Embedding``
-    under it is one the executor left in its master dtype because only
-    gathers read it: the rows are cast, not the table. Trace-time Python
-    state only: nothing here reaches the program."""
-    prev = getattr(_tracing, "rows", None)
-    _tracing.rows = dtype
-    try:
-        yield
-    finally:
-        _tracing.rows = prev
-
-
 # What XLA's memory-space assignment gives a scatter on the v5e: the table
 # stands in VMEM (``S(1)`` in the compiled text) where it and the rows added
 # into it fit 112 MiB together, the chip's 128 less the 16 a kernel may
@@ -421,19 +401,24 @@ _rows_as.defvjp(_rows_as_fwd, _rows_as_bwd)
     arg_names=("data", "weight"),
     param_spec={"input_dim": 0, "output_dim": 0, "dtype": "float32"},
     no_grad_inputs=("data",),
+    # casting the table is a pass over the whole of it for the few rows a
+    # step gathers: the rows are cast instead
+    master_inputs=("weight",),
+    simple=False,
 )
-def _embedding(attrs, data, weight):
+def _embedding(attrs, inputs, aux, ctx):
     """Table lookup (reference indexing_op.cc Embedding +
     EmbeddingOpBackward). A float32 table the executor left in its master
-    dtype gives its rows in the compute dtype (``gathered_rows_as``); the
+    dtype gives its rows in the compute dtype (``ctx.compute_dtype``); the
     backward of either is ``_rows_as_bwd``."""
+    data, weight = inputs
     ids = data.astype(jnp.int32)
-    dtype = getattr(_tracing, "rows", None)
+    dtype = ctx.compute_dtype
     if dtype is None or weight.dtype != jnp.float32:
         dtype = weight.dtype
     if not jnp.issubdtype(weight.dtype, jnp.floating):
-        return jnp.take(weight, ids, axis=0)  # no cotangent to build
-    return _rows_as(weight, ids, jnp.dtype(dtype), current_node())
+        return (jnp.take(weight, ids, axis=0),), ()  # no cotangent to build
+    return (_rows_as(weight, ids, jnp.dtype(dtype), current_node()),), ()
 
 
 @defop("take", arg_names=("a", "indices"), param_spec={"axis": 0, "mode": "clip"}, no_grad_inputs=("indices",))

@@ -394,7 +394,9 @@ def _aux_names(attrs):
     arg_names=("data", "router_data", "router_weight", "gate_weight",
                "up_weight", "down_weight"),
     aux_names=_aux_names,
-    float32_aux=("expert_bias",),  # added to float32 scores to CHOOSE
+    # added to float32 scores to CHOOSE: rounded to bfloat16 it chooses
+    # other experts
+    master_inputs=("expert_bias",),
     num_outputs=2,
     output_names=("output", "expert_tokens"),
     param_spec={"num_experts": 1, "experts_held": 0, "first_expert": 0,

@@ -101,6 +101,9 @@ class OpContext:
 
     is_train: bool = False
     rng: Any = None  # jax PRNG key, present iff opdef.needs_rng
+    # the dtype the executor's graph computes in (None: as stored); an op
+    # reads it for a leaf it declared in ``OpDef.master_inputs``
+    compute_dtype: Any = None
 
 
 @dataclasses.dataclass
@@ -117,8 +120,10 @@ class OpDef:
     uses_train: bool = False
     variadic: bool = False  # takes arbitrary list of inputs (Concat, add_n)
     no_grad_inputs: Sequence[str] = ()  # e.g. labels
-    # auxiliary states a compute dtype leaves float32 (the executor's casts)
-    float32_aux: Sequence[str] = ()
+    # arguments and auxiliary states, by name, that the op takes in their
+    # master dtype under a compute dtype: the executor leaves such a leaf
+    # uncast where every op that reads it says so
+    master_inputs: Sequence[str] = ()
     doc: str = ""
     py_name: Optional[str] = None  # name exposed in nd/sym namespaces
     output_names: Any = None  # list or fn(attrs)->list; default [name_output]
@@ -238,7 +243,7 @@ def defop(
     output_names=None,
     simple=True,
     param_docs=None,
-    float32_aux=(),
+    master_inputs=(),
 ):
     """Decorator registering an operator implementation.
 
@@ -266,7 +271,7 @@ def defop(
             uses_train=uses_train,
             variadic=variadic,
             no_grad_inputs=no_grad_inputs,
-            float32_aux=float32_aux,
+            master_inputs=master_inputs,
             doc=fn.__doc__ or "",
             py_name=py_name or name,
             output_names=output_names,

@@ -506,9 +506,13 @@ class Symbol:
                             compute_dtype=compute_dtype)
 
     # --- evaluation helper used by Executor -------------------------------
-    def build_eval(self, remat_segments=None):
+    def build_eval(self, remat_segments=None, compute_dtype=None):
         """Return fn(arg_values: dict, aux_values: dict, is_train, rng)
         -> (outputs list, aux_updates dict). Pure; jit-able.
+
+        compute_dtype: the dtype the caller computes the graph in (the
+        executor's); every node's ``OpContext`` carries it, for the ops
+        that take a leaf in its master dtype (``OpDef.master_inputs``).
 
         remat_segments > 1 partitions the graph into that many contiguous
         topological segments, each wrapped in ``jax.checkpoint``: backward
@@ -541,7 +545,8 @@ class Symbol:
                               if flag == 1 else flag)
         if remat_segments and remat_segments > 1:
             return self._build_eval_segmented(nodes, entries,
-                                              int(remat_segments))
+                                              int(remat_segments),
+                                              compute_dtype)
 
         def eval_fn(arg_values, aux_values, is_train, rng):
             env: Dict[Tuple[int, int], Any] = {}
@@ -572,7 +577,7 @@ class Symbol:
                 with jax.named_scope(node.name), at_node(node.name):
                     outs, aux_out = op.impl(
                         attrs, tuple(vals[:n_args]), tuple(vals[n_args:]),
-                        OpContext(is_train, node_rng),
+                        OpContext(is_train, node_rng, compute_dtype),
                     )
                 for i, o in enumerate(outs):
                     env[(id(node), i)] = o
@@ -588,7 +593,8 @@ class Symbol:
 
         return eval_fn
 
-    def _build_eval_segmented(self, nodes, entries, n_segments):
+    def _build_eval_segmented(self, nodes, entries, n_segments,
+                              compute_dtype=None):
         """Segmented evaluator: contiguous topo chunks, each under
         jax.checkpoint; only chunk-boundary values are saved for backward.
 
@@ -672,7 +678,8 @@ class Symbol:
                             outs, aux_out = op.impl(
                                 attrs, tuple(vals[:n_args]),
                                 tuple(vals[n_args:]),
-                                OpContext(is_train, node_rng))
+                                OpContext(is_train, node_rng,
+                                          compute_dtype))
                         for i, o in enumerate(outs):
                             local[(id(node), i)] = o
                             if i in tagged_out:

@@ -1,18 +1,21 @@
 """What each step program the executor built IS: one record a program.
 
-``Executor.make_train_step`` calls :func:`note` where it holds the compiled
-step that RUNS (on the AUTO-layout path the one jit compiled for the learned
-layouts, asked back from jit after the first dispatch; the program cache's
-executable) or, on the plain ``jax.jit`` path, once the first call has
-built it.
+``Executor.make_train_step`` calls :func:`note` once a call has built a
+step program, with the compiled step that RUNS: the one jit built, asked
+back from jit after the dispatch at the arguments it ran at (jit keeps its
+lowering and its executable, so asking compiles nothing). On the
+AUTO-layout path that is the program jit compiled for the learned layouts
+(with ``chain`` > 1 the learning compile's own executable); on the plain
+``jax.jit`` path the program the dispatch built, the first call's or a new
+shape's. The executor's span attributes that summarise the layers come
+from here too (:func:`layer_summary`).
 :func:`programs` (``telemetry.programs()``) returns the records, oldest
 first, each a dict:
 
 - ``program`` (``"train_step"``), ``step`` (the ``executor.train_step``
   span's ``step`` that built it), ``build`` (the ``id`` of the
   ``executor.train_step.build`` record its building began under
-  (``auto_layout_learn``, ``progcache``); None on the plain path, which has
-  none);
+  (``auto_layout_learn``); None on the plain path, which has none);
 - ``ops``: every instruction of the optimized program that does work,
   ``{"name", "opcode", "kernel", "node"}``: its own name (what stands before
   `` = `` on its line, and on a profiler trace's), whether it is a Mosaic
@@ -24,7 +27,8 @@ first, each a dict:
   listed in the loop's place. ``node`` is ``""`` for what is traced outside
   every node (the optimizer's rule, the executor's casts): not guessed. The
   asynchronous ``-start`` / ``-done`` pairs run beside the work and are
-  left out. None on the plain path (no compiled object in hand);
+  left out. None where no compiled object was in hand (jit did not hand
+  it back; a new shape on the AUTO-layout path);
 - ``nodes``: ``{node: {"op", "inputs"}}``, each graph node's operator and
   the names of what it reads: enough to tell an attention projection from
   a feed-forward product;
@@ -32,11 +36,11 @@ first, each a dict:
   (``ops/registry.py`` ``note_built``), each with its ``node``;
 - ``memory``: ``argument``, ``output``, ``alias``, ``temp`` and
   ``generated_code`` bytes of the compiled program's ``memory_analysis()``
-  (each None on the plain path) and ``uncast_table_bytes`` (static: the
-  tables the step leaves in their master dtype);
+  (each None where ``ops`` is) and ``uncast_table_bytes`` (static: the
+  arguments the step leaves in their master dtype, ``OpDef.master_inputs``);
 - ``note_s``: what noting it cost the step that built it (fetching the
   HLO modules and the memory analysis), seconds; ``read_s``: what the first
-  read cost, ``{"text", "parse"}`` seconds (None on the plain path).
+  read cost, ``{"text", "parse"}`` seconds (None where ``ops`` is).
 
 Noting costs next to nothing: it keeps the program's HLO modules (host
 objects; never the compiled step, whose device memory the executor frees)
@@ -148,6 +152,56 @@ def graph_nodes(symbol):
     """``nodes`` of a record: every operator node of ``symbol``."""
     return {n.name: {"op": n.op.name, "inputs": [c.name for c, _ in n.inputs]}
             for n in symbol._nodes() if not n.is_var}
+
+
+def layer_summary(layers, symbol):
+    """The ``executor.train_step`` span's static attributes, from what the
+    step's layers told of themselves as it was traced (``layers``, the
+    record's): where a ``LoopExitLoss`` was traced, ``loop_exits``, its
+    exits; ``loop_layers``, the layers held, told apart by the leaves their
+    mixer reads (its own and its projections'); ``loop_steps``, the mixers
+    traced over the layers held: the times the step runs each layer. Where
+    a multi-token-prediction module was traced, ``mtp_depth`` (its
+    ``MultiTokenLoss`` nodes: the modules) and ``mtp_weight`` (the first
+    one's weight in the loss). For the ``ExpertFFN`` layers, how many, and
+    of one layer the experts held, the choices a token, the rows of its
+    sorted-assignment buffer as allocated and the assignments expected
+    under uniform routing."""
+    def of(*ops):
+        return [layer for layer in layers if layer["op"] in ops]
+
+    attrs = {}
+    exits = of("LoopExitLoss")
+    if exits:
+        nodes = {n.name: n for n in symbol._nodes() if not n.is_var}
+
+        def leaves(node, depth=2):
+            out = set()
+            for child, _ in node.inputs:
+                if child.is_var:
+                    out.add(child.name)
+                elif depth > 1:
+                    out |= leaves(child, depth - 1)
+            return frozenset(out)
+
+        runs = [leaves(nodes[layer["node"]])
+                for layer in of("MultiHeadAttention", "ShortConv")
+                if layer["node"] in nodes]
+        held = len(set(runs))
+        attrs.update(loop_exits=exits[0]["exits"], loop_layers=held,
+                     loop_steps=len(runs) / held if held else 0.0)
+    mtp = of("MultiTokenLoss")
+    if mtp:
+        attrs.update(mtp_depth=len(mtp), mtp_weight=mtp[0]["weight"])
+    moe = of("ExpertFFN")
+    if moe:
+        one = moe[0]
+        attrs.update(moe_layers=len(moe),
+                     moe_experts_held=one["experts_held"],
+                     moe_top_k=one["top_k"],
+                     moe_buffer_rows=one["buffer_rows"],
+                     moe_expected_rows=one["expected_rows"])
+    return attrs
 
 
 class _Record:

@@ -516,10 +516,11 @@ def test_fused_step_keeps_the_bias_float32_and_untouched(fam, toy):
     bfloat16 cannot hold still decides), is the same bits after the steps,
     and the step's span and the program's record say what was traced."""
     from mxnet_tpu import telemetry
-    from mxnet_tpu.executor import _float32_state
+    from mxnet_tpu.executor import master_leaves
 
     sym = fam.symbol(toy, True)
-    assert _float32_state(sym) == set(sym.list_auxiliary_states())
+    # the table is tied to the head: the states alone stay float32
+    assert master_leaves(sym) == set(sym.list_auxiliary_states())
     exe = _bound(sym, compute_dtype="bfloat16")
     params = fam.init_params(toy, 3)
     bias = {n: a.asnumpy().copy() for n, a in exe.aux_dict.items()}
@@ -569,20 +570,20 @@ def test_fused_step_keeps_the_bias_float32_and_untouched(fam, toy):
 
 def test_float32_state_is_what_the_op_declares(fam, toy, monkeypatch):
     """The executor knows no op by name: the states a compute dtype leaves
-    alone are those ``OpDef.float32_aux`` lists, by the names the graph
+    alone are those ``OpDef.master_inputs`` lists, by the names the graph
     gives them; BatchNorm's moving statistics, which no op lists, are
     cast as before."""
-    from mxnet_tpu.executor import _float32_state
+    from mxnet_tpu.executor import master_leaves
 
     op = get_op("ExpertFFN")
-    assert op.float32_aux == ("expert_bias",)
-    assert get_op("BatchNorm").float32_aux == ()
+    assert op.master_inputs == ("expert_bias",)
+    assert get_op("BatchNorm").master_inputs == ()
     sym = fam.symbol(toy, True)
-    assert _float32_state(sym) == set(fam.state_shapes(toy))
-    monkeypatch.setattr(op, "float32_aux", ())
-    assert _float32_state(sym) == frozenset()
+    assert master_leaves(sym) == set(fam.state_shapes(toy))
+    monkeypatch.setattr(op, "master_inputs", ())
+    assert master_leaves(sym) == frozenset()
     bn = mx.sym.BatchNorm(mx.sym.Variable("data"), name="bn")
-    assert bn.list_auxiliary_states() and _float32_state(bn) == frozenset()
+    assert bn.list_auxiliary_states() and master_leaves(bn) == frozenset()
 
 
 def test_init_params_raises_where_the_bias_cannot_be_seeded(fam, toy):

@@ -278,6 +278,10 @@ def test_restore_ladder_rejects_mismatched_max(cache_dir):
 
 
 def test_fused_train_step_cache_roundtrip(cache_dir):
+    """The fused train step's warm restart is JAX's persistent compilation
+    cache, not this one: with the program cache on, two runs of
+    ``Module.fit_step`` neither store nor load a program here, and end on
+    bitwise the same weights."""
     def fit(steps=2):
         sym, params = _model()
         mod = mx.mod.Module(sym, data_names=("data",),
@@ -297,14 +301,14 @@ def test_fused_train_step_cache_roundtrip(cache_dir):
                 label=[mx.nd.array(r.randint(0, HIDDEN, (4,))
                                    .astype(np.float32))])
             mod.fit_step(batch)
+        assert mod.fit_step_path == "fused"
         return {n: a.asnumpy() for n, a in mod.get_params()[0].items()}
 
+    assert progcache.enabled()
     w_cold = fit()
-    s = progcache.stats()
-    assert s["stores"] >= 1
-    hits_before = s["hits"]
     w_warm = fit()
-    assert progcache.stats()["hits"] > hits_before
+    s = progcache.stats()
+    assert (s["stores"], s["hits"], s["misses"]) == (0, 0, 0), s
     for n in w_cold:
         assert np.array_equal(w_cold[n], w_warm[n]), n
 

@@ -8,11 +8,11 @@ children, a reshape compiles once more and the record names the step;
 (d) ``Module.fit``: the ``module.*`` children in order under one ``step``;
 (e) ``MXNET_TELEMETRY=0`` records none of it; (f) every ``per_layer`` entry
 of ``BENCHMARK.json`` has its reader file. The program's record of the step
-it built (``telemetry.programs()``): (g) through the program cache, every
-working instruction under its graph node and the compiled program's bytes;
-(h) the plain path, without them; (i) a reshape's second record; (j) the
-master kill and ``reset``; (k) noting prints nothing, the first read parses
-once.
+it built (``telemetry.programs()``): (g) on the plain ``jax.jit`` path, its
+program asked back from jit, every working instruction under its graph node
+and the compiled program's bytes; (h) asking back builds no program; (i) a
+reshape's second record; (j) the master kill and ``reset``; (k) noting
+prints nothing, the first read parses once.
 """
 import glob
 import importlib.util
@@ -421,31 +421,29 @@ def _programs_module():
     return importlib.import_module("mxnet_tpu.telemetry.programs")
 
 
-def test_record_through_the_program_cache(monkeypatch, tmp_path):
-    """(g) and (k): one record, hung on the ``progcache`` build; its ``ops``
-    are the entry computation's working instructions, each under the node
-    its text names; its bytes are the compiled program's; the text is
-    parsed at the first read, once."""
+def test_record_through_the_program_cache(monkeypatch):
+    """(g) and (k): one record of the plainly jitted step, whose program
+    jit handed back; its ``ops`` are the entry computation's working
+    instructions, each under the node its text names; its bytes are the
+    compiled program's; the text is parsed at the first read, once."""
     from mxnet_tpu import executor
 
-    monkeypatch.setenv("MXNET_PROGCACHE_DIR", str(tmp_path))
+    assert "MXNET_PROGCACHE_DIR" not in os.environ
     mod, compiled, parses = _programs_module(), [], []
-    store, parse = executor._progcache.store, mod.device_ops
-    monkeypatch.setattr(executor._progcache, "store", lambda key, exe, **kw: (
-        compiled.append(exe), store(key, exe, **kw))[1])
+    note, parse = executor._note_program, mod.device_ops
+    monkeypatch.setattr(executor, "_note_program", lambda *a, **kw: (
+        compiled.append(kw["compiled"]), note(*a, **kw))[1])
     monkeypatch.setattr(mod, "device_ops",
                         lambda text: (parses.append(1), parse(text))[1])
     exe, step, params = _toy_lm_step()
     for _ in range(2):
         _, params, _ = step(params, {}, _lm_feed())
-    assert len(compiled) == 1 and parses == []
+    assert len(compiled) == 1 and compiled[0] is not None and parses == []
     (rec,) = telemetry.programs()
     telemetry.programs()
     assert parses == [1] and rec["read_s"]["parse"] > 0
-    (build,) = [r for r in _records("executor.train_step.build")
-                if r["args"]["phase"] == "progcache"]
     assert (rec["program"], rec["step"], rec["build"]) == (
-        "train_step", 1, build["args"]["id"])
+        "train_step", 1, None)
 
     text = compiled[0].as_text()
     comps, entry = mod.computations(text)
@@ -490,24 +488,47 @@ def test_record_through_the_program_cache(monkeypatch, tmp_path):
          "backward": "direct"}]
 
 
-def test_record_of_a_plainly_jitted_step_and_of_a_reshape():
-    """(h) and (i): no compiled object in hand, so ``ops`` and the
-    program's bytes are None and the layers are there; a reshape builds a
-    second record and the first is kept."""
+def test_record_of_a_plainly_jitted_step_and_of_a_reshape(monkeypatch):
+    """(h) and (i): the program jit built is asked back, so ``ops`` and the
+    program's bytes are there beside the layers, and asking built no
+    program beyond the one the dispatch built; a reshape builds a second
+    record and the first is kept."""
+    def built():
+        return sum(v for k, v in telemetry.registry.get_name_value()
+                   if k.startswith("compiles_total"))
+
+    asked, compile_ = [], jax.stages.Lowered.compile
+
+    def counted(self, *args, **kwargs):
+        before = built()
+        out = compile_(self, *args, **kwargs)
+        asked.append(built() - before)
+        return out
+
+    monkeypatch.setattr(jax.stages.Lowered, "compile", counted)
     exe, step, params = _toy_lm_step()
     for _ in range(3):
         _, params, _ = step(params, {}, _lm_feed())
     (rec,) = telemetry.programs()
-    assert rec["ops"] is None and rec["read_s"] is None
+    assert rec["ops"] and rec["read_s"] is not None
     assert rec["build"] is None and rec["step"] == 1
-    assert rec["memory"]["temp"] is None
+    assert all(rec["memory"][k] is not None
+               for k in ("argument", "output", "alias", "temp",
+                         "generated_code"))
+    assert rec["memory"]["argument"] > 0
     assert [(r["op"], r["node"], r.get("head_dim")) for r in rec["layers"]] == [
         ("MultiHeadAttention", "layer0_attn", 4), ("Embedding", "embed", None)]
     assert rec["nodes"]["embed"]["op"] == "Embedding"
     for _ in range(2):
         _, params, _ = step(params, {}, _lm_feed(batch=4))
     first, second = telemetry.programs()
-    assert first is rec and second["step"] == 4
+    assert first is rec and second["step"] == 4 and second["ops"]
+    # one step program a shape, built inside the dispatch; asking jit back
+    # for it built none
+    assert asked == [0, 0]
+    dispatched = [r["args"].get("programs")
+                  for r in _records("executor.train_step.dispatch")]
+    assert dispatched == [1, None, None, 1, None]
     # the same layers, traced again at the new shape: twice the ids
     assert second["layers"] == [dict(r, ids=32) if r["op"] == "Embedding"
                                 else r for r in first["layers"]]

@@ -22,7 +22,7 @@ import pytest
 import mxnet_tpu as mx
 from mxnet_tpu import models, telemetry
 from mxnet_tpu import symbol as sym
-from mxnet_tpu.executor import _gathered_only
+from mxnet_tpu.executor import master_leaves
 from mxnet_tpu.models import transformer
 from mxnet_tpu.ops import matrix
 from mxnet_tpu.ops.registry import OpContext, built_layers, get_op
@@ -203,7 +203,7 @@ def _toy_lm(vocab=56):
 def test_fused_lm_step_gathers_from_the_master_table(toy_vmem):
     vocab, dm, rows = 56, _LM["model_dim"], 24
     net = _toy_lm(vocab)
-    assert _gathered_only(net) == {"embed_weight"}
+    assert master_leaves(net) == {"embed_weight"}
     exe, step, params, states, feed = _fused(net, _LM_SHAPES, types=_INT)
     text = step.lower(params, states, feed).as_text()
     # the head's weight is cast whole, for the MXU; the table no longer
@@ -255,7 +255,7 @@ def _conv_net():
 
 def test_fused_conv_step_casts_every_float32_leaf_as_before():
     net = _conv_net()
-    assert _gathered_only(net) == frozenset()
+    assert master_leaves(net) == frozenset()
     shapes = {"data": (2, 3, 8, 8), "softmax_label": (2,)}
     exe, step, params, states, feed = _fused(net, shapes)
     text = step.lower(params, states, feed).as_text()
@@ -279,7 +279,7 @@ def _tied_lm(vocab=56, dm=16):
 
 def test_a_table_tied_to_the_head_is_still_cast():
     net = _tied_lm()
-    assert _gathered_only(net) == frozenset()
+    assert master_leaves(net) == frozenset()
     exe, step, params, states, feed = _fused(net, _LM_SHAPES, types=_INT)
     text = step.lower(params, states, feed).as_text()
     assert _converts(text, (56, 16)) == 1
@@ -294,15 +294,120 @@ def test_gathered_only_reads_the_graph(case):
     if case == "output":
         # a table that is also a head of the graph is read by the caller
         net = sym.Group([a, sym.Variable("a_weight")])
-        assert _gathered_only(net) == frozenset()
+        assert master_leaves(net) == frozenset()
     elif case == "frozen":
         # the rule reads consumers, not grad_req: a frozen table qualifies
-        assert _gathered_only(sym.sum(a)) == {"a_weight"}
+        assert master_leaves(sym.sum(a)) == {"a_weight"}
     else:
         b = sym.Embedding(data=data, input_dim=9, output_dim=4, name="b")
         scaled = sym._mul_scalar(sym.Variable("b_weight"), scalar=2.0)
         net = sym.Group([a + b, sym.sum(scaled)])
-        assert _gathered_only(net) == {"a_weight"}
+        assert master_leaves(net) == {"a_weight"}
+
+
+def _bound_toy_lm():
+    """(executor, params, feed) of the toy LM bound under bfloat16."""
+    net = _toy_lm()
+    exe = net.simple_bind(
+        mx.cpu(), grad_req={n: "null" if n in INPUTS else "write"
+                            for n in net.list_arguments()},
+        type_dict=_INT, compute_dtype="bfloat16", **_LM_SHAPES)
+    rng = np.random.RandomState(0)
+    params = {n: jnp.asarray(rng.normal(0, 0.1, a.shape), jnp.float32)
+              for n, a in exe.arg_dict.items() if n not in INPUTS}
+    feed = {n: jnp.asarray(rng.randint(0, 8, _LM_SHAPES[n]), jnp.int32)
+            for n in INPUTS}
+    return exe, params, feed
+
+
+@pytest.mark.parametrize("path", ["forward", "forward_backward",
+                                  "fused_step"])
+def test_every_compiled_path_gathers_from_the_master_table(path,
+                                                           monkeypatch):
+    """Under bfloat16 each of the executor's three programs hands
+    ``Embedding`` its table in float32, and gives bitwise the outputs and
+    gradients it gives where the table is cast in advance (``Embedding``
+    declaring nothing): a cast commutes with a gather, and the rows'
+    cotangent is summed in bfloat16 either way."""
+    op = get_op("Embedding")
+    impl, seen = op.impl, []
+
+    def spy(attrs, inputs, aux, ctx):
+        if ctx.compute_dtype is not None:  # the executor's, not inference's
+            seen.append(jnp.dtype(inputs[1].dtype))
+        return impl(attrs, inputs, aux, ctx)
+
+    monkeypatch.setattr(op, "impl", spy)
+
+    def run():
+        del seen[:]
+        exe, params, feed = _bound_toy_lm()
+        if path == "fused_step":
+            step = exe.make_train_step(lambda p, g, s: (g, s))
+            outs, grads, _ = step(params, {}, feed)
+        else:
+            for n, a in {**params, **feed}.items():
+                exe.arg_dict[n]._data = a
+            if path == "forward":
+                outs, grads = exe.forward(is_train=True), {}
+            else:
+                outs = exe.forward_backward()
+                grads = {n: exe.grad_dict[n]._data for n in params}
+        return (set(seen), [np.asarray(getattr(o, "_data", o)) for o in outs],
+                {n: np.asarray(g) for n, g in grads.items()})
+
+    kept, outs, grads = run()
+    monkeypatch.setattr(op, "master_inputs", ())
+    cast, want_outs, want_grads = run()
+    assert kept == {jnp.dtype("float32")} and cast == {jnp.dtype("bfloat16")}
+    assert len(outs) == len(want_outs) == 1
+    np.testing.assert_array_equal(outs[0], want_outs[0])
+    assert sorted(grads) == sorted(want_grads)
+    assert bool(grads) == (path != "forward")
+    for n in grads:
+        assert grads[n].dtype == np.float32, n
+        np.testing.assert_array_equal(grads[n], want_grads[n], err_msg=n)
+
+
+def test_an_op_that_declares_a_master_input_needs_no_executor_edit(
+        monkeypatch):
+    """An op registered here declares its ``scale`` argument and its
+    ``shift`` state: under bfloat16 the executor hands it both in float32
+    and the data in bfloat16, with the compute dtype in its context. Once
+    another op reads ``scale`` too, the executor casts it."""
+    from mxnet_tpu.ops.registry import OP_REGISTRY, OpDef
+
+    seen = {}
+
+    def impl(attrs, inputs, aux, ctx):
+        (data, scale), (shift,) = inputs, aux
+        seen.update(data=data.dtype, scale=scale.dtype, shift=shift.dtype,
+                    compute=ctx.compute_dtype)
+        out = data * scale.astype(data.dtype) + shift.astype(data.dtype)
+        return (out,), (shift + 1,)
+
+    op = OpDef("_ScaleShiftForTest", impl, arg_names=("data", "scale"),
+               aux_names=("shift",), master_inputs=("scale", "shift"))
+    monkeypatch.setitem(OP_REGISTRY, op.name, op)
+    scale_shift = sym._make_sym_function(op)
+    scale = sym.Variable("scale")
+    alone = sym.sum(scale_shift(sym.Variable("data"), scale, name="ss"))
+    shared = alone + sym.sum(scale)
+    assert master_leaves(alone) == {"scale", "ss_shift"}
+    assert master_leaves(shared) == {"ss_shift"}
+    for net, scale_dtype in ((alone, jnp.float32), (shared, jnp.bfloat16)):
+        seen.clear()
+        exe = net.bind(mx.cpu(), args={"data": mx.nd.ones((2, 3)),
+                                       "scale": mx.nd.ones((3,))},
+                       aux_states={"ss_shift": mx.nd.zeros((3,))},
+                       compute_dtype="bfloat16")
+        (out,) = exe.forward(is_train=True)
+        assert seen == {"data": jnp.bfloat16, "scale": scale_dtype,
+                        "shift": jnp.float32, "compute": jnp.bfloat16}
+        assert out.dtype == np.float32 and float(out.asnumpy()) == (
+            6.0 if net is alone else 9.0)
+        np.testing.assert_array_equal(exe.aux_dict["ss_shift"].asnumpy(),
+                                      np.ones(3, np.float32))
 
 
 # --- (d) the counter ---------------------------------------------------------------
@@ -343,20 +448,21 @@ def always_compact(monkeypatch):
 
 
 def _embed(weight, ids, rows_as=None):
-    """The operator itself, under ``gathered_rows_as`` where asked."""
+    """The operator itself, in a context that computes in ``rows_as``
+    where asked."""
     op = get_op("Embedding")
     attrs = op.parse_attrs({"input_dim": weight.shape[0],
                             "output_dim": weight.shape[1]})
-    with matrix.gathered_rows_as(rows_as):
-        (out,), _ = op.impl(attrs, (ids, weight), (), OpContext())
+    (out,), _ = op.impl(attrs, (ids, weight), (),
+                        OpContext(compute_dtype=rows_as))
     return out
 
 
 def _table_grads(ids, dtype, branch):
     """(the operator's table gradient, ``jnp.take``'s own in ``dtype``, and
     in float32 from the same rounded cotangent): ``branch`` ``"master"`` is
-    a float32 table under ``gathered_rows_as(dtype)``, ``"cast"`` a table
-    that reaches the operator in ``dtype``."""
+    a float32 table in a context that computes in ``dtype``, ``"cast"`` a
+    table that reaches the operator in ``dtype``."""
     rng = np.random.RandomState(3)
     ids = jnp.asarray(ids, jnp.int32)
     w = jnp.asarray(rng.normal(0, 1, (_VOCAB, _DM)), jnp.float32)
