@@ -59,8 +59,11 @@ LAYER_KINDS = {
     'mixer': 'attention',  # 'attention' | 'short_conv': ShortConv, which
                            # has no position encoding and no heads |
                            # 'latent': attention whose q, k and v come from
-                           # low-rank latents (MLA: _latent_qkv)
-    'conv_kernel': 3,     # taps a channel of a 'short_conv' mixer
+                           # low-rank latents (MLA: _latent_qkv) | 'gdn':
+                           # the gated delta rule (_gated_delta)
+    'conv_kernel': 3,     # taps a channel of a 'short_conv' or 'gdn' mixer
+    'gdn_heads': (0, 0),  # a 'gdn' mixer's (key heads, value heads)
+    'gdn_dims': (0, 0),   # a 'gdn' mixer's (key size, value size) a head
     'q_rank': 0,          # a 'latent' mixer's query latent width
     'kv_rank': 0,         # a 'latent' mixer's key-value latent width
     'window': 0,          # keys a query sees, its own included; 0: all
@@ -70,6 +73,8 @@ LAYER_KINDS = {
                           # k head (a 'latent' mixer's shared rotary key);
                           # 0: over the whole head
     'qk_norm': False,     # RMSNorm of every q and k head before the rotation
+    'attn_gate': False,   # attention's output times sigmoid of a gate
+                          # projected from the mixer's input, per element
     'ffn': 'gelu',        # 'gelu': biased dense GELU | 'swiglu': dense
                           # gated, ffn2(silu(ffn1 x) * ffn3 x), no bias |
                           # 'experts': ExpertFFN
@@ -77,6 +82,8 @@ LAYER_KINDS = {
     'shared_dim': 0,      # beside 'experts': a shared expert, a dense
                           # SwiGLU of this width on the experts' input,
                           # added unweighted; 0: none
+    'shared_gate': False,  # the shared expert scaled by sigmoid(h . w_sg),
+                           # a scalar a token from the experts' input
     'router_input': 'mixer',  # what an 'experts' router reads: the
                               # 'mixer' input's norm | the 'ffn' input's
     'post_norm': False,   # True: the mixer's and the feed-forward's outputs
@@ -164,6 +171,11 @@ def _attention(h, num_heads, dm, names, num_kv_heads, use_flash, head_dim,
         if eps is not None:
             att_kw['qk_norm_eps'] = eps
         att_kw.update(names.given('_attn', 'q_norm_gamma', 'k_norm_gamma'))
+    if kind['attn_gate']:
+        att_kw['output_gate'] = True
+        att_kw['gate'] = sym.FullyConnected(
+            data=h, num_hidden=dq, flatten=False, no_bias=True,
+            name=name + '_q_gate', **names.given('_q_gate', 'weight'))
     att = sym.MultiHeadAttention(query=q, key=k, value=v, num_heads=num_heads,
                                  num_kv_heads=num_kv_heads, causal=True,
                                  use_rope=bool(kind['rope']),
@@ -171,6 +183,42 @@ def _attention(h, num_heads, dm, names, num_kv_heads, use_flash, head_dim,
     return sym.FullyConnected(data=att, num_hidden=dm, flatten=False,
                               no_bias=True, name=name + '_o',
                               **names.given('_o', 'weight'))
+
+
+def _gated_delta(h, dm, names, kind, eps):
+    """A 'gdn' mixer over the normed input ``h`` (Qwen3-Next's Gated
+    DeltaNet): projections to q | k | v, to z and to b | a, the
+    ``GatedDeltaRule`` core over q | k | v and b | a (its convolution, norms,
+    gates and the rule), an RMSNorm over each value head of the core's
+    output, times silu(z), and the out projection. No bias. The nodes are
+    named ``<node>_gdn_<part>``."""
+    (hk, hv), (dk, dv) = kind['gdn_heads'], kind['gdn_dims']
+    if not (hk and hv and dk and dv) or hv % hk:
+        raise ValueError("a gdn mixer takes gdn_heads (key, value), the "
+                         "value heads a multiple of the key heads, and "
+                         "gdn_dims (got %r, %r)"
+                         % (kind['gdn_heads'], kind['gdn_dims']))
+    part = names.node + '_gdn_'
+    qkv, wv = 2 * hk * dk + hv * dv, hv * dv
+
+    def project(x, width, what):
+        return sym.FullyConnected(data=x, num_hidden=width, flatten=False,
+                                  no_bias=True, name=part + what,
+                                  **names.given('_gdn_' + what, 'weight'))
+
+    core = sym.GatedDeltaRule(
+        data=project(h, qkv, 'qkv'), ba=project(h, 2 * hv, 'ba'),
+        num_key_heads=hk, num_value_heads=hv,
+        key_dim=dk, value_dim=dv, conv_kernel=kind['conv_kernel'],
+        name=part + 'core',
+        **names.given('_gdn_core', 'conv_weight', 'A_log', 'dt_bias'))
+    heads = sym.Reshape(core, shape=(0, 0, hv, dv), name=part + 'heads')
+    normed = sym.Reshape(_norm(heads, 'rms', dv, names, '_gdn_norm', eps),
+                         shape=(0, 0, wv), name=part + 'flat')
+    gated = sym.broadcast_mul(
+        normed, sym.Activation(data=project(h, wv, 'z'), act_type='silu',
+                               name=part + 'silu'), name=part + 'gated')
+    return project(gated, dm, 'out')
 
 
 def _swiglu(h, dff, dm, names, part=''):
@@ -205,6 +253,8 @@ def _block(x, num_heads, dm, dff, names, num_kv_heads=0, use_flash=None,
     kind = dict(LAYER_KINDS, **kind)
     if kind['shared_dim'] and kind['ffn'] != 'experts':
         raise ValueError("shared_dim: a shared expert sits beside 'experts'")
+    if kind['shared_gate'] and not kind['shared_dim']:
+        raise ValueError("shared_gate: a gate on a shared expert (shared_dim)")
     dff = kind['ffn_dim'] or dff
     name = names.node
 
@@ -226,9 +276,11 @@ def _block(x, num_heads, dm, dff, names, num_kv_heads=0, use_flash=None,
     elif kind['mixer'] in ('attention', 'latent'):
         mixed = _attention(h, num_heads, dm, names, num_kv_heads, use_flash,
                            head_dim, kind, eps)
+    elif kind['mixer'] == 'gdn':
+        mixed = _gated_delta(h, dm, names, kind, eps)
     else:
-        raise ValueError("mixer %r: 'attention', 'latent' or 'short_conv'"
-                         % (kind['mixer'],))
+        raise ValueError("mixer %r: 'attention', 'latent', 'short_conv' or "
+                         "'gdn'" % (kind['mixer'],))
     x = add(x, mixed, '_post1')
     h = _norm(x, kind['norm'], dm, names, '_ln2', eps)
     if kind['ffn'] == 'experts':
@@ -246,7 +298,17 @@ def _block(x, num_heads, dm, dff, names, num_kv_heads=0, use_flash=None,
             down_weight=names.var('_down_weight', (held, dm, dff)),
             name=name + '_experts', **experts)[0]
         if kind['shared_dim']:
-            out = out + _swiglu(h, kind['shared_dim'], dm, names, '_shared')
+            shared = _swiglu(h, kind['shared_dim'], dm, names, '_shared')
+            if kind['shared_gate']:
+                gate = sym.FullyConnected(
+                    data=h, num_hidden=1, flatten=False, no_bias=True,
+                    name=name + '_shared_gate',
+                    **names.given('_shared_gate', 'weight'))
+                shared = sym.broadcast_mul(
+                    shared, sym.Activation(data=gate, act_type='sigmoid',
+                                           name=name + '_shared_sigmoid'),
+                    name=name + '_shared_gated')
+            out = out + shared
         return add(x, out, '_post2')
     if kind['ffn'] == 'swiglu':
         return add(x, _swiglu(h, dff, dm, names), '_post2')
@@ -348,14 +410,16 @@ def get_symbol(num_classes=32000, seq_len=512, num_layers=4, num_heads=8,
     The block's kinds, all defaulting to the dense block this builder
     always built: ``layers``, one dict a layer over ``LAYER_KINDS`` (norm,
     and with ``post_norm`` the sandwich; the mixer: attention with its
-    window, rope, rope_base, rope_dims and qk_norm, latent attention with
-    its ranks, or a gated short convolution; the feed-forward: biased GELU,
-    dense gated SwiGLU or experts with a shared expert or none, and its
-    width where a layer's differs), so that window + RoPE layers, global NoPE
-    layers and convolution layers sit in one model; ``head_dim`` where it
-    is not model_dim / num_heads; ``experts``, the ``ExpertFFN`` attributes
-    (num_experts, experts_held, first_expert, top_k, route, ...) of the
-    layers whose ffn is 'experts', with ``ffn_dim`` one expert's width;
+    window, rope, rope_base, rope_dims, qk_norm and an output gate, latent
+    attention with its ranks, a gated short convolution, or the gated delta
+    rule with its heads; the feed-forward: biased GELU, dense gated SwiGLU
+    or experts with a shared expert, gated or not, or none, and its width
+    where a layer's differs), so that window + RoPE layers, global NoPE
+    layers, convolution and delta-rule layers sit in one model;
+    ``head_dim`` where it is not model_dim / num_heads; ``experts``, the
+    ``ExpertFFN`` attributes (num_experts, experts_held, first_expert,
+    top_k, route, ...) of the layers whose ffn is 'experts', with
+    ``ffn_dim`` one expert's width;
     ``final_norm``; ``norm_eps`` for every norm of the model (None: each
     op's default); ``head_bias`` False for a bias-free head, ``tie_head``
     for one that multiplies by the embedding table.

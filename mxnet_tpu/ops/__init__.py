@@ -21,5 +21,6 @@ from . import rnn_fused  # noqa: F401
 from . import attention  # noqa: F401
 from . import moe  # noqa: F401
 from . import shortconv  # noqa: F401
+from . import delta_rule  # noqa: F401
 from . import contrib  # noqa: F401
 from . import custom  # noqa: F401

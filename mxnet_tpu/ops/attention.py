@@ -106,9 +106,12 @@ def dot_product_attention(q, k, v, causal=False, scale=None, mask=None,
 
 
 def _mha_arg_names(attrs):
+    names = ("query", "key", "value")
     if attrs.get("qk_norm"):
-        return ("query", "key", "value", "q_norm_gamma", "k_norm_gamma")
-    return ("query", "key", "value")
+        names += ("q_norm_gamma", "k_norm_gamma")
+    if attrs.get("output_gate"):
+        names += ("gate",)
+    return names
 
 
 @defop(
@@ -117,10 +120,9 @@ def _mha_arg_names(attrs):
     param_spec={"num_heads": 1, "num_kv_heads": 0, "causal": False,
                 "use_rope": False, "use_flash": True, "window": 0,
                 "rope_base": 10000.0, "rope_dims": 0, "qk_norm": False,
-                "qk_norm_eps": 1e-6},
+                "qk_norm_eps": 1e-6, "output_gate": False},
 )
-def _multi_head_attention(attrs, query, key, value, q_norm_gamma=None,
-                          k_norm_gamma=None):
+def _multi_head_attention(attrs, query, key, value, *extra):
     """Fused multi-head attention on (B, T, H*D) projected inputs.
 
     Splits heads, optionally applies RoPE, runs (flash) attention, and
@@ -148,7 +150,16 @@ def _multi_head_attention(attrs, query, key, value, q_norm_gamma=None,
     (head size,): every query head and every key head is RMS-normalised
     over its own head size (``qk_norm_eps``) and scaled, one scale shared
     by the heads, BEFORE the rotation.
+
+    ``output_gate`` adds an input ``gate`` (B, Tq, H*D), after the norms'
+    scales where both are set: the merged heads' output is multiplied by
+    ``sigmoid(gate)`` element by element (Qwen3-Next's gated attention),
+    in float32.
     """
+    extra = list(extra)
+    q_norm_gamma, k_norm_gamma = (extra.pop(0), extra.pop(0)) \
+        if attrs["qk_norm"] else (None, None)
+    gate = extra.pop(0) if attrs["output_gate"] else None
     h = int(attrs["num_heads"])
     hkv = int(attrs["num_kv_heads"]) or h
     if h % hkv:
@@ -177,7 +188,7 @@ def _multi_head_attention(attrs, query, key, value, q_norm_gamma=None,
     note_built({"op": "MultiHeadAttention", "head_dim": d,
                 "rope_dims": (rope_dims or d) if attrs["use_rope"] else 0,
                 "window": None, "kernel": False, "backward": None,
-                "q_super": None})
+                "q_super": None, "output_gate": gate is not None})
     if attrs["qk_norm"]:
         q = _head_norm(q, q_norm_gamma, attrs["qk_norm_eps"])
         k = _head_norm(k, k_norm_gamma, attrs["qk_norm_eps"])
@@ -196,7 +207,11 @@ def _multi_head_attention(attrs, query, key, value, q_norm_gamma=None,
         out = _grouped_attention(q, k, v, hkv, causal, window=window)
     else:
         out = dot_product_attention(q, k, v, causal=causal, window=window)
-    return out.transpose(0, 2, 1, 3).reshape(b, tq, dm)
+    out = out.transpose(0, 2, 1, 3).reshape(b, tq, dm)
+    if gate is None:
+        return out
+    return (out.astype(jnp.float32)
+            * jax.nn.sigmoid(gate.astype(jnp.float32))).astype(out.dtype)
 
 
 def _head_norm(x, gamma, eps):
@@ -208,11 +223,17 @@ def _head_norm(x, gamma, eps):
 
 
 def _mha_infer(attrs, shapes):
-    """The two head-norm scales: (head size,), from the query's width."""
-    if len(shapes) > 3 and shapes[0] is not None:
-        d = shapes[0][-1] // int(attrs["num_heads"])
-        shapes[3] = shapes[3] or (d,)
-        shapes[4] = shapes[4] or (d,)
+    """The two head-norm scales: (head size,), from the query's width; the
+    output gate: the query's shape."""
+    if shapes[0] is None:
+        return shapes
+    names = _mha_arg_names(attrs)
+    d = shapes[0][-1] // int(attrs["num_heads"])
+    for i, name in enumerate(names):
+        if name.endswith("_norm_gamma"):
+            shapes[i] = shapes[i] or (d,)
+        elif name == "gate":
+            shapes[i] = shapes[i] or tuple(shapes[0])
     return shapes
 
 

@@ -110,10 +110,12 @@ BLOCK_K = 512
 # bookkeeping vs XLA's fused einsum). Gate re-placed accordingly
 # (was 1024 when the 256-wide tiles made S=512 a 0.91x loss).
 MIN_SEQ = 512
-# Longest sequence whose K/V (one side) stays whole in VMEM: 8192 * 128
-# lanes * 2B = 2 MiB per buffer, measured to fit alongside everything
-# else; 16384 exceeds the 16 MiB scoped-VMEM limit (the compile error
-# that motivated the streaming regime).
+# Longest sequence whose K/V (one side) stays whole in VMEM at a head of up
+# to 128 lanes: 8192 * 128 lanes * 2B = 2 MiB per buffer, measured to fit
+# alongside everything else; 16384 exceeds the 16 MiB scoped-VMEM limit
+# (the compile error that motivated the streaming regime). A wider head
+# stays whole at proportionally fewer rows (`_superblocks`): 4096 at 256,
+# where 8192 x 256 asks 17.5 MiB of the forward.
 _RESIDENT_MAX = 8192
 # Streaming superblock target size (keys or queries per grid step).
 SUPER_TARGET = 4096
@@ -150,9 +152,13 @@ def _split_super(t, block, target=None):
     return t // nsup, nsup
 
 
-def _superblocks(t, block):
-    """(super, n_super) of the walked side: whole while it fits VMEM."""
-    return (t, 1) if t <= _RESIDENT_MAX else _split_super(t, block)
+def _superblocks(t, block, d):
+    """(super, n_super) of the walked side at head size ``d``: whole while
+    its rows of max(d, 128) lanes fit what `_RESIDENT_MAX` rows of 128
+    take."""
+    if t * max(d, _LANES) <= _RESIDENT_MAX * _LANES:
+        return t, 1
+    return _split_super(t, block)
 
 
 # --- what the four tile bodies share ------------------------------------------
@@ -356,7 +362,7 @@ def _fa_forward(q, k, v, causal, scale, interpret, with_lse=False,
     tk = k.shape[1]
     block_q = _pick_block(tq, BLOCK_Q)
     block_k = _pick_block(tk, BLOCK_K)
-    super_k, num_super = _superblocks(tk, block_k)
+    super_k, num_super = _superblocks(tk, block_k, d)
     q_spec = pl.BlockSpec((1, 1, block_q, d),
                           lambda b, gi, i, ski: (b, gi, i, 0))
     # k/v block index ignores (gi, i): Pallas re-fetches only on index
@@ -737,7 +743,7 @@ def _fa_backward_split(args, causal, scale, interpret, window=0):
 
     block_q = _pick_block(tq, BLOCK_Q)
     block_k = _pick_block(tk, BLOCK_K)
-    super_k, num_super = _superblocks(tk, block_k)
+    super_k, num_super = _superblocks(tk, block_k, d)
     q_spec = pl.BlockSpec((1, 1, block_q, d),
                           lambda b, gi, i, ski: (b, gi, i, 0))
     qrow_spec = pl.BlockSpec((1, 1, 1, block_q),
@@ -761,7 +767,7 @@ def _fa_backward_split(args, causal, scale, interpret, window=0):
         **_compiler_params(interpret),
     )(*args)
 
-    super_q, num_super = _superblocks(tq, block_q)
+    super_q, num_super = _superblocks(tq, block_q, d)
 
     # causal: q superblocks strictly above this k block's diagonal are
     # fully masked, as are those past a band; clamp their index so the

@@ -12,8 +12,10 @@ and attention layers, a dense SwiGLU layer, the sigmoid-and-bias route, the
 tied head); and for the toy Ouro cell (layers run several times with one
 set of weights, the sandwich norms, the exits' objective); and for the
 toy GLM cell (latent attention, a shared expert, the multi-token-prediction
-module); and the ``step.ms.*`` metrics on a hand-made record and trace for
-each toy cell's graph (``test_program_groups``).
+module); and for the toy Qwen3-Next cell (Gated DeltaNet layers, gated
+attention, a gated shared expert); and the ``step.ms.*`` metrics on a
+hand-made record and trace for each toy cell's graph
+(``test_program_groups``).
 """
 import importlib.util
 import os
@@ -36,7 +38,8 @@ def _load(name):
 
 for _name in ("test_benchmark", "test_span_readers",
               "test_smallthinker_cell", "test_lfm2_cell", "test_ouro_cell",
-              "test_glm_cell", "test_program_groups"):
+              "test_glm_cell", "test_qwen3next_cell",
+              "test_program_groups"):
     # tests, fixtures and the helpers they name
     globals().update({k: v for k, v in vars(_load(_name)).items()
                       if not k.startswith("_")})

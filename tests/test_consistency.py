@@ -329,7 +329,9 @@ def test_pallas_kernel_coverage_is_complete():
     tested = {"flash_attention", "lstm_step", "gmm", "tgmm",
               "grouped_matmul",
               # tests/test_lfm2.py: interpreted against the XLA formulation
-              "gated_conv"}
+              "gated_conv",
+              # tests/test_qwen3_next.py: interpreted against the plain path
+              "gated_delta"}
     helpers = {"on_tpu", "use_for", "kernel_qualifies", "fits",
                # selection predicates and what they count, not kernels
                "gmm_vmem_bytes", "tgmm_vmem_bytes", "group_visits",

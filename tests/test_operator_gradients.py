@@ -372,6 +372,16 @@ _case("ShortConv:", lambda: (
     {"data": _u((2, 5, 4)), "in_weight": _u((12, 4)),
      "conv_weight": _u((4, 3)), "out_weight": _u((4, 4))},
     {"numeric_eps": 1e-2, "rtol": 0.12, "atol": 3e-2}))
+_case("GatedDeltaRule:", lambda: (
+    # one key head read by two value heads of 2; decays of e^-0.3 to e^-1
+    # a token, so that the state carries over the five tokens
+    sym.GatedDeltaRule(V("data"), V("ba"), V("conv_weight"), V("A_log"),
+                       V("dt_bias"), num_key_heads=1, num_value_heads=2,
+                       key_dim=2, value_dim=2),
+    {"data": _u((1, 5, 8)), "ba": _u((1, 5, 4)), "conv_weight": _u((8, 4)),
+     "A_log": np.log(np.array([0.3, 0.7], np.float32)),
+     "dt_bias": np.ones((2,), np.float32)},
+    {"numeric_eps": 1e-2, "rtol": 0.12, "atol": 3e-2}))
 for _sop in ("SequenceMask", "SequenceReverse", "SequenceLast"):
     _case("%s:lens" % _sop,
           lambda n=_sop: (getattr(sym, n)(V("data"), V("sl"),

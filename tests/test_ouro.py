@@ -208,9 +208,9 @@ def test_post_norm_alone_is_the_sandwich():
 # graph hashes of the three cells' toy configurations (the families'
 # symbols under a fresh NameManager), taken at the parent of ISSUE 39: a loop
 # of one pass and no post_norm build exactly the graph the builder built.
-# Since ``MultiHeadAttention`` has ``rope_dims`` every attention node also
-# serializes that attribute's default, which the hash leaves out: nothing
-# else may differ
+# Since ``MultiHeadAttention`` has ``rope_dims`` and ``output_gate`` every
+# attention node also serializes those attributes' defaults, which the hash
+# leaves out: nothing else may differ
 TODAYS = {"toy_lm": ("transformer_lm", "db00ad483be9ac8d", 31),
           "toy_smallthinker": ("smallthinker_lm", "581884492ef5d781", 45),
           "toy_lfm2": ("lfm2_moe_lm", "3834b4a502980c58", 51)}
@@ -225,7 +225,9 @@ def test_one_pass_builds_todays_graph(toy_name):
     graph = json.loads(sym.tojson())
     attention = [n["attrs"] for n in graph["nodes"]
                  if n["op"] == "MultiHeadAttention"]
-    assert attention and all(a.pop("rope_dims") == "0" for a in attention)
+    assert attention and all(a.pop("rope_dims") == "0"
+                             and a.pop("output_gate") == "False"
+                             for a in attention)
     text = json.dumps(graph, indent=2)
     assert hashlib.sha256(text.encode()).hexdigest()[:16] == digest
     assert len(sym.list_arguments()) == n_args

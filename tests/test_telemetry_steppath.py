@@ -482,7 +482,7 @@ def test_record_through_the_program_cache(monkeypatch):
     assert rec["layers"] == [
         {"op": "MultiHeadAttention", "node": "layer0_attn", "head_dim": 4,
          "rope_dims": 4, "window": None, "kernel": False, "backward": None,
-         "q_super": None},
+         "q_super": None, "output_gate": False},
         # the backward's choice lands in the record too (16 ids, 50 rows)
         {"op": "Embedding", "node": "embed", "rows": 50, "ids": 16,
          "backward": "direct"}]

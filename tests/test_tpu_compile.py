@@ -72,9 +72,12 @@ _FLASH_HEAD64 = {
 
 
 # the same at head size 256 (glm_flash_train_4k: 20 heads, one a KV head;
-# the fused backward at query superblocks of 512 rows at 4096)
+# the fused backward at query superblocks of 512 rows at 4096;
+# qwen3next_train_8k: 16 heads over 2 KV heads at 8192, K/V streamed in
+# superblocks of 4096 rows, the dq and dkv kernels)
 _FLASH_HEAD256 = {
     "glm_4k": (20, 1, 4096, 4096, True, "bfloat16", 1),
+    "qwen3next_8k": (2, 8, 8192, 8192, True, "bfloat16", 2),
 }
 
 
@@ -326,6 +329,37 @@ def test_short_conv_kernels_compile_for_v5e(batch, one_chip):
         assert text.count('custom_call_target="tpu_custom_call"') == 1
         assert "%" + name in text
         assert '"scoped_memory_configs":[{' not in text
+
+
+def test_gated_delta_kernels_compile_for_v5e(one_chip):
+    """``qwen3next_train_8k``'s delta rule: (1, 8192, 16 x 128) q and k,
+    (1, 8192, 32 x 128) v in bfloat16, the forward, the backward's state
+    walk and its reverse walk, one Mosaic call each, named for what it is,
+    in the VMEM a kernel gets unasked; the backward sums a key head's dq
+    and dk itself (no reduce after it)."""
+    from mxnet_tpu.ops.pallas import gated_delta as kernels
+
+    def sds(shape, dt="bfloat16"):
+        return jax.ShapeDtypeStruct(shape, jnp.dtype(dt), sharding=one_chip)
+
+    t, hk, hv, d = 8192, 16, 32, 128
+    assert kernels.fits(t, d, d) and kernels._heads(hv, hv // hk) == 4
+    q, v, g = sds((1, t, hk * d)), sds((1, t, hv * d)), sds((1, t, hv),
+                                                            "float32")
+    for name, f, args in (
+            ("gated_delta_fwd", lambda q, k, v, g, b: kernels._walk(
+                q, k, v, g, b, hk, hv, False, False), (q, q, v, g, g)),
+            ("gated_delta_states", lambda q, k, v, g, b: kernels._walk(
+                q, k, v, g, b, hk, hv, False, True), (q, q, v, g, g)),
+            ("gated_delta_bwd", lambda q, k, v, g, b, do: kernels._backward(
+                q, k, v, g, b, do, hk, hv, False), (q, q, v, g, g, v))):
+        text = jax.jit(f).lower(*args).compile().as_text()
+        calls = 2 if name == "gated_delta_bwd" else 1
+        assert text.count('custom_call_target="tpu_custom_call"') == calls
+        assert "%" + name in text
+        assert '"scoped_memory_configs":[{' not in text
+        if name == "gated_delta_bwd":
+            assert not re.search(r"= bf16\[1,8192,16,2,128\]", text)
 
 
 @pytest.fixture(scope="module")
