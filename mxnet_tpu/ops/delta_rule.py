@@ -298,9 +298,13 @@ def _gated_delta_op(attrs, data, ba, conv_weight, A_log, dt_bias):
             % (hk, dk, hv, dv, width, 2 * hv, width, taps, hv, data.shape,
                ba.shape, conv_weight.shape, A_log.shape, dt_bias.shape))
     path = delta_path(data.shape[1], dk, dv)
+    # where the backward's states come from: the kernels' forward keeps
+    # them; the plain path's scan leaves them to autodiff
     note_built({"op": "GatedDeltaRule", "path": path, "chunk": CHUNK,
                 "key_heads": hk, "value_heads": hv,
-                "state_dtype": "float32"})
+                "state_dtype": "float32",
+                "backward_states": "forward" if path == "kernel"
+                else "autodiff"})
     # stored in the projection's dtype: what the backward keeps of it
     dt = data.dtype
     x = jax.nn.silu(_taps(data, conv_weight, 1)).astype(dt)

@@ -15,18 +15,19 @@ cell takes its row. The mathematics of a chunk is
 ``delta_rule.chunk_forward`` and ``chunk_backward``, the same functions the
 plain path runs:
 
-- ``gated_delta_fwd``: reads q, k, v, g, beta once, writes o;
-- ``gated_delta_states``: the backward's first pass, the same walk without
-  the output, writes the state before every chunk (B x value heads x
-  chunks x dk x dv float32, the step's temporary for one layer);
-- ``gated_delta_bwd``: walks the chunks in REVERSE, the gradient of the
-  state after the chunk in VMEM, and writes the gradients of q and k a key
-  head (the value heads that share it summed in the cell; a value head
-  each, summed after, where a cell holds one), of v, of g's cumulative
-  sums and of beta.
+- ``gated_delta_fwd``: reads q, k, v, g, beta once, writes o; where it is
+  differentiated, also the state before every chunk (B x value heads x
+  chunks x dk x dv float32, held from the layer's forward to its
+  backward);
+- ``gated_delta_bwd``: walks the chunks in REVERSE, reading those states,
+  the gradient of the state after the chunk in VMEM, and writes the
+  gradients of q and k a key head (the value heads that share it summed
+  in the cell; a value head each, summed after, where a cell holds one),
+  of v, of g's cumulative sums and of beta.
 
-Joined by a ``custom_vjp``: the forward keeps only its inputs, and the
-backward rebuilds each chunk's state rather than the forward storing them.
+Joined by a ``custom_vjp``: the forward keeps its inputs and the states it
+walked, so the backward walks the chunks once, in reverse. A call without
+a gradient (inference, evaluation) writes o alone.
 """
 from __future__ import annotations
 
@@ -45,8 +46,9 @@ _F32 = jnp.float32
 
 # value heads a grid cell walks side by side: their chains of small
 # products interleave (the compiler's bundles a head and chunk for a
-# described v5e at 4 heads: 1526 forward, 1470 state walk, 2337 backward,
-# where one head alone takes 2014, 2111 and 2965; 8 heads save 5% more)
+# described v5e at 4 heads: 1542 forward with the states, 1534 without,
+# 2337 backward, where one head alone takes 2071, 2014 and 2965; 8 heads
+# save 5% more)
 HEADS = 4
 
 
@@ -114,9 +116,11 @@ def _key_cols(j, grp, heads, dk):
     return slice(0, dk)
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, out_ref, s_ref, *,
-                states, grp):
+def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, o_ref, *refs, grp):
+    """A chunk of the cell's heads: o, and where ``refs`` holds an output
+    before the state's scratch, the state before the chunk."""
     i = pl.program_id(1)
+    s_ref = refs[-1]
     heads, dk, dv = s_ref.shape
 
     @pl.when(i == 0)
@@ -126,14 +130,12 @@ def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, out_ref, s_ref, *,
     for j in range(heads):   # independent heads: their chains interleave
         kc, vc = _key_cols(j, grp, heads, dk), slice(j * dv, (j + 1) * dv)
         s = s_ref[j]
-        if states:
-            out_ref[j, 0] = s
+        if len(refs) == 2:
+            refs[0][j, 0] = s
         o, s_ref[j] = chunk_forward(
             q_ref[0, :, kc], k_ref[0, :, kc], v_ref[0, :, vc],
-            g_ref[j, pl.ds(i, 1), :], b_ref[j, pl.ds(i, 1), :], s,
-            want_o=not states)
-        if not states:
-            out_ref[0, :, vc] = o.astype(out_ref.dtype)
+            g_ref[j, pl.ds(i, 1), :], b_ref[j, pl.ds(i, 1), :], s)
+        o_ref[0, :, vc] = o.astype(o_ref.dtype)
 
 
 def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, st_ref, do_ref, dq_ref,
@@ -171,40 +173,40 @@ def _sizes(q, v, hk, hv):
 
 
 def _walk(q, k, v, gam, beta, hk, hv, interpret, states):
-    """The forward walk: o (B, T, hv dv), or with ``states`` the state
-    before every chunk (B hv, chunks, dk, dv) float32."""
+    """The forward walk: o (B, T, hv dv); with ``states`` (o, the state
+    before every chunk (B hv, chunks, dk, dv) float32)."""
     b, t, dk, dv, nc, grp = _sizes(q, v, hk, hv)
     heads = _heads(hv, grp)
     key, val, _, rows, state = _specs(hv, grp, nc, dk, dv, heads)
-    itemsize = q.dtype.itemsize
     flops = b * hv * t * 2 * (5 * CHUNK * dk + 3 * dk * dv + CHUNK * CHUNK)
+    out_specs = [val]
+    out_shape = [jax.ShapeDtypeStruct(v.shape, v.dtype)]
     if states:
-        out_spec = state
-        out_shape = jax.ShapeDtypeStruct((b * hv, nc, dk, dv), _F32)
-    else:
-        out_spec = val
-        out_shape = jax.ShapeDtypeStruct(v.shape, v.dtype)
-    return pl.pallas_call(
-        functools.partial(_fwd_kernel, states=states, grp=grp),
+        out_specs.append(state)
+        out_shape.append(jax.ShapeDtypeStruct((b * hv, nc, dk, dv), _F32))
+    out = pl.pallas_call(
+        functools.partial(_fwd_kernel, grp=grp),
         grid=(b * hv // heads, nc),
         in_specs=[key, key, val, rows, rows],
-        out_specs=out_spec,
+        out_specs=out_specs,
         out_shape=out_shape,
         scratch_shapes=[pltpu.VMEM((heads, dk, dv), _F32)],
         cost_estimate=pl.CostEstimate(
             flops=flops, transcendentals=b * hv * t * (CHUNK + 2),
             bytes_accessed=b * t * (grp * 2 * hk * dk + 2 * hv * dv)
-            * itemsize),
-        name="gated_delta_states" if states else "gated_delta_fwd",
+            * q.dtype.itemsize + (b * hv * nc * dk * dv * 4 if states
+                                  else 0)),
+        name="gated_delta_fwd",
         interpret=interpret,
         **_params(interpret),
     )(q, k, v, _rows(gam), _rows(beta))
+    return tuple(out) if states else out[0]
 
 
-def _backward(q, k, v, gam, beta, do, hk, hv, interpret):
+def _backward(q, k, v, gam, beta, st, do, hk, hv, interpret):
+    """The reverse walk over the states ``st`` the forward kept."""
     b, t, dk, dv, nc, grp = _sizes(q, v, hk, hv)
     heads = _heads(hv, grp)
-    st = _walk(q, k, v, gam, beta, hk, hv, interpret, True)
     key, val, per_value_key, rows, state = _specs(hv, grp, nc, dk, dv, heads,
                                                   reverse=True)
     # where a cell holds whole key heads it sums their value heads' dq and
@@ -255,14 +257,13 @@ def gated_delta(q, k, v, gam, beta, key_heads, value_heads, interpret=False):
 
 
 def _gated_delta_fwd(q, k, v, gam, beta, key_heads, value_heads, interpret):
-    o = _walk(q, k, v, gam, beta, key_heads, value_heads, interpret, False)
-    return o, (q, k, v, gam, beta)
+    o, st = _walk(q, k, v, gam, beta, key_heads, value_heads, interpret,
+                  True)
+    return o, (q, k, v, gam, beta, st)
 
 
 def _gated_delta_bwd(key_heads, value_heads, interpret, res, do):
-    q, k, v, gam, beta = res
-    return _backward(q, k, v, gam, beta, do, key_heads, value_heads,
-                     interpret)
+    return _backward(*res, do, key_heads, value_heads, interpret)
 
 
 gated_delta.defvjp(_gated_delta_fwd, _gated_delta_bwd)

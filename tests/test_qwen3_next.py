@@ -135,14 +135,14 @@ def test_chunked_rule_is_the_token_by_token_recurrence(fam, regime):
 @pytest.mark.parametrize("heads", [1, 4])
 @pytest.mark.parametrize("regime", sorted(_G_SCALE))
 def test_pallas_kernels_are_the_plain_path(regime, heads, monkeypatch):
-    """The kernels (forward; the state walk and the reverse walk of the
-    backward) in interpret mode against the plain path at three chunks of
-    two sequences, sixteen value heads over eight key heads, walked one
-    value head a grid cell or four (two whole key heads) side by side, so
-    that a sequence has four cells and a block index counted in the wrong
-    unit lands on another head's block (interpret mode clamps one past
-    the end back onto the right one): the
-    output and the five gradients, the value heads' dq and dk summed onto
+    """The kernels (the forward, which keeps the states, and the reverse
+    walk of the backward) in interpret mode against the plain path at
+    three chunks of two sequences, sixteen value heads over eight key
+    heads, walked one value head a grid cell or four (two whole key heads)
+    side by side, so that a sequence has four cells and a block index
+    counted in the wrong unit lands on another head's block (interpret
+    mode clamps one past the end back onto the right one): the output and
+    the five gradients, the value heads' dq and dk summed onto
     their key head (by the backward kernel itself where a cell holds whole
     key heads)."""
     from mxnet_tpu.ops.pallas import gated_delta
@@ -159,6 +159,129 @@ def test_pallas_kernels_are_the_plain_path(regime, heads, monkeypatch):
     with jax.default_matmul_precision("highest"):
         _close(kernel, lambda *a: delta_rule.gated_delta_rule(
             *a, 8, 16, "plain"), args, tol=2e-5)
+
+
+def _kernel_inputs(hk=8, hv=16, t=192):
+    """The rule's inputs as the kernels take them: two sequences of three
+    chunks, g's cumulative sums within each chunk."""
+    q, k, v, g, beta = _rule_inputs("g_near_0", b=2, t=t, hk=hk, hv=hv,
+                                    seed=1)
+    b, _, h = g.shape
+    gam = jnp.cumsum(g.reshape(b, t // 64, 64, h), 2).reshape(b, t, h)
+    return q, k, v, gam, beta
+
+
+@pytest.mark.parametrize("heads", [1, 4])
+def test_training_forward_keeps_the_state_before_every_chunk(heads,
+                                                             monkeypatch):
+    """The forward a gradient runs (the ``custom_vjp``'s forward rule)
+    writes the o of the call without a gradient and, bit for bit, the state
+    before every chunk that ``chunk_forward`` gives chunk by chunk: two
+    sequences, sixteen value heads over eight key heads, one value head a
+    grid cell or four."""
+    from mxnet_tpu.ops.pallas import gated_delta
+
+    monkeypatch.setattr(gated_delta, "HEADS", heads)
+    assert gated_delta._heads(16, 2) == heads
+    q, k, v, gam, beta = _kernel_inputs()
+    o, res = gated_delta._gated_delta_fwd(q, k, v, gam, beta, 8, 16, True)
+    st = res[-1]
+    assert st.shape == (2 * 16, 3, 16, 16) and st.dtype == jnp.float32
+    np.testing.assert_array_equal(
+        o, gated_delta.gated_delta(q, k, v, gam, beta, 8, 16, True))
+    chunk = jax.jit(delta_rule.chunk_forward)
+    for b in range(2):
+        for h in range(16):
+            s = jnp.zeros((16, 16), jnp.float32)
+            kc, vc = slice(h // 2 * 16, (h // 2 + 1) * 16), slice(
+                h * 16, (h + 1) * 16)
+            for c in range(3):
+                np.testing.assert_array_equal(st[b * 16 + h, c], s)
+                t = slice(c * 64, (c + 1) * 64)
+                _, s = chunk(q[b, t, kc], k[b, t, kc], v[b, t, vc],
+                             gam[b, None, t, h], beta[b, None, t, h], s)
+
+
+def _traced_kernels(monkeypatch):
+    """Each ``pallas_call`` traced from here on, as (name, outputs)."""
+    from mxnet_tpu.ops.pallas import gated_delta
+
+    calls, real = [], gated_delta.pl.pallas_call
+
+    def traced(*args, **kw):
+        calls.append((kw["name"], len(kw["out_shape"])))
+        return real(*args, **kw)
+
+    monkeypatch.setattr(gated_delta.pl, "pallas_call", traced)
+    return calls
+
+
+def test_a_gradient_runs_the_forward_and_the_reverse_walk_alone(
+        monkeypatch):
+    """Under ``jax.grad`` the rule runs two kernels: the forward, with o and
+    the states among its two outputs, and the reverse walk; no second
+    forward walk for the states."""
+    from mxnet_tpu.ops.pallas import gated_delta
+
+    calls = _traced_kernels(monkeypatch)
+    args = _kernel_inputs()
+    jax.grad(lambda *a: gated_delta.gated_delta(*a, 8, 16, True).sum(),
+             argnums=tuple(range(5)))(*args)
+    assert calls == [("gated_delta_fwd", 2), ("gated_delta_bwd", 5)]
+
+
+def test_without_a_gradient_the_forward_writes_no_states(monkeypatch):
+    """Inference and evaluation call the rule without a gradient: one
+    kernel, whose one output is o."""
+    from mxnet_tpu.ops.pallas import gated_delta
+
+    calls = _traced_kernels(monkeypatch)
+    args = _kernel_inputs()
+    o = jax.jit(lambda *a: gated_delta.gated_delta(*a, 8, 16, True))(*args)
+    assert o.shape == args[2].shape
+    assert calls == [("gated_delta_fwd", 1)]
+
+
+@pytest.mark.parametrize("path,states", [("kernel", "forward"),
+                                         ("plain", "autodiff")])
+def test_record_says_where_the_backward_reads_its_states(path, states,
+                                                         monkeypatch):
+    """``GatedDeltaRule``'s record says ``backward_states``: ``forward``
+    where the kernels run (their forward keeps the states), ``autodiff``
+    on the plain path; both paths give the op's output and gradients
+    alike (the kernels interpreted)."""
+    import functools
+
+    from mxnet_tpu.ops.pallas import gated_delta
+    from mxnet_tpu.ops.registry import built_layers
+
+    hk, hv, d = 2, 4, 16
+    op = get_op("GatedDeltaRule")
+    attrs = op.parse_attrs({"num_key_heads": hk, "num_value_heads": hv,
+                            "key_dim": d, "value_dim": d})
+    rng = np.random.RandomState(5)
+    width = 2 * hk * d + hv * d
+    args = (_rand(rng, 1, 128, width), _rand(rng, 1, 128, 2 * hv),
+            _rand(rng, width, 4, scale=0.5), _rand(rng, hv, scale=0.5),
+            _rand(rng, hv, scale=0.5))
+
+    def rule(*a):
+        return op.impl(attrs, a, (), None)[0][0]
+
+    with jax.default_matmul_precision("highest"):
+        want, vjp_want = jax.vjp(rule, *args)
+        monkeypatch.setattr(delta_rule, "delta_path", lambda *a: path)
+        monkeypatch.setattr(gated_delta, "gated_delta", functools.partial(
+            gated_delta.gated_delta, interpret=True))
+        with built_layers() as built:
+            out, vjp = jax.vjp(rule, *args)
+        w = _rand(rng, *out.shape)
+        grads, grads_want = vjp(w), vjp_want(w)
+    assert [(r["op"], r["path"], r["backward_states"])
+            for r in built.layers] == [("GatedDeltaRule", path, states)]
+    np.testing.assert_allclose(out, want, atol=2e-5, rtol=2e-5)
+    for a, b in zip(grads, grads_want):
+        np.testing.assert_allclose(a, b, atol=2e-5, rtol=2e-5)
 
 
 def test_the_kernels_take_whole_chunks_of_whole_lanes():

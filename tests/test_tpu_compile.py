@@ -333,10 +333,12 @@ def test_short_conv_kernels_compile_for_v5e(batch, one_chip):
 
 def test_gated_delta_kernels_compile_for_v5e(one_chip):
     """``qwen3next_train_8k``'s delta rule: (1, 8192, 16 x 128) q and k,
-    (1, 8192, 32 x 128) v in bfloat16, the forward, the backward's state
-    walk and its reverse walk, one Mosaic call each, named for what it is,
-    in the VMEM a kernel gets unasked; the backward sums a key head's dq
-    and dk itself (no reduce after it)."""
+    (1, 8192, 32 x 128) v in bfloat16, the forward without a gradient (o
+    alone), the forward a gradient runs (o and the state before every
+    chunk, float32, in one call) and the backward's reverse walk over those
+    states, one Mosaic call each, named for what it is, in the VMEM a
+    kernel gets unasked; the backward sums a key head's dq and dk itself
+    (no reduce after it)."""
     from mxnet_tpu.ops.pallas import gated_delta as kernels
 
     def sds(shape, dt="bfloat16"):
@@ -346,18 +348,23 @@ def test_gated_delta_kernels_compile_for_v5e(one_chip):
     assert kernels.fits(t, d, d) and kernels._heads(hv, hv // hk) == 4
     q, v, g = sds((1, t, hk * d)), sds((1, t, hv * d)), sds((1, t, hv),
                                                             "float32")
-    for name, f, args in (
+    st = sds((hv, t // 64, d, d), "float32")
+    for name, f, args, outs in (
             ("gated_delta_fwd", lambda q, k, v, g, b: kernels._walk(
-                q, k, v, g, b, hk, hv, False, False), (q, q, v, g, g)),
-            ("gated_delta_states", lambda q, k, v, g, b: kernels._walk(
-                q, k, v, g, b, hk, hv, False, True), (q, q, v, g, g)),
-            ("gated_delta_bwd", lambda q, k, v, g, b, do: kernels._backward(
-                q, k, v, g, b, do, hk, hv, False), (q, q, v, g, g, v))):
+                q, k, v, g, b, hk, hv, False, False), (q, q, v, g, g),
+             r"bf16\[1,8192,4096\]\{"),
+            ("gated_delta_fwd", lambda q, k, v, g, b: kernels._walk(
+                q, k, v, g, b, hk, hv, False, True), (q, q, v, g, g),
+             r"\(bf16\[1,8192,4096\]\{[^}]*\}, f32\[32,128,128,128\]"),
+            ("gated_delta_bwd", lambda q, k, v, g, b, st, do:
+             kernels._backward(q, k, v, g, b, st, do, hk, hv, False),
+             (q, q, v, g, g, st, v), None)):
         text = jax.jit(f).lower(*args).compile().as_text()
-        calls = 2 if name == "gated_delta_bwd" else 1
-        assert text.count('custom_call_target="tpu_custom_call"') == calls
+        assert text.count('custom_call_target="tpu_custom_call"') == 1
         assert "%" + name in text
         assert '"scoped_memory_configs":[{' not in text
+        if outs:   # the call's results: o, or o and the states
+            assert re.search(r"%%%s\.\d+ = %s" % (name, outs), text)
         if name == "gated_delta_bwd":
             assert not re.search(r"= bf16\[1,8192,16,2,128\]", text)
 
